@@ -10,17 +10,12 @@ import os as _os
 
 import jax as _jax
 
-# Sharding-invariant RNG (the modern JAX default).  On old JAX the default
-# (False) lowers jitted `jax.random.*` with sharded out_shardings to
-# per-shard streams, so the SAME seed yields DIFFERENT params on different
-# meshes — which silently breaks every dp/mp-vs-single-device parity
-# guarantee the parallel trainers advertise.  This is a process-global knob;
-# an explicit JAX_THREEFRY_PARTITIONABLE env setting wins (see README).
+# Sharding-invariant RNG: the same seed must draw the same params on every
+# mesh, or the dp/mp-vs-single-device parity the parallel trainers advertise
+# breaks.  Process-global; an explicit JAX_THREEFRY_PARTITIONABLE env
+# setting wins (see README).
 if "JAX_THREEFRY_PARTITIONABLE" not in _os.environ:
-    try:
-        _jax.config.update("jax_threefry_partitionable", True)
-    except Exception:  # flag removed once True became the only behavior
-        pass
+    _jax.config.update("jax_threefry_partitionable", True)
 
 # ---- core ----
 from .core import dtype as _dtype_mod

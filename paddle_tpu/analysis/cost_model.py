@@ -92,8 +92,9 @@ _KIND_MATCH = (("v6", "v6e"), ("v5p", "v5p"), ("v5e", "v5e"), ("v5", "v5e"),
 
 def device_spec(device=None) -> DeviceSpec:
     """Spec for `device` (default: jax.devices()[0]) by device_kind
-    substring; unknown accelerators fall back to the v5e row (the bench
-    fleet's chip), CPU hosts to the cpu row."""
+    substring; CPU hosts get the cpu row.  An accelerator that is not in the
+    table is an error: a roofline against another chip's peaks is a wrong
+    number under the right name."""
     import jax
 
     if device is None:
@@ -105,7 +106,9 @@ def device_spec(device=None) -> DeviceSpec:
             return DEVICE_SPECS[tag]
     if platform == "cpu":
         return DEVICE_SPECS["cpu"]
-    return DEVICE_SPECS["v5e"]
+    raise ValueError(
+        f"no DEVICE_SPECS row for platform {platform!r}, device_kind "
+        f"{kind!r}: add its peaks (with their source) to the table")
 
 
 # ---------------------------------------------------------------------------
@@ -170,7 +173,7 @@ def _sub_jaxprs(eqn) -> List[Tuple[object, int]]:
     once — the serving programs' only loop is the layer scan).  `cond`
     eqns execute exactly ONE branch, so the walk takes the max over this
     list instead of the sum for them."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     prim = eqn.primitive.name
     mult = int(eqn.params.get("length", 1)) if prim == "scan" else 1
@@ -200,7 +203,7 @@ def _jaxpr_walk(jaxpr, aliased_outs) -> Tuple[int, int, str]:
     — except `aliased_outs`, which write into a donated input buffer and
     allocate nothing.  Higher-order eqns recurse: their body's peak rides on
     top of the outer live set at that program point."""
-    from jax.core import Literal
+    from jax.extend.core import Literal
 
     eqns = list(jaxpr.eqns)
     last_use: Dict[object, int] = {}
@@ -472,14 +475,14 @@ def program_cost(name: str, fn, args, *, compile_collectives: bool = False
     the optimized module (skipped on the bench path, where an extra compile
     would perturb the program-count stats)."""
     import jax
-    from jax.core import Literal
+    from jax.extend.core import Literal
 
     closed = jax.make_jaxpr(fn)(*args)
     body = closed.jaxpr
     consts = closed.consts
     donated = ()
     for eqn in closed.jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             sub = eqn.params["jaxpr"]
             body, consts = sub.jaxpr, sub.consts
             donated = eqn.params.get("donated_invars", ())
@@ -641,20 +644,12 @@ def engine_at_rest(engine) -> AtRestAccount:
 # ---------------------------------------------------------------------------
 
 
-def engine_step_cost(engine, *, compile_collectives: Optional[bool] = None
-                     ) -> ProgramCost:
-    """Cost of the engine's decode-side program (fused `serve_step_paged`,
-    or the legacy decode under `fuse=False`) at the ENGINE's own shapes,
-    traced with abstract inputs carrying the engine's REAL shardings — no
-    dispatch, no transfer, and the program-count stats stay untouched
-    (the compile, when taken, goes through the jit wrapper's lower(),
-    outside the `_AotCache` dispatch cache).
-
-    `compile_collectives` defaults to `engine.mp > 1`: the mp program's
-    per-layer all-reduces only exist in the compiled module, and the
-    roofline's ICI term needs them — the same account `tools/tpu_cost.py`
-    prints, so the bench JSON and the CLI cannot disagree.  Single-chip
-    engines skip the compile (nothing to collect)."""
+def engine_step_target(engine):
+    """(jitted fn, abstract args) of the engine's decode-side program (fused
+    `serve_step_paged`, or the legacy decode under `fuse=False`) at the
+    ENGINE's own shapes, the inputs carrying the engine's REAL shardings —
+    what `fn.lower(*args)` needs, with no dispatch and no transfer, and
+    outside the `_AotCache` dispatch cache (program-count stats untouched)."""
     import jax
     import numpy as np
 
@@ -671,6 +666,7 @@ def engine_step_cost(engine, *, compile_collectives: Optional[bool] = None
         params = jax.tree_util.tree_map(sds, engine.params)
     pool = {k: sds(v, engine._pool_sharding)
             for k, v in engine._pool.items()}
+
     def host(shape, dtype=np.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
 
@@ -682,6 +678,20 @@ def engine_step_cost(engine, *, compile_collectives: Optional[bool] = None
     else:
         args = (params, host((B,)), pool, host((B, P)), host((B,)),
                 sds(engine._key, repl), host((B,), np.bool_))
+    return fn, args
+
+
+def engine_step_cost(engine, *, compile_collectives: Optional[bool] = None
+                     ) -> ProgramCost:
+    """Cost of the engine's decode-side program (`engine_step_target`),
+    traced abstractly — no dispatch, no transfer.
+
+    `compile_collectives` defaults to `engine.mp > 1`: the mp program's
+    per-layer all-reduces only exist in the compiled module, and the
+    roofline's ICI term needs them — the same account `tools/tpu_cost.py`
+    prints, so the bench JSON and the CLI cannot disagree.  Single-chip
+    engines skip the compile (nothing to collect)."""
+    fn, args = engine_step_target(engine)
     if compile_collectives is None:
         compile_collectives = engine.mp > 1
     return program_cost("serve.step", fn, args,

@@ -63,7 +63,7 @@ def _iter_eqns(jaxpr):
 
 
 def _as_jaxprs(value):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
     elif isinstance(value, Jaxpr):
@@ -113,7 +113,7 @@ def audit_jaxpr(name: str, fn, args, *, donate_paths: Sequence[str] = (),
     # the jitted callable traces to a single pjit eqn carrying the program
     pjit_eqn = None
     for eqn in closed.jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             pjit_eqn = eqn
             break
 

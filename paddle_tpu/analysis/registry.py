@@ -375,10 +375,6 @@ PROGRAM_SOURCES: Tuple[ProgramSource, ...] = (
              "executables, no standalone program)"),
     # ---- parallel trainers ------------------------------------------------
     ProgramSource(
-        "paddle_tpu/parallel/ring_attention.py", "shard_map_compat",
-        note="the repo-wide shard_map wrapper (new-API/old-API fallback); "
-             "call sites through it register at their own qualnames"),
-    ProgramSource(
         "paddle_tpu/parallel/ring_attention.py", "ring_attention",
         note="context-parallel ring attention body"),
     ProgramSource(
@@ -406,6 +402,10 @@ PROGRAM_SOURCES: Tuple[ProgramSource, ...] = (
         "paddle_tpu/parallel/hybrid.py", "HybridParallelTrainer.eval_loss",
         note="jitted eval loss, compiled once (test_eval_loss_jitted_once)"),
     # ---- kernels ----------------------------------------------------------
+    ProgramSource(
+        "paddle_tpu/incubate/kernels/flash_attention.py", "_per_shard",
+        note="flash fwd/bwd kernels per shard of a partitioned train step "
+             "(inside the step's custom_vjp halves, no standalone program)"),
     ProgramSource(
         "paddle_tpu/incubate/kernels/paged_attention.py",
         "paged_attention_decode_mp",

@@ -47,7 +47,7 @@ _SCALARIZE_FUNCS = frozenset({"float", "int", "bool", "complex"})
 _SPAN_CALL_RE = re.compile(r"(^|\.)(_span|RecordEvent)$")
 
 _JIT_FUNCS = frozenset({"jax.jit", "jit", "pjit", "jax.pjit", "_AotCache"})
-_SHARD_RE = re.compile(r"(^|\.)(shard_map|shard_map_compat)$")
+_SHARD_RE = re.compile(r"(^|\.)shard_map$")
 
 # parameter names treated as static/config (never traced data) in TPL004
 _STATIC_PARAM_NAMES = frozenset({"self", "cls", "cfg", "config", "mesh",

@@ -27,6 +27,23 @@ def _free_port():
     return port
 
 
+def _cpu_only_platform(env, nproc):
+    """JAX_PLATFORMS for the children of a multi-process local pod.
+
+    A chip belongs to one process, and nothing here binds a child to its
+    own chip (FLAGS_selected_tpus is only an id), so N > 1 trainers on one
+    host are the CPU simulation: they run only when the environment already
+    says cpu, and are then pinned to it from their first import."""
+    if env.get("PADDLE_DIST_DEVICE", "").lower() == "cpu" or \
+            env.get("JAX_PLATFORMS", "").lower() == "cpu":
+        return "cpu"
+    raise RuntimeError(
+        f"{nproc} local trainer processes would all claim this host's "
+        "accelerator; one process drives every local chip.  Start one "
+        "process, or set PADDLE_DIST_DEVICE=cpu for the multi-process CPU "
+        "simulation.")
+
+
 class Container:
     """One trainer process (reference `launch/job/container.py`)."""
 
@@ -79,6 +96,8 @@ class CollectiveController:
         for i in range(n):
             endpoints.append(f"{host}:{int(mport) + i}")
         base_env = dict(os.environ)
+        if n > 1:
+            base_env["JAX_PLATFORMS"] = _cpu_only_platform(base_env, n)
         for rank in range(n):
             env = dict(base_env)
             env.update({

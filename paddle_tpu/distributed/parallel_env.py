@@ -86,16 +86,12 @@ def init_parallel_env():
         if master:
             host, _, port = master.partition(":")
             coord = f"{host}:{int(port) + 7}"
-            try:
+            # a rank that cannot join raises: carrying on "in local mode"
+            # would train world_size disconnected copies under one job name
+            if not jax.distributed.is_initialized():
                 jax.distributed.initialize(coordinator_address=coord,
                                            num_processes=env.world_size,
                                            process_id=env.rank)
-            # tpu-lint: disable=TPL006 -- multi-process init is best-effort (already-initialized, single-host sim, no coordinator); degrades to local mode with a warning
-            except Exception as e:  # already initialized or single-host sim
-                if "already" not in str(e):
-                    import warnings
-                    warnings.warn(f"jax.distributed.initialize failed: {e}; "
-                                  "continuing in local mode")
     _initialized = True
     from .communication.group import _init_default_group
     _init_default_group(env)

@@ -18,6 +18,11 @@ def _wrap(func, rank, nprocs, master, args):
 def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
     if nprocs < 1:
         nprocs = int(os.getenv("PADDLE_TRAINERS_NUM", "1"))
+    if nprocs > 1:
+        # same rule as the launch CLI: N > 1 local processes are the CPU
+        # simulation; the children inherit the pin through the environment
+        from .launch.main import _cpu_only_platform
+        os.environ["JAX_PLATFORMS"] = _cpu_only_platform(os.environ, nprocs)
     import socket
     s = socket.socket()
     s.bind(("127.0.0.1", 0))

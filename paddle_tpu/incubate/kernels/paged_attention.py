@@ -62,7 +62,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .flash_attention import NEG_INF, _on_tpu
+from . import _on_tpu, _out_struct
+from .flash_attention import NEG_INF
 
 # Tensor-parallel serving (multi-chip): attention is embarrassingly parallel
 # over heads — no cross-head reduction anywhere in the softmax/PV chain — so
@@ -70,9 +71,8 @@ from .flash_attention import NEG_INF, _on_tpu
 # of EVERY page".  The page pool shards on its KVH axis, q on its head axis,
 # and the page table / lengths / q_offset / valid scalars stay replicated
 # (they are host-side scheduler state, identical on every chip).  Two routes:
-# - Pallas (TPU): the kernel is grid-per-shard — shard_map_compat (the PR-1
-#   full-manual fallback on old JAX) runs the UNMODIFIED kernel on the local
-#   head slice of the pool.
+# - Pallas (TPU): the kernel is grid-per-shard — `jax.shard_map` runs the
+#   UNMODIFIED kernel on the local head slice of the pool.
 # - XLA oracle (CPU / kernel-unfriendly layouts): sharding constraints pin the
 #   head layout and GSPMD partitions the gather+einsum (the gather indexes the
 #   pool's page axis, which is unsharded, so it stays collective-free).
@@ -113,7 +113,6 @@ def paged_attention_decode_mp(q, k_pages, v_pages, page_table, lengths,
     True with interpret=True to run the shard_mapped kernel on CPU.
     kv_scales (int8 pool) shard on the same KVH axis as the pages — the
     dequant is per-head-local, so the mp distribution is unchanged."""
-    from ...parallel.ring_attention import shard_map_compat
 
     mp = _mp_degree(mesh)
     _check_mp_heads(q.shape[1], k_pages.shape[2], mp)
@@ -126,7 +125,7 @@ def paged_attention_decode_mp(q, k_pages, v_pages, page_table, lengths,
                 return paged_attention_pallas(q_l, k_l, v_l, tbl, ln,
                                               scale=scale, interpret=interpret,
                                               kv_scales=(ks_l, vs_l))
-            return shard_map_compat(
+            return jax.shard_map(
                 local_q, mesh=mesh, axis_names={"mp"},
                 in_specs=(P(None, None), P(None), _head_spec(3), _POOL_SPEC,
                           _POOL_SPEC, _SCALE_SPEC, _SCALE_SPEC),
@@ -136,7 +135,7 @@ def paged_attention_decode_mp(q, k_pages, v_pages, page_table, lengths,
         def local(tbl, ln, q_l, k_l, v_l):
             return paged_attention_pallas(q_l, k_l, v_l, tbl, ln, scale=scale,
                                           interpret=interpret)
-        return shard_map_compat(
+        return jax.shard_map(
             local, mesh=mesh, axis_names={"mp"},
             in_specs=(P(None, None), P(None), _head_spec(3), _POOL_SPEC,
                       _POOL_SPEC),
@@ -157,7 +156,6 @@ def paged_prefill_attention_mp(q, k_pages, v_pages, page_table, q_offset,
                                interpret=False, kv_scales=None):
     """Head-sharded `paged_prefill_attention` (and, via
     `paged_verify_attention`, the spec-decode verify lane) over `mp`."""
-    from ...parallel.ring_attention import shard_map_compat
 
     mp = _mp_degree(mesh)
     _check_mp_heads(q.shape[2], k_pages.shape[2], mp)
@@ -170,7 +168,7 @@ def paged_prefill_attention_mp(q, k_pages, v_pages, page_table, q_offset,
                 return paged_prefill_attention_pallas(
                     q_l, k_l, v_l, tbl, qo, vl, scale=scale,
                     interpret=interpret, kv_scales=(ks_l, vs_l))
-            return shard_map_compat(
+            return jax.shard_map(
                 local_q, mesh=mesh, axis_names={"mp"},
                 in_specs=(P(None, None), P(None), P(None), _head_spec(4),
                           _POOL_SPEC, _POOL_SPEC, _SCALE_SPEC, _SCALE_SPEC),
@@ -181,7 +179,7 @@ def paged_prefill_attention_mp(q, k_pages, v_pages, page_table, q_offset,
             return paged_prefill_attention_pallas(q_l, k_l, v_l, tbl, qo, vl,
                                                   scale=scale,
                                                   interpret=interpret)
-        return shard_map_compat(
+        return jax.shard_map(
             local, mesh=mesh, axis_names={"mp"},
             in_specs=(P(None, None), P(None), P(None), _head_spec(4),
                       _POOL_SPEC, _POOL_SPEC),
@@ -358,12 +356,12 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
             pltpu.VMEM((H, 1), jnp.float32),
         ],
     )
-    cparams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        compiler_params=cparams(dimension_semantics=("parallel", "arbitrary")),
+        out_shape=_out_struct((B, H, hd), q.dtype, q, k_pages, v_pages),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
       *args)
@@ -408,16 +406,26 @@ def paged_prefill_attention_xla(q, k_pages, v_pages, page_table, q_offset,
     return out.transpose(0, 3, 1, 2, 4).reshape(B, T, H, hd)
 
 
+# Query rows (heads x tokens) one grid step of the prefill kernel keeps in
+# VMEM.  The q/o blocks, the f32 accumulator and the score tile all scale with
+# it; 2048 rows of hd=128 is ~9 MiB of the 16 MiB a v5e core has, where the
+# whole-T layout asked for 24 MiB at T=1024 (and 17.6 MiB at T=256).
+_MAX_Q_ROWS = 2048
+
+
 def _paged_prefill_kernel(tbl_ref, qoff_ref, val_ref, q_ref, k_ref, v_ref,
                           *refs, page: int,
-                          KVH: int, G: int, T: int, n_pages: int,
+                          KVH: int, G: int, bt: int, n_pages: int,
                           scale: float, quantized: bool = False):
-    """Grid (B, max_pages): slots parallel, pages innermost with
-    online-softmax scratch carry over T*H query rows (kh-major stacking, same
-    discipline as the decode kernel).  The causal-at-offset mask
-    `kv_pos <= q_offset + t` replaces the decode kernel's length mask; page 0
-    always computes (every query row attends at least to kv position 0), so
+    """Grid (B, T/bt, max_pages): slots and query tiles parallel, pages
+    innermost with online-softmax scratch carry over the tile's bt*H query
+    rows (kh-major stacking, same discipline as the decode kernel) — VMEM use
+    is set by the tile, not by T, as the flash kernel tiles S.  The
+    causal-at-offset mask `kv_pos <= q_offset + t` replaces the decode
+    kernel's length mask; a tile's page 0 always computes while the tile
+    holds a real row (every query row attends at least to kv position 0), so
     the running max is finite before any fully-masked row/page combination.
+    A tile entirely past `valid` computes nothing and writes zeros.
     `quantized` adds two per-page scale refs after v_ref: the int8 page
     block dequantizes to f32 on read, same math as the decode kernel."""
     from jax.experimental import pallas as pl
@@ -427,8 +435,8 @@ def _paged_prefill_kernel(tbl_ref, qoff_ref, val_ref, q_ref, k_ref, v_ref,
     else:
         o_ref, acc_ref, m_ref, l_ref = refs
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    H = KVH * G
+    ti = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -437,13 +445,15 @@ def _paged_prefill_kernel(tbl_ref, qoff_ref, val_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     qoff = qoff_ref[b]
-    last_q = qoff + val_ref[b] - 1      # highest real query position
+    t0 = ti * bt                        # first chunk row of this tile
+    n_real = jnp.minimum(val_ref[b] - t0, bt)   # real rows in the tile
+    last_q = qoff + t0 + n_real - 1     # highest real query position
     k_start = j * page
 
-    # page entirely past every real query position: skip compute
-    @pl.when(k_start <= last_q)
+    # skip: tile of padding rows, or page past every real query position
+    @pl.when((n_real > 0) & (k_start <= last_q))
     def _compute():
-        q = q_ref[0]                                    # [T, H, hd]
+        q = q_ref[0]                                    # [bt, H, hd]
         k = k_ref[0]                                    # [page, KVH, hd]
         v = v_ref[0]
         if quantized:
@@ -451,14 +461,14 @@ def _paged_prefill_kernel(tbl_ref, qoff_ref, val_ref, q_ref, k_ref, v_ref,
             v = v.astype(jnp.float32) * vs_ref[0][..., None]
         rows = []
         for kh in range(KVH):
-            qh = q[:, kh * G:(kh + 1) * G, :].reshape(T * G, -1)
+            qh = q[:, kh * G:(kh + 1) * G, :].reshape(bt * G, -1)
             rows.append(jnp.dot(qh, k[:, kh, :].T,
                                 preferred_element_type=jnp.float32))
         s = (jnp.concatenate(rows, axis=0) if KVH > 1 else rows[0]) * scale
-        R = KVH * T * G
+        R = KVH * bt * G
         kv_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (R, page), 1)
-        t_row = (jax.lax.broadcasted_iota(jnp.int32, (R, page), 0)
-                 % (T * G)) // G
+        t_row = t0 + (jax.lax.broadcasted_iota(jnp.int32, (R, page), 0)
+                      % (bt * G)) // G
         s = jnp.where(kv_pos <= qoff + t_row, s, NEG_INF)
         m_prev = m_ref[...]
         l_prev = l_ref[...]
@@ -468,7 +478,7 @@ def _paged_prefill_kernel(tbl_ref, qoff_ref, val_ref, q_ref, k_ref, v_ref,
         l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         upd = []
         for kh in range(KVH):
-            ph = p[kh * T * G:(kh + 1) * T * G].astype(v.dtype)
+            ph = p[kh * bt * G:(kh + 1) * bt * G].astype(v.dtype)
             upd.append(jnp.dot(ph, v[:, kh, :],
                                preferred_element_type=jnp.float32))
         pv = jnp.concatenate(upd, axis=0) if KVH > 1 else upd[0]   # [R, hd]
@@ -478,9 +488,9 @@ def _paged_prefill_kernel(tbl_ref, qoff_ref, val_ref, q_ref, k_ref, v_ref,
     @pl.when(j == n_pages - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        out = acc_ref[...] / l                          # [KVH*T*G, hd]
+        out = acc_ref[...] / l                          # [KVH*bt*G, hd]
         for kh in range(KVH):
-            blk = out[kh * T * G:(kh + 1) * T * G].reshape(T, G, -1)
+            blk = out[kh * bt * G:(kh + 1) * bt * G].reshape(bt, G, -1)
             o_ref[0, :, kh * G:(kh + 1) * G, :] = blk.astype(o_ref.dtype)
 
 
@@ -489,9 +499,10 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, page_table, q_offset,
                                    kv_scales=None):
     """Pallas chunked-prefill paged attention — same contract as
     `paged_prefill_attention_xla`.  page_table / q_offset / valid ride
-    `PrefetchScalarGridSpec`; `kv_scales` (int8 pool) adds table-indexed
-    per-page scale blocks dequantized on read; `interpret=True` runs on CPU
-    for numerics tests."""
+    `PrefetchScalarGridSpec`; the T query tokens are tiled over a grid axis
+    (`_MAX_Q_ROWS`), T padded up to a whole number of tiles; `kv_scales`
+    (int8 pool) adds table-indexed per-page scale blocks dequantized on read;
+    `interpret=True` runs on CPU for numerics tests."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -501,43 +512,48 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, page_table, q_offset,
     G = H // KVH
     n_pages = page_table.shape[1]
     s = scale if scale is not None else 1.0 / math.sqrt(hd)
+    bt = min(T, max(1, _MAX_Q_ROWS // H))
+    n_t = pl.cdiv(T, bt)
+    if n_t * bt != T:
+        # padded rows sit past `valid` (valid <= T): masked like any pad row
+        q = jnp.pad(q, ((0, 0), (0, n_t * bt - T), (0, 0), (0, 0)))
 
     kernel = functools.partial(_paged_prefill_kernel, page=page, KVH=KVH,
-                               G=G, T=T, n_pages=n_pages, scale=s,
+                               G=G, bt=bt, n_pages=n_pages, scale=s,
                                quantized=kv_scales is not None)
-    pool_spec = pl.BlockSpec((1, page, KVH, hd),
-                             lambda b, j, tbl, qo, vl: (tbl[b, j], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, T, H, hd), lambda b, j, tbl, qo, vl: (b, 0, 0, 0)),
-        pool_spec, pool_spec,
-    ]
+    pool_spec = pl.BlockSpec(
+        (1, page, KVH, hd), lambda b, t, j, tbl, qo, vl: (tbl[b, j], 0, 0, 0))
+    q_spec = pl.BlockSpec((1, bt, H, hd),
+                          lambda b, t, j, tbl, qo, vl: (b, t, 0, 0))
+    in_specs = [q_spec, pool_spec, pool_spec]
     args = [q, k_pages, v_pages]
     if kv_scales is not None:
-        scale_spec = pl.BlockSpec((1, page, KVH),
-                                  lambda b, j, tbl, qo, vl: (tbl[b, j], 0, 0))
+        scale_spec = pl.BlockSpec(
+            (1, page, KVH), lambda b, t, j, tbl, qo, vl: (tbl[b, j], 0, 0))
         in_specs += [scale_spec, scale_spec]
         args += [kv_scales[0], kv_scales[1]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # (page_table, q_offset, valid)
-        grid=(B, n_pages),
+        grid=(B, n_t, n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, T, H, hd),
-                               lambda b, j, tbl, qo, vl: (b, 0, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((KVH * T * G, hd), jnp.float32),
-            pltpu.VMEM((KVH * T * G, 1), jnp.float32),
-            pltpu.VMEM((KVH * T * G, 1), jnp.float32),
+            pltpu.VMEM((KVH * bt * G, hd), jnp.float32),
+            pltpu.VMEM((KVH * bt * G, 1), jnp.float32),
+            pltpu.VMEM((KVH * bt * G, 1), jnp.float32),
         ],
     )
-    cparams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, H, hd), q.dtype),
-        compiler_params=cparams(dimension_semantics=("parallel", "arbitrary")),
+        out_shape=_out_struct((B, n_t * bt, H, hd), q.dtype, q, k_pages,
+                              v_pages),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(q_offset, jnp.int32),
       jnp.asarray(valid, jnp.int32), *args)
+    return out[:, :T] if n_t * bt != T else out
 
 
 def paged_prefill_attention(q, k_pages, v_pages, page_table, q_offset, valid,

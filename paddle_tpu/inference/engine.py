@@ -1396,7 +1396,9 @@ class LLMEngine:
         `jax.transfer_guard("disallow")` — a bare Python list/int through
         `jnp.asarray` is an implicit transfer, and under mp a single-device
         array would be implicitly resharded to the mesh at every AOT
-        dispatch."""
+        dispatch.  (The other direction is `jax.device_get`: on the chip
+        `np.asarray(device_array)` is an implicit device->host transfer and
+        the guard refuses it; the CPU backend has no copy to refuse.)"""
         a = np.asarray(a, dtype)
         if self._repl_sharding is not None:
             return jax.device_put(a, self._repl_sharding)
@@ -1681,8 +1683,8 @@ class LLMEngine:
             return
         t_sync = self._now()
         with self._span("engine.sample.sync"):
-            out = np.asarray(inf["out"])        # blocks on the device result
-            accept = np.asarray(inf["accept"])
+            # blocks on the device result
+            out, accept = jax.device_get((inf["out"], inf["accept"]))
         self._step_sync_s += self._now() - t_sync
         drafts = inf["drafts"]
         with self._span("engine.spec.accept"):
@@ -1859,7 +1861,7 @@ class LLMEngine:
         self._faults.d2h()
         t0 = self._now()
         with self._span("engine.swap.d2h"):
-            rec["data"] = {name: np.asarray(a)[:, :rec["n"]]
+            rec["data"] = {name: jax.device_get(a)[:, :rec["n"]]
                            for name, a in rec["data"].items()}
         self._swap_ms_c.inc((self._now() - t0) * 1e3)
         rec["fetched"] = True
@@ -1936,7 +1938,7 @@ class LLMEngine:
         self._faults.d2h()
         t0 = self._now()
         with self._span("engine.swap.d2h"):
-            data = {name: np.asarray(a) for name, a in rec["data"].items()}
+            data = jax.device_get(rec["data"])
         self._swap_ms_c.inc((self._now() - t0) * 1e3)
         rec["fetched"] = True
         tier = self.cache._tier
@@ -2344,7 +2346,7 @@ class LLMEngine:
                     mgr.register_prefix(slot, prompt, lp)
                 t_sync = self._now()
                 with self._span("engine.sample.sync"):
-                    first = int(np.asarray(first)[0])   # blocks on the result
+                    first = int(jax.device_get(first)[0])   # blocks on the result
                 self._step_sync_s += self._now() - t_sync
                 self._start_decoding(
                     req, slot, first, cached_out, finished, prompt_len=lp,
@@ -2393,7 +2395,7 @@ class LLMEngine:
             del self._prefilling[slot]
             t_sync = self._now()
             with self._span("engine.sample.sync"):
-                tok = int(np.asarray(tok)[0])           # blocks on the result
+                tok = int(jax.device_get(tok)[0])       # blocks on the result
             self._step_sync_s += self._now() - t_sync
             self._start_decoding(st.request, slot, tok, st.cached_tokens,
                                  finished, prompt_len=lp, prior=st.prior,
@@ -2528,7 +2530,7 @@ class LLMEngine:
         self._step_slots["verify"] += len(active)
         t_sync = self._now()
         with self._span("engine.sample.sync"):
-            preds = np.asarray(preds)       # blocks on the device result
+            preds = jax.device_get(preds)   # blocks on the device result
         self._step_sync_s += self._now() - t_sync
         self._verify_steps.inc()
         with self._span("engine.spec.accept"):
@@ -2577,7 +2579,7 @@ class LLMEngine:
         self._decode_tokens.inc(len(active))
         t_sync = self._now()
         with self._span("engine.sample.sync"):
-            nxt = np.asarray(nxt)           # blocks on the device result
+            nxt = jax.device_get(nxt)       # blocks on the device result
         self._step_sync_s += self._now() - t_sync
         for slot in slots:
             seq = self._running[slot]
@@ -2646,7 +2648,7 @@ class LLMEngine:
         self._swap_out_used = True
         # round-trip through host numpy so the swap-in signature matches the
         # real resume path (replicated staging uploads, not device outputs)
-        staged = {n: self._h2d(np.asarray(a)) for n, a in data.items()}
+        staged = {n: self._h2d(a) for n, a in jax.device_get(data).items()}
         self._pool = self._swap_in_fn(self._pool, self._h2d(ids), staged)
         self._swap_in_used = True
 

@@ -79,6 +79,13 @@ def _mp_worker_main(wid, num_workers, dataset, collate_fn, worker_init_fn,
     Module-level (not a bound method) so only these picklable fields cross the
     spawn boundary — an unpicklable places/batch_sampler on the DataLoader
     itself must not reach Process.start()."""
+    # first thing: a chip belongs to one process and the parent holds it; a
+    # worker only builds host batches, so it is held to the CPU backend (the
+    # env var covers anything the worker execs, the config this process,
+    # whose jax was imported before this line runs)
+    import jax
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
     from .shm_ring import ShmRing
     global _worker_info
     _worker_info = WorkerInfo(wid, num_workers, dataset)

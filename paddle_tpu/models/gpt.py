@@ -170,17 +170,6 @@ def init_params(config: GPTConfig, key) -> Dict[str, Any]:
     return params
 
 
-def pvary_compat(x, axes):
-    """Mark x varying over manual mesh axes (pvary was deprecated for pcast).
-    Old JAX (< 0.5) has neither and no varying-axes tracking at all (shard_map
-    runs check_rep=False there) — identity is the correct no-op."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axes)
-    return x
-
-
 def _norm(x, w, b, config):
     if config.use_rms_norm:
         return rms_norm_fused(x, w)
@@ -316,9 +305,9 @@ def run_blocks(blocks, x, config, mp_constraint=None, remat=False, moe_impl=None
     # inside a shard_map (pp loop) x is varying over the manual axes; the aux
     # carry must carry the same vma type or scan rejects the carry signature
     aux0 = jnp.zeros((), jnp.float32)
-    vma = getattr(jax.typeof(x), "vma", None) if hasattr(jax, "typeof") else None
+    vma = jax.typeof(x).vma
     if vma:
-        aux0 = pvary_compat(aux0, tuple(vma))
+        aux0 = jax.lax.pcast(aux0, tuple(vma), to="varying")
     (out, aux), _ = jax.lax.scan(step, (x, aux0), blocks)
     return out, aux
 
@@ -403,7 +392,6 @@ def _embed(params, tokens, config: GPTConfig, mesh=None):
         return jnp.take(params["wte"], tokens, axis=0)
 
     from jax.sharding import PartitionSpec as P
-    from ..parallel.ring_attention import shard_map_compat
     quant = "wte_q" in params
 
     def local(table, scale, tok):
@@ -418,7 +406,7 @@ def _embed(params, tokens, config: GPTConfig, mesh=None):
         rows = jnp.where(ok[..., None], rows, jnp.zeros((), rows.dtype))
         return jax.lax.psum(rows, "mp")
 
-    sm = shard_map_compat(
+    sm = jax.shard_map(
         local, mesh=mesh, axis_names={"mp"},
         in_specs=(P("mp", None), P("mp", None), P()), out_specs=P())
     if quant:
@@ -490,7 +478,6 @@ def sharded_argmax(logits, mesh=None):
     if _mesh_mp(mesh) <= 1:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     from jax.sharding import PartitionSpec as P
-    from ..parallel.ring_attention import shard_map_compat
     V = logits.shape[-1]
     lead = logits.ndim - 1
 
@@ -503,20 +490,20 @@ def sharded_argmax(logits, mesh=None):
         cand = jnp.where(lv == gm, li, V)
         return jax.lax.pmin(cand, "mp").astype(jnp.int32)
 
-    return shard_map_compat(
+    return jax.shard_map(
         local, mesh=mesh, axis_names={"mp"},
         in_specs=(P(*([None] * lead), "mp"),), out_specs=P())(logits)
 
 
 def backbone(params, tokens, config: GPTConfig, mp_constraint=None, remat=False,
-             moe_impl=None, type_ids=None):
+             moe_impl=None, type_ids=None, attn_impl=None):
     """Shared trunk: tokens [B, S] -> (activations [B, S, D], head, moe aux)."""
     x = jnp.take(params["wte"], tokens, axis=0)
     x = embed_prologue(params, x, config, type_ids)
     if mp_constraint:
         x = mp_constraint(x, "act")
     x, aux = run_blocks(params["blocks"], x, config, mp_constraint, remat=remat,
-                        moe_impl=moe_impl)
+                        moe_impl=moe_impl, attn_impl=attn_impl)
     x = epilogue(params, x, config)
     return x, head_matrix(params, config), aux
 
@@ -537,14 +524,16 @@ def _ce_sums(logits, labels):
 
 
 def loss_fn(params, tokens, labels, config: GPTConfig, mp_constraint=None,
-            remat=False, loss_chunk: Optional[int] = 512, moe_impl=None):
+            remat=False, loss_chunk: Optional[int] = 512, moe_impl=None,
+            attn_impl=None):
     """Causal LM loss; labels [B, S] with -100 = ignore.
 
     loss_chunk: when set, the LM head + softmax run over sequence chunks inside a
     rematerialized scan, so the [B, S, V] float32 log-probs never materialize —
     the dominant HBM transient at GPT-3 vocab (V=50k: 3.3 GB at B=8, S=2048).
     """
-    x, head, aux = backbone(params, tokens, config, mp_constraint, remat, moe_impl)
+    x, head, aux = backbone(params, tokens, config, mp_constraint, remat, moe_impl,
+                            attn_impl=attn_impl)
     moe_pen = config.moe_aux_weight * aux if config.moe_num_experts > 0 else 0.0
     B, S, D = x.shape
     if not loss_chunk or S % loss_chunk != 0 or S <= loss_chunk:
@@ -1086,9 +1075,8 @@ def prefill_paged(params, input_ids, config: GPTConfig, cache, pages, length,
         # attention never mixes heads: run the (Pallas or XLA) flash body
         # per-shard on each chip's head slice — same trick as the paged lanes
         from ..incubate.kernels.paged_attention import _head_spec
-        from ..parallel.ring_attention import shard_map_compat
         hs = _head_spec(4)
-        return shard_map_compat(
+        return jax.shard_map(
             lambda a, b, d: flash_attention_fused(a, b, d, causal=True),
             mesh=mesh, axis_names={"mp"}, in_specs=(hs, hs, hs),
             out_specs=hs)(q, k, v)
@@ -1381,7 +1369,6 @@ def sample_token(logits, key, *, sample, temperature, top_k, mesh=None):
             return jnp.argmax(lg + noise, axis=-1).astype(jnp.int32), key
 
         from jax.sharding import PartitionSpec as P
-        from ..parallel.ring_attention import shard_map_compat
         V = lg.shape[-1]
         kk = int(top_k) if top_k else 0
 
@@ -1400,7 +1387,7 @@ def sample_token(logits, key, *, sample, temperature, top_k, mesh=None):
             cand = jnp.where(lv == gm, li, V)
             return jax.lax.pmin(cand, "mp").astype(jnp.int32)
 
-        ids = shard_map_compat(
+        ids = jax.shard_map(
             local, mesh=mesh, axis_names={"mp"},
             in_specs=(P(None, "mp"), P(None, "mp")), out_specs=P())(lg, noise)
         return ids, key

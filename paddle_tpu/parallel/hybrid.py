@@ -8,7 +8,7 @@ sharding optimizer):
   column-split, proj/fc2 row-split, vocab-split embedding); the batch is sharded over
   dp; XLA inserts the exact allreduce/allgather/reduce-scatter set the reference codes
   by hand in mp_ops.py and the DP reducer — fused into the backward schedule.
-- **pp**: a GPipe microbatch loop written with `shard_map_compat(axis_names={'pp'})` +
+- **pp**: a GPipe microbatch loop written with `jax.shard_map(axis_names={'pp'})` +
   `ppermute` inside the SAME jitted program — stages exchange activations over ICI
   each tick; `jax.grad` differentiates through the scan, producing the reverse
   pipeline automatically (the reference's hand-written 1F1B send/recv schedule,
@@ -31,7 +31,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import gpt as gpt_mod
-from .ring_attention import shard_map_compat
 
 
 @dataclasses.dataclass
@@ -266,6 +265,31 @@ def _opt_state_spec(param_spec: P, shape, cfg: MeshConfig):
 
 
 # ---------------------------------------------------------------------------
+# flash attention under a partitioned step
+# ---------------------------------------------------------------------------
+
+_BATCH_AXES = ("dp", "sharding", "ep")
+
+
+def _flash_per_shard(mesh, config, manual=()):
+    """`attn_impl` for `block_forward` when the step is partitioned over more
+    than one device: tells the flash entry which mesh axes q/k/v split over
+    — batch over the data axes, heads over mp — so its Mosaic kernels run
+    per shard (GSPMD cannot partition them).  Mosaic lowers only where
+    EVERY mesh axis is manual, size-1 axes included.  `manual` names the axes
+    the caller is already manual over (the pp loop): the region covers the
+    rest and resolves against the context mesh."""
+    from ..incubate.kernels.flash_attention import flash_attention_fused
+
+    batch = tuple(a for a in _BATCH_AXES if a not in manual)
+    shard = (None if manual else mesh,
+             frozenset(mesh.axis_names) - frozenset(manual),
+             P(batch or None, None, "mp", None))
+    return functools.partial(flash_attention_fused, causal=config.causal,
+                             shard=shard)
+
+
+# ---------------------------------------------------------------------------
 # expert parallelism: global_scatter/global_gather over the 'ep' axis
 # ---------------------------------------------------------------------------
 
@@ -318,7 +342,7 @@ def _moe_ffn_ep(bp, x, config, cfg: MeshConfig, mesh):
         y, aux = _moe_local(bp_local, x_l, config, cfg.ep)
         return y, jax.lax.psum(aux, "ep") / cfg.ep
 
-    return shard_map_compat(
+    return jax.shard_map(
         local, mesh=mesh, axis_names={"ep"},
         in_specs=(P(), P("ep"), P("ep"), P("ep"), P("ep"), P("ep")),
         out_specs=(P("ep"), P()))(
@@ -360,7 +384,7 @@ def _cp_loss(params, tokens, labels, config, cfg: MeshConfig, mesh):
         return h, jax.lax.psum(aux, "cp")
 
     blk_specs = jax.tree_util.tree_map(lambda _: P(), params["blocks"])
-    h, aux = shard_map_compat(
+    h, aux = jax.shard_map(
         local, mesh=mesh, axis_names={"cp"},
         in_specs=(blk_specs, P(), P(), P(None, "cp", None)),
         out_specs=(P(None, "cp", None), P()))(
@@ -395,8 +419,9 @@ def _vp_embed(wte, tokens, mesh, cfg: MeshConfig):
         e = jnp.where(ok[..., None], e, jnp.zeros((), e.dtype))
         return jax.lax.psum(e, "mp")
 
-    return shard_map_compat(local, mesh=mesh, axis_names={"mp"},
-                         in_specs=(P("mp", None), P()), out_specs=P())(wte, tokens)
+    return jax.shard_map(local, mesh=mesh, axis_names={"mp"},
+                         in_specs=(P("mp", None), P()),
+                         out_specs=P())(wte, tokens)
 
 
 def _vp_ce(h, head, labels, mesh, cfg: MeshConfig):
@@ -465,7 +490,7 @@ def _vp_ce(h, head, labels, mesh, cfg: MeshConfig):
     spec_b = P(batch_axes if batch_axes else None,
                seq_axes if seq_axes else None)
     spec_head = P(None, "mp") if have_mp else P()
-    ls, n = shard_map_compat(local, mesh=mesh, axis_names=manual,
+    ls, n = jax.shard_map(local, mesh=mesh, axis_names=manual,
                           in_specs=(spec_b, spec_head, spec_b),
                           out_specs=(P(), P()))(h, head, labels)
     return ls / jnp.maximum(n, 1.0)
@@ -535,8 +560,7 @@ def _pp_loss(params, tokens, labels, config, cfg: MeshConfig, mesh):
         # allgather to replicated, reshape, reslice onto pp — each transition
         # is one the partitioner lowers efficiently.  The mp allgather is not
         # extra work: the shard_map below consumes P(None, "pp") inputs, so
-        # axes outside pp were ALWAYS replicated at this boundary (the PR-1
-        # full-manual fallback computes redundantly per mp rank by design).
+        # axes outside pp were ALWAYS replicated at this boundary.
         def _vpp_reshape(a):
             a = jax.lax.with_sharding_constraint(a, NamedSharding(mesh, P()))
             a = a.reshape((vpp, Ppp, a.shape[0] // (vpp * Ppp)) + a.shape[1:])
@@ -549,11 +573,12 @@ def _pp_loss(params, tokens, labels, config, cfg: MeshConfig, mesh):
         blocks_arg = params["blocks"]
         T = M + Ppp - 1
 
-    attn_impl = None
     if cp_manual:
         from .ring_attention import ring_attention_local
         attn_impl = functools.partial(ring_attention_local, axis_name="cp",
                                       cp=cfg.cp, causal=True)
+    else:
+        attn_impl = _flash_per_shard(mesh, config, manual)
 
     def local_fn(blocks_local, xs_rep):
         p = jax.lax.axis_index("pp")
@@ -586,11 +611,10 @@ def _pp_loss(params, tokens, labels, config, cfg: MeshConfig, mesh):
             # invalid (warmup/cooldown) ticks run on garbage; mask their aux
             return (nxt, aux_acc + (aux * valid.astype(aux.dtype))[None]), out
 
-        buf0 = gpt_mod.pvary_compat(jnp.zeros((mb_l, S_l, D), xs_rep.dtype),
-                                    manual)
-        # aux rides the boundary rank-1: old-JAX shard_map autodiff fails
-        # to promote scalar residuals (_SpecError), and a (1,) lane is free
-        aux0 = gpt_mod.pvary_compat(jnp.zeros((1,), jnp.float32), manual)
+        buf0 = jax.lax.pcast(jnp.zeros((mb_l, S_l, D), xs_rep.dtype), manual,
+                             to="varying")
+        aux0 = jax.lax.pcast(jnp.zeros((1,), jnp.float32), manual,
+                             to="varying")
         (_, aux_sum), outs = jax.lax.scan(tick, (buf0, aux0), jnp.arange(T))
         # drop warmup/cooldown garbage IN-shard: only M ticks (and their grad
         # cotangents) cross the shard_map boundary.  The finish ticks are
@@ -618,7 +642,7 @@ def _pp_loss(params, tokens, labels, config, cfg: MeshConfig, mesh):
                 "cp" if cp_manual else None)
     out_spec = P("pp", "ep" if moe_manual else None,
                  "cp" if cp_manual else None)
-    f = shard_map_compat(
+    f = jax.shard_map(
         local_fn, mesh=mesh, axis_names=set(manual),
         in_specs=(blk_in, xs_spec),
         out_specs=(out_spec, P()))
@@ -704,10 +728,10 @@ class HybridParallelTrainer:
             return x
         if kind in ("hidden_mp", "ffn_mp"):
             return jax.lax.with_sharding_constraint(
-                x, NamedSharding(self.mesh, P(("dp", "sharding", "ep"), None, "mp")))
+                x, NamedSharding(self.mesh, P(_BATCH_AXES, None, "mp")))
         if kind == "act" and cfg.sequence_parallel:
             return jax.lax.with_sharding_constraint(
-                x, NamedSharding(self.mesh, P(("dp", "sharding", "ep"), "mp", None)))
+                x, NamedSharding(self.mesh, P(_BATCH_AXES, "mp", None)))
         return x
 
     def _build_step(self):
@@ -729,6 +753,8 @@ class HybridParallelTrainer:
                 "vpp (interleaved virtual stages) requires pp > 1 (ref: " \
                 "virtual_pp_degree needs pipeline parallelism)"
 
+        attn_impl = _flash_per_shard(mesh, config) if cfg.size > 1 else None
+
         def loss_of(params, tokens, labels):
             if cfg.pp > 1:
                 return _pp_loss(params, tokens, labels, config, cfg, mesh)
@@ -736,7 +762,8 @@ class HybridParallelTrainer:
                 return _cp_loss(params, tokens, labels, config, cfg, mesh)
             return gpt_mod.loss_fn(params, tokens, labels, config,
                                    mp_constraint=self._mp_constraint,
-                                   remat=cfg.remat, moe_impl=moe_impl)
+                                   remat=cfg.remat, moe_impl=moe_impl,
+                                   attn_impl=attn_impl)
 
         def step(params, opt_state, tokens, labels):
             loss, grads = jax.value_and_grad(loss_of)(params, tokens, labels)
@@ -779,8 +806,7 @@ class HybridParallelTrainer:
         # batch splits over dp AND sharding AND ep: the zero group is a
         # data-parallel group with sharded states, and ep ranks each own a batch
         # shard whose tokens they route (ref: moe_group is a data-parallel group)
-        batch_axes = ("dp", "sharding", "ep")
-        data_sharding = NamedSharding(self.mesh, P(batch_axes, None))
+        data_sharding = NamedSharding(self.mesh, P(_BATCH_AXES, None))
         opt_sh = {"m": self._m_shardings, "v": self._m_shardings, "step": None}
         # out_shardings pinned so params stay in the param layout across steps (else
         # XLA propagates the ZeRO 'dp' shard from the moments onto updated params and
@@ -791,7 +817,7 @@ class HybridParallelTrainer:
                        out_shardings=(None, self.param_shardings, opt_sh))
 
     def shard_batch(self, tokens, labels):
-        ds = NamedSharding(self.mesh, P(("dp", "sharding", "ep"), None))
+        ds = NamedSharding(self.mesh, P(_BATCH_AXES, None))
         return (jax.device_put(jnp.asarray(tokens), ds),
                 jax.device_put(jnp.asarray(labels), ds))
 

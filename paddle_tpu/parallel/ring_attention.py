@@ -27,22 +27,6 @@ from jax.sharding import PartitionSpec as P
 NEG_INF = -1e30
 
 
-def shard_map_compat(f, *, mesh, axis_names, in_specs, out_specs):
-    """`jax.shard_map` (axis_names=manual axes) with a fallback to
-    `jax.experimental.shard_map` on older JAX.  The fallback goes FULL manual
-    (all mesh axes) rather than `auto=<complement>`: partial-manual regions on
-    old jaxlib hit an SPMD-partitioner CHECK crash (IsManualSubgroup mismatch)
-    and subtle replication bugs.  Axes absent from the specs then just compute
-    redundantly per rank — semantically identical, and only the new-JAX path
-    runs at scale."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, axis_names=set(axis_names),
-                             in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def ring_attention_local(q, k, v, axis_name: str, cp: int, causal: bool = True,
                          scale=None):
     """Runs INSIDE a manual region over `axis_name` (cp ranks).
@@ -90,12 +74,13 @@ def ring_attention_local(q, k, v, axis_name: str, cp: int, causal: bool = True,
         vc = jax.lax.ppermute(vc, axis_name, perm)
         return (kc, vc, o, m, l), None
 
-    from ..models.gpt import pvary_compat
-    vma = (tuple(getattr(jax.typeof(q), "vma", (axis_name,))) or (axis_name,)) \
-        if hasattr(jax, "typeof") else (axis_name,)
-    o0 = pvary_compat(jnp.zeros((B, H, Sl, D), jnp.float32), vma)
-    m0 = pvary_compat(jnp.full((B, H, Sl), NEG_INF, jnp.float32), vma)
-    l0 = pvary_compat(jnp.zeros((B, H, Sl), jnp.float32), vma)
+    # the scan carry must vary over the same manual axes as q
+    vma = tuple(jax.typeof(q).vma) or (axis_name,)
+    o0 = jax.lax.pcast(jnp.zeros((B, H, Sl, D), jnp.float32), vma,
+                       to="varying")
+    m0 = jax.lax.pcast(jnp.full((B, H, Sl), NEG_INF, jnp.float32), vma,
+                       to="varying")
+    l0 = jax.lax.pcast(jnp.zeros((B, H, Sl), jnp.float32), vma, to="varying")
 
     (kf, vf, o, m, l), _ = jax.lax.scan(step, (k, v, o0, m0, l0),
                                         jnp.arange(cp))
@@ -114,7 +99,7 @@ def ring_attention(q, k, v, mesh, axis_name: str = "cp", causal: bool = True,
     fn = functools.partial(ring_attention_local, axis_name=axis_name, cp=cp,
                            causal=causal, scale=scale)
     spec = P(None, axis_name, None, None)
-    return shard_map_compat(lambda a, b, c: fn(a, b, c), mesh=mesh,
-                            axis_names={axis_name},
-                            in_specs=(spec, spec, spec),
-                            out_specs=spec)(q, k, v)
+    return jax.shard_map(lambda a, b, c: fn(a, b, c), mesh=mesh,
+                         axis_names={axis_name},
+                         in_specs=(spec, spec, spec),
+                         out_specs=spec)(q, k, v)

@@ -185,25 +185,21 @@ class Profiler:
         _recording = True
         self._state = self._scheduler(self._step)
         if not self._timer_only:
-            try:
-                import jax.profiler
-                self._jax_dir = os.path.join(self._log_dir, f"jaxtrace_{int(time.time())}")
-                jax.profiler.start_trace(self._jax_dir)
-            # tpu-lint: disable=TPL006 -- device capture is best-effort: ANY backend failure must degrade to host-only tracing, not kill the run
-            except Exception:
-                self._jax_dir = None
+            # a device capture that was asked for and cannot start raises:
+            # the trace is the source of every device metric, and a
+            # host-only trace under its name would read as an idle chip
+            import jax.profiler
+            jax_dir = os.path.join(self._log_dir, f"jaxtrace_{int(time.time())}")
+            jax.profiler.start_trace(jax_dir)
+            self._jax_dir = jax_dir
 
     def stop(self):
         global _recording
         _recording = False
         if self._jax_dir is not None:
-            try:
-                import jax.profiler
-                jax.profiler.stop_trace()
-            # tpu-lint: disable=TPL006 -- stop must mirror the best-effort start: a capture that failed to open raises here, host spans still flush
-            except Exception:
-                pass
+            import jax.profiler
             self._jax_dir = None
+            jax.profiler.stop_trace()
         if self._on_trace_ready:
             self._on_trace_ready(self)
 

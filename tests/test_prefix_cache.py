@@ -122,11 +122,18 @@ def test_cache_match_revives_evictable_page():
 # chunked prefill numerics: q_offset kernel lane + logit parity
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("q_rows", [None, 12], ids=["one_tile", "tiled"])
 @pytest.mark.parametrize("kvh", [2, 1], ids=["gqa", "mqa"])
-def test_paged_prefill_attention_pallas_matches_xla_oracle(kvh):
+def test_paged_prefill_attention_pallas_matches_xla_oracle(kvh, q_rows,
+                                                           monkeypatch):
     """The Pallas chunked-prefill kernel (interpret mode on CPU) agrees with
     the gather oracle, including the causal-at-q_offset mask, GQA/MQA
-    grouping, and padded chunk rows (compared only where valid)."""
+    grouping, and padded chunk rows (compared only where valid).  "tiled"
+    shrinks the VMEM row bound so T=8 splits into three 3-row query tiles:
+    T padded to 9, and slot 1's last tile holds no real row."""
+    if q_rows is not None:
+        from paddle_tpu.incubate.kernels import paged_attention
+        monkeypatch.setattr(paged_attention, "_MAX_Q_ROWS", q_rows)
     rng = np.random.RandomState(0)
     B, T, H, hd, page, P, mp = 2, 8, 4, 64, 8, 9, 4
     q = jnp.asarray(rng.randn(B, T, H, hd), jnp.float32)

@@ -1,0 +1,191 @@
+"""Compile-only checks against a real TPU target, with no TPU.
+
+`jax.experimental.topologies` describes a v5e 2x2 host to the installed
+libtpu, and `jit(f).lower(<ShapeDtypeStructs sharded on its devices>)
+.compile()` then runs Mosaic and the TPU compiler — no device, no memory,
+nothing executed.  That is enough to catch what the virtual CPU mesh cannot:
+a kernel that asks for more VMEM than a core has, a Mosaic call GSPMD is asked
+to partition, a `pallas_call` without `vma` inside `shard_map`.  It is NOT
+evidence that a program runs or is right; `chip_smoke.py` on the chip is.
+
+Shapes are GPT-3 1.3B widths (16 heads x 128, page 16, bf16) — the ones
+`chip_smoke.py` executes; depth is cut to 2 layers (the layer scan compiles
+its body once, so depth does not change what is checked).
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from paddle_tpu.incubate.kernels import flash_attention as FA
+from paddle_tpu.incubate.kernels import paged_attention as PA
+from paddle_tpu.incubate.kernels import rms_norm as RN
+from paddle_tpu.models import gpt as G
+
+H, HD, PAGE, SLOTS, MAX_LEN = 16, 128, 16, 32, 1024
+MAX_PAGES = MAX_LEN // PAGE
+POOL_PAGES = SLOTS * MAX_PAGES // 2 + 1          # the engine's default pool
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or one that cannot describe a v5e
+        pytest.skip(f"cannot build a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture(autouse=True)
+def route_to_kernels(monkeypatch):
+    """The entries route on the DEFAULT backend (CPU under pytest); the
+    programs here are compiled for TPU devices, so force the kernel route."""
+    for mod in (FA, PA, RN):
+        monkeypatch.setattr(mod, "_on_tpu", lambda: True)
+
+
+def _compile(fn, *args, **jit_kw):
+    text = jax.jit(fn, **jit_kw).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.fixture(scope="module")
+def one(topo):
+    return functools.partial(_on, SingleDeviceSharding(topo.devices[0]))
+
+
+def _s(*shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def test_flash_fwd_bwd_and_varlen(one):
+    qkv = one([_s(4, 2048, H, HD)] * 3)
+
+    def loss(q, k, v):
+        return FA.flash_attention_fused(q, k, v, causal=True) \
+            .astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+
+    def vloss(q, k, v, seg):
+        return FA.flash_attention_varlen(q, k, v, seg) \
+            .astype(jnp.float32).sum()
+
+    _compile(jax.grad(vloss, argnums=(0, 1, 2)), *qkv,
+             one(_s(4, 2048, dtype=jnp.int32)))
+
+
+def test_rms_norm(one):
+    _compile(RN.rms_norm_fused, *one([_s(4 * 2048, 2048), _s(2048)]))
+
+
+@pytest.mark.parametrize("T", [5, 256, MAX_LEN],
+                         ids=["verify5", "chunk256", "tail1024"])
+def test_paged_prefill_kernel_any_width(one, T):
+    """VMEM of the chunk/verify kernel must not grow with T: 256 (the
+    README's prefill_chunk) and 1024 (the bucketed engine's prefix-hit tail)
+    asked for 17.6M / 24M of a 16M core before the query rows were tiled."""
+    B = SLOTS if T < MAX_LEN else 1
+    i32 = jnp.int32
+    _compile(PA.paged_serve_attention, *one([
+        _s(B, T, H, HD), _s(POOL_PAGES, PAGE, H, HD),
+        _s(POOL_PAGES, PAGE, H, HD), _s(B, MAX_PAGES, dtype=i32),
+        _s(B, dtype=i32), _s(B, dtype=i32)]))
+
+
+def test_paged_decode_kernel_fp_and_int8(one):
+    i32 = jnp.int32
+    tbl, ln = _s(SLOTS, MAX_PAGES, dtype=i32), _s(SLOTS, dtype=i32)
+    _compile(PA.paged_attention_decode, *one([
+        _s(SLOTS, H, HD), _s(POOL_PAGES, PAGE, H, HD),
+        _s(POOL_PAGES, PAGE, H, HD), tbl, ln]))
+    # int8 pool: the kernel route needs whole (32, 128) int8 tiles -> page 32
+    page, n = 32, MAX_LEN // 32
+    pool = _s(POOL_PAGES, page, H, HD, dtype=jnp.int8)
+    sc = _s(POOL_PAGES, page, H, dtype=jnp.float32)
+    _compile(lambda q, k, v, t, l, ks, vs: PA.paged_attention_decode(
+        q, k, v, t, l, kv_scales=(ks, vs)), *one([
+            _s(SLOTS, H, HD), pool, pool, _s(SLOTS, n, dtype=i32), ln,
+            sc, sc]))
+
+
+def _model(layers=2):
+    cfg = G.GPTConfig(vocab_size=50304, hidden_size=H * HD, num_layers=layers,
+                      num_heads=H, max_seq_len=2048, dtype=BF16)
+    params = jax.eval_shape(functools.partial(G.init_params, cfg),
+                            jax.random.key(0))
+    pool = jax.eval_shape(functools.partial(
+        G.init_paged_cache, cfg, POOL_PAGES, PAGE))
+    return cfg, params, pool
+
+
+def test_fused_serve_step_T5(one):
+    """`serve_step_paged` as the default engine compiles it: 32 slots,
+    spec_len 4 -> T=5, pool donated."""
+    cfg, params, pool = _model()
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    _compile(
+        lambda p, tok, pl_, tbl, qo, vl, k, g: G.serve_step_paged(
+            p, tok, pl_, tbl, qo, vl, cfg, key=k, greedy=g),
+        *one([params, _s(SLOTS, 5, dtype=i32), pool,
+              _s(SLOTS, MAX_PAGES, dtype=i32), _s(SLOTS, dtype=i32),
+              _s(SLOTS, dtype=i32), key, _s(SLOTS, dtype=jnp.bool_)]),
+        donate_argnums=(2,))
+
+
+def test_prefix_hit_tail_program_at_max_model_len(one):
+    """Bucketed mode sends prefix-hit tails through `prefill_chunk_paged` at
+    width max_model_len (engine `_chunk = buckets[-1]`): RESOURCE_EXHAUSTED
+    24.00M > 16.00M at the seed."""
+    cfg, params, pool = _model()
+    i32 = jnp.int32
+    _compile(
+        lambda p, ids, pl_, tbl, qo, vl: G.prefill_chunk_paged(
+            p, ids, cfg, pl_, tbl, qo, vl),
+        *one([params, _s(1, MAX_LEN, dtype=i32), pool,
+              _s(1, MAX_PAGES, dtype=i32), _s(1, dtype=i32),
+              _s(1, dtype=i32)]),
+        donate_argnums=(2,))
+
+
+def test_shard_mapped_kernels_on_four_devices(topo):
+    """Pallas under a mesh: the paged kernel head-sharded over mp=4 (the
+    serving route) and the flash kernel per shard of a dp2 x mp2 step (the
+    trainer's GSPMD route) — `vma` on the out_shapes, every mesh axis manual."""
+    mesh = Mesh(np.array(topo.devices), ("mp",))
+    rep = NamedSharding(mesh, P())
+    heads = lambda nd: NamedSharding(mesh, PA._head_spec(nd))   # noqa: E731
+    pool_sh = NamedSharding(mesh, PA._POOL_SPEC)
+    i32 = jnp.int32
+    _compile(
+        lambda q, k, v, t, qo, vl: PA.paged_serve_attention(
+            q, k, v, t, qo, vl, mesh=mesh),
+        _on(heads(4), _s(SLOTS, 5, H, HD)),
+        _on(pool_sh, _s(POOL_PAGES, PAGE, H, HD)),
+        _on(pool_sh, _s(POOL_PAGES, PAGE, H, HD)),
+        *_on(rep, [_s(SLOTS, MAX_PAGES, dtype=i32), _s(SLOTS, dtype=i32),
+                   _s(SLOTS, dtype=i32)]))
+
+    from paddle_tpu.parallel.hybrid import MeshConfig, _flash_per_shard, \
+        build_mesh
+    cfg, _, _ = _model()
+    mesh = build_mesh(MeshConfig(dp=2, mp=2), topo.devices)
+    attn = _flash_per_shard(mesh, cfg)
+    qkv = _on(NamedSharding(mesh, P(("dp", "sharding", "ep"), None, "mp")),
+              [_s(4, 2048, H, HD)] * 3)
+    _compile(jax.grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+                      argnums=(0, 1, 2)), *qkv)

@@ -168,12 +168,10 @@ def test_jxp006_replicated_ceiling():
 def _toy_psum_target():
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from paddle_tpu.parallel.ring_attention import shard_map_compat
-
     mesh = Mesh(np.array(jax.devices()[:2]), ("mp",))
-    fn = jax.jit(shard_map_compat(lambda x: jax.lax.psum(x, "mp"),
-                                  mesh=mesh, axis_names=("mp",),
-                                  in_specs=(P("mp"),), out_specs=P()))
+    fn = jax.jit(jax.shard_map(lambda x: jax.lax.psum(x, "mp"),
+                               mesh=mesh, axis_names={"mp"},
+                               in_specs=(P("mp"),), out_specs=P()))
     return fn, (jnp.ones((8, 16), jnp.float32),)
 
 
@@ -186,12 +184,14 @@ def test_collective_total_matches_jaxpr():
     fn, args = _toy_psum_target()
     c = program_cost("toy.mp2.x", fn, args, compile_collectives=True)
     # ground truth straight from the traced program
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def psums(j):
         out = []
         for e in j.eqns:
-            if e.primitive.name == "psum":
+            # under shard_map's vma tracking a psum of a varying operand
+            # traces as `psum_invariant`
+            if e.primitive.name == "psum_invariant":
                 out.append(e)
             for v in e.params.values():
                 stack = [v]

@@ -495,9 +495,8 @@ def test_jxp002_donated_persistent_buffer_flagged():
 
 
 def test_jxp003_f64_upcast_flagged():
-    from jax.experimental import enable_x64
     args = (jnp.ones((4,), jnp.float32),)
-    with enable_x64():
+    with jax.enable_x64():
         fs = audit_jaxpr("bad", jax.jit(lambda x: x.astype("float64")), args)
     assert any(f.rule == "JXP003" for f in fs)
     assert audit_jaxpr("good", jax.jit(lambda x: x * 2), args) == []
