@@ -9,9 +9,9 @@ One parse per file builds everything the rules query:
 - a **device-value taint** pass over hot functions: values produced by
   device dispatches (`jnp.*`/`jax.*` calls, `*_fn`/`*_impl` executables) are
   tracked through assignments; scalarizations (`int()`, `.item()`, implicit
-  `bool()`) and bulk fetches (`np.asarray`, `jax.device_get`) of tainted
-  values become sync events, annotated with whether they sit inside a
-  `RecordEvent`/`_span` context;
+  `bool()`) and bulk fetches or waits (`np.asarray`, `jax.device_get`,
+  `jax.block_until_ready`) of tainted values become sync events, annotated
+  with whether they sit inside a `RecordEvent`/`_span` context;
 - every **jit/shard_map call site** (incl. local aliases like the engine's
   `jit_ =` wrapper and `functools.partial(jax.jit, ...)` decorators), with
   the jitted function resolved to its def where possible so donation and
@@ -41,7 +41,8 @@ _DEVICE_CALL_RE = re.compile(
 # calls that fetch a device value to the host (bulk, legitimate, must be
 # spanned) vs. scalarize it (per-element, TPL001)
 _FETCH_FUNCS = frozenset({"np.asarray", "np.array", "numpy.asarray",
-                          "numpy.array", "jax.device_get"})
+                          "numpy.array", "jax.device_get",
+                          "jax.block_until_ready"})
 _SCALARIZE_FUNCS = frozenset({"float", "int", "bool", "complex"})
 # span context managers: entering one of these `with` blocks times the sync
 _SPAN_CALL_RE = re.compile(r"(^|\.)(_span|RecordEvent)$")

@@ -157,6 +157,7 @@ def _flash_fwd_impl(q, k, v, causal, scale):
                                n_k=n_k, causal=causal, scale=scale)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -299,6 +300,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
         causal=causal, scale=scale)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid=(B * H, n_k, n_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),   # q
@@ -329,6 +331,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
         causal=causal, scale=scale)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid=(B * H, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # q
@@ -636,6 +639,7 @@ def _flash_seg_fwd_impl(q, k, v, seg_q, seg_k, causal, scale):
                                scale=scale)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_seg_fwd",
         grid=(B * H, S // block_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -687,6 +691,7 @@ def _flash_seg_bwd_impl(q, k, v, seg_q, seg_k, out, lse, g, causal, scale):
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_seg_dkv_kernel, block_q=block_q,
                           block_k=block_k, n_q=n_q, causal=causal, scale=scale),
+        name="flash_seg_bwd_dkv",
         grid=(B * H, n_k, n_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),
@@ -717,6 +722,7 @@ def _flash_seg_bwd_impl(q, k, v, seg_q, seg_k, out, lse, g, causal, scale):
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_seg_dq_kernel, block_q=block_q,
                           block_k=block_k, n_k=n_k, causal=causal, scale=scale),
+        name="flash_seg_bwd_dq",
         grid=(B * H, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),

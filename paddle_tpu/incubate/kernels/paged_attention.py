@@ -358,6 +358,7 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
     )
     return pl.pallas_call(
         kernel,
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=_out_struct((B, H, hd), q.dtype, q, k_pages, v_pages),
         compiler_params=pltpu.CompilerParams(
@@ -545,6 +546,7 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, page_table, q_offset,
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_prefill",
         grid_spec=grid_spec,
         out_shape=_out_struct((B, n_t * bt, H, hd), q.dtype, q, k_pages,
                               v_pages),

@@ -35,6 +35,7 @@ def _rms_fwd_impl(x2d, w, eps):
     block = max(block, 1)
     return pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
+        name="rms_norm",
         grid=(N // block,),
         in_specs=[pl.BlockSpec((block, D), lambda i: (i, 0)),
                   pl.BlockSpec((D,), lambda i: (0,))],

@@ -191,7 +191,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -253,6 +253,12 @@ class RequestMetrics:
     cached_tokens: int = 0                  # prompt tokens from the prefix cache
     n_generated: int = 0
     preemptions: int = 0                    # times this request was preempted
+    # one (engine clock, tokens) pair each time tokens were appended to the
+    # request: the first where t_first_token is stamped, then one a harvest
+    # (several tokens under speculation) — inter-token latency comes from
+    # here; sum(tokens) == n_generated, bounded by max_new_tokens
+    emit_times: List[Tuple[float, int]] = dataclasses.field(
+        default_factory=list)
 
 
 @dataclasses.dataclass
@@ -334,6 +340,12 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def begin(self):
+        pass
+
+    def end(self):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -345,17 +357,28 @@ _NULL_SPAN = _NullSpan()
 # The fused step (default) dispatches through engine.fused.dispatch; the
 # decode/verify/prefill dispatch spans belong to the legacy fuse=False path
 # (prefill.dispatch also covers the bucketed cold path in fused mode).
+# engine.turnaround (fused mode, see `LLMEngine._turn_begin`) is the host
+# stretch the device waits for between two fused programs; emit, admit,
+# batch.build and fused.dispatch (with fused.h2d, its five puts, inside) tile
+# it.  spec.accept is the legacy verify path's host-side draft acceptance.
+# swap.d2h holds .ready (wait for the gather) and .copy (device -> host).
 ENGINE_SPANS = (
     "engine.step",
+    "engine.turnaround",
+    "engine.emit",
     "engine.admit",
     "engine.prefill.dispatch",
     "engine.spec.propose",
+    "engine.batch.build",
     "engine.fused.dispatch",
+    "engine.fused.h2d",
     "engine.verify.dispatch",
     "engine.spec.accept",
     "engine.decode.dispatch",
     "engine.sample.sync",
     "engine.swap.d2h",
+    "engine.swap.d2h.ready",
+    "engine.swap.d2h.copy",
     "engine.swap.h2d",
 )
 
@@ -802,6 +825,27 @@ class LLMEngine:
             "swapped_pages", "KV pages delivered to the host swap pool")
         self._swap_ms_c = m.counter(
             "swap_ms", "milliseconds spent in swap d2h/h2d copies")
+        # what crossed at the two swap boundaries against what was wanted:
+        # both directions move a max_pages_per_slot-wide buffer whatever the
+        # page count, so moved/useful is the padding's share of the copy
+        self._d2h_fetches = m.counter(
+            "swap_d2h_fetches",
+            "gathered swap/spill buffers fetched to the host")
+        self._d2h_bytes = m.counter(
+            "swap_d2h_bytes", "bytes those fetches moved (the buffers' nbytes)")
+        self._d2h_useful = m.counter(
+            "swap_d2h_useful_bytes",
+            "bytes of the pages those fetches were for (pages x page bytes)")
+        self._h2d_bytes = m.counter(
+            "swap_h2d_bytes",
+            "bytes staged to the device by swap-in/tier-restore scatters")
+        self._h2d_useful = m.counter(
+            "swap_h2d_useful_bytes",
+            "bytes of the pages those scatters restored")
+        self._turnaround_ms_c = m.counter(
+            "turnaround_ms",
+            "host milliseconds between a fused program's result in hand and "
+            "the next fused launch's return (fused mode)")
         self._recomputed_tokens = m.counter(
             "recomputed_tokens",
             "prompt tokens re-prefilled because of preemption")
@@ -957,6 +1001,10 @@ class LLMEngine:
         self._step_idx = 0
         self._step_trace: deque = deque(maxlen=trace_ring)
         self._tracing = False
+        self._turn_t0: Optional[float] = None
+        self._turn_span = _NULL_SPAN
+        self._step_turnaround_s = 0.0
+        self._step_d2h_s = 0.0
 
         sample = bool(temperature and temperature > 0.0)
         self._sample = sample
@@ -1412,6 +1460,41 @@ class LLMEngine:
             return _prof.RecordEvent(name)
         return _NULL_SPAN
 
+    def _step_marker(self):
+        """`StepTraceAnnotation("engine_step")` numbered like the ring record
+        this step will append, so a record joins its device operations in a
+        profiler trace; behind the same gate as `_span`."""
+        if self._tracing or _prof.is_recording():
+            return jax.profiler.StepTraceAnnotation(
+                "engine_step", step_num=self._step_idx + 1)
+        return _NULL_SPAN
+
+    def _turn_begin(self, t: float) -> None:
+        """Open `engine.turnaround`, the host stretch the device waits for
+        (fused mode only): from `t`, the instant the previous fused program's
+        result was in hand — `_harvest`'s device_get has returned — or the
+        step's start when nothing was in flight, to the return of this
+        step's fused launch (`_turn_end`).  Always measured (one more clock
+        read a step) into the ring's `turnaround_ms` and the `turnaround_ms`
+        counter; a span only while something records.  With
+        `double_buffer=False` the harvest follows the launch inside the same
+        step, so the stretch runs from the step's start to the launch and
+        leaves out that harvest's `engine.emit`: the device then waits for
+        turnaround + emit + whatever the caller does between steps."""
+        self._turn_t0 = t
+        self._turn_span = self._span("engine.turnaround")
+        self._turn_span.begin()
+
+    def _turn_end(self, launched: bool) -> None:
+        """Close the stretch.  A step that launched nothing closes the span
+        at its end and adds nothing to ring or counter."""
+        self._turn_span.end()
+        self._turn_span = _NULL_SPAN
+        if launched and self._turn_t0 is not None:
+            self._step_turnaround_s = self._now() - self._turn_t0
+            self._turnaround_ms_c.inc(self._step_turnaround_s * 1e3)
+        self._turn_t0 = None
+
     # ---- per-request tracing ----------------------------------------------
     def _tev(self, rid: int, name: str, **attrs) -> None:
         """Stamp one event on a request's timeline (no-op with tracing off or
@@ -1481,8 +1564,13 @@ class LLMEngine:
         self._step_sync_s = 0.0
         self._step_preempted = 0
         self._step_slots = {"decode": 0, "verify": 0, "chunk": 0}
-        with self._span("engine.step"):
-            self._harvest(finished)     # step n-1's tokens land first
+        self._step_turnaround_s = 0.0
+        self._step_d2h_s = 0.0
+        with self._step_marker(), self._span("engine.step"):
+            if self.fused and self._inflight is None:
+                self._turn_begin(t0)
+            # step n-1's tokens land first
+            self._harvest(finished, turnaround=self.fused)
             if self._has_deadlines:
                 # right after harvest: bookkeeping is exact, nothing in flight
                 self._expire_deadlines(finished)
@@ -1509,6 +1597,7 @@ class LLMEngine:
                 self._step_slots["verify"]
             # deferred swap-out fetches: the d2h lands while the device is
             # busy with the dispatch above, not before it
+            self._turn_end(launched=False)      # no-op after a launch
             if self._pending_d2h:
                 self._drain_swap_d2h()
         dur = self._now() - t0
@@ -1553,6 +1642,10 @@ class LLMEngine:
             # blocking device->host sync time spent inside this step's
             # engine.sample.sync spans (harvest + legacy inline fetches)
             "sync_ms": self._step_sync_s * 1e3,
+            # engine.turnaround of this step (0 when it launched nothing or
+            # in legacy mode) and the swap/spill fetches it drained
+            "turnaround_ms": self._step_turnaround_s * 1e3,
+            "d2h_ms": self._step_d2h_s * 1e3,
             # per-mode slot occupancy of this step's decode-path dispatches
             "slots": dict(self._step_slots),
             # overload lane (v2-additive): victims evicted this step and the
@@ -1611,46 +1704,50 @@ class LLMEngine:
         # optimistic admission: every running slot must own pages for the
         # positions this dispatch writes — growth failures preempt victims
         # out of self._running (and out of drafts) before the batch is built
-        self._grow_running(drafts)
-        if not self._running and chunk_job is None:
-            return                      # everything got preempted this step
-        if self._running:
-            self._decode_iters.inc()
-        tokens = np.zeros((B, T), np.int32)
-        valid = np.ones((B,), np.int32)
-        qoff = np.zeros((B,), np.int32)
-        greedy = np.zeros((B,), bool)
-        table = mgr.page_table.copy()
-        slots: List[int] = []
-        nds: Dict[int, int] = {}
-        chunk_slot = chunk_job["slot"] if chunk_job is not None else None
-        for slot in range(B):
-            seq = self._running.get(slot)
-            if seq is not None:
-                slots.append(slot)
-                tokens[slot, 0] = seq.generated[-1]
-                qoff[slot] = mgr.lengths[slot]
-                greedy[slot] = seq.greedy
-                d = drafts.get(slot)
-                if d is not None:
-                    tokens[slot, 1:1 + d.size] = d
-                    valid[slot] = 1 + d.size
-                    nds[slot] = d.size
-            elif slot == chunk_slot:
-                st = chunk_job["st"]
-                n = chunk_job["n"]
-                q0 = chunk_job["q_offset"]
-                tokens[slot, :n] = st.prompt[q0:q0 + n]
-                valid[slot] = n
-                qoff[slot] = q0
-                greedy[slot] = self._req_greedy(st.request)
-            else:
-                table[slot, :] = 0          # inactive: KV to the null page
+        with self._span("engine.batch.build"):
+            self._grow_running(drafts)
+            if not self._running and chunk_job is None:
+                return                  # everything got preempted this step
+            if self._running:
+                self._decode_iters.inc()
+            tokens = np.zeros((B, T), np.int32)
+            valid = np.ones((B,), np.int32)
+            qoff = np.zeros((B,), np.int32)
+            greedy = np.zeros((B,), bool)
+            table = mgr.page_table.copy()
+            slots: List[int] = []
+            nds: Dict[int, int] = {}
+            chunk_slot = chunk_job["slot"] if chunk_job is not None else None
+            for slot in range(B):
+                seq = self._running.get(slot)
+                if seq is not None:
+                    slots.append(slot)
+                    tokens[slot, 0] = seq.generated[-1]
+                    qoff[slot] = mgr.lengths[slot]
+                    greedy[slot] = seq.greedy
+                    d = drafts.get(slot)
+                    if d is not None:
+                        tokens[slot, 1:1 + d.size] = d
+                        valid[slot] = 1 + d.size
+                        nds[slot] = d.size
+                elif slot == chunk_slot:
+                    st = chunk_job["st"]
+                    n = chunk_job["n"]
+                    q0 = chunk_job["q_offset"]
+                    tokens[slot, :n] = st.prompt[q0:q0 + n]
+                    valid[slot] = n
+                    qoff[slot] = q0
+                    greedy[slot] = self._req_greedy(st.request)
+                else:
+                    table[slot, :] = 0      # inactive: KV to the null page
         with self._span("engine.fused.dispatch"):
+            with self._span("engine.fused.h2d"):
+                tokens, table, qoff, valid, greedy = (
+                    self._h2d(a) for a in (tokens, table, qoff, valid, greedy))
             out, accept, self._pool, self._key = self._decode_fn(
-                self.params, self._h2d(tokens), self._pool,
-                self._h2d(table), self._h2d(qoff), self._h2d(valid),
-                self._key, self._h2d(greedy))
+                self.params, tokens, self._pool, table, qoff, valid,
+                self._key, greedy)
+        self._turn_end(launched=True)
         self._decode_used = True
         self._step_dispatches += 1
         self._step_slots["verify"] += len(nds)
@@ -1670,12 +1767,15 @@ class LLMEngine:
             self._harvest(finished, inflight)
 
     def _harvest(self, finished: List[RequestOutput],
-                 inflight: Optional[Dict[str, object]] = None) -> None:
+                 inflight: Optional[Dict[str, object]] = None,
+                 turnaround: bool = False) -> None:
         """Fetch and apply the result of a fused dispatch: the `[B, T] + [B]`
         int token/accept buffer (the step's ONLY device->host transfer —
         O(B*K) ints, not [B, V] logits).  Emits each running slot's accepted
         prefix + bonus (or its single decode/sampled token), resolves a
-        completed chunk into the decode set, and retires finishers."""
+        completed chunk into the decode set, and retires finishers.
+        `turnaround`: the step-top harvest opens `engine.turnaround` the
+        moment the result is in hand (`_turn_begin`)."""
         inf = inflight if inflight is not None else self._inflight
         if inflight is None:
             self._inflight = None
@@ -1685,9 +1785,12 @@ class LLMEngine:
         with self._span("engine.sample.sync"):
             # blocks on the device result
             out, accept = jax.device_get((inf["out"], inf["accept"]))
-        self._step_sync_s += self._now() - t_sync
+        t_hand = self._now()
+        self._step_sync_s += t_hand - t_sync
+        if turnaround:
+            self._turn_begin(t_hand)
         drafts = inf["drafts"]
-        with self._span("engine.spec.accept"):
+        with self._span("engine.emit"):
             for slot in inf["slots"]:
                 seq = self._running[slot]
                 d = drafts.get(slot)
@@ -1723,6 +1826,7 @@ class LLMEngine:
             emitted = emitted[:emitted.index(self.eos_token_id) + 1]
         self.cache.lengths[slot] += len(emitted)
         seq.generated.extend(emitted)
+        self._stamp_emit(seq.request.request_id, len(emitted))
         self._decode_tokens.inc(len(emitted))
         if nd:
             self._spec_events.inc()
@@ -1745,6 +1849,13 @@ class LLMEngine:
             else:
                 seq.spec_zero_streak = 0
         return self._maybe_finish(seq, finished)
+
+    def _stamp_emit(self, rid: int, n: int, t: Optional[float] = None) -> None:
+        """One `RequestMetrics.emit_times` pair: `n` tokens were appended to
+        request `rid` now (or at `t`, a clock reading the caller holds)."""
+        lc = self._lifecycles.get(rid)
+        if lc is not None and n:
+            lc.emit_times.append((self._now() if t is None else t, n))
 
     # ---- oversubscription: growth, preemption, swap, deadlines ------------
     def _grow_running(self, drafts: Dict[int, np.ndarray]) -> None:
@@ -1859,15 +1970,32 @@ class LLMEngine:
         if rec.get("fetched"):
             return
         self._faults.d2h()
-        t0 = self._now()
-        with self._span("engine.swap.d2h"):
-            rec["data"] = {name: jax.device_get(a)[:, :rec["n"]]
-                           for name, a in rec["data"].items()}
-        self._swap_ms_c.inc((self._now() - t0) * 1e3)
+        data = self._fetch_gathered(rec["data"], rec["n"])
+        rec["data"] = {name: a[:, :rec["n"]] for name, a in data.items()}
         rec["fetched"] = True
         self._swapped_pages_c.inc(rec["n"])
         self._preempt_swaps.inc()
         self._tev(rec["rid"], "swap_out", pages=int(rec["n"]))
+
+    def _fetch_gathered(self, data, n: int) -> Dict[str, np.ndarray]:
+        """The blocking device->host fetch of one `swap_out_pages` buffer
+        (`max_pages_per_slot` pages wide, `n` of them wanted), split where
+        its two causes part: `.ready` waits for the gather and whatever was
+        queued before it, `.copy` moves the bytes.  The same two calls run
+        traced or not.  Counts what crossed against what was wanted."""
+        t0 = self._now()
+        with self._span("engine.swap.d2h"):
+            with self._span("engine.swap.d2h.ready"):
+                jax.block_until_ready(data)
+            with self._span("engine.swap.d2h.copy"):
+                host = jax.device_get(data)
+        dt = self._now() - t0
+        self._swap_ms_c.inc(dt * 1e3)
+        self._step_d2h_s += dt
+        self._d2h_fetches.inc()
+        self._d2h_bytes.inc(sum(a.nbytes for a in host.values()))
+        self._d2h_useful.inc(n * self._kv_page_bytes)
+        return host
 
     def _degrade_to_recompute(self, rec: Dict[str, object]) -> None:
         """A swap whose d2h/h2d copy failed falls back to recompute: drop
@@ -1936,10 +2064,7 @@ class LLMEngine:
         if rec.get("fetched"):
             return
         self._faults.d2h()
-        t0 = self._now()
-        with self._span("engine.swap.d2h"):
-            data = jax.device_get(rec["data"])
-        self._swap_ms_c.inc((self._now() - t0) * 1e3)
+        data = self._fetch_gathered(rec["data"], rec["n"])
         rec["fetched"] = True
         tier = self.cache._tier
         landed = 0
@@ -2013,6 +2138,7 @@ class LLMEngine:
             self._pool = self._swap_in_fn(self._pool, self._h2d(ids), up)
         self._swap_in_used = True
         self._swap_ms_c.inc((self._now() - t0) * 1e3)
+        self._note_h2d(up, k)
         mgr.commit_restore(slot, plan)
         tokens = sum(ntok for _, _, ntok in plan)
         self._tier_restores.inc()
@@ -2020,6 +2146,12 @@ class LLMEngine:
         self._tev(rid, "tier_restore", slot=slot, pages=int(k),
                   tokens=int(tokens))
         return True
+
+    def _note_h2d(self, staged, n: int) -> None:
+        """Count one swap-in/tier-restore scatter: the staged buffers' bytes
+        against the bytes of the `n` pages it was for."""
+        self._h2d_bytes.inc(sum(a.nbytes for a in staged.values()))
+        self._h2d_useful.inc(n * self._kv_page_bytes)
 
     def export_prefix(self, tokens: np.ndarray,
                       rid: Optional[int] = None) -> Dict[str, int]:
@@ -2152,6 +2284,7 @@ class LLMEngine:
             self._pool = self._swap_in_fn(self._pool, self._h2d(ids), data)
         self._swap_in_used = True
         self._swap_ms_c.inc((self._now() - t0) * 1e3)
+        self._note_h2d(data, n)
         mgr.note_swap_in(rid)
         self._preempted.pop(rid)
         self._tev(rid, "swap_in", slot=slot, pages=int(n))
@@ -2428,6 +2561,7 @@ class LLMEngine:
             self._h_ttft.observe(ttft,
                                  exemplar=self._exemplar(req.request_id))
             self._tev(req.request_id, "first_token")
+        self._stamp_emit(req.request_id, 1, now)
         seq = _Running(req, slot, generated, cached, ttft,
                        self._req_greedy(req))
         seq.spec_off = spec_off
@@ -2585,6 +2719,7 @@ class LLMEngine:
             seq = self._running[slot]
             mgr.lengths[slot] += 1          # the token we just fed is cached
             seq.generated.append(int(nxt[slot]))
+            self._stamp_emit(seq.request.request_id, 1)
             if self._maybe_finish(seq, finished):
                 del self._running[slot]
 
@@ -3132,6 +3267,15 @@ class LLMEngine:
             "preempt_recomputes": self._preempt_recomputes.value,
             "swapped_pages": self._swapped_pages_c.value,
             "swap_ms": self._swap_ms_c.value,
+            # the two swap boundaries: buffers fetched, bytes that crossed
+            # and bytes of the pages they were for; and the host's turnaround
+            # between fused programs, summed (turnaround_ms / engine_steps)
+            "swap_d2h_fetches": self._d2h_fetches.value,
+            "swap_d2h_bytes": self._d2h_bytes.value,
+            "swap_d2h_useful_bytes": self._d2h_useful.value,
+            "swap_h2d_bytes": self._h2d_bytes.value,
+            "swap_h2d_useful_bytes": self._h2d_useful.value,
+            "turnaround_ms": self._turnaround_ms_c.value,
             "recomputed_tokens": self._recomputed_tokens.value,
             "timeouts": self._timeouts.value,
             "rejected_requests": self._rejected_requests.value,
@@ -3224,8 +3368,9 @@ class LLMEngine:
         """Per-request state map for the debug bundle: every live request
         (queued — including preempted/swapped resumes waiting at the head —
         prefilling, running) plus the last `finished_limit` retired ones,
-        each with its scheduler coordinates and its trace timeline (empty
-        with tracing off).  Keys are request-id strings (JSON object keys)."""
+        each with its scheduler coordinates, its trace timeline (empty
+        with tracing off) and, once decoding, its `emit_times` stamps.  Keys
+        are request-id strings (JSON object keys)."""
         def base(req, state, **extra):
             tr = self._trace_for(req.request_id)
             d = {"state": state, "prompt_len": int(req.prompt.size),
@@ -3235,6 +3380,9 @@ class LLMEngine:
                  "events": list(tr.events) if tr is not None else []}
             d.update(extra)
             return d
+
+        def emits(lc):
+            return [] if lc is None else [list(p) for p in lc.emit_times]
 
         out: Dict[str, Dict[str, object]] = {}
         # snapshot the live containers: an obs-server handler thread walks
@@ -3255,7 +3403,8 @@ class LLMEngine:
                 seq.request, "running", slot=slot,
                 n_generated=len(seq.generated),
                 kv_len=int(self.cache.lengths[slot]),
-                spec_off=seq.spec_off)
+                spec_off=seq.spec_off, emit_times=emits(
+                    self._lifecycles.get(seq.request.request_id)))
         # last-N retired requests WITHOUT materializing the all-time output
         # ledger (unbounded on a long-running server): walk the insertion
         # order backwards, then flip to oldest-first
@@ -3267,6 +3416,7 @@ class LLMEngine:
                 "prompt_len": int(np.asarray(o.prompt).size),
                 "n_generated": len(o.token_ids),
                 "cached_tokens": int(o.cached_tokens),
+                "emit_times": emits(o.metrics),
                 "events": list(o.trace.events) if o.trace is not None else [],
             }
         return out

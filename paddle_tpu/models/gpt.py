@@ -1153,20 +1153,27 @@ def _paged_chunk_hidden(params, input_ids, config: GPTConfig, cache,
     off = pos % page
 
     def layer(x, layer_in):
+        # the named scopes are metadata for a profiler trace (kv_write: the
+        # pool update; attn: qkv projection + paged attention; mlp: the
+        # block's tail, out-projection and FFN); the program is unchanged
         bp, kv = layer_in
-        q, k, v = _prefill_qkv(bp, x, c, pos=pos, parts=parts)
-        if pin:
-            q, k, v = pin(q, "heads"), pin(k, "heads"), pin(v, "heads")
-        if quant:
-            k, ks = _quantize_kv(k)
-            v, vs = _quantize_kv(v)
-            kv = dict(kv, k_scale=kv["k_scale"].at[pidx, off].set(ks),
-                      v_scale=kv["v_scale"].at[pidx, off].set(vs))
-        kv = dict(kv, k=kv["k"].at[pidx, off].set(k),   # token-granular write
-                  v=kv["v"].at[pidx, off].set(v))
-        attn = attn_fn(q, kv["k"], kv["v"], page_table, q_offset, valid,
-                       mesh=mesh, kv_scales=_kv_scales(kv))
-        x = _layer_tail(bp, x, attn.reshape(B, C, D), c, pin)
+        with jax.named_scope("attn"):
+            q, k, v = _prefill_qkv(bp, x, c, pos=pos, parts=parts)
+            if pin:
+                q, k, v = pin(q, "heads"), pin(k, "heads"), pin(v, "heads")
+        with jax.named_scope("kv_write"):
+            if quant:
+                k, ks = _quantize_kv(k)
+                v, vs = _quantize_kv(v)
+                kv = dict(kv, k_scale=kv["k_scale"].at[pidx, off].set(ks),
+                          v_scale=kv["v_scale"].at[pidx, off].set(vs))
+            kv = dict(kv, k=kv["k"].at[pidx, off].set(k),   # token-granular
+                      v=kv["v"].at[pidx, off].set(v))
+        with jax.named_scope("attn"):
+            attn = attn_fn(q, kv["k"], kv["v"], page_table, q_offset, valid,
+                           mesh=mesh, kv_scales=_kv_scales(kv))
+        with jax.named_scope("mlp"):
+            x = _layer_tail(bp, x, attn.reshape(B, C, D), c, pin)
         return x, kv
 
     x, new_cache = jax.lax.scan(
@@ -1271,9 +1278,10 @@ def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
                                    page_table, q_offset, valid,
                                    attn_entry=paged_serve_attention,
                                    mesh=mesh)
-    x = epilogue(params, x, config)
-    logits = head_logits(x, params, config, mesh=mesh)  # [B, T, V] (V/mp ea.)
-    out = sharded_argmax(logits, mesh)                        # [B, T]
+    with jax.named_scope("head"):
+        x = epilogue(params, x, config)
+        logits = head_logits(x, params, config, mesh=mesh)  # [B,T,V] (V/mp ea.)
+        out = sharded_argmax(logits, mesh)                    # [B, T]
     B, T = tokens.shape
     rows = jnp.arange(B)
     if sample:

@@ -21,6 +21,7 @@ sharding optimizer):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Optional
@@ -31,6 +32,18 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import gpt as gpt_mod
+from ..profiler import profiler as _prof
+
+# Host spans of `HybridParallelTrainer.train_step`, recorded through
+# `profiler.RecordEvent` only while a Profiler records (the engine's
+# `ENGINE_SPANS` gate): placing the batch, and the call that launches the step
+# program (it returns before the device finishes).
+TRAINER_SPANS = ("trainer.shard_batch", "trainer.dispatch")
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _no_span(name: str):
+    return _NO_SPAN
 
 
 @dataclasses.dataclass
@@ -720,6 +733,7 @@ class HybridParallelTrainer:
         self.opt_state = init_opt(self.params)
         self._step_fn = self._build_step()
         self._eval_fn = None    # built lazily on first eval_loss
+        self.steps_dispatched = 0   # train_step calls; numbers the step marker
 
     # ---- sharding constraint hook handed to the model ----
     def _mp_constraint(self, x, kind):
@@ -766,7 +780,18 @@ class HybridParallelTrainer:
                                    attn_impl=attn_impl)
 
         def step(params, opt_state, tokens, labels):
-            loss, grads = jax.value_and_grad(loss_of)(params, tokens, labels)
+            # named scopes are metadata: fwd / bwd / opt prefix the
+            # operations' names in a profiler trace, the program is the same
+            # as jax.value_and_grad(loss_of)'s
+            with jax.named_scope("fwd"):
+                loss, pullback = jax.vjp(
+                    lambda p: loss_of(p, tokens, labels), params)
+            with jax.named_scope("bwd"):
+                grads, = pullback(jnp.ones_like(loss))
+            with jax.named_scope("opt"):
+                return (loss,) + update(params, opt_state, grads)
+
+        def update(params, opt_state, grads):
             if cfg.sharding_stage >= 2 and cfg.zero_axis is not None:
                 # ZeRO-2: pin grads to the moment layout so XLA reduce-scatters
                 # them over the zero axis instead of all-reducing full grads
@@ -801,7 +826,7 @@ class HybridParallelTrainer:
                                            is_leaf=lambda x: isinstance(x, tuple))
             new_v = jax.tree_util.tree_map(lambda t: t[2], out,
                                            is_leaf=lambda x: isinstance(x, tuple))
-            return loss, new_params, {"m": new_m, "v": new_v, "step": stepno}
+            return new_params, {"m": new_m, "v": new_v, "step": stepno}
 
         # batch splits over dp AND sharding AND ep: the zero group is a
         # data-parallel group with sharded states, and ep ranks each own a batch
@@ -822,9 +847,23 @@ class HybridParallelTrainer:
                 jax.device_put(jnp.asarray(labels), ds))
 
     def train_step(self, tokens, labels):
-        tokens, labels = self.shard_batch(tokens, labels)
-        loss, self.params, self.opt_state = self._step_fn(
-            self.params, self.opt_state, tokens, labels)
+        """One optimizer step; returns the loss un-synced.  While a Profiler
+        records, the call sits in a `StepTraceAnnotation("train_step")`
+        numbered by `steps_dispatched` and its two host phases are
+        `TRAINER_SPANS`; otherwise it pays one flag read."""
+        self.steps_dispatched += 1
+        if _prof.is_recording():
+            span = _prof.RecordEvent
+            mark = jax.profiler.StepTraceAnnotation(
+                "train_step", step_num=self.steps_dispatched)
+        else:
+            span, mark = _no_span, _NO_SPAN
+        with mark:
+            with span("trainer.shard_batch"):
+                tokens, labels = self.shard_batch(tokens, labels)
+            with span("trainer.dispatch"):
+                loss, self.params, self.opt_state = self._step_fn(
+                    self.params, self.opt_state, tokens, labels)
         return loss
 
     def eval_loss(self, tokens, labels):

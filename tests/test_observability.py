@@ -315,10 +315,13 @@ def test_chrome_trace_and_step_timeline(spec_eng, tmp_path):
     host = json.loads((td / "host_trace.json").read_text())
     names = {e["name"] for e in host["traceEvents"]}
     # fused engine (default): the one-dispatch step emits the fused span in
-    # place of the legacy verify/decode/chunk dispatch spans
-    assert {"engine.step", "engine.admit", "engine.fused.dispatch",
-            "engine.spec.propose", "engine.spec.accept",
-            "engine.sample.sync"} <= names
+    # place of the legacy verify/decode/chunk dispatch spans; acceptance is
+    # on the device, so the harvest's host loop is engine.emit (spec.accept
+    # belongs to the legacy verify path)
+    assert {"engine.step", "engine.turnaround", "engine.emit", "engine.admit",
+            "engine.batch.build", "engine.fused.dispatch", "engine.fused.h2d",
+            "engine.spec.propose", "engine.sample.sync"} <= names
+    assert "engine.spec.accept" not in names
     assert names <= set(ENGINE_SPANS)
     for e in host["traceEvents"]:
         assert e["ph"] == "X" and e["dur"] >= 0
@@ -327,7 +330,8 @@ def test_chrome_trace_and_step_timeline(spec_eng, tmp_path):
     for key in ("decode_batch", "chunk", "verify_dispatches",
                 "tokens_emitted", "pages_in_use", "pages_free",
                 "pages_evictable", "queued", "running", "prefilling",
-                "v", "fused", "dispatches", "sync_ms", "slots"):
+                "v", "fused", "dispatches", "sync_ms", "slots",
+                "turnaround_ms", "d2h_ms"):
         assert key in timeline[-1]
     assert any(r["tokens_emitted"] > 0 for r in timeline)
     snap = json.loads((td / "metrics.json").read_text())
@@ -433,6 +437,11 @@ NEW_STATS_KEYS = frozenset({
     # added by the KV tiering PR: per-tier occupancy + spill/restore traffic
     # + the rolling-hash partial-index hit counter
     "kv_tier",
+}) | frozenset({
+    # added by the tracing PR (ISSUE 26): what crossed at the two swap
+    # boundaries against what was wanted, and the host turnaround
+    "swap_d2h_fetches", "swap_d2h_bytes", "swap_d2h_useful_bytes",
+    "swap_h2d_bytes", "swap_h2d_useful_bytes", "turnaround_ms",
 }) | frozenset({
     # added by the disaggregated-serving PR: the engine's fleet role
     # (None / "prefill" / "decode") so health and routing can label it

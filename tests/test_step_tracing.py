@@ -1,0 +1,308 @@
+"""The serving step measured from inside (ISSUE 26): `engine.turnaround` and
+the spans that tile it, the split swap fetch with its byte counters, a stamp
+per emission, and the trainer's spans and step marker — all through the one
+recorder (`profiler.RecordEvent` behind the `is_recording()` gate).  CPU, tiny
+engine; an auto-ticking clock where exact sums are asserted."""
+import numpy as np
+import pytest
+
+import jax
+
+from paddle_tpu.inference import engine as E
+from paddle_tpu.inference.engine import ENGINE_SPANS, LLMEngine
+from paddle_tpu.models import gpt as G
+from paddle_tpu.parallel import HybridParallelTrainer, MeshConfig
+from paddle_tpu.parallel.hybrid import TRAINER_SPANS
+from paddle_tpu.profiler import profiler as prof
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = G.gpt_tiny(64)
+    return cfg, G.init_params(cfg, jax.random.key(0))
+
+
+class TickClock:
+    """Every reading is 1 ms after the last: durations count clock reads."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1e-3
+        return self.t
+
+
+def engine(tiny, **kw):
+    cfg, params = tiny
+    base = dict(num_slots=2, page_size=8, num_pages=9, max_model_len=64,
+                prefill_chunk=16, seed=3, swap_pool_pages=64)
+    base.update(kw)
+    return LLMEngine(params, cfg, **base)
+
+
+def session_stream(eng, churn=6):
+    """A session's first turn, distinct prompts that evict (and spill) its
+    pages, then the returning turn, which restores them from the host tier."""
+    rng = np.random.RandomState(7)
+    V = eng.config.vocab_size
+    shared = rng.randint(0, V, (20,)).astype(np.int32)
+    r1 = eng.add_request(shared, max_new_tokens=5)
+    outs = dict(eng.run())
+    for _ in range(churn):
+        eng.add_request(rng.randint(0, V, (30,)).astype(np.int32),
+                        max_new_tokens=4)
+    outs.update(eng.run())
+    eng.add_request(np.concatenate(
+        [shared, np.asarray(outs[r1].token_ids, np.int32),
+         rng.randint(0, V, (4,)).astype(np.int32)]), max_new_tokens=5)
+    outs.update(eng.run())
+    return outs
+
+
+# ---------------------------------------------------------------------------
+# spans nest and tile
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def recorded(tiny):
+    """[(name, start, end)] of one spilling, restoring stream under a
+    host-only Profiler."""
+    eng = engine(tiny)
+    with prof.Profiler(timer_only=True):
+        session_stream(eng)
+    assert eng.stats()["kv_tier"]["spills"] > 0
+    return [(e.name, e.start, e.end) for e in prof._events]
+
+
+def inside(child, parents):
+    return any(p[1] <= child[1] and child[2] <= p[2] for p in parents)
+
+
+@pytest.mark.parametrize("parent,child", [
+    ("engine.step", "engine.turnaround"),
+    ("engine.turnaround", "engine.emit"),
+    ("engine.turnaround", "engine.admit"),
+    ("engine.turnaround", "engine.batch.build"),
+    ("engine.turnaround", "engine.fused.dispatch"),
+    ("engine.fused.dispatch", "engine.fused.h2d"),
+    ("engine.swap.d2h", "engine.swap.d2h.ready"),
+    ("engine.swap.d2h", "engine.swap.d2h.copy"),
+])
+def test_spans_nest(recorded, parent, child):
+    parents = [e for e in recorded if e[0] == parent]
+    children = [e for e in recorded if e[0] == child]
+    assert parents and children
+    if child == "engine.admit":
+        # admission also runs in steps that launch nothing
+        children = [c for c in children
+                    if inside(c, [e for e in recorded
+                                  if e[0] == "engine.turnaround"])]
+        assert children
+    assert all(inside(c, parents) for c in children)
+
+
+def test_spans_tile_the_turnaround_and_are_all_named(recorded):
+    names = {e[0] for e in recorded}
+    assert names <= set(ENGINE_SPANS)
+    assert "engine.spec.accept" not in names       # speculation is off
+    tiles = ("engine.emit", "engine.admit", "engine.spec.propose",
+             "engine.batch.build", "engine.fused.dispatch")
+    turns = [e for e in recorded if e[0] == "engine.turnaround"]
+    launched = 0
+    for t in turns:
+        kids = sorted((e for e in recorded if e[0] in tiles and inside(e, [t])),
+                      key=lambda e: e[1])
+        for a, b in zip(kids, kids[1:]):
+            assert a[2] <= b[1]                     # side by side, no overlap
+        assert sum(e[2] - e[1] for e in kids) <= t[2] - t[1]
+        if any(e[0] == "engine.fused.dispatch" for e in kids):
+            launched += 1
+            assert kids[-1][0] == "engine.fused.dispatch"
+            # the stretch ends with the launch's return
+            assert t[2] - kids[-1][2] < 0.2 * (t[2] - t[1]) + 50_000
+    assert launched > 0
+    # one fetch's two halves fill its parent
+    for d in (e for e in recorded if e[0] == "engine.swap.d2h"):
+        halves = [e for e in recorded if e[0].startswith("engine.swap.d2h.")
+                  and inside(e, [d])]
+        assert sorted(e[0] for e in halves) == ["engine.swap.d2h.copy",
+                                                "engine.swap.d2h.ready"]
+
+
+# ---------------------------------------------------------------------------
+# nothing is built when nothing records
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ENGINE_SPANS)
+def test_span_is_the_null_span_when_nothing_records(tiny, name):
+    eng = engine(tiny)
+    assert not prof.is_recording()
+    assert eng._span(name) is E._NULL_SPAN
+    assert eng._step_marker() is E._NULL_SPAN
+
+
+def test_no_span_object_is_built_in_a_step_when_nothing_records(
+        tiny, monkeypatch):
+    def refuse(*a, **k):
+        raise AssertionError("a span was built with nothing recording")
+    monkeypatch.setattr(prof, "RecordEvent", refuse)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", refuse)
+    eng = engine(tiny)
+    outs = session_stream(eng)
+    assert eng._turn_span is E._NULL_SPAN and len(outs) == 8
+    assert eng.stats()["swap_d2h_fetches"] > 0      # the fetch path ran
+
+
+# ---------------------------------------------------------------------------
+# ring records and counters
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("mode", [
+    dict(), dict(double_buffer=False), dict(fuse=False),
+    dict(spec_len=3), dict(prefill_chunk=None)])
+def test_ring_carries_turnaround_and_d2h(tiny, mode):
+    eng = engine(tiny, clock=TickClock(), **mode)
+    session_stream(eng)
+    ring = eng.step_trace()
+    assert ring
+    for r in ring:
+        assert r["turnaround_ms"] >= 0.0 and r["d2h_ms"] >= 0.0
+        assert r["turnaround_ms"] + r["sync_ms"] <= 1e3 * r["dur_s"] + 1e-6
+        if not eng.fused or not r["dispatches"]:
+            assert r["turnaround_ms"] == 0.0
+    st = eng.stats()
+    assert st["turnaround_ms"] == pytest.approx(
+        sum(r["turnaround_ms"] for r in ring))
+    assert st["turnaround_ms"] == pytest.approx(
+        eng.metrics.snapshot()["counters"]["turnaround_ms"])
+    launched = [r for r in ring if r["decode_batch"] or r["slots"]["chunk"]]
+    if eng.fused:
+        assert launched and all(r["turnaround_ms"] > 0 for r in ring
+                                if r["slots"]["decode"] or
+                                r["slots"]["verify"])
+    else:
+        assert st["turnaround_ms"] == 0.0           # legacy: no fused launch
+    # the fetches' time lands in the step that drained them
+    assert st["swap_d2h_fetches"] > 0
+    assert sum(r["d2h_ms"] for r in ring) > 0
+    assert sum(r["d2h_ms"] for r in ring) <= st["swap_ms"] + 1e-6
+
+
+@pytest.mark.parametrize("direction", ["d2h", "h2d"])
+def test_swap_bytes_match_the_shapes(tiny, direction):
+    eng = engine(tiny)
+    session_stream(eng)
+    st, cfg, mgr = eng.stats(), eng.config, eng.cache
+    page_bytes = 2 * cfg.num_layers * mgr.page_size * cfg.kv_heads * \
+        cfg.head_dim * np.dtype(cfg.dtype).itemsize
+    buffer_bytes = mgr.max_pages_per_slot * page_bytes
+    if direction == "d2h":
+        moved, useful = st["swap_d2h_bytes"], st["swap_d2h_useful_bytes"]
+        calls = st["swap_d2h_fetches"]
+        assert useful >= st["kv_tier"]["spills"] * page_bytes
+    else:
+        moved, useful = st["swap_h2d_bytes"], st["swap_h2d_useful_bytes"]
+        calls = st["kv_tier"]["restores"]
+    assert calls > 0
+    assert moved == calls * buffer_bytes
+    assert moved >= useful > 0 and useful % page_bytes == 0
+    assert useful <= calls * buffer_bytes
+    snap = eng.metrics.snapshot()["counters"]
+    assert snap[f"swap_{direction}_bytes"] == moved
+    assert snap[f"swap_{direction}_useful_bytes"] == useful
+
+
+# ---------------------------------------------------------------------------
+# a stamp per emission
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("mode", [
+    dict(), dict(double_buffer=False), dict(fuse=False),
+    dict(prefill_chunk=None), dict(spec_len=3),
+    dict(spec_len=3, fuse=False), dict(num_pages=7, admission="optimistic")])
+def test_emit_times_account_for_every_token(tiny, mode):
+    eng = engine(tiny, clock=TickClock(), **mode)
+    if eng.spec_len:
+        # a repeating prompt, so that the n-gram proposer's drafts land
+        rep = np.tile(np.arange(6, dtype=np.int32), 5)
+        eng.add_request(rep, max_new_tokens=12)
+    outs = session_stream(eng)
+    multi = 0
+    for out in outs.values():
+        m = out.metrics
+        stamps = m.emit_times
+        assert sum(n for _, n in stamps) == m.n_generated == len(out.token_ids)
+        times = [t for t, _ in stamps]
+        assert times == sorted(times)
+        assert stamps[0] == (m.t_first_token, 1)
+        assert len(stamps) <= len(out.token_ids)
+        assert times[-1] <= m.t_finish
+        multi += sum(n > 1 for _, n in stamps)
+    if eng.spec_len:
+        assert eng.stats()["spec_accepted_tokens"] > 0 and multi > 0
+    else:
+        assert multi == 0                       # one token a harvest
+    # the postmortem bundle is the stamps' reader today
+    states = eng.debug_bundle()["requests"]
+    rid, out = next(iter(outs.items()))
+    assert states[str(rid)]["emit_times"] == [list(p) for p in
+                                              out.metrics.emit_times]
+
+
+# ---------------------------------------------------------------------------
+# the trainer's spans and the step markers
+# ---------------------------------------------------------------------------
+
+class Marks(list):
+    """Stands in for jax.profiler.StepTraceAnnotation: records its calls."""
+
+    def __call__(self, name, **kw):
+        self.append((name, kw))
+        return E._NULL_SPAN
+
+
+@pytest.fixture(scope="module")
+def trainer(tiny):
+    cfg, _ = tiny
+    return HybridParallelTrainer(cfg, MeshConfig(), seed=0,
+                                 devices=jax.devices()[:1])
+
+
+@pytest.mark.parametrize("recording", [True, False])
+def test_trainer_spans_and_step_marker(trainer, monkeypatch, recording):
+    marks = Marks()
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", marks)
+    tok = np.zeros((2, 16), np.int32)
+    n0 = trainer.steps_dispatched
+    if recording:
+        with prof.Profiler(timer_only=True):
+            losses = [trainer.train_step(tok, tok) for _ in range(2)]
+        names = [e.name for e in prof._events]
+        assert names == list(TRAINER_SPANS) * 2
+        assert marks == [("train_step", {"step_num": n0 + 1}),
+                         ("train_step", {"step_num": n0 + 2})]
+    else:
+        before = len(prof._events)
+        losses = [trainer.train_step(tok, tok) for _ in range(2)]
+        assert marks == [] and len(prof._events) == before
+    assert trainer.steps_dispatched == n0 + 2
+    assert all(np.isfinite(float(l)) for l in losses)
+
+
+@pytest.mark.parametrize("recording", [True, False])
+def test_engine_step_marker_numbers_the_ring_record(tiny, monkeypatch,
+                                                    recording):
+    marks = Marks()
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", marks)
+    eng = engine(tiny)
+    eng.add_request(np.arange(5, dtype=np.int32), max_new_tokens=3)
+    if recording:
+        with prof.Profiler(timer_only=True):
+            eng.run()
+        assert [kw["step_num"] for _, kw in marks] == \
+            [r["step"] for r in eng.step_trace()]
+        assert {name for name, _ in marks} == {"engine_step"}
+    else:
+        eng.run()
+        assert marks == []

@@ -241,27 +241,33 @@ def test_tpl004_silent_on_static_tests(tmp_path):
 # TPL005 — blocking fetch outside a RecordEvent span
 # ---------------------------------------------------------------------------
 
-def test_tpl005_flags_unspanned_fetch(tmp_path):
-    fs = lint_snippet(tmp_path, """
+@pytest.mark.parametrize("fetch", ["np.asarray(out)",
+                                   "jax.block_until_ready(out)"])
+def test_tpl005_flags_unspanned_fetch(tmp_path, fetch):
+    fs = lint_snippet(tmp_path, f"""
+        import jax
         import numpy as np
 
         class Engine:
             def step(self):
                 out = self._decode_fn(1)
-                return np.asarray(out)          # untimed blocking fetch
+                return {fetch}                  # untimed blocking fetch/wait
     """, rule="TPL005")
     assert len(fs) == 1 and "RecordEvent" in fs[0].message
 
 
-def test_tpl005_silent_inside_span(tmp_path):
-    fs = lint_snippet(tmp_path, """
+@pytest.mark.parametrize("fetch", ["np.asarray(out)",
+                                   "jax.block_until_ready(out)"])
+def test_tpl005_silent_inside_span(tmp_path, fetch):
+    fs = lint_snippet(tmp_path, f"""
+        import jax
         import numpy as np
 
         class Engine:
             def step(self):
                 out = self._decode_fn(1)
-                with self._span("engine.sample.sync"):
-                    return np.asarray(out)
+                with self._span("engine.swap.d2h.ready"):
+                    return {fetch}
     """, rule="TPL005")
     assert fs == []
 

@@ -99,6 +99,10 @@ REQUIRED_STATS_KEYS = frozenset({
     # KV tiering PR (ISSUE 15): per-tier occupancy + spill/restore traffic
     # + the rolling-hash partial-index hit counter
     "kv_tier",
+    # tracing PR (ISSUE 26): bytes moved against bytes wanted at the two
+    # swap boundaries, and the host turnaround between fused programs
+    "swap_d2h_fetches", "swap_d2h_bytes", "swap_d2h_useful_bytes",
+    "swap_h2d_bytes", "swap_h2d_useful_bytes", "turnaround_ms",
 })
 REQUIRED_KV_TIER_KEYS = frozenset({
     "enabled", "spill_dir", "pages_host", "pages_disk", "spills",
@@ -142,6 +146,17 @@ REQUIRED_COUNTERS = frozenset({
     "partial_page_hits",
     # disaggregated serving PR: prefill->decode handoffs through the store
     "kv_handoff_exports", "kv_handoff_pages", "kv_handoff_tokens",
+    # tracing PR: the swap boundaries' byte account + host turnaround
+    "swap_d2h_fetches", "swap_d2h_bytes", "swap_d2h_useful_bytes",
+    "swap_h2d_bytes", "swap_h2d_useful_bytes", "turnaround_ms",
+})
+# the v2 step-ring record (`step_trace()`, /debug's "step_trace")
+REQUIRED_STEP_RECORD_KEYS = frozenset({
+    "v", "step", "t", "dur_s", "queued", "prefilling", "running",
+    "decode_batch", "chunk", "verify_dispatches", "tokens_emitted",
+    "finished", "pages_in_use", "pages_free", "pages_evictable", "fused",
+    "dispatches", "sync_ms", "turnaround_ms", "d2h_ms", "slots", "preempted",
+    "pool_pressure",
 })
 REQUIRED_DEBUG_BUNDLE_KEYS = frozenset({
     "version", "t", "engine", "pool", "requests", "step_trace", "stats",
@@ -530,6 +545,11 @@ def check_obs_server(eng, rid, errors):
         missing = REQUIRED_DEBUG_BUNDLE_KEYS - set(bundle)
         if status != 200 or missing:
             errors.append(f"/debug -> {status}, missing {sorted(missing)}")
+        ring = bundle.get("step_trace") or [{}]
+        missing = REQUIRED_STEP_RECORD_KEYS - set(ring[-1])
+        if missing:
+            errors.append(f"/debug step_trace record missing "
+                          f"{sorted(missing)}")
         # /healthz is the REAL health evaluation now: a structured state
         # with per-signal detail, never the old hardcoded {"ok": true}
         status, text = get(srv, "/healthz")
