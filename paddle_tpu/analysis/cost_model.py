@@ -142,6 +142,19 @@ def _prod(xs: Iterable[int]) -> int:
     return out
 
 
+# Primitives XLA runs in place on operand 0 when nothing else reads it
+# afterwards, and the position of the update they write into it.
+_UPDATE_OPERAND = {"scatter": 2, "scatter-add": 2, "dynamic_update_slice": 1}
+# ... and the ones whose output is the operand's bytes under another shape
+_VIEWS = ("reshape",)
+
+
+def _carry_slice(eqn) -> slice:
+    """Where a scan eqn's carries sit among its invars (and its body's)."""
+    nc = eqn.params["num_consts"]
+    return slice(nc, nc + eqn.params["num_carry"])
+
+
 def eqn_flops(eqn) -> int:
     """Analytic flop count of one (leaf) eqn: dot_general exact from its
     dimension numbers, everything else one op per output element — the
@@ -155,8 +168,14 @@ def eqn_flops(eqn) -> int:
         m = _prod(d for i, d in enumerate(lhs) if i not in lc and i not in lb)
         n = _prod(d for i, d in enumerate(rhs) if i not in rc and i not in rb)
         return 2 * batch * m * n * contract
+    if eqn.primitive.name in _VIEWS:
+        return 0
+    # an in-place update's work is the update, not the buffer it lands in
+    # (the serving passes scatter a few tokens into the whole page pool)
+    upd = _UPDATE_OPERAND.get(eqn.primitive.name)
+    vs = [eqn.invars[upd]] if upd is not None else eqn.outvars
     return sum(aval_bytes(v.aval) // max(_itemsize(v.aval), 1)
-               for v in eqn.outvars if hasattr(v, "aval"))
+               for v in vs if hasattr(v, "aval"))
 
 
 def _itemsize(aval) -> int:
@@ -196,13 +215,24 @@ def _sub_jaxprs(eqn) -> List[Tuple[object, int]]:
 # ---------------------------------------------------------------------------
 
 
-def _jaxpr_walk(jaxpr, aliased_outs) -> Tuple[int, int, str]:
+def _jaxpr_walk(jaxpr, aliased_outs, writable=frozenset()
+                ) -> Tuple[int, int, str]:
     """(flops, live-byte peak of body-DEFINED values, label of the peak eqn)
     for one jaxpr.  Invars are excluded (the caller accounts them as
     argument bytes); outvars are included from their defining eqn to the end
     — except `aliased_outs`, which write into a donated input buffer and
     allocate nothing.  Higher-order eqns recurse: their body's peak rides on
-    top of the outer live set at that program point."""
+    top of the outer live set at that program point.
+
+    In-place updates allocate nothing: a scatter / dynamic_update_slice or a
+    reshape whose operand 0 is read for the last time by that eqn hands the
+    operand's buffer to its output, and so does a scan for each carry.  The
+    operand has to be the program's to overwrite: defined in this jaxpr, or
+    one of `writable` (its donated arguments; in a scan's body, the
+    carries).  That is how the paged passes hold the page pool — one donated
+    buffer, reshaped, carried through the layer scan and scattered into —
+    and a pool that is copied instead (not donated, read again after the
+    update) is priced at its full size, as before."""
     from jax.extend.core import Literal
 
     eqns = list(jaxpr.eqns)
@@ -220,6 +250,21 @@ def _jaxpr_walk(jaxpr, aliased_outs) -> Tuple[int, int, str]:
     peak = 0
     peak_at = ""
     sizes: Dict[object, int] = {}
+
+    def takes_over(eqn, i):
+        """{outvar: operand} for the outputs of eqn that reuse an operand's
+        buffer."""
+        prim = eqn.primitive.name
+        if prim in _UPDATE_OPERAND or prim in _VIEWS:
+            pairs = [(eqn.outvars[0], eqn.invars[0])]
+        elif prim == "scan":
+            pairs = zip(eqn.outvars, eqn.invars[_carry_slice(eqn)])
+        else:
+            return {}
+        return {o: v for o, v in pairs
+                if not isinstance(v, Literal) and last_use.get(v) == i
+                and (v in sizes or v in writable)}
+
     for i, eqn in enumerate(eqns):
         subs = _sub_jaxprs(eqn)
         inner_peak = 0
@@ -228,14 +273,21 @@ def _jaxpr_walk(jaxpr, aliased_outs) -> Tuple[int, int, str]:
             take_max = eqn.primitive.name == "cond"
             branch_flops = []
             for sub, mult in subs:
-                f, p, _ = _jaxpr_walk(sub, frozenset())
+                carries = frozenset(sub.invars[_carry_slice(eqn)]) \
+                    if eqn.primitive.name == "scan" else frozenset()
+                f, p, _ = _jaxpr_walk(sub, frozenset(), carries)
                 branch_flops.append(f * mult)
                 inner_peak = max(inner_peak, p)
             flops += max(branch_flops) if take_max else sum(branch_flops)
         else:
             flops += eqn_flops(eqn)
         alloc = 0
+        reused = takes_over(eqn, i)
         for v in eqn.outvars:
+            if v in reused:
+                # the operand's bytes live on under the output's name
+                sizes[v] = sizes.pop(reused[v], 0)
+                continue
             sz = 0 if v in aliased_outs else aval_bytes(getattr(v, "aval",
                                                                 None))
             sizes[v] = sz
@@ -511,7 +563,9 @@ def program_cost(name: str, fn, args, *, compile_collectives: bool = False
             aliased.add(v)
             alias_bytes += aval_bytes(v.aval)
 
-    flops, temp_peak, peak_at = _jaxpr_walk(body, frozenset(aliased))
+    writable = frozenset(v for d, v in zip(donated, body.invars) if d)
+    flops, temp_peak, peak_at = _jaxpr_walk(body, frozenset(aliased),
+                                            writable)
 
     collectives = None
     xla_temp = None

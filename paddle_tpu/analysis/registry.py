@@ -89,31 +89,35 @@ SERVE_RESOURCE_BUDGET: Dict[str, object] = {
     # 50304 x D x 2 bytes PER CHIP no matter how large the mesh.
     "replicated_bytes_ceiling": 4_096,
     # Per-executable modeled peak HBM (JXP008): argument bytes + the
-    # donation-aware liveness watermark.  Measured 2026-08 at mp1/mp2
-    # (fused 689k/762k, decode 676k/750k, chunk 633k/710k, bucketed
-    # 607k/681k, verify 680k/753k, cow 82k/152k) + ~25% headroom for jax
-    # tracing drift; a real regression (an undonated pool copy, a second
-    # materialized logits buffer) blows through 25% immediately.
+    # donation-aware liveness watermark, in which an in-place update of a
+    # buffer the program owns allocates nothing (`cost_model._jaxpr_walk`:
+    # the paged passes carry the donated pool through the layer scan and
+    # scatter into it).  Measured 2026-10 at mp1/mp2 (fused 648k/655k,
+    # decode 608k/647k, chunk 607k/634k, bucketed 607k/607k, verify
+    # 615k/650k, cow 82k/82k; mp4 = mp2) + ~10% headroom for jax tracing
+    # drift.  The audit pool is 74k (37k a lane): a pool that is copied —
+    # not donated, read again after its update, or scanned over and
+    # re-stacked — or a second materialized logits buffer blows through it.
     "peak_hbm_bytes": {
-        "fused_step": 950_000,
-        "decode": 940_000,
-        "chunk_prefill": 890_000,
-        "bucketed_prefill": 850_000,
-        "verify": 940_000,
-        "cow_copy": 190_000,
+        "fused_step": 720_000,
+        "decode": 712_000,
+        "chunk_prefill": 698_000,
+        "bucketed_prefill": 667_000,
+        "verify": 715_000,
+        "cow_copy": 90_000,
         # preemption KV swap copies (oversubscription PR): the gather holds
         # pool + one slot-capacity staging buffer; the scatter holds pool +
-        # two staging uploads.  Measured 2026-08 (swap_out 139k/172k mp1/mp2,
-        # swap_in 139k/213k; collective-free at mp2 — the page axis is
-        # unsharded) + ~30% headroom.
-        "swap_out": 230_000,
-        "swap_in": 280_000,
+        # the staging uploads and writes the donated pool in place.
+        # Measured 2026-10 (swap_out 139k/172k mp1/mp2, swap_in 139k/172k;
+        # collective-free at mp2 — the page axis is unsharded) + ~10%.
+        "swap_out": 190_000,
+        "swap_in": 190_000,
         # quantized fused step (weight+kv int8): int8 at-rest args shrink
-        # the account to LESS than the fp program — measured 2026-08
-        # 322k/345k mp1/mp2 (+25% headroom).  A dequant that materializes
+        # the account to LESS than the fp program — measured 2026-10
+        # 307k/307k mp1/mp2 (+10% headroom).  A dequant that materializes
         # the whole fp weight stack (instead of one block inside the layer
         # scan) or an fp KV pool copy blows through this immediately.
-        "fused_step_int8": 430_000,
+        "fused_step_int8": 337_000,
     },
     # Per-executable collective bytes per step (JXP007), keyed by the FULL
     # target name: only the mp>1 programs may communicate at all.  The

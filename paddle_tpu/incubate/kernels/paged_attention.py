@@ -210,7 +210,9 @@ def paged_attention_xla(q, k_pages, v_pages, page_table, lengths, scale=None,
     """Gather-based paged decode attention (fallback + oracle).
 
     q: [B, H, hd] — one query token per slot.
-    k_pages/v_pages: [P, page_size, KVH, hd] — the page pool for one layer.
+    k_pages/v_pages: [P, page_size, KVH, hd] — a page pool (the model's
+        paged passes hand in all layers' pages as one [L*P, ...] pool and
+        a page table offset to the layer's rows).
     page_table: [B, max_pages] int32 page ids (0 = reserved null page).
     lengths: [B] int32 — number of valid tokens per slot (including the token
         just written at position lengths-1).
@@ -374,7 +376,7 @@ def paged_prefill_attention_xla(q, k_pages, v_pages, page_table, q_offset,
 
     q: [B, T, H, hd] — a chunk of T query tokens per slot; query t sits at
         absolute position q_offset[b] + t.
-    k_pages/v_pages: [P, page_size, KVH, hd] — the page pool for one layer.
+    k_pages/v_pages: [P, page_size, KVH, hd] — a page pool (see above).
     page_table: [B, max_pages] int32 page ids (0 = reserved null page).
     q_offset: [B] int32 — absolute position of q[:, 0] (prefix already
         written below it: cached pages or earlier chunks).

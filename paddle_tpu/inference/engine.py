@@ -1111,8 +1111,11 @@ class LLMEngine:
             # just burn a "donation unusable" warning per swap-in
             return pin_pool(gpt_mod.swap_in_pages(pool, ids, data))
 
-        # pool donated: each step updates it in place instead of copying the
-        # whole page pool every iteration.  The mp path AOT-compiles (see
+        # pool donated, and carried through each paged pass's layer loop
+        # (`gpt._scan_paged_layers`): the program scatters the new tokens' KV
+        # into the one buffer and the kernels read it there — no copy of the
+        # pool at the jit boundary and none per layer (donation alone saves
+        # only the first).  The mp path AOT-compiles (see
         # _AotCache) so the program set stays exact under committed-sharded
         # donated inputs; single-chip keeps plain jit.
         jit_ = (lambda fn, donate, skip=0: _AotCache(fn, donate, skip)) \

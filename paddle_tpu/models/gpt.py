@@ -5,10 +5,14 @@ Two faces over one implementation:
   params pytree — the compiled hybrid-parallel trainer consumes this directly;
 - a `GPTForCausalLM` nn.Layer wrapper exposing the eager paddle-style API.
 
-TPU-native choices: blocks are stacked on a leading L axis and run under `lax.scan`
-(one compiled block, XLA-friendly, and the L axis is what pipeline parallelism
-shards); attention is the Pallas flash kernel; norms hit the fused RMSNorm kernel;
-RoPE is fused into the attention prologue.  Mirrors the reference's GPT in
+TPU-native choices: block PARAMETERS are stacked on a leading L axis and scanned
+over with `lax.scan` (one compiled block, XLA-friendly, and the L axis is what
+pipeline parallelism shards); activations are the scan's carry.  So is the paged
+KV pool in the serving passes (`_scan_paged_layers`): one donated buffer carried
+through the layer loop and written and read in place, never scanned over — a
+scanned pool is sliced and re-stacked whole in every layer.  Attention is the
+Pallas flash kernel; norms hit the fused RMSNorm kernel; RoPE is fused into the
+attention prologue.  Mirrors the reference's GPT in
 PaddleNLP structure (embed -> L x [ln, attn, ln, mlp] -> ln -> tied lm head).
 """
 from __future__ import annotations
@@ -952,8 +956,8 @@ def _quantize_kv(x):
 
 
 def _kv_scales(kv):
-    """The attention entries' kv_scales lane: (k_scale, v_scale) for a
-    quantized per-layer pool slice, None for the fp pool."""
+    """The attention entries' kv_scales lane: (k_scale, v_scale) of a
+    quantized pool, None for the fp pool."""
     if "k_scale" in kv:
         return kv["k_scale"], kv["v_scale"]
     return None
@@ -979,6 +983,33 @@ def serving_mp_constraint(mesh):
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
     return pin
+
+
+def _scan_paged_layers(params, x, cache, layer):
+    """The layer loop of every paged pass.  The pool is a loop CARRY that each
+    layer updates in place — never a scanned input and a stacked output, which
+    would slice every layer's [P, page, KVH, hd] plane out of the pool and
+    write it back whole (the entire pool read and written ~3 times a program
+    to store a few hundred KB of new keys and values).
+
+    Inside the loop every lane of `cache` ([L, P, page, ...]) is viewed as
+    [L*P, page, ...] (a reshape of leading axes: no data moves), so layer l
+    owns rows [l*P, (l+1)*P).  `layer(bp, x, pool, base)` writes its KV at page
+    ids `base + page_id` and attends through `page_table + base` (base =
+    l*P; the null page of layer l is row base + 0 — route padded rows to
+    page 0 BEFORE adding base).  The attention entries read the flat pool
+    unchanged: they find a page by its id in the leading axis and skip by
+    lengths, not by page id.  Returns (x, cache in its [L, P, ...] layout)."""
+    L, P = cache["k"].shape[:2]
+    pool = {n: a.reshape((L * P,) + a.shape[2:]) for n, a in cache.items()}
+
+    def body(carry, layer_in):
+        bp, l = layer_in
+        return layer(bp, *carry, l * P), None
+
+    (x, pool), _ = jax.lax.scan(
+        body, (x, pool), (params["blocks"], jnp.arange(L, dtype=jnp.int32)))
+    return x, {n: a.reshape(cache[n].shape) for n, a in pool.items()}
 
 
 def decode_step_paged(params, tokens, cache, page_table, lengths,
@@ -1017,26 +1048,25 @@ def decode_step_paged(params, tokens, cache, page_table, lengths,
                                    axis=1)[:, 0]             # [B]
     offset = pos % page
 
-    def layer(x, layer_in):
-        bp, kv = layer_in                   # kv pool slices [P, page, KVH, hd]
+    def layer(bp, x, kv, base):             # kv: the flat pool [L*P, ...]
         q, k, v = _decode_qkv(bp, x, c, pos, parts=parts)
         if pin:
             q, k, v = pin(q, "heads"), pin(k, "heads"), pin(v, "heads")
+        rows = base + page_idx
         if quant:
             k, ks = _quantize_kv(k)
             v, vs = _quantize_kv(v)
-            kv = dict(kv, k_scale=kv["k_scale"].at[page_idx, offset].set(ks),
-                      v_scale=kv["v_scale"].at[page_idx, offset].set(vs))
-        kv = dict(kv, k=kv["k"].at[page_idx, offset].set(k),   # page scatter
-                  v=kv["v"].at[page_idx, offset].set(v))
-        attn = paged_attention_decode(q, kv["k"], kv["v"], page_table,
+            kv = dict(kv, k_scale=kv["k_scale"].at[rows, offset].set(ks),
+                      v_scale=kv["v_scale"].at[rows, offset].set(vs))
+        kv = dict(kv, k=kv["k"].at[rows, offset].set(k),     # page scatter
+                  v=kv["v"].at[rows, offset].set(v))
+        attn = paged_attention_decode(q, kv["k"], kv["v"], page_table + base,
                                       pos + 1, mesh=mesh,
                                       kv_scales=_kv_scales(kv))
         x = _layer_tail(bp, x, attn.reshape(B, c.hidden_size), c, pin)
         return x, kv
 
-    x, new_cache = jax.lax.scan(
-        lambda carry, inp: layer(carry, inp), x, (params["blocks"], cache))
+    x, new_cache = _scan_paged_layers(params, x, cache, layer)
     x = epilogue(params, x, c)
     return head_logits(x, params, c, mesh=mesh), new_cache
 
@@ -1081,8 +1111,7 @@ def prefill_paged(params, input_ids, config: GPTConfig, cache, pages, length,
             mesh=mesh, axis_names={"mp"}, in_specs=(hs, hs, hs),
             out_specs=hs)(q, k, v)
 
-    def layer(x, layer_in):
-        bp, kv = layer_in
+    def layer(bp, x, kv, base):             # kv: the flat pool [L*P, ...]
         q, k, v = _prefill_qkv(bp, x, c, parts=parts)
         if pin:
             q, k, v = pin(q, "heads"), pin(k, "heads"), pin(v, "heads")
@@ -1090,20 +1119,21 @@ def prefill_paged(params, input_ids, config: GPTConfig, cache, pages, length,
         # only the pool write quantizes, so a one-shot prompt's own logits
         # see zero KV quantization error (it lands on later readers)
         wk, wv = k, v
+        rows = base + pages                 # whole pages of this layer
         if quant:
             wk, ks = _quantize_kv(k)
             wv, vs = _quantize_kv(v)
             kv = dict(
                 kv,
-                k_scale=kv["k_scale"].at[pages].set(
+                k_scale=kv["k_scale"].at[rows].set(
                     ks.reshape(B, n_chunks, page, KVH)),
-                v_scale=kv["v_scale"].at[pages].set(
+                v_scale=kv["v_scale"].at[rows].set(
                     vs.reshape(B, n_chunks, page, KVH)))
         kv = dict(kv,
-                  k=kv["k"].at[pages].set(wk.reshape(B, n_chunks, page, KVH,
-                                                     hd)),
-                  v=kv["v"].at[pages].set(wv.reshape(B, n_chunks, page, KVH,
-                                                     hd)))
+                  k=kv["k"].at[rows].set(wk.reshape(B, n_chunks, page, KVH,
+                                                    hd)),
+                  v=kv["v"].at[rows].set(wv.reshape(B, n_chunks, page, KVH,
+                                                    hd)))
         if KVH != H:
             k = jnp.repeat(k, H // KVH, axis=2)
             v = jnp.repeat(v, H // KVH, axis=2)
@@ -1111,8 +1141,7 @@ def prefill_paged(params, input_ids, config: GPTConfig, cache, pages, length,
         x = _layer_tail(bp, x, attn, c, pin)
         return x, kv
 
-    x, new_cache = jax.lax.scan(
-        lambda carry, inp: layer(carry, inp), x, (params["blocks"], cache))
+    x, new_cache = _scan_paged_layers(params, x, cache, layer)
     x = x[jnp.arange(B), length - 1]                 # last real position
     x = epilogue(params, x, c)
     return head_logits(x, params, c, mesh=mesh), new_cache
@@ -1152,33 +1181,31 @@ def _paged_chunk_hidden(params, input_ids, config: GPTConfig, cache,
     pidx = jnp.where(real, pidx, 0)                          # pad -> null page
     off = pos % page
 
-    def layer(x, layer_in):
+    def layer(bp, x, kv, base):             # kv: the flat pool [L*P, ...]
         # the named scopes are metadata for a profiler trace (kv_write: the
         # pool update; attn: qkv projection + paged attention; mlp: the
         # block's tail, out-projection and FFN); the program is unchanged
-        bp, kv = layer_in
         with jax.named_scope("attn"):
             q, k, v = _prefill_qkv(bp, x, c, pos=pos, parts=parts)
             if pin:
                 q, k, v = pin(q, "heads"), pin(k, "heads"), pin(v, "heads")
         with jax.named_scope("kv_write"):
+            rows = base + pidx              # after the pad -> null-page route
             if quant:
                 k, ks = _quantize_kv(k)
                 v, vs = _quantize_kv(v)
-                kv = dict(kv, k_scale=kv["k_scale"].at[pidx, off].set(ks),
-                          v_scale=kv["v_scale"].at[pidx, off].set(vs))
-            kv = dict(kv, k=kv["k"].at[pidx, off].set(k),   # token-granular
-                      v=kv["v"].at[pidx, off].set(v))
+                kv = dict(kv, k_scale=kv["k_scale"].at[rows, off].set(ks),
+                          v_scale=kv["v_scale"].at[rows, off].set(vs))
+            kv = dict(kv, k=kv["k"].at[rows, off].set(k),   # token-granular
+                      v=kv["v"].at[rows, off].set(v))
         with jax.named_scope("attn"):
-            attn = attn_fn(q, kv["k"], kv["v"], page_table, q_offset, valid,
-                           mesh=mesh, kv_scales=_kv_scales(kv))
+            attn = attn_fn(q, kv["k"], kv["v"], page_table + base, q_offset,
+                           valid, mesh=mesh, kv_scales=_kv_scales(kv))
         with jax.named_scope("mlp"):
             x = _layer_tail(bp, x, attn.reshape(B, C, D), c, pin)
         return x, kv
 
-    x, new_cache = jax.lax.scan(
-        lambda carry, inp: layer(carry, inp), x, (params["blocks"], cache))
-    return x, new_cache
+    return _scan_paged_layers(params, x, cache, layer)
 
 
 def prefill_chunk_paged(params, input_ids, config: GPTConfig, cache,

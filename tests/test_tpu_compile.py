@@ -132,19 +132,52 @@ def _model(layers=2):
     return cfg, params, pool
 
 
+def _pool_sized_moves(text, layers):
+    """Instructions of a compiled program that copy, slice out or write back
+    a pool lane or one layer's plane of it (an in-place scatter is none)."""
+    import re
+    dims = f"{POOL_PAGES},{PAGE},{H},{HD}]"
+    shapes = (f"[{layers},{dims}", f"[1,{dims}", f"[{dims}",
+              f"[{layers * POOL_PAGES},{PAGE},{H},{HD}]")
+    hits = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if m and m.group(2) in ("copy", "copy-start", "dynamic-slice",
+                                "dynamic-update-slice") \
+                and any(sh in m.group(1) for sh in shapes):
+            hits.append(line.strip()[:120])
+    return hits
+
+
 def test_fused_serve_step_T5(one):
     """`serve_step_paged` as the default engine compiles it: 32 slots,
-    spec_len 4 -> T=5, pool donated."""
+    spec_len 4 -> T=5, pool donated — and carried through the layer loop: the
+    TPU compiler leaves no copy, slice or write-back of a pool plane around
+    the Mosaic call."""
     cfg, params, pool = _model()
     i32 = jnp.int32
     key = jax.eval_shape(lambda: jax.random.key(0))
-    _compile(
+    text = _compile(
         lambda p, tok, pl_, tbl, qo, vl, k, g: G.serve_step_paged(
             p, tok, pl_, tbl, qo, vl, cfg, key=k, greedy=g),
         *one([params, _s(SLOTS, 5, dtype=i32), pool,
               _s(SLOTS, MAX_PAGES, dtype=i32), _s(SLOTS, dtype=i32),
               _s(SLOTS, dtype=i32), key, _s(SLOTS, dtype=jnp.bool_)]),
         donate_argnums=(2,))
+    assert _pool_sized_moves(text, cfg.num_layers) == []
+
+
+def test_bucketed_prefill_moves_no_pool_plane(one):
+    """`prefill_paged` at a 256 bucket, the program every admission runs:
+    flash kernel inside, whole-page writes into the carried pool in place."""
+    cfg, params, pool = _model()
+    i32 = jnp.int32
+    text = _compile(
+        lambda p, ids, pl_, pg, ln: G.prefill_paged(p, ids, cfg, pl_, pg, ln),
+        *one([params, _s(1, 256, dtype=i32), pool,
+              _s(1, 256 // PAGE, dtype=i32), _s(1, dtype=i32)]),
+        donate_argnums=(2,))
+    assert _pool_sized_moves(text, cfg.num_layers) == []
 
 
 def test_prefix_hit_tail_program_at_max_model_len(one):

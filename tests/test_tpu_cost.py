@@ -77,6 +77,43 @@ def test_donation_excluded_from_peak():
     assert plain.hbm_min_bytes - donated.hbm_min_bytes == 65536
 
 
+def _carried_pool(pool, x, reread=False):
+    """A layer loop of 3 that scatters one row into a [4*3, 256] view of the
+    pool a layer — the paged passes' shape in small."""
+    flat = pool.reshape(12, 256)
+
+    def body(carry, l):
+        buf, acc = carry
+        new = buf.at[l * 4].set(x)
+        # `reread` keeps the pre-update buffer alive past the scatter
+        acc = acc + (buf[0, 0] if reread else new[0, 0])
+        return (new, acc), None
+
+    (flat, acc), _ = jax.lax.scan(body, (flat, jnp.float32(0)),
+                                  jnp.arange(3))
+    return flat.reshape(3, 4, 256), acc
+
+
+@pytest.mark.parametrize("case", ["donated", "not_donated", "reread"])
+def test_in_place_update_through_a_scan_carry(case):
+    """A donated buffer that is reshaped, carried through a scan and
+    scattered into allocates nothing — the paged passes' page pool; the same
+    program without the donation, or one that reads the buffer again after
+    the update, pays for a copy of it (12288 B).  A scatter's flops are its
+    update's elements (256 a layer), not the buffer's."""
+    pool = jnp.zeros((3, 4, 256), jnp.float32)          # 12288 B
+    x = jnp.ones((256,), jnp.float32)
+    fn = jax.jit(lambda p, x: _carried_pool(p, x, reread=case == "reread"),
+                 donate_argnums=() if case == "not_donated" else (0,))
+    c = program_cost(case, fn, (pool, x))
+    assert c.flops < 3 * 256 + 64
+    if case == "donated":
+        assert c.alias_bytes == 12288
+        assert c.temp_peak_bytes < 64           # scalars only
+    else:
+        assert 12288 <= c.temp_peak_bytes < 12288 + 64
+
+
 def test_cond_takes_max_branch_not_sum():
     """`lax.cond` executes one branch: flops are the worst branch, not the
     sum of both."""
