@@ -374,6 +374,34 @@ class HostKVTier:
         return True
 
 
+class RecurrentStateTable:
+    """Which slots hold a live recurrent state (the second kind of serving
+    state: per slot and state-space layer a convolution window and an SSM
+    state of fixed size, indexed by SLOT and not by page — the device lanes
+    are `models.hybrid.init_paged_cache`'s "conv.i"/"ssm.i").  `PagedKVCache`
+    owns one (`attach_state`) and moves it with the slot's pages: allocated
+    where the pages are reserved, freed where they are released, so admission
+    asks one manager once.  Zeroing is the program's: a slot's first program
+    (q_offset 0) starts it from zeros and counts the reset."""
+
+    def __init__(self, num_slots: int, bytes_per_slot: int):
+        self.num_slots = num_slots
+        self.bytes_per_slot = int(bytes_per_slot)
+        self.live: Set[int] = set()
+
+    @property
+    def pool_bytes(self) -> int:
+        return self.num_slots * self.bytes_per_slot
+
+    def alloc(self, slot: int) -> None:
+        if slot in self.live:
+            raise RuntimeError(f"slot {slot} already holds recurrent state")
+        self.live.add(slot)
+
+    def free(self, slot: int) -> None:
+        self.live.discard(slot)
+
+
 class PagedKVCache:
     """Page-table + free-list + prefix-index bookkeeping for `num_slots`
     decode slots over a pool of `num_pages` pages of `page_size` tokens."""
@@ -419,6 +447,11 @@ class PagedKVCache:
         # released — this tracks the off-device obligation so drain checks
         # can prove nothing leaked there either)
         self._swapped: Dict[int, int] = {}
+        # recurrent configurations: the slot-indexed state's bookkeeping
+        self.state: Optional[RecurrentStateTable] = None
+
+    def attach_state(self, table: RecurrentStateTable) -> None:
+        self.state = table
 
     # ---- capacity queries -------------------------------------------------
     @property
@@ -992,6 +1025,8 @@ class PagedKVCache:
             matched += pmatch
         if plan:
             self._restore_plan[slot] = plan
+        if self.state is not None:
+            self.state.alloc(slot)
         return self.page_table[slot], matched, cow
 
     def take_restore(self, slot: int
@@ -1070,6 +1105,8 @@ class PagedKVCache:
         failed restore) must not leak the un-consumed restore plan: the plan
         is discarded here — the planned nodes simply stay in the tier."""
         self._restore_plan.pop(slot, None)
+        if self.state is not None:
+            self.state.free(slot)
         for p in reversed(self._used[slot]):
             self._ref[p] -= 1
             if self._ref[p] == 0:
@@ -1098,6 +1135,11 @@ class PagedKVCache:
         the page.  Tests call this around speculative rollback and abort to
         prove neither path can leak or double-free a page."""
         assert (self._ref >= 0).all(), "negative refcount"
+        if self.state is not None:
+            held = {s for s, pages in self._used.items() if pages}
+            assert self.state.live == held, \
+                (f"recurrent state live in slots {sorted(self.state.live)} "
+                 f"but pages held by slots {sorted(held)}")
         assert self._ref[NULL_PAGE] == 0, "null page must never be refcounted"
         counts = np.zeros((self.num_pages,), np.int64)
         for pages in self._used.values():

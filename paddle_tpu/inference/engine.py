@@ -199,8 +199,9 @@ import numpy as np
 
 from ..analysis.registry import SERVE_SLO
 from ..models import gpt as gpt_mod
+from ..models import hybrid as hybrid_mod
 from ..profiler import profiler as _prof
-from .cache import PagedKVCache
+from .cache import PagedKVCache, RecurrentStateTable
 from .faults import FaultInjected, FaultPlan
 from .health import HEALTH_CODES, evaluate_engine_health
 from .metrics import MetricsRegistry
@@ -579,6 +580,17 @@ class LLMEngine:
         # OFF — the fp engine is byte-identical to a quantization-free build.
         self.weight_dtype = normalize_quant_dtype(weight_dtype, "weight_dtype")
         self.kv_dtype = normalize_quant_dtype(kv_dtype, "kv_dtype")
+        # a patterned configuration (`models.hybrid`) keeps recurrent state
+        # per slot beside the page pool.  Every path that moves, shares or
+        # rolls back a request's pages would have to move, snapshot or roll
+        # back that state too; the ones that cannot yet are refused here,
+        # loudly, rather than served from a wrong state
+        self.recurrent = getattr(config, "layer_pattern", None) is not None
+        if self.recurrent:
+            self._refuse_for_recurrent(
+                spec_len=spec_len, fuse=fuse, admission=admission,
+                preempt=preempt, weight_dtype=self.weight_dtype,
+                kv_dtype=self.kv_dtype, mp=mp, mesh=mesh, role=role)
         self._kv_page_bytes = kv_page_bytes(config, page_size, self.kv_dtype)
         if self.weight_dtype == "int8":
             # quantization is host numpy; re-place the tree ONCE here so no
@@ -679,7 +691,15 @@ class LLMEngine:
         # chunk width also serves prefix-hit tails in bucketed mode, where the
         # largest bucket bounds any tail in one call
         self._chunk = prefill_chunk if self.chunked else self.buckets[-1]
-        self.prefix_cache = prefix_cache
+        # a prefix hit hands a request the PAGES of its prefix; the recurrent
+        # state at that boundary was never kept, so for a recurrent
+        # configuration no hit is usable: the index is off, finished
+        # requests' pages go straight back to the free list (nothing to keep
+        # or spill), and every admission that would have looked is counted
+        # (`prefix_lookups_skipped_no_state`)
+        self._prefix_wanted = bool(prefix_cache)
+        self.prefix_cache = prefix_cache = bool(prefix_cache) and \
+            not self.recurrent
         if spec_len and spec_len + 1 > max_model_len:
             raise ValueError(f"spec_len {spec_len} + 1 exceeds max_model_len")
         self.spec_len = spec_len
@@ -710,6 +730,9 @@ class LLMEngine:
         self._faults = fault_plan or FaultPlan()
         self.cache = PagedKVCache(num_pages, page_size, num_slots,
                                   max_pages_per_slot)
+        if self.recurrent:
+            self.cache.attach_state(RecurrentStateTable(
+                num_slots, config.state_bytes_per_slot()))
         # UNIFIED host pool bound, in pages: preempt="swap" victim parking
         # AND the kv_tier spilled-prefix store share this one ceiling (the
         # JXP009 budget).  Default mirrors the device pool — the host
@@ -753,8 +776,12 @@ class LLMEngine:
         # optimistic-admission watermark: global free-page headroom kept back
         # at admission (vLLM's watermark_blocks), ~1% of the pool
         self._watermark = max(1, (self.cache.num_pages - 1) // 100)
-        self._pool = gpt_mod.init_paged_cache(config, num_pages, page_size,
-                                              kv_dtype=self.kv_dtype)
+        if self.recurrent:
+            self._pool = hybrid_mod.init_paged_cache(config, num_pages,
+                                                     page_size, num_slots)
+        else:
+            self._pool = gpt_mod.init_paged_cache(config, num_pages, page_size,
+                                                  kv_dtype=self.kv_dtype)
         if self._pool_sharding is not None:
             self._pool = jax.device_put(
                 self._pool, {n: self._pool_sharding for n in self._pool})
@@ -842,6 +869,40 @@ class LLMEngine:
         self._h2d_useful = m.counter(
             "swap_h2d_useful_bytes",
             "bytes of the pages those scatters restored")
+        # recurrent configurations only (zero otherwise): the expert layers'
+        # routing account and the state lanes' traffic, counted inside the
+        # two hybrid programs and fetched with their tokens (`_note_aux`)
+        self._aux_counters = {
+            "moe_pairs_here": m.counter(
+                "moe_pairs_here",
+                "token-expert picks that fell on experts held here (computed)"),
+            "moe_pairs_away": m.counter(
+                "moe_pairs_away",
+                "token-expert picks that fell on absent experts (left out)"),
+            "moe_experts_touched": m.counter(
+                "moe_experts_touched",
+                "distinct held experts with >= 1 token, summed over expert "
+                "layers and programs"),
+            "ssm_slots_live": m.counter(
+                "ssm_slots_live",
+                "slots whose recurrent state a program read and wrote, "
+                "summed over programs"),
+            "ssm_state_resets": m.counter(
+                "ssm_state_resets",
+                "slots a program started from a zero state (a new request)"),
+        }
+        self._moe_load_max = 0
+        m.gauge("moe_load_max", lambda: self._moe_load_max,
+                "busiest held expert's tokens in one layer of the last "
+                "program harvested", agg="max")
+        self._ssm_state_bytes = m.counter(
+            "ssm_state_bytes",
+            "bytes of recurrent state read and written (live slots x state "
+            "bytes per slot x 2)")
+        self._prefix_skipped = m.counter(
+            "prefix_lookups_skipped_no_state",
+            "admissions that skipped the prefix index because a recurrent "
+            "configuration has no state snapshot at a prefix boundary")
         self._turnaround_ms_c = m.counter(
             "turnaround_ms",
             "host milliseconds between a fused program's result in hand and "
@@ -1087,6 +1148,26 @@ class LLMEngine:
                 mesh=mesh_)
             return out, accept, pin_pool(pool), key
 
+        if self.recurrent:
+            # the recurrent configuration's two programs, under the names the
+            # dense ones have (a trace finds `jit_fused_impl` /
+            # `jit_prefill_impl` whichever family is served): the same
+            # contracts, one more small result (`aux`, the program's
+            # counters) and, for the prefill, the slot its state is kept in
+            def prefill_impl(params, ids, pool, pages, length, key, greedy,
+                             slots):
+                logits, pool, aux = hybrid_mod.prefill_paged(
+                    params, ids, cfg, pool, pages, length, slots)
+                first, key = pick(logits, key, greedy)
+                return first, pool, key, aux
+
+            def fused_impl(params, tokens, pool, table, q_offset, valid, key,
+                           greedy):
+                return hybrid_mod.serve_step_paged(
+                    params, tokens, pool, table, q_offset, valid, cfg,
+                    key=key, greedy=greedy, sample=sample, temperature=temp_,
+                    top_k=topk_)
+
         def copy_impl(pool, src, dst):
             # COW page copy: one [page, KVH, hd] slab per layer, src -> dst
             # (page axis is unsharded, so the copy is collective-free under mp)
@@ -1122,7 +1203,13 @@ class LLMEngine:
             if self.mp > 1 \
             else (lambda fn, donate, skip=0:
                   jax.jit(fn, donate_argnums=donate))
-        if self.fused:
+        if self.recurrent:
+            # fused mode only (`_refuse_for_recurrent`); no prefix hit, so no
+            # tail for a standalone chunk program to serve
+            self._decode_fn = jit_(fused_impl, (2,), 1)
+            self._verify_fn = None
+            self._chunk_fn = None
+        elif self.fused:
             # the fused program IS the decode-side executable; the legacy
             # verify program is never built (decode-side count: exactly 1),
             # and in chunked mode the chunk rides the fused batch so the
@@ -1569,6 +1656,8 @@ class LLMEngine:
         self._step_slots = {"decode": 0, "verify": 0, "chunk": 0}
         self._step_turnaround_s = 0.0
         self._step_d2h_s = 0.0
+        self._step_aux = dict.fromkeys(("moe_pairs_here", "moe_pairs_away",
+                                        "moe_experts_touched"), 0)
         with self._step_marker(), self._span("engine.step"):
             if self.fused and self._inflight is None:
                 self._turn_begin(t0)
@@ -1655,6 +1744,9 @@ class LLMEngine:
             # live pool-pressure fraction the decision saw
             "preempted": self._step_preempted,
             "pool_pressure": round(mgr.pool_pressure(), 4),
+            # expert-routing account of the programs harvested this step
+            # (recurrent configurations; 0 otherwise)
+            **self._step_aux,
         })
         return finished
 
@@ -1747,7 +1839,7 @@ class LLMEngine:
             with self._span("engine.fused.h2d"):
                 tokens, table, qoff, valid, greedy = (
                     self._h2d(a) for a in (tokens, table, qoff, valid, greedy))
-            out, accept, self._pool, self._key = self._decode_fn(
+            out, accept, self._pool, self._key, *aux = self._decode_fn(
                 self.params, tokens, self._pool, table, qoff, valid,
                 self._key, greedy)
         self._turn_end(launched=True)
@@ -1761,7 +1853,7 @@ class LLMEngine:
             # dispatch (the counter keeps its "verify-program dispatches"
             # meaning for timeline/bench consumers)
             self._verify_steps.inc()
-        inflight = {"out": out, "accept": accept, "slots": slots,
+        inflight = {"out": out, "accept": accept, "aux": aux, "slots": slots,
                     "drafts": {s: drafts[s] for s in nds},
                     "chunk": chunk_job}
         if self.double_buffer:
@@ -1787,13 +1879,16 @@ class LLMEngine:
         t_sync = self._now()
         with self._span("engine.sample.sync"):
             # blocks on the device result
-            out, accept = jax.device_get((inf["out"], inf["accept"]))
+            out, accept, aux = jax.device_get(
+                (inf["out"], inf["accept"], inf["aux"]))
         t_hand = self._now()
         self._step_sync_s += t_hand - t_sync
         if turnaround:
             self._turn_begin(t_hand)
         drafts = inf["drafts"]
         with self._span("engine.emit"):
+            if aux:
+                self._note_aux(aux[0])
             for slot in inf["slots"]:
                 seq = self._running[slot]
                 d = drafts.get(slot)
@@ -1852,6 +1947,50 @@ class LLMEngine:
             else:
                 seq.spec_zero_streak = 0
         return self._maybe_finish(seq, finished)
+
+    @staticmethod
+    def _refuse_for_recurrent(*, spec_len, fuse, admission, preempt,
+                              weight_dtype, kv_dtype, mp, mesh, role) -> None:
+        """What a configuration with recurrent state cannot be served with
+        yet, each with the reason (ROADMAP queue B has what would lift it)."""
+        why = "a configuration with recurrent state (layer_pattern) "
+        if spec_len:
+            raise ValueError(
+                why + "cannot be served with speculative decoding: a "
+                "rejected draft has already moved the state, and there is "
+                "no roll-back of it (spec_len must be 0)")
+        if admission == "optimistic" and preempt == "swap":
+            raise ValueError(
+                why + "cannot be preempted by swap: the swap programs move "
+                "pages, not the slot's state (use preempt='recompute')")
+        if not fuse:
+            raise ValueError(
+                why + "is served by the fused step only (fuse=True)")
+        if weight_dtype is not None or kv_dtype is not None:
+            raise ValueError(
+                why + "has no quantized serving path (weight_dtype and "
+                "kv_dtype must be None)")
+        if (mp is not None and mp > 1) or mesh is not None:
+            raise ValueError(
+                why + "is served on one chip (no mp / mesh): its state "
+                "lanes and expert layer have no sharded form yet")
+        if role is not None:
+            raise ValueError(
+                why + "cannot hand prompts off through the KV tier store "
+                "(role must be None): the store keeps pages, not state")
+
+    def _note_aux(self, aux: np.ndarray) -> None:
+        """Fold one hybrid program's counter vector (`hybrid.AUX_FIELDS`,
+        already on the host: it came with the tokens) into the registry and
+        the step's ring fields."""
+        vals = dict(zip(hybrid_mod.AUX_FIELDS, (int(v) for v in aux)))
+        self._moe_load_max = vals.pop("moe_load_max")
+        for name, v in vals.items():
+            self._aux_counters[name].inc(v)
+            if name in self._step_aux:
+                self._step_aux[name] += v
+        self._ssm_state_bytes.inc(
+            2 * vals["ssm_slots_live"] * self.cache.state.bytes_per_slot)
 
     def _stamp_emit(self, rid: int, n: int, t: Optional[float] = None) -> None:
         """One `RequestMetrics.emit_times` pair: `n` tokens were appended to
@@ -2436,6 +2575,8 @@ class LLMEngine:
                 self._h_queue.observe(lc.queue_s, exemplar=self._exemplar(rid))
                 lc.cached_tokens = matched
             self._admitted_requests.inc()
+            if self.recurrent and self._prefix_wanted:
+                self._prefix_skipped.inc()
             self._tev(rid, "admit", slot=slot, prefix_hit_tokens=int(matched),
                       cow=cow is not None, resume=rec is not None)
             if rec is not None:
@@ -2472,18 +2613,26 @@ class LLMEngine:
                 ids[0, :lp] = prompt
                 pages = row[:bucket // mgr.page_size][None, :]
                 with self._span("engine.prefill.dispatch"):
-                    first, self._pool, self._key = self._prefill_fn(
+                    # a recurrent configuration's prefill is also told the
+                    # slot (where the prompt's state is kept) and also
+                    # returns its counters
+                    first, self._pool, self._key, *aux = self._prefill_fn(
                         self.params, self._h2d(ids), self._pool,
                         self._h2d(pages), self._h2d([lp], np.int32),
-                        self._key, self._h2d([self._req_greedy(req)]))
+                        self._key, self._h2d([self._req_greedy(req)]),
+                        *([self._h2d([slot], np.int32)]
+                          if self.recurrent else []))
                 self._seen_buckets.add(bucket)
                 self._prefilled_tokens.inc(lp)
                 if self.prefix_cache:
                     mgr.register_prefix(slot, prompt, lp)
                 t_sync = self._now()
                 with self._span("engine.sample.sync"):
-                    first = int(jax.device_get(first)[0])   # blocks on the result
+                    first, aux = jax.device_get((first, aux))   # blocks
+                    first = int(first[0])
                 self._step_sync_s += self._now() - t_sync
+                if aux:
+                    self._note_aux(aux[0])
                 self._start_decoding(
                     req, slot, first, cached_out, finished, prompt_len=lp,
                     prior=prior, ttft=r_ttft, spec_off=r_spec_off,
@@ -2754,7 +2903,7 @@ class LLMEngine:
         B = self.cache.num_slots
         tbl = np.zeros((B, self.cache.max_pages_per_slot), np.int32)
         if self.fused:
-            _, _, self._pool, self._key = self._decode_fn(
+            _, _, self._pool, self._key, *_ = self._decode_fn(
                 self.params, self._h2d(np.zeros((B, self._fused_T), np.int32)),
                 self._pool, self._h2d(tbl),
                 self._h2d(np.zeros((B,), np.int32)),
@@ -3279,6 +3428,14 @@ class LLMEngine:
             "swap_h2d_bytes": self._h2d_bytes.value,
             "swap_h2d_useful_bytes": self._h2d_useful.value,
             "turnaround_ms": self._turnaround_ms_c.value,
+            # recurrent configurations: the expert layers' routing account
+            # and the state lanes (all 0 for a dense configuration)
+            **{n: c.value for n, c in self._aux_counters.items()},
+            "moe_load_max": self._moe_load_max,
+            "ssm_state_bytes": self._ssm_state_bytes.value,
+            "ssm_state_pool_bytes": 0 if self.cache.state is None else
+                                    self.cache.state.pool_bytes,
+            "prefix_lookups_skipped_no_state": self._prefix_skipped.value,
             "recomputed_tokens": self._recomputed_tokens.value,
             "timeouts": self._timeouts.value,
             "rejected_requests": self._rejected_requests.value,

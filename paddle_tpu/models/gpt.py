@@ -69,23 +69,34 @@ class GPTConfig:
     moe_topk: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    # RMSNorm's epsilon (1e-6 is what every program computed before the field
+    # existed) and the attention head width, hidden_size // num_heads unless
+    # the architecture states another (heads x head_dim != hidden)
+    rms_norm_eps: float = 1e-6
+    head_dim: Optional[int] = None
+
+    def __post_init__(self):
+        if self.head_dim is None:
+            self.head_dim = self.hidden_size // self.num_heads
 
     @property
     def ffn_size(self):
         return self.intermediate_size or 4 * self.hidden_size
 
     @property
-    def head_dim(self):
-        return self.hidden_size // self.num_heads
-
-    @property
     def kv_heads(self):
         return self.num_kv_heads or self.num_heads
 
     @property
+    def kv_layers(self):
+        """Layers that keep keys and values in the paged pool (all of them,
+        unless a layer pattern says otherwise: `models.hybrid`)."""
+        return self.num_layers
+
+    @property
     def qkv_dim(self):
-        """Packed q|k|v output width: D + 2 * kv_heads * head_dim."""
-        return self.hidden_size + 2 * self.kv_heads * self.head_dim
+        """Packed q|k|v output width: (heads + 2 * kv_heads) * head_dim."""
+        return (self.num_heads + 2 * self.kv_heads) * self.head_dim
 
 
 def gpt3_1p3b():
@@ -125,7 +136,8 @@ def init_params(config: GPTConfig, key) -> Dict[str, Any]:
     blocks = {
         "ln1_w": ln1_w, "ln1_b": ln1_b,
         "qkv_w": (jax.random.normal(next(k), (L, D, c.qkv_dim)) * std).astype(c.dtype),
-        "proj_w": (jax.random.normal(next(k), (L, D, D)) * proj_std).astype(c.dtype),
+        "proj_w": (jax.random.normal(next(k), (L, c.num_heads * c.head_dim, D))
+                   * proj_std).astype(c.dtype),
         "ln2_w": ln2_w, "ln2_b": ln2_b,
     }
     if c.use_bias:
@@ -176,7 +188,7 @@ def init_params(config: GPTConfig, key) -> Dict[str, Any]:
 
 def _norm(x, w, b, config):
     if config.use_rms_norm:
-        return rms_norm_fused(x, w)
+        return rms_norm_fused(x, w, config.rms_norm_eps)
     mu = jnp.mean(x.astype(jnp.float32), axis=-1, keepdims=True)
     var = jnp.var(x.astype(jnp.float32), axis=-1, keepdims=True)
     out = (x.astype(jnp.float32) - mu) * jax.lax.rsqrt(var + 1e-5)
@@ -242,7 +254,7 @@ def block_forward(bp, x, config: GPTConfig, mp_constraint=None, moe_impl=None,
         attn = attn_impl(q, kk, v)
     else:
         attn = flash_attention_fused(q, kk, v, causal=c.causal)
-    attn = attn.reshape(B, S, D)
+    attn = attn.reshape(B, S, H * hd)
     attn = jnp.matmul(attn, bp["proj_w"])
     if "proj_b" in bp:
         attn = attn + bp["proj_b"]
@@ -862,7 +874,7 @@ def decode_step(params, token, cache, pos, config: GPTConfig):
         s = jnp.where((kv_pos <= pos)[None, None, None], s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
         attn = jnp.einsum("bkgs,bskd->bkgd", p.astype(vc.dtype), vc)
-        x = _layer_tail(bp, x, attn.reshape(B, D), c)
+        x = _layer_tail(bp, x, attn.reshape(B, H * hd), c)
         return x, (kc, vc)
 
     def scan_body(carry, inp):
@@ -898,7 +910,8 @@ def prefill(params, input_ids, config: GPTConfig, cache):
         if KVH != H:
             k = jnp.repeat(k, H // KVH, axis=2)
             v = jnp.repeat(v, H // KVH, axis=2)
-        attn = flash_attention_fused(q, k, v, causal=True).reshape(B, Tp, D)
+        attn = flash_attention_fused(q, k, v, causal=True).reshape(
+            B, Tp, H * hd)
         x = _layer_tail(bp, x, attn, c)
         return x, (kc, vc)
 
@@ -1063,7 +1076,7 @@ def decode_step_paged(params, tokens, cache, page_table, lengths,
         attn = paged_attention_decode(q, kv["k"], kv["v"], page_table + base,
                                       pos + 1, mesh=mesh,
                                       kv_scales=_kv_scales(kv))
-        x = _layer_tail(bp, x, attn.reshape(B, c.hidden_size), c, pin)
+        x = _layer_tail(bp, x, attn.reshape(B, -1), c, pin)
         return x, kv
 
     x, new_cache = _scan_paged_layers(params, x, cache, layer)
@@ -1137,7 +1150,7 @@ def prefill_paged(params, input_ids, config: GPTConfig, cache, pages, length,
         if KVH != H:
             k = jnp.repeat(k, H // KVH, axis=2)
             v = jnp.repeat(v, H // KVH, axis=2)
-        attn = attn_call(q, k, v).reshape(B, Sb, D)
+        attn = attn_call(q, k, v).reshape(B, Sb, H * hd)
         x = _layer_tail(bp, x, attn, c, pin)
         return x, kv
 
@@ -1202,7 +1215,7 @@ def _paged_chunk_hidden(params, input_ids, config: GPTConfig, cache,
             attn = attn_fn(q, kv["k"], kv["v"], page_table + base, q_offset,
                            valid, mesh=mesh, kv_scales=_kv_scales(kv))
         with jax.named_scope("mlp"):
-            x = _layer_tail(bp, x, attn.reshape(B, C, D), c, pin)
+            x = _layer_tail(bp, x, attn.reshape(B, C, -1), c, pin)
         return x, kv
 
     return _scan_paged_layers(params, x, cache, layer)

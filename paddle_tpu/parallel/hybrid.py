@@ -688,6 +688,12 @@ class HybridParallelTrainer:
                  learning_rate=1e-4, weight_decay=0.01, beta1=0.9, beta2=0.95,
                  grad_clip_norm: Optional[float] = 1.0, seed=0, devices=None,
                  moment_dtype=jnp.float32):
+        if getattr(config, "layer_pattern", None) is not None:
+            raise ValueError(
+                "a patterned configuration (layer_pattern, models.hybrid) "
+                "is served, not trained: the trainer has one stacked dense "
+                "block and no backward pass for the state-space scan or the "
+                "dropless expert layer")
         self.config = config
         self.cfg = mesh_cfg
         self.mesh = build_mesh(mesh_cfg, devices)

@@ -148,7 +148,7 @@ def kv_page_bytes(config, page_size: int,
     plus the per-token scale lanes when quantized) — the formula the engine's
     `swap_pool_bytes`, the bench's equal-byte pool sizing and the
     `tpu_cost` accounts all agree on."""
-    L, KVH, hd = config.num_layers, config.kv_heads, config.head_dim
+    L, KVH, hd = config.kv_layers, config.kv_heads, config.head_dim
     if normalize_quant_dtype(kv_dtype, "kv_dtype") == "int8":
         per_tok = hd * 1 + np.dtype(KV_SCALE_DTYPE).itemsize
     else:

@@ -446,6 +446,12 @@ NEW_STATS_KEYS = frozenset({
     # added by the disaggregated-serving PR: the engine's fleet role
     # (None / "prefill" / "decode") so health and routing can label it
     "role",
+}) | frozenset({
+    # added by the hybrid PR (ISSUE 28): the expert layers' routing account
+    # and the recurrent state lanes (all 0 for a dense configuration)
+    "moe_pairs_here", "moe_pairs_away", "moe_experts_touched", "moe_load_max",
+    "ssm_slots_live", "ssm_state_resets", "ssm_state_bytes",
+    "ssm_state_pool_bytes", "prefix_lookups_skipped_no_state",
 })
 
 

@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 from paddle_tpu.incubate.kernels import flash_attention as FA
+from paddle_tpu.incubate.kernels import grouped_matmul as GM
 from paddle_tpu.incubate.kernels import paged_attention as PA
 from paddle_tpu.incubate.kernels import rms_norm as RN
 from paddle_tpu.models import gpt as G
@@ -46,7 +47,7 @@ def topo():
 def route_to_kernels(monkeypatch):
     """The entries route on the DEFAULT backend (CPU under pytest); the
     programs here are compiled for TPU devices, so force the kernel route."""
-    for mod in (FA, PA, RN):
+    for mod in (FA, GM, PA, RN):
         monkeypatch.setattr(mod, "_on_tpu", lambda: True)
 
 
@@ -193,6 +194,90 @@ def test_prefix_hit_tail_program_at_max_model_len(one):
               _s(1, MAX_PAGES, dtype=i32), _s(1, dtype=i32),
               _s(1, dtype=i32)]),
         donate_argnums=(2,))
+
+
+# ---- the hybrid configuration at its published widths ----------------------
+# (Nemotron-3-Nano-30B-A3B: hidden 2688, experts 2688 -> 1856 -> 2688 with 64
+# of 128 held, attention 32 Q / 2 KV heads of 128, Mamba-2 64 heads x 64 with
+# state 128; depth cut to one layer of each kind)
+
+def _hybrid(pattern="M*E*"):
+    from paddle_tpu.models import hybrid
+    cfg = hybrid.HybridConfig(
+        vocab_size=65536, hidden_size=2688, num_layers=len(pattern),
+        layer_pattern=pattern, num_heads=32, num_kv_heads=2, head_dim=128,
+        max_seq_len=2048, dtype=BF16, mamba_num_heads=64, mamba_head_dim=64,
+        ssm_state_size=128, mamba_n_groups=8, n_routed_experts=128,
+        experts_here=64, num_experts_per_tok=6, moe_intermediate_size=1856,
+        moe_shared_intermediate_size=3712, routed_scaling_factor=2.5)
+    params = jax.eval_shape(functools.partial(hybrid.init_params, cfg),
+                            jax.random.key(0))
+    pool = jax.eval_shape(functools.partial(
+        hybrid.init_paged_cache, cfg, 64 * 128 + 1, PAGE, 64))
+    return hybrid, cfg, params, pool
+
+
+@pytest.mark.parametrize("rows", [384, 6144], ids=["decode64x6", "prefill1024x6"])
+def test_grouped_expert_matmul_at_published_widths(one, rows):
+    """The megablox kernel under `grouped_matmul`'s tiling, both products of
+    an expert (2688 -> 1856 -> 2688; 1856 is no multiple of 128), 64 held
+    experts of a 128-wide router."""
+    i32 = jnp.int32
+    _compile(lambda x, w, sz: GM.grouped_matmul(x, w, sz, 0, True),
+             *one([_s(rows, 2688), _s(64, 1856, 2688), _s(128, dtype=i32)]))
+    _compile(lambda x, w, sz: GM.grouped_matmul(x, w, sz, 0),
+             *one([_s(rows, 1856), _s(64, 1856, 2688), _s(128, dtype=i32)]))
+
+
+def _lane_sized_moves(text, pool):
+    """Instructions that copy or slice out a whole lane of the hybrid state
+    tree (the K/V pool or a slot-indexed state lane), or all of a layer's
+    expert matrices (a [D, F] up-projection with F = 1856 got a transposed
+    layout on the chip and was copied for the kernel every call)."""
+    import re
+    # (the 2.4 MB convolution windows are re-tiled in passing: [64, 3, 6144]
+    # has no tile-aligned layout; the lanes that weigh are the K/V pool's and
+    # the 134 MB SSM states)
+    shapes = {"[" + ",".join(map(str, a.shape)) + "]" for a in pool.values()
+              if a.size * a.dtype.itemsize > 16e6}
+    shapes.add("[64,1856,2688]")        # and of a layer's expert matrices
+    hits = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if m and m.group(2) in ("copy", "copy-start", "dynamic-slice") \
+                and any(sh in m.group(1) for sh in shapes):
+            hits.append(line.strip()[:120])
+    return hits
+
+
+def test_hybrid_fused_step_keeps_both_pools_in_place(one):
+    """`hybrid.serve_step_paged` as the engine compiles it for the benchmark
+    cell (64 slots, T=1, the whole state tree donated): the paged kernel at
+    G=16, the expert kernel, and no copy of the K/V pool or of a state lane."""
+    hybrid, cfg, params, pool = _hybrid()
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    text = _compile(
+        lambda p, tok, pl_, tbl, qo, vl, k, g: hybrid.serve_step_paged(
+            p, tok, pl_, tbl, qo, vl, cfg, key=k, greedy=g),
+        *one([params, _s(64, 1, dtype=i32), pool, _s(64, 128, dtype=i32),
+              _s(64, dtype=i32), _s(64, dtype=i32), key,
+              _s(64, dtype=jnp.bool_)]),
+        donate_argnums=(2,))
+    assert _lane_sized_moves(text, pool) == []
+
+
+def test_hybrid_bucketed_prefill_keeps_both_pools_in_place(one):
+    hybrid, cfg, params, pool = _hybrid()
+    i32 = jnp.int32
+    text = _compile(
+        lambda p, ids, pl_, pg, ln, sl: hybrid.prefill_paged(
+            p, ids, cfg, pl_, pg, ln, sl),
+        *one([params, _s(1, 256, dtype=i32), pool,
+              _s(1, 256 // PAGE, dtype=i32), _s(1, dtype=i32),
+              _s(1, dtype=i32)]),
+        donate_argnums=(2,))
+    assert _lane_sized_moves(text, pool) == []
 
 
 def test_shard_mapped_kernels_on_four_devices(topo):

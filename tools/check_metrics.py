@@ -103,6 +103,11 @@ REQUIRED_STATS_KEYS = frozenset({
     # swap boundaries, and the host turnaround between fused programs
     "swap_d2h_fetches", "swap_d2h_bytes", "swap_d2h_useful_bytes",
     "swap_h2d_bytes", "swap_h2d_useful_bytes", "turnaround_ms",
+    # hybrid PR (ISSUE 28): the expert layers' routing account and the
+    # recurrent state lanes (0 for a dense configuration)
+    "moe_pairs_here", "moe_pairs_away", "moe_experts_touched", "moe_load_max",
+    "ssm_slots_live", "ssm_state_resets", "ssm_state_bytes",
+    "ssm_state_pool_bytes", "prefix_lookups_skipped_no_state",
 })
 REQUIRED_KV_TIER_KEYS = frozenset({
     "enabled", "spill_dir", "pages_host", "pages_disk", "spills",
@@ -149,6 +154,10 @@ REQUIRED_COUNTERS = frozenset({
     # tracing PR: the swap boundaries' byte account + host turnaround
     "swap_d2h_fetches", "swap_d2h_bytes", "swap_d2h_useful_bytes",
     "swap_h2d_bytes", "swap_h2d_useful_bytes", "turnaround_ms",
+    # hybrid PR: expert routing and recurrent state
+    "moe_pairs_here", "moe_pairs_away", "moe_experts_touched",
+    "ssm_slots_live", "ssm_state_resets", "ssm_state_bytes",
+    "prefix_lookups_skipped_no_state",
 })
 # the v2 step-ring record (`step_trace()`, /debug's "step_trace")
 REQUIRED_STEP_RECORD_KEYS = frozenset({
@@ -156,7 +165,7 @@ REQUIRED_STEP_RECORD_KEYS = frozenset({
     "decode_batch", "chunk", "verify_dispatches", "tokens_emitted",
     "finished", "pages_in_use", "pages_free", "pages_evictable", "fused",
     "dispatches", "sync_ms", "turnaround_ms", "d2h_ms", "slots", "preempted",
-    "pool_pressure",
+    "pool_pressure", "moe_pairs_here", "moe_pairs_away", "moe_experts_touched",
 })
 REQUIRED_DEBUG_BUNDLE_KEYS = frozenset({
     "version", "t", "engine", "pool", "requests", "step_trace", "stats",
