@@ -235,6 +235,34 @@ def kernels_phase(cfg, dev) -> None:
         assert e < FWD_TOL, (B, T, e)
         say("kernels", kernel="paged serve/prefill", shape=[B, T, H, hd],
             rel_err=e)
+
+    # the fused step's attention as the three serving cells run it (T = 1):
+    # GPT-3, Mistral-7B and hybrid widths, ragged lengths in one batch - one
+    # token, one page, a block's edge and one past it, a slot that fills its
+    # whole table - and an inactive slot (null row, nothing valid), which
+    # must come back as zeros
+    for B, heads, kvh, entries in ((SLOTS, H, H, max_pages), (32, 32, 8, 128),
+                                  (64, 32, 2, 128)):
+        pool = B * entries // 2 + 1
+        kp, vp = rnd(pool, PAGE, kvh, hd), rnd(pool, PAGE, kvh, hd)
+        cap = entries * PAGE
+        ln = rng.randint(1, cap // 3, size=B)
+        ln[:6] = 1, PAGE, 256, 257, cap, 0
+        need = -(-ln // PAGE)
+        free, at = rng.permutation(np.arange(1, pool)), 0
+        tbl = np.zeros((B, entries), np.int32)
+        for b in range(B):
+            tbl[b, :need[b]] = free[at:at + need[b]]
+            at += need[b]
+        valid = (ln > 0).astype(np.int32)
+        args = (rnd(B, 1, heads, hd), kp, vp, jnp.asarray(tbl),
+                jnp.asarray(ln - valid, jnp.int32), jnp.asarray(valid))
+        got = np.asarray(PA.paged_serve_attention(*args), np.float32)
+        ref = np.asarray(PA.paged_prefill_attention_xla(*args), np.float32)
+        e = rel_err(got[valid > 0], ref[valid > 0])
+        assert e < FWD_TOL and not got[valid == 0].any(), (heads, kvh, e)
+        say("kernels", kernel="paged serve/prefill", shape=[B, 1, heads, hd],
+            kv_heads=kvh, table_entries=entries, rel_err=e)
     say("kernels", **hbm(dev))
 
 

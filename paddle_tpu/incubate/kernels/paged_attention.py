@@ -30,6 +30,20 @@ prefix (cached pages or earlier chunks), positions inside the chunk mask
 causally.  The `q_offset` lane rides the scalar prefetch next to the page
 table in the Pallas kernel and is a broadcast add in the XLA oracle.
 
+How the prefill kernel walks a slot's table (it is the kernel of every
+serving step, see below): one grid step per (slot, query tile), and inside it
+a loop over the slot's LIVE pages only, in blocks of several pages.  The
+pools stay in HBM; the kernel starts one asynchronous copy a page, addressed
+through the prefetched table, into a double-buffered VMEM scratch, block
+i + 1 in flight while block i is weighed, and does one online-softmax update
+a block.  The trip count comes from `q_offset` and `valid`, so a dead table
+entry costs nothing - no grid step, no copy, no compute - and an inactive
+slot (`valid` 0: the models pass 0 for a null row) a few scalar
+instructions.  Pages a block and kv heads a score tile are functions of the
+shapes (`_pages_per_block`, `_heads_per_tile`).  The decode kernel
+(`paged_attention_pallas`, legacy `fuse=False` path only) still takes one
+grid step a table entry.
+
 Speculative decode (Leviathan et al. 2023) verifies `spec_len + 1` candidate
 tokens per slot in one pass.  That IS the q_len > 1 decode case: query t sits
 at position `lengths[b] + t` and attends causally through the page table —
@@ -411,90 +425,195 @@ def paged_prefill_attention_xla(q, k_pages, v_pages, page_table, q_offset,
 
 # Query rows (heads x tokens) one grid step of the prefill kernel keeps in
 # VMEM.  The q/o blocks, the f32 accumulator and the score tile all scale with
-# it; 2048 rows of hd=128 is ~9 MiB of the 16 MiB a v5e core has, where the
+# it; 2048 rows of hd=128 is ~6 MiB of the 16 MiB a v5e core has, where the
 # whole-T layout asked for 24 MiB at T=1024 (and 17.6 MiB at T=256).
 _MAX_Q_ROWS = 2048
+# What the prefill kernel may hold in VMEM in all (of a v5e core's 16 MiB
+# scoped limit; the rest is left to Mosaic's own temporaries), and what one
+# block of the walk aims at: about 1 MiB of K and V and at most 256 keys
+# (measured on the v5e: 64 KB pages are fastest 8 to a block, 32 KB and 8 KB
+# pages 16 to a block; a longer block only delays the first compute behind a
+# longer cold copy).
+_VMEM_BUDGET = 11 << 20
+_BLOCK_BYTES = 1 << 20
+_MAX_BLOCK_KEYS = 256
+# A query tile of at most this many rows scores its block against ALL kv
+# heads' keys at once (see `_heads_per_tile`).
+_ALL_HEADS_MAX_ROWS = 256
 
 
-def _paged_prefill_kernel(tbl_ref, qoff_ref, val_ref, q_ref, k_ref, v_ref,
-                          *refs, page: int,
-                          KVH: int, G: int, bt: int, n_pages: int,
-                          scale: float, quantized: bool = False):
-    """Grid (B, T/bt, max_pages): slots and query tiles parallel, pages
-    innermost with online-softmax scratch carry over the tile's bt*H query
-    rows (kh-major stacking, same discipline as the decode kernel) — VMEM use
-    is set by the tile, not by T, as the flash kernel tiles S.  The
-    causal-at-offset mask `kv_pos <= q_offset + t` replaces the decode
-    kernel's length mask; a tile's page 0 always computes while the tile
-    holds a real row (every query row attends at least to kv position 0), so
-    the running max is finite before any fully-masked row/page combination.
-    A tile entirely past `valid` computes nothing and writes zeros.
-    `quantized` adds two per-page scale refs after v_ref: the int8 page
-    block dequantizes to f32 on read, same math as the decode kernel."""
+def _heads_per_tile(KVH: int, rows: int) -> int:
+    """KV heads whose keys share one score tile: all of them while the query
+    tile is short, one otherwise.  A block arrives as [ppb, page, KVH, hd];
+    all heads together it IS a [keys * KVH, hd] slab (key-major, head-minor)
+    the MXU takes as it lies, at the price of scoring every row against every
+    head's keys and masking the other heads' columns out.  The MXU streams a
+    short tile's rows through each 128-key slab in the time it takes to load
+    the slab, whoever's keys it holds, so up to a few hundred rows (every
+    T = 1 and speculation step) that price is nil and the per-head gather of
+    key rows out of the page layout - what bounded the kernel at KVH = 2 - is
+    saved; a prefill-sized tile would pay KVH times its matmuls, so it
+    gathers."""
+    return KVH if rows <= _ALL_HEADS_MAX_ROWS else 1
+
+
+def _pages_per_block(page: int, KVH: int, hd: int, itemsize: int, rows: int,
+                     n_pages: int, quantized: bool = False) -> int:
+    """Pages one block of the prefill kernel's walk fetches and weighs
+    together - a function of the shapes alone.  What scales with the block:
+    the double buffers of K and V, the block's K and V as values in the
+    compute dtype, and the float32 score tile [rows, ppb * page * heads-per-tile] with its exp and mask beside it.
+    What does not: the query/output tiles (double-buffered by the pipeline)
+    and the acc / m / l carry, all set by `rows`, and an int8 pool's scales."""
+    page_bytes = page * KVH * hd * itemsize
+    fixed = rows * (4 * hd * itemsize + 2 * hd * 4 + 2 * 128 * 4)
+    per_page = 4 * page_bytes + \
+        2 * page * KVH * hd * (4 if quantized else itemsize) + \
+        3 * rows * page * _heads_per_tile(KVH, rows) * 4
+    if quantized:
+        # the slot's gathered scale lanes, both double-buffered by the
+        # pipeline: [n_pages, page, KVH] f32 with KVH padded to the lanes
+        fixed += 4 * n_pages * page * 128 * 4
+    fit = (_VMEM_BUDGET - fixed) // per_page
+    return int(max(1, min(fit, _BLOCK_BYTES // (2 * page_bytes),
+                          _MAX_BLOCK_KEYS // page, n_pages)))
+
+
+def _paged_prefill_kernel(tbl_ref, qoff_ref, val_ref, q_ref, k_hbm, v_hbm,
+                          *refs, page: int, KVH: int, G: int, bt: int,
+                          ppb: int, hg: int, scale: float,
+                          quantized: bool = False):
+    """Grid (B, T/bt), both parallel: one grid step per slot and query tile
+    of bt*H rows (kh-major stacking, same discipline as the decode kernel;
+    VMEM use is set by the tile, not by T).  The pools stay in HBM.  Inside
+    the step a `fori_loop` walks the slot's LIVE pages only — those at or
+    below the tile's highest real query position — in blocks of `ppb` pages:
+    block i+1's page copies (one `make_async_copy` a live page, addressed
+    through the scalar-prefetched table) are started into the other half of a
+    double-buffered VMEM scratch before block i is weighed, and each block is
+    ONE online-softmax update over its ppb*page keys.  A dead table entry
+    costs nothing: no grid step, no copy, no compute.  Inside the last block
+    the pages past the slot's last live one are not copied; whatever the
+    buffer holds there is masked out of the scores (`kv_pos <= q_offset + t`,
+    clamped to the last real query so padding rows read nothing either) and
+    zeroed out of V, so no stale or uninitialised value is ever weighed.
+    Block 0 holds kv position 0, which every real row attends, so the running
+    max is finite before any fully-masked row/block combination.  A tile of
+    padding rows (or an inactive slot: `valid` 0) has a trip count of 0: no
+    copy starts, and it writes zeros.  `quantized` adds the slot's two scale
+    lanes, gathered through its table by the caller ([1, entries, page, KVH]
+    blocks: Mosaic cannot address a copy into an HBM array whose minor
+    dimension is narrower than the lanes): the int8 block dequantizes to f32
+    on read, same math as the decode kernel."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        o_ref, acc_ref, m_ref, l_ref = refs
+        ks_ref, vs_ref, *refs = refs
+    o_ref, k_buf, v_buf, sem, acc_ref, m_ref, l_ref = refs
+    lanes = ((k_hbm, k_buf), (v_hbm, v_buf))
     b = pl.program_id(0)
     ti = pl.program_id(1)
-    j = pl.program_id(2)
+    R = KVH * bt * G
+    keys = ppb * page
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
     qoff = qoff_ref[b]
     t0 = ti * bt                        # first chunk row of this tile
     n_real = jnp.minimum(val_ref[b] - t0, bt)   # real rows in the tile
     last_q = qoff + t0 + n_real - 1     # highest real query position
-    k_start = j * page
+    n_live = jnp.where(n_real > 0, last_q // page + 1, 0)   # pages to walk
+    n_blocks = (n_live + ppb - 1) // ppb
 
-    # skip: tile of padding rows, or page past every real query position
-    @pl.when((n_real > 0) & (k_start <= last_q))
-    def _compute():
+    def each_live_page(i, half, act):
+        """`act` on the copies of every live page of block i (none of a
+        block past the walk's end): K and V of table entry i * ppb + p into
+        row p of the buffers' `half`."""
+        def one(p, carry):
+            pid = tbl_ref[b, i * ppb + p]
+            for n, (src, buf) in enumerate(lanes):
+                act(pltpu.make_async_copy(src.at[pid], buf.at[half, p],
+                                          sem.at[n, half]))
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(n_live - i * ppb, ppb), one, 0)
+
+    def start(i, half):
+        each_live_page(i, half, lambda cp: cp.start())
+
+    def wait(i, half):
+        each_live_page(i, half, lambda cp: cp.wait())
+
+    start(0, 0)
+
+    def block(i, carry):
+        half = i % 2
+        start(i + 1, 1 - half)
+        wait(i, half)
         q = q_ref[0]                                    # [bt, H, hd]
-        k = k_ref[0]                                    # [page, KVH, hd]
-        v = v_ref[0]
+        k = k_buf[half]                                 # [ppb, page, KVH, hd]
+        v = v_buf[half]
         if quantized:
-            k = k.astype(jnp.float32) * ks_ref[0][..., None]
-            v = v.astype(jnp.float32) * vs_ref[0][..., None]
+            at = pl.ds(i * ppb, ppb)
+            k = k.astype(jnp.float32) * ks_ref[0, at][..., None]
+            v = v.astype(jnp.float32) * vs_ref[0, at][..., None]
+        k_start = i * keys
+        # a page not copied holds anything, NaN included: 0 * NaN is NaN
+        at_page = (ppb, page, 1, 1)
+        row = k_start + page * jax.lax.broadcasted_iota(
+            jnp.int32, at_page, 0) + jax.lax.broadcasted_iota(
+            jnp.int32, at_page, 1)
+        v = jnp.where(row <= last_q, v, jnp.zeros_like(v))
+        # heads in groups of hg: the group's keys as one [keys * hg, hd]
+        # slab (key-major, head-minor), its rows against all of it, and the
+        # columns of another head than the row's masked out
+        n_g = KVH // hg
+        rg = bt * G * hg                                # rows of a group
+        cols = keys * hg
         rows = []
-        for kh in range(KVH):
-            qh = q[:, kh * G:(kh + 1) * G, :].reshape(bt * G, -1)
-            rows.append(jnp.dot(qh, k[:, kh, :].T,
+        for g0 in range(n_g):
+            qh = [q[:, kh * G:(kh + 1) * G, :].reshape(bt * G, -1)
+                  for kh in range(g0 * hg, (g0 + 1) * hg)]
+            qh = jnp.concatenate(qh, axis=0) if hg > 1 else qh[0]
+            kk = (k if n_g == 1 else k[:, :, g0 * hg:(g0 + 1) * hg, :]
+                  ).reshape(cols, -1)
+            rows.append(jnp.dot(qh, kk.T,
                                 preferred_element_type=jnp.float32))
-        s = (jnp.concatenate(rows, axis=0) if KVH > 1 else rows[0]) * scale
-        R = KVH * bt * G
-        kv_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (R, page), 1)
-        t_row = t0 + (jax.lax.broadcasted_iota(jnp.int32, (R, page), 0)
-                      % (bt * G)) // G
-        s = jnp.where(kv_pos <= qoff + t_row, s, NEG_INF)
+        s = (jnp.concatenate(rows, axis=0) if n_g > 1 else rows[0]) * scale
+        col = jax.lax.broadcasted_iota(jnp.int32, (R, cols), 1)
+        rr = jax.lax.broadcasted_iota(jnp.int32, (R, cols), 0) % rg
+        kv_pos = k_start + col // hg
+        t_row = t0 + (rr % (bt * G)) // G
+        ok = kv_pos <= jnp.minimum(qoff + t_row, last_q)
+        if hg > 1:
+            ok = ok & (col % hg == rr // (bt * G))
+        s = jnp.where(ok, s, NEG_INF)
         m_prev = m_ref[...]
         l_prev = l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                          # [R, page]
+        p = jnp.exp(s - m_new)                          # [R, cols]
         l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         upd = []
-        for kh in range(KVH):
-            ph = p[kh * bt * G:(kh + 1) * bt * G].astype(v.dtype)
-            upd.append(jnp.dot(ph, v[:, kh, :],
-                               preferred_element_type=jnp.float32))
-        pv = jnp.concatenate(upd, axis=0) if KVH > 1 else upd[0]   # [R, hd]
+        for g0 in range(n_g):
+            ph = p[g0 * rg:(g0 + 1) * rg].astype(v.dtype)
+            vv = (v if n_g == 1 else v[:, :, g0 * hg:(g0 + 1) * hg, :]
+                  ).reshape(cols, -1)
+            upd.append(jnp.dot(ph, vv, preferred_element_type=jnp.float32))
+        pv = jnp.concatenate(upd, axis=0) if n_g > 1 else upd[0]   # [R, hd]
         acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = m_new
+        return carry
 
-    @pl.when(j == n_pages - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        out = acc_ref[...] / l                          # [KVH*bt*G, hd]
-        for kh in range(KVH):
-            blk = out[kh * bt * G:(kh + 1) * bt * G].reshape(bt, G, -1)
-            o_ref[0, :, kh * G:(kh + 1) * G, :] = blk.astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    l = jnp.maximum(l_ref[...], 1e-30)
+    out = acc_ref[...] / l                              # [KVH*bt*G, hd]
+    for kh in range(KVH):
+        blk = out[kh * bt * G:(kh + 1) * bt * G].reshape(bt, G, -1)
+        o_ref[0, :, kh * G:(kh + 1) * G, :] = blk.astype(o_ref.dtype)
 
 
 def paged_prefill_attention_pallas(q, k_pages, v_pages, page_table, q_offset,
@@ -502,10 +621,13 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, page_table, q_offset,
                                    kv_scales=None):
     """Pallas chunked-prefill paged attention — same contract as
     `paged_prefill_attention_xla`.  page_table / q_offset / valid ride
-    `PrefetchScalarGridSpec`; the T query tokens are tiled over a grid axis
-    (`_MAX_Q_ROWS`), T padded up to a whole number of tiles; `kv_scales`
-    (int8 pool) adds table-indexed per-page scale blocks dequantized on read;
-    `interpret=True` runs on CPU for numerics tests."""
+    `PrefetchScalarGridSpec` and are all the walk needs: the trip count of a
+    slot's loop and the address of every page copy come from them.  The T
+    query tokens are tiled over a grid axis (`_MAX_Q_ROWS`), T padded up to a
+    whole number of tiles; the pools are handed over in HBM and read in
+    place, `_pages_per_block` pages a block; an int8 pool's `kv_scales` (1/32
+    of its bytes at hd 128) are gathered through the table here and arrive a
+    slot a block; `interpret=True` runs on CPU for numerics tests."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -520,27 +642,37 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, page_table, q_offset,
     if n_t * bt != T:
         # padded rows sit past `valid` (valid <= T): masked like any pad row
         q = jnp.pad(q, ((0, 0), (0, n_t * bt - T), (0, 0), (0, 0)))
+    quantized = kv_scales is not None
+    ppb = _pages_per_block(page, KVH, hd, k_pages.dtype.itemsize, bt * H,
+                           n_pages, quantized)
 
     kernel = functools.partial(_paged_prefill_kernel, page=page, KVH=KVH,
-                               G=G, bt=bt, n_pages=n_pages, scale=s,
-                               quantized=kv_scales is not None)
-    pool_spec = pl.BlockSpec(
-        (1, page, KVH, hd), lambda b, t, j, tbl, qo, vl: (tbl[b, j], 0, 0, 0))
+                               G=G, bt=bt, ppb=ppb, hg=_heads_per_tile(
+                                   KVH, bt * H), scale=s,
+                               quantized=quantized)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     q_spec = pl.BlockSpec((1, bt, H, hd),
-                          lambda b, t, j, tbl, qo, vl: (b, t, 0, 0))
-    in_specs = [q_spec, pool_spec, pool_spec]
+                          lambda b, t, tbl, qo, vl: (b, t, 0, 0))
+    in_specs = [q_spec, hbm, hbm]
     args = [q, k_pages, v_pages]
-    if kv_scales is not None:
-        scale_spec = pl.BlockSpec(
-            (1, page, KVH), lambda b, t, j, tbl, qo, vl: (tbl[b, j], 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        args += [kv_scales[0], kv_scales[1]]
+    scratch = [pltpu.VMEM((2, ppb, page, KVH, hd), k_pages.dtype),
+               pltpu.VMEM((2, ppb, page, KVH, hd), v_pages.dtype)]
+    if quantized:
+        # entries padded to whole blocks, so the last block's slice is inside
+        wide = pl.cdiv(n_pages, ppb) * ppb
+        tbl = jnp.pad(jnp.asarray(page_table, jnp.int32),
+                      ((0, 0), (0, wide - n_pages)))
+        sc_spec = pl.BlockSpec((1, wide, page, KVH),
+                               lambda b, t, tbl, qo, vl: (b, 0, 0, 0))
+        in_specs += [sc_spec, sc_spec]
+        args += [kv_scales[0][tbl], kv_scales[1][tbl]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # (page_table, q_offset, valid)
-        grid=(B, n_t, n_pages),
+        grid=(B, n_t),
         in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=[
+        scratch_shapes=scratch + [
+            pltpu.SemaphoreType.DMA((2, 2)),            # K or V x half
             pltpu.VMEM((KVH * bt * G, hd), jnp.float32),
             pltpu.VMEM((KVH * bt * G, 1), jnp.float32),
             pltpu.VMEM((KVH * bt * G, 1), jnp.float32),
@@ -553,7 +685,7 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, page_table, q_offset,
         out_shape=_out_struct((B, n_t * bt, H, hd), q.dtype, q, k_pages,
                               v_pages),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(q_offset, jnp.int32),
       jnp.asarray(valid, jnp.int32), *args)

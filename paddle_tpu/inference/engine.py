@@ -852,6 +852,17 @@ class LLMEngine:
             "swapped_pages", "KV pages delivered to the host swap pool")
         self._swap_ms_c = m.counter(
             "swap_ms", "milliseconds spent in swap d2h/h2d copies")
+        # how far the paged kernel's length-bounded walk engages: the pages
+        # it visits against the table entries the programs were handed
+        # (walked / entries is the share of a whole-table walk that is left)
+        self._pages_walked = m.counter(
+            "paged_pages_walked",
+            "KV pages the paged attention kernel walks, a layer: sum over "
+            "dispatched rows of ceil((q_offset + valid) / page)")
+        self._table_entries = m.counter(
+            "paged_table_entries",
+            "page-table entries handed to those dispatches (rows x "
+            "max pages a slot)")
         # what crossed at the two swap boundaries against what was wanted:
         # both directions move a max_pages_per_slot-wide buffer whatever the
         # page count, so moved/useful is the padding's share of the copy
@@ -1656,6 +1667,7 @@ class LLMEngine:
         self._step_slots = {"decode": 0, "verify": 0, "chunk": 0}
         self._step_turnaround_s = 0.0
         self._step_d2h_s = 0.0
+        self._step_pages_walked = 0
         self._step_aux = dict.fromkeys(("moe_pairs_here", "moe_pairs_away",
                                         "moe_experts_touched"), 0)
         with self._step_marker(), self._span("engine.step"):
@@ -1724,6 +1736,9 @@ class LLMEngine:
             "tokens_emitted": self._decode_tokens.value - tok0,
             "finished": len(finished),
             "pages_in_use": mgr.pages_in_use(),
+            # pages the paged kernel walked in this step's dispatches, a
+            # layer (`_note_walk`)
+            "pages_walked": self._step_pages_walked,
             "pages_free": mgr.num_free_pages,
             "pages_evictable": mgr.num_evictable_pages,
             "fused": self.fused,
@@ -1835,6 +1850,7 @@ class LLMEngine:
                     greedy[slot] = self._req_greedy(st.request)
                 else:
                     table[slot, :] = 0      # inactive: KV to the null page
+            self._note_walk(table, qoff, valid)
         with self._span("engine.fused.dispatch"):
             with self._span("engine.fused.h2d"):
                 tokens, table, qoff, valid, greedy = (
@@ -1978,6 +1994,20 @@ class LLMEngine:
             raise ValueError(
                 why + "cannot hand prompts off through the KV tier store "
                 "(role must be None): the store keeps pages, not state")
+
+    def _note_walk(self, table, q_offset, valid) -> None:
+        """Account one dispatch of a program that holds the paged prefill
+        kernel, from the host arrays it was built with: the kernel walks the
+        pages at or below each row's last real query position, and none of
+        a null row (the models tell it an inactive slot has no real query)."""
+        table = np.asarray(table)
+        page = self.cache.page_size
+        walked = int(np.sum(
+            -(-(np.asarray(q_offset) + np.asarray(valid)) // page),
+            where=table[:, 0] != 0))
+        self._step_pages_walked += walked
+        self._pages_walked.inc(walked)
+        self._table_entries.inc(table.size)
 
     def _note_aux(self, aux: np.ndarray) -> None:
         """Fold one hybrid program's counter vector (`hybrid.AUX_FIELDS`,
@@ -2661,6 +2691,7 @@ class LLMEngine:
                   q_offset=int(st.filled), n=int(n))
         ids = np.zeros((1, C), np.int32)
         ids[0, :n] = st.prompt[st.filled:st.filled + n]
+        self._note_walk(mgr.page_table[slot][None, :], [st.filled], [n])
         with self._span("engine.prefill.dispatch"):
             tok, self._pool, self._key = self._chunk_fn(
                 self.params, self._h2d(ids), self._pool,
@@ -2807,6 +2838,7 @@ class LLMEngine:
                 tokens[slot, 1:1 + d.size] = d
                 valid[slot] = 1 + d.size
             qoff[slot] = mgr.lengths[slot]
+        self._note_walk(table, qoff, valid)
         with self._span("engine.verify.dispatch"):
             preds, self._pool = self._verify_fn(
                 self.params, self._h2d(tokens), self._pool,
@@ -3418,6 +3450,8 @@ class LLMEngine:
             "preempt_swaps": self._preempt_swaps.value,
             "preempt_recomputes": self._preempt_recomputes.value,
             "swapped_pages": self._swapped_pages_c.value,
+            "paged_pages_walked": self._pages_walked.value,
+            "paged_table_entries": self._table_entries.value,
             "swap_ms": self._swap_ms_c.value,
             # the two swap boundaries: buffers fetched, bytes that crossed
             # and bytes of the pages they were for; and the host's turnaround

@@ -1193,6 +1193,9 @@ def _paged_chunk_hidden(params, input_ids, config: GPTConfig, cache,
     pidx = jnp.take_along_axis(page_table, pos // page, axis=1)
     pidx = jnp.where(real, pidx, 0)                          # pad -> null page
     off = pos % page
+    # what the attention is told: a null row (an inactive slot, whatever its
+    # `valid`) holds no real query, so the kernel's walk skips it whole
+    n_real = jnp.where(page_table[:, 0] != 0, valid, 0)
 
     def layer(bp, x, kv, base):             # kv: the flat pool [L*P, ...]
         # the named scopes are metadata for a profiler trace (kv_write: the
@@ -1213,7 +1216,7 @@ def _paged_chunk_hidden(params, input_ids, config: GPTConfig, cache,
                       v=kv["v"].at[rows, off].set(v))
         with jax.named_scope("attn"):
             attn = attn_fn(q, kv["k"], kv["v"], page_table + base, q_offset,
-                           valid, mesh=mesh, kv_scales=_kv_scales(kv))
+                           n_real, mesh=mesh, kv_scales=_kv_scales(kv))
         with jax.named_scope("mlp"):
             x = _layer_tail(bp, x, attn.reshape(B, C, -1), c, pin)
         return x, kv

@@ -390,7 +390,7 @@ def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
         flat = {"k": flat["k"].at[rows, off].set(k),
                 "v": flat["v"].at[rows, off].set(v)}
         attn = paged_serve_attention(q, flat["k"], flat["v"],
-                                     page_table + base, q_offset, valid)
+                                     page_table + base, q_offset, n_real)
         return jnp.matmul(attn.reshape(B, T, H * hd), lp["proj_w"]), \
             dict(cache, **{n: a.reshape(cache[n].shape)
                            for n, a in flat.items()})

@@ -331,7 +331,7 @@ def test_chrome_trace_and_step_timeline(spec_eng, tmp_path):
                 "tokens_emitted", "pages_in_use", "pages_free",
                 "pages_evictable", "queued", "running", "prefilling",
                 "v", "fused", "dispatches", "sync_ms", "slots",
-                "turnaround_ms", "d2h_ms"):
+                "turnaround_ms", "d2h_ms", "pages_walked"):
         assert key in timeline[-1]
     assert any(r["tokens_emitted"] > 0 for r in timeline)
     snap = json.loads((td / "metrics.json").read_text())
@@ -378,6 +378,57 @@ def test_step_trace_ring_bounded(tiny):
     eng.reset_counters()
     assert eng.step_trace() == []
     assert eng.stats()["decode_tokens"] == 0
+
+
+@pytest.mark.parametrize("mode", ["fused", "chunked", "legacy_spec"])
+def test_pages_walked_counts_what_the_programs_were_handed(tiny, mode):
+    """`pages_walked` (ring) and `paged_pages_walked` / `paged_table_entries`
+    (stats, /metrics) equal sum ceil((q_offset + valid) / page) over the
+    non-null rows of every dispatch that holds the paged prefill kernel -
+    recomputed here from the arrays the programs actually received - against
+    rows x table width; by hand for the first step of the plain engine."""
+    cfg, params = tiny
+    kw = {"fused": {}, "chunked": {"prefill_chunk": 16},
+          "legacy_spec": {"fuse": False, "spec_len": 3}}[mode]
+    eng = LLMEngine(params, cfg, num_slots=3, page_size=8, max_model_len=64,
+                    seed=1, **kw)
+    seen = []
+
+    def spy(fn, t_at, q_at, v_at):
+        def call(*args, **kwargs):
+            t, q, v = (np.asarray(args[i]) for i in (t_at, q_at, v_at))
+            seen.append((int(np.sum(-(-(q + v) // 8), where=t[:, 0] != 0)),
+                         t.size))
+            return fn(*args, **kwargs)
+        return call
+
+    if eng.fused:   # the legacy decode program holds the other kernel
+        eng._decode_fn = spy(eng._decode_fn, 3, 4, 5)
+    if getattr(eng, "_verify_fn", None) is not None:
+        eng._verify_fn = spy(eng._verify_fn, 3, 4, 5)
+    if getattr(eng, "_chunk_fn", None) is not None:
+        eng._chunk_fn = spy(eng._chunk_fn, 3, 4, 5)
+    rng = np.random.RandomState(2)
+    for n in (18, 5, 30):
+        eng.add_request(rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32),
+                        max_new_tokens=9)
+    eng.run()
+    st, ring = eng.stats(), eng.step_trace()
+    assert seen and st["paged_pages_walked"] == sum(w for w, _ in seen)
+    assert st["paged_table_entries"] == sum(e for _, e in seen)
+    assert sum(r["pages_walked"] for r in ring) == st["paged_pages_walked"]
+    assert 0 < st["paged_pages_walked"] < st["paged_table_entries"]
+    if mode == "fused":
+        # bucketed admission prefills through flash; the first fused step
+        # decodes all three slots at q_offset = prompt length, valid = 1:
+        # ceil(19/8) + ceil(6/8) + ceil(31/8) pages of 3 x 8 entries
+        first = next(r for r in ring if r["pages_walked"])
+        assert first["pages_walked"] == 3 + 1 + 4 and seen[0] == (8, 24)
+    snap = eng.metrics.snapshot()["counters"]
+    assert snap["paged_pages_walked"] == st["paged_pages_walked"]
+    assert "paged_table_entries" in eng.metrics.to_prometheus()
+    eng.reset_counters()
+    assert eng.stats()["paged_pages_walked"] == 0
 
 
 def test_stats_execs_fallback_attribute_error_only(spec_eng, monkeypatch):
@@ -452,6 +503,10 @@ NEW_STATS_KEYS = frozenset({
     "moe_pairs_here", "moe_pairs_away", "moe_experts_touched", "moe_load_max",
     "ssm_slots_live", "ssm_state_resets", "ssm_state_bytes",
     "ssm_state_pool_bytes", "prefix_lookups_skipped_no_state",
+}) | frozenset({
+    # added by the paged-walk PR (ISSUE 29): pages the paged kernel walks
+    # against the table entries its programs were handed
+    "paged_pages_walked", "paged_table_entries",
 })
 
 

@@ -122,34 +122,119 @@ def test_cache_match_revives_evictable_page():
 # chunked prefill numerics: q_offset kernel lane + logit parity
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("q_rows", [None, 12], ids=["one_tile", "tiled"])
-@pytest.mark.parametrize("kvh", [2, 1], ids=["gqa", "mqa"])
-def test_paged_prefill_attention_pallas_matches_xla_oracle(kvh, q_rows,
-                                                           monkeypatch):
-    """The Pallas chunked-prefill kernel (interpret mode on CPU) agrees with
-    the gather oracle, including the causal-at-q_offset mask, GQA/MQA
-    grouping, and padded chunk rows (compared only where valid).  "tiled"
-    shrinks the VMEM row bound so T=8 splits into three 3-row query tiles:
-    T padded to 9, and slot 1's last tile holds no real row."""
-    if q_rows is not None:
-        from paddle_tpu.incubate.kernels import paged_attention
-        monkeypatch.setattr(paged_attention, "_MAX_Q_ROWS", q_rows)
-    rng = np.random.RandomState(0)
-    B, T, H, hd, page, P, mp = 2, 8, 4, 64, 8, 9, 4
+# (kvh, H, T, page-table width, [(written length incl. the chunk, valid)] a
+# slot, _MAX_Q_ROWS or None).  page 8, hd 64, float32: at a 128-entry table
+# the walk's block is 32 pages = 256 keys (`_pages_per_block`), so 255 / 256 /
+# 257 / 512 straddle a block's edge and 1024 fills every entry; (0, 0) is an
+# inactive slot (null row, nothing valid).
+_WALK_CASES = {
+    "gqa-one_tile": (2, 4, 8, 4, [(18, 8), (22, 5)], None),
+    "mqa-one_tile": (1, 4, 8, 4, [(18, 8), (22, 5)], None),
+    # T=8 splits into three 3-row query tiles: T padded to 9, and slot 1's
+    # last tile holds no real row
+    "gqa-tiled": (2, 4, 8, 4, [(18, 8), (22, 5)], 12),
+    "mqa-tiled": (1, 4, 8, 4, [(18, 8), (22, 5)], 12),
+    "ragged-null_slot-T1": (2, 4, 1, 16, [(1, 1), (0, 0), (8, 1), (9, 1),
+                                          (128, 1), (77, 1)], None),
+    "ragged-null_slot-T5": (2, 4, 5, 16, [(5, 5), (0, 0), (8, 3), (9, 1),
+                                          (128, 5), (77, 2)], None),
+    "block_edges-128_entries-T1": (2, 4, 1, 128, [
+        (255, 1), (256, 1), (257, 1), (512, 1), (1024, 1), (0, 0)], None),
+    "block_edges-128_entries-T5": (2, 4, 5, 128, [
+        (255, 5), (256, 2), (257, 5), (513, 4), (1024, 5), (0, 0)], None),
+    "kvh2_g16-T1": (2, 32, 1, 128, [(300, 1), (16, 1), (0, 0)], None),
+    "kvh2_g16-T5": (2, 32, 5, 128, [(300, 5), (16, 2), (0, 0)], None),
+    "kvh8_g4-T1": (8, 32, 1, 128, [(300, 1), (16, 1), (0, 0)], None),
+    "kvh8_g4-T5": (8, 32, 5, 128, [(300, 4), (16, 5), (0, 0)], None),
+    "mha-T1": (4, 4, 1, 16, [(100, 1), (128, 1), (0, 0)], None),
+    "mha-T5": (4, 4, 5, 16, [(100, 5), (128, 1), (0, 0)], None),
+}
+
+
+def _walk_inputs(kvh, H, T, width, slots, seed=0, hd=64, page=8):
+    """q, a pool in which every slot owns distinct pages (page 0 is the null
+    page), the table, q_offset, valid - and the mask of the pool's live
+    positions (those some slot's walk has to weigh)."""
+    rng = np.random.RandomState(seed)
+    B = len(slots)
+    need = [-(-n // page) for n, _ in slots]
+    P = 1 + sum(need)
     q = jnp.asarray(rng.randn(B, T, H, hd), jnp.float32)
-    k = jnp.asarray(rng.randn(P, page, kvh, hd), jnp.float32)
-    v = jnp.asarray(rng.randn(P, page, kvh, hd), jnp.float32)
-    tbl = np.zeros((B, mp), np.int32)
-    tbl[0, :3] = [1, 2, 3]
-    tbl[1, :4] = [4, 5, 6, 7]
-    qoff = jnp.asarray([10, 17], jnp.int32)
-    valid = jnp.asarray([8, 5], jnp.int32)
-    ref = paged_prefill_attention_xla(q, k, v, jnp.asarray(tbl), qoff, valid)
-    got = paged_prefill_attention_pallas(q, k, v, jnp.asarray(tbl), qoff,
-                                         valid, interpret=True)
-    for b, n in enumerate(np.asarray(valid)):
+    k = rng.randn(P, page, kvh, hd).astype(np.float32)
+    v = rng.randn(P, page, kvh, hd).astype(np.float32)
+    tbl = np.zeros((B, width), np.int32)
+    live = np.zeros((P, page), bool)
+    ids = rng.permutation(np.arange(1, P))
+    at = 0
+    for b, (n, _) in enumerate(slots):
+        tbl[b, :need[b]] = ids[at:at + need[b]]
+        live[tbl[b, :need[b]]] = True
+        if n % page:
+            live[tbl[b, need[b] - 1], n % page:] = False
+        at += need[b]
+    valid = np.asarray([vl for _, vl in slots], np.int32)
+    qoff = np.asarray([n - vl for n, vl in slots], np.int32)
+    return q, k, v, jnp.asarray(tbl), jnp.asarray(qoff), valid, live
+
+
+@pytest.mark.parametrize("heads", ["all_heads", "per_head"])
+@pytest.mark.parametrize("case", list(_WALK_CASES))
+def test_paged_prefill_attention_pallas_matches_xla_oracle(case, heads,
+                                                           monkeypatch):
+    """The Pallas chunked-prefill kernel (interpret mode on CPU: its manual
+    copies, semaphores and length-bounded loop run there) agrees with the
+    gather oracle: the causal-at-q_offset mask, GQA/MQA/MHA grouping, padded
+    chunk rows (compared only where valid), ragged lengths in one batch,
+    lengths on and around a block's edge, a slot that fills its whole table,
+    and an inactive slot, which must come back as zeros.  `heads` runs both
+    forms of the score tile (`_heads_per_tile`): all kv heads' keys in one
+    tile, as a short query tile takes them, and one head a tile."""
+    from paddle_tpu.incubate.kernels import paged_attention
+    kvh, H, T, width, slots, q_rows = _WALK_CASES[case]
+    if q_rows is not None:
+        monkeypatch.setattr(paged_attention, "_MAX_Q_ROWS", q_rows)
+    if heads == "per_head":
+        monkeypatch.setattr(paged_attention, "_ALL_HEADS_MAX_ROWS", 0)
+    q, k, v, tbl, qoff, valid, _ = _walk_inputs(kvh, H, T, width, slots)
+    k, v = jnp.asarray(k), jnp.asarray(v)
+    ref = paged_prefill_attention_xla(q, k, v, tbl, qoff, jnp.asarray(valid))
+    got = paged_prefill_attention_pallas(q, k, v, tbl, qoff,
+                                         jnp.asarray(valid), interpret=True)
+    for b, n in enumerate(valid):
         np.testing.assert_allclose(np.asarray(got)[b, :n],
                                    np.asarray(ref)[b, :n], atol=2e-5)
+        if n == 0:
+            assert not np.asarray(got)[b].any()
+
+
+@pytest.mark.parametrize("heads", ["all_heads", "per_head"])
+def test_paged_prefill_walk_reads_nothing_it_should_not_weigh(heads,
+                                                              monkeypatch):
+    """Every pool position no live query position maps to is NaN - dead
+    pages, the null page, the unwritten tail of a slot's last page - and the
+    TPU interpreter hands out NaN for memory never written and raises on a
+    read out of bounds: the result is finite and equals the oracle's on the
+    clean pool, padding rows included."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.incubate.kernels import paged_attention
+    if heads == "per_head":
+        monkeypatch.setattr(paged_attention, "_ALL_HEADS_MAX_ROWS", 0)
+    slots = [(255, 5), (256, 2), (300, 5), (3, 3), (1024, 1), (0, 0)]
+    q, k, v, tbl, qoff, valid, live = _walk_inputs(2, 8, 5, 128, slots,
+                                                   seed=1)
+    clean = [jnp.asarray(np.where(live[..., None, None], a, 0.0))
+             for a in (k, v)]
+    dirty = [jnp.asarray(np.where(live[..., None, None], a, np.nan))
+             for a in (k, v)]
+    ref = paged_prefill_attention_xla(q, *clean, tbl, qoff,
+                                      jnp.asarray(valid))
+    got = np.asarray(paged_prefill_attention_pallas(
+        q, *dirty, tbl, qoff, jnp.asarray(valid),
+        interpret=pltpu.InterpretParams()))
+    assert np.isfinite(got).all()
+    for b, n in enumerate(valid):
+        np.testing.assert_allclose(got[b, :n], np.asarray(ref)[b, :n],
+                                   atol=2e-5)
 
 
 @pytest.mark.parametrize("preset", PRESETS, ids=IDS)

@@ -93,18 +93,40 @@ def test_rms_norm(one):
     _compile(RN.rms_norm_fused, *one([_s(4 * 2048, 2048), _s(2048)]))
 
 
-@pytest.mark.parametrize("T", [5, 256, MAX_LEN],
-                         ids=["verify5", "chunk256", "tail1024"])
-def test_paged_prefill_kernel_any_width(one, T):
-    """VMEM of the chunk/verify kernel must not grow with T: 256 (the
-    README's prefill_chunk) and 1024 (the bucketed engine's prefix-hit tail)
-    asked for 17.6M / 24M of a 16M core before the query rows were tiled."""
-    B = SLOTS if T < MAX_LEN else 1
+# (B, T, H, KVH, table entries): the three widths the benchmark's fused steps
+# run at T = 1, then the default engine's verify width, the README's
+# prefill_chunk and the bucketed engine's prefix-hit tail at GPT-3 widths
+_PAGED_PREFILL_SHAPES = {
+    "gpt3_t1": (SLOTS, 1, H, H, MAX_PAGES),
+    "mistral_t1": (32, 1, 32, 8, 128),
+    "hybrid_t1": (64, 1, 32, 2, 128),
+    "verify5": (SLOTS, 5, H, H, MAX_PAGES),
+    "mistral_verify5": (32, 5, 32, 8, 128),
+    "chunk256": (SLOTS, 256, H, H, MAX_PAGES),
+    "tail1024": (1, MAX_LEN, H, H, MAX_PAGES),
+}
+
+
+@pytest.mark.parametrize("shape", list(_PAGED_PREFILL_SHAPES))
+def test_paged_prefill_kernel_any_width(one, shape):
+    """VMEM of the chunk/verify kernel must fit a v5e core at every width a
+    cell or an engine mode runs it: it must not grow with T (256 and 1024
+    asked for 17.6M / 24M of a 16M core before the query rows were tiled),
+    and the walk's double buffers and score tile (`_pages_per_block`,
+    `_heads_per_tile`) must fit beside the query tile at every page size -
+    64 KB at GPT-3's 16 kv heads, 32 KB at Mistral's 8, 8 KB at the
+    hybrid's 2."""
+    B, T, heads, kvh, entries = _PAGED_PREFILL_SHAPES[shape]
+    pool_pages = B * entries // 2 + 1
     i32 = jnp.int32
-    _compile(PA.paged_serve_attention, *one([
-        _s(B, T, H, HD), _s(POOL_PAGES, PAGE, H, HD),
-        _s(POOL_PAGES, PAGE, H, HD), _s(B, MAX_PAGES, dtype=i32),
+    text = _compile(PA.paged_serve_attention, *one([
+        _s(B, T, heads, HD), _s(pool_pages, PAGE, kvh, HD),
+        _s(pool_pages, PAGE, kvh, HD), _s(B, entries, dtype=i32),
         _s(B, dtype=i32), _s(B, dtype=i32)]))
+    # the pools are read where they lie: no copy of one feeds the call
+    assert f"[{pool_pages},{PAGE},{kvh},{HD}]" in text
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and f"[{pool_pages},{PAGE}," in ln]
 
 
 def test_paged_decode_kernel_fp_and_int8(one):

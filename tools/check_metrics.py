@@ -108,6 +108,9 @@ REQUIRED_STATS_KEYS = frozenset({
     "moe_pairs_here", "moe_pairs_away", "moe_experts_touched", "moe_load_max",
     "ssm_slots_live", "ssm_state_resets", "ssm_state_bytes",
     "ssm_state_pool_bytes", "prefix_lookups_skipped_no_state",
+    # paged-walk PR (ISSUE 29): pages the paged kernel walks against the
+    # table entries its programs were handed
+    "paged_pages_walked", "paged_table_entries",
 })
 REQUIRED_KV_TIER_KEYS = frozenset({
     "enabled", "spill_dir", "pages_host", "pages_disk", "spills",
@@ -158,6 +161,8 @@ REQUIRED_COUNTERS = frozenset({
     "moe_pairs_here", "moe_pairs_away", "moe_experts_touched",
     "ssm_slots_live", "ssm_state_resets", "ssm_state_bytes",
     "prefix_lookups_skipped_no_state",
+    # paged-walk PR (ISSUE 29): how far the kernel's bounded walk engages
+    "paged_pages_walked", "paged_table_entries",
 })
 # the v2 step-ring record (`step_trace()`, /debug's "step_trace")
 REQUIRED_STEP_RECORD_KEYS = frozenset({
@@ -166,6 +171,7 @@ REQUIRED_STEP_RECORD_KEYS = frozenset({
     "finished", "pages_in_use", "pages_free", "pages_evictable", "fused",
     "dispatches", "sync_ms", "turnaround_ms", "d2h_ms", "slots", "preempted",
     "pool_pressure", "moe_pairs_here", "moe_pairs_away", "moe_experts_touched",
+    "pages_walked",
 })
 REQUIRED_DEBUG_BUNDLE_KEYS = frozenset({
     "version", "t", "engine", "pool", "requests", "step_trace", "stats",
