@@ -26,18 +26,15 @@ verify — 1.0 means drafts never helped) and the decode tokens/s delta vs the
 confirms the two passes emitted byte-identical tokens (greedy acceptance is
 lossless whenever verify and decode logits agree at argmax — exact at
 matching kernel numerics; a TPU bf16 near-tie can in principle diverge).
-The decode and verify executables are compiled during warmup
-(`LLMEngine.warm_decode`/`warm_spec`) so the timed section measures
-steady-state serving.
+The step program is compiled during warmup (`LLMEngine.warm_decode`) so the
+timed section measures steady-state serving.
 
-The engine defaults to the fused ONE-dispatch step (decode + interleaved
-chunk + verify in a single program, on-device sampling, double-buffered
-scheduling); `--no-fuse` is the escape hatch back to the legacy three-program
-step, and the default run replays the same stream unfused to report
-`fused_speedup` and byte-exact `fuse_parity`.  The JSON carries
-`dispatches_per_step` (decode-path program dispatches per dispatching step —
-1.0 fused) and `host_sync_ms_per_step` (blocking d2h sync time) straight from
-the step timeline, plus the static roofline's `predicted_step_ms` for the
+The engine's step is ONE dispatch (decode + interleaved chunk + verify in a
+single program, on-device sampling, double-buffered scheduling).  The JSON
+carries `dispatches_per_step` (decode-path program dispatches per dispatching
+step — 1.0 in chunked mode; a prefix-hit tail adds one in bucketed mode) and
+`host_sync_ms_per_step` (blocking d2h sync time) straight from the step
+timeline, plus the static roofline's `predicted_step_ms` for the
 decode-side program at this engine's shapes (`analysis/cost_model.py`:
 analytic flops vs compulsory HBM bytes over nameplate device specs) next to
 `measured_step_ms`, with `model_error` = measured/predicted — meaningful on
@@ -113,7 +110,7 @@ def run_serve_bench(config=None, *, num_requests=32, num_slots=4,
                     page_size=8, max_model_len=None, max_new_tokens=8,
                     request_rate=float("inf"), seed=0, params=None,
                     prefill_chunk=None, prefix_cache=True,
-                    shared_prefix_frac=0.0, spec_len=0, mp=1, fuse=True,
+                    shared_prefix_frac=0.0, spec_len=0, mp=1,
                     oversubscribe=0.0, preempt="recompute",
                     weight_dtype=None, kv_dtype=None,
                     kv_tier=True, spill_dir=None,
@@ -221,7 +218,7 @@ def run_serve_bench(config=None, *, num_requests=32, num_slots=4,
     # multi-turn chat sessions: clamp first-turn prompts so the LAST turn's
     # context (prompt + every reply + every fresh user chunk) still fits,
     # pre-draw the per-turn user chunks and each session's turn count NOW
-    # (identical randomness across the tier/no-tier/spec/fuse comparison
+    # (identical randomness across the tier/no-tier/spec comparison
     # passes), and size the host pool to hold every session's final context
     # so the capacity tier — not its eviction policy — is what is measured
     swap_pool_pages = None
@@ -283,7 +280,7 @@ def run_serve_bench(config=None, *, num_requests=32, num_slots=4,
     eng = LLMEngine(params, config, num_slots=num_slots, page_size=page_size,
                     num_pages=num_pages,
                     max_model_len=max_model_len, prefill_chunk=prefill_chunk,
-                    prefix_cache=prefix_cache, spec_len=spec_len, fuse=fuse,
+                    prefix_cache=prefix_cache, spec_len=spec_len,
                     admission=admission, preempt=preempt,
                     kv_tier=kv_tier, spill_dir=spill_dir,
                     swap_pool_pages=swap_pool_pages,
@@ -321,11 +318,10 @@ def run_serve_bench(config=None, *, num_requests=32, num_slots=4,
         eng.add_request(pair, max_new_tokens=1)
         eng.run()                       # extension: full-page share + COW
     # 1-token warmup requests pick their token at prefill and retire without
-    # ever dispatching decode or verify — warm those two explicitly so their
-    # compiles stay out of the timed section (the spec on/off ratio would
-    # otherwise compare a compile-laden pass against a compile-light one)
+    # ever dispatching the step program — warm it explicitly so its compile
+    # stays out of the timed section (the spec on/off ratio would otherwise
+    # compare a compile-laden pass against a compile-light one)
     eng.warm_decode()
-    eng.warm_spec()                     # verify executable (no-op spec off)
     eng.warm_swap()                     # swap gather/scatter (no-op unless
                                         # optimistic + preempt="swap")
     eng.reset_counters()
@@ -441,10 +437,10 @@ def run_serve_bench(config=None, *, num_requests=32, num_slots=4,
     # on however many devices the host exposes (forced-CPU CI counts them all)
     n_chips = eng.mp if eng.mp > 1 else max(1, len(jax.devices()))
     # dispatch/sync aggregates from the step timeline: decode-path program
-    # dispatches (fused/decode/verify/chunk-interleave; the admission-time
-    # one-shot prefill is the cold path) and blocking host-sync time, both
-    # averaged over the steps that dispatched anything — the one-dispatch
-    # claim in numbers (fused: 1.0; unfused busy steps: up to 3)
+    # dispatches (the fused step and the standalone chunk program; the
+    # admission-time one-shot prefill is the cold path) and blocking
+    # host-sync time, both averaged over the steps that dispatched anything
+    # — the one-dispatch claim in numbers
     timeline = eng.step_trace()
     busy = [r for r in timeline if r["dispatches"] > 0]
     dispatches_per_step = (sum(r["dispatches"] for r in busy) / len(busy)
@@ -504,7 +500,6 @@ def run_serve_bench(config=None, *, num_requests=32, num_slots=4,
         tracing_overhead_measured = tracing_host_ms / (dt * 1e3)
     return {
         "mp": eng.mp,
-        "fused": eng.fused,
         "request_tracing": request_tracing,
         # the always-on plane's cost, directly accounted (see above): stamp
         # count, its priced host time, and that time over the timed section —
@@ -1002,11 +997,6 @@ def main():
                          "program past what verify already needs")
     ap.add_argument("--no-prefix-cache", action="store_true",
                     help="disable copy-on-write prefix page sharing")
-    ap.add_argument("--no-fuse", action="store_true",
-                    help="disable the fused one-dispatch step: legacy "
-                         "three-program scheduling (decode + chunk + verify "
-                         "programs, host-side sampling) — the A/B baseline; "
-                         "also skips the fused-vs-unfused comparison pass")
     ap.add_argument("--spec-len", type=int, default=4,
                     help="speculative decoding draft length (n-gram "
                          "self-drafting + one K+1-token verify executable)")
@@ -1106,8 +1096,8 @@ def main():
                          "the main pass's deterministic stamp-count x "
                          "unit-cost account; the wall-clock pairs only "
                          "corroborate it, so the default pays ONE extra "
-                         "pair (2 passes, like the spec/fuse comparison "
-                         "passes).  Raise it on a noisy shared box where a "
+                         "pair (2 passes, like the spec comparison "
+                         "pass).  Raise it on a noisy shared box where a "
                          "single adjacent-pair ratio drifts several %%")
     ap.add_argument("--no-history", action="store_true",
                     help="do not append this run's trajectory row to "
@@ -1190,9 +1180,8 @@ def main():
                   request_rate=float("inf") if args.request_rate is None
                   else args.request_rate)
         metric = "serve_decode_tokens_per_sec (cpu smoke)"
-    fuse = not args.no_fuse
     quant = dict(weight_dtype=args.weight_dtype, kv_dtype=args.kv_dtype)
-    stats = run_serve_bench(spec_len=spec_len, fuse=fuse,
+    stats = run_serve_bench(spec_len=spec_len,
                             trace_dir=args.trace_dir, **quant, **kw)
     if args.weight_dtype == "int8" or args.kv_dtype == "int8":
         # fp comparison on the SAME stream: the quantized pass's capacity
@@ -1200,7 +1189,7 @@ def main():
         # its accuracy price (top-1 token agreement — weight-only int8 +
         # int8 KV is a lossy approximation, so the bar is a rate, not the
         # byte parity every fp A/B in this bench holds itself to)
-        base = run_serve_bench(spec_len=spec_len, fuse=fuse, **kw)
+        base = run_serve_bench(spec_len=spec_len, **kw)
         total = agree = 0
         for qt, ft in zip(stats["output_tokens"], base["output_tokens"]):
             total += max(len(qt), len(ft))
@@ -1220,7 +1209,7 @@ def main():
         # the returning-turn prefill the tier made unnecessary
         # (returning_prefilled_drop) plus the TTFT a returning session no
         # longer spends re-prefilling its conversation
-        base = run_serve_bench(spec_len=spec_len, fuse=fuse, **quant,
+        base = run_serve_bench(spec_len=spec_len, **quant,
                                **dict(kw, kv_tier=False))
         stats["no_tier_prefilled_tokens"] = base["prefilled_tokens"]
         stats["no_tier_returning_prefilled_tokens"] = \
@@ -1239,7 +1228,7 @@ def main():
         # preemption must cost throughput, not tokens — greedy outputs
         # byte-identical, goodput_ratio the honest price of running F x
         # oversubscribed
-        base = run_serve_bench(spec_len=spec_len, fuse=fuse, **quant,
+        base = run_serve_bench(spec_len=spec_len, **quant,
                                **dict(kw, oversubscribe=1.0))
         stats["unpressured_goodput_tokens_per_sec"] = \
             base["goodput_tokens_per_sec"]
@@ -1253,7 +1242,7 @@ def main():
         # so the digests must match and the tokens/s ratio is the honest win
         # (the comparison pass inherits the main pass's tracing setting, so
         # both sides carry the same tracing cost and the ratio stays fair)
-        base = run_serve_bench(spec_len=0, fuse=fuse, **quant, **kw)
+        base = run_serve_bench(spec_len=0, **quant, **kw)
         stats["no_spec_decode_tokens_per_sec_per_chip"] = \
             base["decode_tokens_per_sec_per_chip"]
         stats["spec_speedup"] = round(
@@ -1261,23 +1250,6 @@ def main():
             max(base["decode_tokens_per_sec_per_chip"], 1e-9), 3)
         stats["spec_parity"] = \
             stats["outputs_digest"] == base["outputs_digest"]
-    if fuse:
-        # fused vs three-program A/B on the SAME stream (the --no-fuse
-        # escape hatch as one flag): greedy parity must be byte-exact, and
-        # the dispatch win shows as dispatches_per_step 1.0 vs up to 3 plus
-        # the tokens/s ratio (on TPU the dispatch overhead is the payoff; on
-        # CPU the bar is "no regression")
-        unfused = run_serve_bench(spec_len=spec_len, fuse=False, **quant,
-                                  **kw)
-        stats["no_fuse_decode_tokens_per_sec_per_chip"] = \
-            unfused["decode_tokens_per_sec_per_chip"]
-        stats["no_fuse_dispatches_per_step"] = \
-            unfused["dispatches_per_step"]
-        stats["fused_speedup"] = round(
-            stats["decode_tokens_per_sec_per_chip"] /
-            max(unfused["decode_tokens_per_sec_per_chip"], 1e-9), 3)
-        stats["fuse_parity"] = \
-            stats["outputs_digest"] == unfused["outputs_digest"]
     if not args.no_request_tracing:
         # tracing on/off A/B on the SAME stream: the always-on plane
         # (per-request timelines + metric exemplars) must cost < 2% of the
@@ -1302,7 +1274,7 @@ def main():
             sides = [True, False] if i % 2 == 0 else [False, True]
             for tracing_on in sides:
                 run = run_serve_bench(
-                    spec_len=spec_len, fuse=fuse, **quant,
+                    spec_len=spec_len, **quant,
                     **(kw if tracing_on
                        else dict(kw, request_tracing=False)))
                 (on_runs if tracing_on else off_runs).append(run)
@@ -1347,9 +1319,9 @@ def main():
     if not args.no_history:
         # the serving trajectory: one schema-versioned row per run (mode
         # axes + key perf metrics) appended AFTER every comparison pass so
-        # fused_speedup/parity land in it — tools/check_bench.py owns the
-        # row shape, validates it here, and --ci enforces the declared
-        # SERVE_PERF_FLOORS against a fresh run
+        # their speedups and parity flags land in it — tools/check_bench.py
+        # owns the row shape, validates it here, and --ci enforces the
+        # declared SERVE_PERF_FLOORS against a fresh run
         from tools.check_bench import DEFAULT_HISTORY, append_bench_row
         path = args.history or DEFAULT_HISTORY
         append_bench_row(stats, path=path)

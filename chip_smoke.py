@@ -199,17 +199,6 @@ def kernels_phase(cfg, dev) -> None:
             at += need
         return jnp.asarray(tbl)
 
-    # paged decode, B=32, one query token per slot
-    lengths = rng.randint(1, n_pages * PAGE // SLOTS, size=SLOTS)
-    lengths[0], lengths[1] = 1, PAGE            # edge: one token, one page
-    tbl = tables(SLOTS, lengths)
-    ln = jnp.asarray(lengths, jnp.int32)
-    q = rnd(SLOTS, H, hd)
-    e = rel_err(PA.paged_attention_pallas(q, k_pages, v_pages, tbl, ln),
-                PA.paged_attention_xla(q, k_pages, v_pages, tbl, ln))
-    assert e < FWD_TOL, e
-    say("kernels", kernel="paged decode", shape=[SLOTS, H, hd], rel_err=e)
-
     # the fused step's attention at T = spec_len + 1, every slot in its own
     # mode (decode valid=1, verify valid=T, chunk valid in between), and the
     # bucketed engine's prefix-hit tail program at T = max_model_len, B=1
@@ -227,7 +216,7 @@ def kernels_phase(cfg, dev) -> None:
         q = rnd(B, T, H, hd)
         args = (q, k_pages, v_pages, tbl, jnp.asarray(qoff),
                 jnp.asarray(valid))
-        got = np.asarray(PA.paged_serve_attention(*args), np.float32)
+        got = np.asarray(PA.paged_prefill_attention(*args), np.float32)
         ref = np.asarray(PA.paged_prefill_attention_xla(*args), np.float32)
         # rows t >= valid are padding the scheduler never reads
         real = np.arange(T)[None, :] < valid[:, None]
@@ -257,7 +246,7 @@ def kernels_phase(cfg, dev) -> None:
         valid = (ln > 0).astype(np.int32)
         args = (rnd(B, 1, heads, hd), kp, vp, jnp.asarray(tbl),
                 jnp.asarray(ln - valid, jnp.int32), jnp.asarray(valid))
-        got = np.asarray(PA.paged_serve_attention(*args), np.float32)
+        got = np.asarray(PA.paged_prefill_attention(*args), np.float32)
         ref = np.asarray(PA.paged_prefill_attention_xla(*args), np.float32)
         e = rel_err(got[valid > 0], ref[valid > 0])
         assert e < FWD_TOL and not got[valid == 0].any(), (heads, kvh, e)
@@ -341,8 +330,8 @@ def server_phase(cfg, params, dev, mp=None):
         num_slots=SLOTS, page_size=PAGE, max_model_len=MAX_LEN,
         spec_len=SPEC_LEN, mp=mp))
     eng = fleet.engines["engine0"]
-    assert eng.fused and eng.double_buffer and eng.prefix_cache and \
-        not eng.chunked, "not the default engine mode"
+    assert eng.double_buffer and eng.prefix_cache and not eng.chunked, \
+        "not the default engine mode"
     _warm(fleet, cfg.vocab_size)
     say(tag, warmup_s=round(time.perf_counter() - t0, 1),
         buckets=eng.buckets, fused_T=eng._fused_T,

@@ -699,10 +699,9 @@ def engine_at_rest(engine) -> AtRestAccount:
 
 
 def engine_step_target(engine):
-    """(jitted fn, abstract args) of the engine's decode-side program (fused
-    `serve_step_paged`, or the legacy decode under `fuse=False`) at the
-    ENGINE's own shapes, the inputs carrying the engine's REAL shardings —
-    what `fn.lower(*args)` needs, with no dispatch and no transfer, and
+    """(jitted fn, abstract args) of the engine's decode-side program (the
+    fused `serve_step_paged`) at the ENGINE's own shapes, the inputs carrying
+    the engine's REAL shardings — what `fn.lower(*args)` needs, with no dispatch and no transfer, and
     outside the `_AotCache` dispatch cache (program-count stats untouched)."""
     import jax
     import numpy as np
@@ -725,13 +724,9 @@ def engine_step_target(engine):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
 
     fn = getattr(engine._decode_fn, "_jit", engine._decode_fn)
-    if engine.fused:
-        args = (params, host((B, engine._fused_T)), pool, host((B, P)),
-                host((B,)), host((B,)), sds(engine._key, repl),
-                host((B,), np.bool_))
-    else:
-        args = (params, host((B,)), pool, host((B, P)), host((B,)),
-                sds(engine._key, repl), host((B,), np.bool_))
+    args = (params, host((B, engine._fused_T)), pool, host((B, P)),
+            host((B,)), host((B,)), sds(engine._key, repl),
+            host((B,), np.bool_))
     return fn, args
 
 
@@ -854,13 +849,13 @@ def run_cost_checks(include_mp: bool = True, mp=(2, 4),
                 passes.append(m)
     spec = device_spec()
     for m in passes:
-        # ONE fused engine serves both the at-rest account and the audit
-        # targets (plus the legacy pair serving_targets needs) — same
-        # instance, so the two accounts cannot diverge
+        # ONE engine serves both the at-rest account and the audit
+        # targets (plus the bucketed one serving_targets takes the chunk
+        # program from) — same instance, so the two accounts cannot diverge
         eng, _ = _build_engine(m)
-        leg, _ = _build_engine(m, fuse=False)
+        bkt, _ = _build_engine(m, prefill_chunk=None)
         at_rest = engine_at_rest(eng)
-        costs, fs = audit_resources(serving_targets(m, engines=(eng, leg)),
+        costs, fs = audit_resources(serving_targets(m, engines=(eng, bkt)),
                                     at_rest, budget)
         findings.extend(fs)
         # JXP009: the UNIFIED host pool (preempt="swap" victim parking +

@@ -30,10 +30,10 @@ audited:
   in-place page pool) are exempt: they never cross to the host.
 
 `audit_jaxpr` is the reusable core (tests feed it toy jits for
-positive/negative pairs); `run_jaxpr_checks` builds tiny CPU engines (the
-default fused engine AND the `fuse=False` legacy trio, so the `--no-fuse`
-escape hatch stays audited) and checks the real serving set — fused step,
-legacy decode/chunk/verify, bucketed prefill, COW copy, and the two
+positive/negative pairs); `run_jaxpr_checks` builds tiny CPU engines (a
+chunked one, and a bucketed one for the standalone chunk program that
+serves prefix-hit tails there) and checks the real serving set — fused step,
+chunk prefill, bucketed prefill, COW copy, and the two
 preemption KV-swap copies (swap-out gather / swap-in scatter) — plus an
 mp=2 pass when enough devices exist.  The quantized serving engine's fused
 step (`quantized_targets`, weight/kv int8) rides the same audit so dequant
@@ -244,7 +244,7 @@ def audit_jaxpr(name: str, fn, args, *, donate_paths: Sequence[str] = (),
 # ---------------------------------------------------------------------------
 
 
-def _build_engine(mp: int, fuse: bool = True, weight_dtype=None,
+def _build_engine(mp: int, prefill_chunk=8, weight_dtype=None,
                   kv_dtype=None):
     import jax
 
@@ -254,7 +254,7 @@ def _build_engine(mp: int, fuse: bool = True, weight_dtype=None,
     cfg = gpt_mod.gpt_tiny(64)
     params = gpt_mod.init_params(cfg, jax.random.key(0))
     return LLMEngine(params, cfg, num_slots=2, page_size=8, max_model_len=64,
-                     prefill_chunk=8, spec_len=2, fuse=fuse,
+                     prefill_chunk=prefill_chunk, spec_len=2,
                      weight_dtype=weight_dtype, kv_dtype=kv_dtype,
                      mp=mp if mp > 1 else None), cfg
 
@@ -263,20 +263,20 @@ def serving_targets(mp: int = 1, engines=None
                     ) -> List[Tuple[str, object, tuple, dict]]:
     """(name, jitted fn, example args, audit kwargs) for every serving
     executable, mirroring the engine's own dispatch shapes.  Two engines:
-    the default FUSED engine supplies the one-dispatch step (audited under
-    JXP001-005 — the host-output budget proves the O(B*K)-int fetch), the
-    bucketed cold prefill and the COW copy; a `fuse=False` engine supplies
-    the legacy decode/chunk/verify trio so the --no-fuse escape hatch stays
-    under the same donation/transfer/dtype discipline.  `engines` injects a
-    prebuilt (fused, legacy) pair so callers that also need the engine for
-    other accounts (tpu_cost's at-rest pass) build it once."""
+    a chunked one supplies the one-dispatch step (audited under JXP001-005 —
+    the host-output budget proves the O(B*K)-int fetch), the bucketed cold
+    prefill and the COW copy; a bucketed one (the mode the benchmark's cells
+    run) supplies the standalone chunk program, which exists there only (it
+    serves prefix-hit tails).  `engines` injects a prebuilt (chunked,
+    bucketed) pair so callers that also need the engine for other accounts
+    (tpu_cost's at-rest pass) build it once."""
     import jax.numpy as jnp
 
     if engines is not None:
-        eng, leg = engines
+        eng, bkt = engines
     else:
         eng, _cfg = _build_engine(mp)
-        leg, _ = _build_engine(mp, fuse=False)
+        bkt, _ = _build_engine(mp, prefill_chunk=None)
     B = eng.cache.num_slots
     P = eng.cache.max_pages_per_slot
     i32 = jnp.int32
@@ -286,9 +286,8 @@ def serving_targets(mp: int = 1, engines=None
     def unwrap(fn):
         return getattr(fn, "_jit", fn)     # _AotCache under mp, jit else
 
-    C = leg.prefill_chunk
+    C = bkt._chunk
     bucket = eng.buckets[0]
-    T = leg.spec_len + 1
     Tf = eng._fused_T
     cfgL = eng._pool["k"].shape[0]      # layers: swap staging leading dim
     return [
@@ -298,25 +297,15 @@ def serving_targets(mp: int = 1, engines=None
           jnp.ones((B,), i32), eng._key, jnp.zeros((B,), bool)),
          dict(donate_paths=("arg2",), keep_paths=("arg0",),
               host_output_budget=B * (Tf + 2) + 2, **mp_kw)),
-        (f"serve.{tag}decode", unwrap(leg._decode_fn),
-         (leg.params, jnp.zeros((B,), i32), leg._pool,
-          jnp.zeros((B, P), i32), jnp.zeros((B,), i32), leg._key,
-          jnp.zeros((B,), bool)),
-         dict(donate_paths=("arg2",), keep_paths=("arg0",), **mp_kw)),
-        (f"serve.{tag}chunk_prefill", unwrap(leg._chunk_fn),
-         (leg.params, jnp.zeros((1, C), i32), leg._pool,
+        (f"serve.{tag}chunk_prefill", unwrap(bkt._chunk_fn),
+         (bkt.params, jnp.zeros((1, C), i32), bkt._pool,
           jnp.zeros((1, P), i32), jnp.zeros((1,), i32),
-          jnp.ones((1,), i32), leg._key, jnp.zeros((1,), bool)),
+          jnp.ones((1,), i32), bkt._key, jnp.zeros((1,), bool)),
          dict(donate_paths=("arg2",), keep_paths=("arg0",), **mp_kw)),
         (f"serve.{tag}bucketed_prefill", unwrap(eng._prefill_fn),
          (eng.params, jnp.zeros((1, bucket), i32), eng._pool,
           jnp.zeros((1, bucket // eng.cache.page_size), i32),
           jnp.ones((1,), i32), eng._key, jnp.zeros((1,), bool)),
-         dict(donate_paths=("arg2",), keep_paths=("arg0",), **mp_kw)),
-        (f"serve.{tag}verify", unwrap(leg._verify_fn),
-         (leg.params, jnp.zeros((B, T), i32), leg._pool,
-          jnp.zeros((B, P), i32), jnp.zeros((B,), i32),
-          jnp.ones((B,), i32)),
          dict(donate_paths=("arg2",), keep_paths=("arg0",), **mp_kw)),
         (f"serve.{tag}cow_copy", unwrap(eng._copy_fn),
          (eng._pool, jnp.zeros((), i32), jnp.ones((), i32)),

@@ -72,9 +72,10 @@ SERVE_PROGRAM_BUDGET_MP: Dict[str, int] = {
 
 # Static HBM/collective ceilings over the SAME tiny audit engines the jaxpr
 # checks trace (`jaxpr_checks._build_engine`: gpt_tiny(64), 2 slots, page 8,
-# chunk 8, spec 2 — mp1, mp2 AND mp4, the mesh size where the sharded-head
-# win compounds).  Units are cost-model bytes (traced aval bytes,
-# `analysis/cost_model.py` — deterministic across backends, no XLA padding).
+# chunk 8, spec 2, and its bucketed twin for the standalone chunk program —
+# mp1, mp2 AND mp4, the mesh size where the sharded-head win compounds).
+# Units are cost-model bytes (traced aval bytes, `analysis/cost_model.py` —
+# deterministic across backends, no XLA padding).
 # These are the repo's memory yardstick: the quantized-KV arc shrank the
 # pool term, the vocab-sharded-head arc moved `wte` out of the replicated
 # set — both show up HERE before any TPU run.
@@ -93,17 +94,15 @@ SERVE_RESOURCE_BUDGET: Dict[str, object] = {
     # buffer the program owns allocates nothing (`cost_model._jaxpr_walk`:
     # the paged passes carry the donated pool through the layer scan and
     # scatter into it).  Measured 2026-10 at mp1/mp2 (fused 648k/655k,
-    # decode 608k/647k, chunk 607k/634k, bucketed 607k/607k, verify
-    # 615k/650k, cow 82k/82k; mp4 = mp2) + ~10% headroom for jax tracing
-    # drift.  The audit pool is 74k (37k a lane): a pool that is copied —
-    # not donated, read again after its update, or scanned over and
+    # chunk 856k/856k at the bucketed engine's tail width of 64 tokens,
+    # bucketed 607k/607k, cow 82k/82k; mp4 = mp2) + ~10% headroom for jax
+    # tracing drift.  The audit pool is 74k (37k a lane): a pool that is
+    # copied — not donated, read again after its update, or scanned over and
     # re-stacked — or a second materialized logits buffer blows through it.
     "peak_hbm_bytes": {
         "fused_step": 720_000,
-        "decode": 712_000,
-        "chunk_prefill": 698_000,
+        "chunk_prefill": 940_000,
         "bucketed_prefill": 667_000,
-        "verify": 715_000,
         "cow_copy": 90_000,
         # preemption KV swap copies (oversubscription PR): the gather holds
         # pool + one slot-capacity staging buffer; the scatter holds pool +
@@ -127,10 +126,10 @@ SERVE_RESOURCE_BUDGET: Dict[str, object] = {
     # (c) the sharded-argmax merge: one (value, index) scalar PAIR per row
     # (pmax + pmin, 2 x 4 B x rows) — NEVER logits-sized.  Measured 2026-08
     # on the audit config (L=2, f32): fused 20608 B/step (16384 layer
-    # psums + 4096 embed psum + 128 argmax pair), decode 2576,
-    # chunk/bucketed 10248, verify 7728 — budgets are measured + ~20%
-    # headroom, so a logits-wide allgather (32 KiB at even this toy vocab)
-    # fails immediately.  Collective payloads are LOGICAL bytes, so mp2 and
+    # psums + 4096 embed psum + 128 argmax pair), bucketed 10248; the chunk
+    # program at the bucketed engine's tail width (64 tokens, 2026-10) 49160
+    # — budgets are measured + ~20% headroom, so a logits-wide allgather
+    # (32 KiB at even this toy vocab) fails immediately.  Collective payloads are LOGICAL bytes, so mp2 and
     # mp4 share one measured account (per-chip shards halve, the summed
     # traffic does not).  An mp1 program with ANY collective, or an mp>1
     # program absent from this table, is undeclared traffic and fails CI.
@@ -139,17 +138,13 @@ SERVE_RESOURCE_BUDGET: Dict[str, object] = {
         # dequant is chip-local (scales shard with their weights/pages), so
         # the quantized fused step carries exactly the fp program's traffic
         "serve.mp2.fused_step_int8": 24_576,
-        "serve.mp2.decode": 4_096,
-        "serve.mp2.chunk_prefill": 12_288,
+        "serve.mp2.chunk_prefill": 59_000,
         "serve.mp2.bucketed_prefill": 12_288,
-        "serve.mp2.verify": 10_240,
         # the mp4 audit pass (same logical payloads, see above)
         "serve.mp4.fused_step": 24_576,
         "serve.mp4.fused_step_int8": 24_576,
-        "serve.mp4.decode": 4_096,
-        "serve.mp4.chunk_prefill": 12_288,
+        "serve.mp4.chunk_prefill": 59_000,
         "serve.mp4.bucketed_prefill": 12_288,
-        "serve.mp4.verify": 10_240,
     },
     # UNIFIED host-pool ceiling (JXP009): the bound
     # `LLMEngine.host_pool_bytes()` declares for EVERYTHING parked in host
@@ -262,7 +257,7 @@ SERVE_PERF_FLOORS: Dict[str, object] = {
     # the same tokens as one engine serving it alone; disagg_parity: the
     # prefill->store->decode handoff AND the engine-restart restore must
     # both reproduce the colocated single-engine stream byte-for-byte)
-    "parity_flags": ("fuse_parity", "spec_parity", "oversubscribe_parity",
+    "parity_flags": ("spec_parity", "oversubscribe_parity",
                      "tracing_parity", "kv_tier_parity", "fleet_parity",
                      "disagg_parity"),
     # the one-dispatch claim in numbers: a fused busy step dispatches
@@ -270,13 +265,6 @@ SERVE_PERF_FLOORS: Dict[str, object] = {
     # the two guards cannot drift apart
     "dispatches_per_step_max": float(
         SERVE_PROGRAM_BUDGET["decode_side_executables"]),
-    # fused-vs-unfused tokens/s ratio.  The fused win is a TPU claim
-    # (dispatch overhead is what fusion removes); on this shared CPU-smoke
-    # box the measured ratio hovers ~0.89-1.46 run-over-run depending on
-    # load and mode, so the floor only catches a COLLAPSE (a fused path
-    # suddenly dispatching extra work), not the win itself — byte parity
-    # and dispatches_per_step carry the deterministic side of the claim.
-    "fused_speedup_min": 0.8,
     # the always-on tracing plane's deterministic stamp-count x unit-cost
     # account (bench `tracing_overhead_measured`) must stay under 2%
     "tracing_overhead_max": 0.02,
@@ -344,15 +332,14 @@ PROGRAM_SOURCES: Tuple[ProgramSource, ...] = (
         "paddle_tpu/inference/engine.py", "LLMEngine.__init__",
         budget="total_executables",
         note="the serving executables built through the jit_ wrapper, fixed "
-             "shapes per engine.  Fused (default): serve_step_paged — THE "
+             "shapes per engine: serve_step_paged — THE "
              "one-dispatch step (decode + verify + interleaved chunk in one "
              "[B, max(K+1, chunk)] batch, on-device sampling/acceptance, "
              "O(B*K)-int host output) — plus the cold prefill paths, the "
              "COW copy and the two KV-swap copies (swap_out gather / "
              "swap_in scatter — shared by preemption swap parking AND the "
              "KV tier's prefix spill/restore, compiled when either path "
-             "fires); fuse=False additionally builds the legacy decode/"
-             "chunk/verify trio (A/B baseline, outside the default budget)"),
+             "fires)"),
     # ---- model core -------------------------------------------------------
     ProgramSource(
         "paddle_tpu/models/gpt.py", "generate",
@@ -412,12 +399,8 @@ PROGRAM_SOURCES: Tuple[ProgramSource, ...] = (
              "(inside the step's custom_vjp halves, no standalone program)"),
     ProgramSource(
         "paddle_tpu/incubate/kernels/paged_attention.py",
-        "paged_attention_decode_mp",
-        note="decode paged attention per-shard under the serving mp mesh"),
-    ProgramSource(
-        "paddle_tpu/incubate/kernels/paged_attention.py",
         "paged_prefill_attention_mp",
-        note="prefill/verify paged attention per-shard under mp"),
+        note="paged attention per-shard under the serving mp mesh"),
     # ---- export / static-graph paths --------------------------------------
     ProgramSource(
         "paddle_tpu/jit/api.py", "save",
@@ -447,7 +430,7 @@ _BY_KEY: Dict[Tuple[str, str], ProgramSource] = {
 def lookup(path: str, qualname: str) -> Optional[ProgramSource]:
     """The declared source covering a jit site at (path, enclosing qualname).
     Falls back to walking qualname prefixes so a site inside a nested def
-    (`LLMEngine.__init__.decode_impl`) is covered by its enclosing entry."""
+    (`LLMEngine.__init__.fused_impl`) is covered by its enclosing entry."""
     parts = qualname.split(".") if qualname else []
     for i in range(len(parts), -1, -1):
         hit = _BY_KEY.get((path, ".".join(parts[:i])))

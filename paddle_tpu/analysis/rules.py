@@ -301,7 +301,7 @@ class BareExceptDeviceRule(Rule):
 class DoubleBufferHazardRule(Rule):
     """TPL007: page-state mutation before harvesting the in-flight batch.
 
-    Under double-buffered scheduling (`fuse=True` + `double_buffer=True`)
+    Under double-buffered scheduling (`double_buffer=True`, the default)
     the fused dispatch of step *n* is still writing KV when the host runs
     between steps — its result is parked in `self._inflight` until the next
     harvest.  A public entry point that frees or reassigns page-table/

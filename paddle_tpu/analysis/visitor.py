@@ -604,7 +604,7 @@ _PAGE_STATE_ATTRS = frozenset({"lengths", "page_table", "refcounts",
 
 def _publishes_inflight(info: FunctionInfo) -> bool:
     """Whether this function assigns a non-None value to `self._inflight` —
-    the double-buffering marker (fuse=True paths park the un-synced dispatch
+    the double-buffering marker (the step parks its un-synced dispatch
     there; `None` assignments are the harvest clearing it)."""
     for node in ast.walk(info.node):
         if isinstance(node, ast.Assign):

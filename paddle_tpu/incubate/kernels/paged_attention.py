@@ -1,71 +1,47 @@
-"""Paged attention decode for TPU serving (ref vLLM PagedAttention, Kwon et al.
+"""Paged attention for TPU serving (ref vLLM PagedAttention, Kwon et al.
 SOSP 2023; reference repo counterpart: the fused variable-length attention used
 by `fluid/inference` / PaddleNLP generation predictors).
 
 The serving engine stores KV in a static pool of fixed-size pages
 (`[num_pages, page_size, KVH, hd]` per layer) plus a per-slot page table, so
-cache memory scales with live tokens instead of `B * max_seq_len`.  Decode
-attention then has to read each slot's keys/values *through* the page table:
+cache memory scales with live tokens instead of `B * max_seq_len`.  Attention
+then has to read each slot's keys/values *through* the page table.  One
+contract covers every lane of the serving step: a chunk of T query tokens per
+slot (`q [B, T, H, hd]`), query t at absolute position `q_offset[b] + t`,
+attends causally (`kv_pos <= q_offset + t`) to everything the table holds at
+or below it — positions below the offset are what was written before (cached
+prefix pages, earlier chunks, earlier decode steps), positions inside the
+chunk mask causally.  `valid[b]` counts the chunk's real rows; rows past it
+are padding whose output the caller ignores (their KV went to the null page
+0).  The slot's mode is in those two numbers and its table row: plain decode
+is `valid = 1` with `q_offset` = tokens cached, speculative verify (Leviathan
+et al. 2023) `valid = 1 + K`, a chunk of a prompt (Sarathi-Serve, Agrawal et
+al. OSDI 2024) `valid` = its tokens, an inactive slot a null row with
+`valid = 0`.  That is what lets the engine dispatch ONE program a step
+(`models.gpt.serve_step_paged`).
 
-- `paged_attention_xla`: gather-based implementation (`pool[page_table]`) — the
-  CPU/debug fallback and the numerics oracle for tests.  XLA lowers the gather
-  to a dynamic-slice loop; fine at test scale, bandwidth-wasteful at pool scale
-  because the gathered `[B, S_max, KVH, hd]` copy round-trips HBM.
-- `paged_attention_pallas`: Pallas TPU kernel using `PrefetchScalarGridSpec` —
-  the page table and per-slot lengths are scalar-prefetched so the BlockSpec
-  index_map DMAs each slot's pages HBM->VMEM directly (no materialized gather),
-  with online-softmax accumulation over the page grid dimension and per-page
-  length masking.  Pages past a slot's length (including the reserved null
-  page 0) are masked out; whole pages beyond the length skip compute.
-
-Layout note: one query token per slot (`q [B, H, hd]`) — decode T=1 is the hot
-case the engine compiles once.  GQA folds into the kernel as G = H // KVH query
-rows per kv head.
-
-Chunked prefill (Sarathi-Serve, Agrawal et al. OSDI 2024) adds the
-`*_prefill_*` pair: a chunk of T query tokens starting at position
-`q_offset != 0` attends through the same page table with the causal mask
-`kv_pos <= q_offset + t` — positions below the offset are the already-written
-prefix (cached pages or earlier chunks), positions inside the chunk mask
-causally.  The `q_offset` lane rides the scalar prefetch next to the page
-table in the Pallas kernel and is a broadcast add in the XLA oracle.
-
-How the prefill kernel walks a slot's table (it is the kernel of every
-serving step, see below): one grid step per (slot, query tile), and inside it
-a loop over the slot's LIVE pages only, in blocks of several pages.  The
-pools stay in HBM; the kernel starts one asynchronous copy a page, addressed
-through the prefetched table, into a double-buffered VMEM scratch, block
-i + 1 in flight while block i is weighed, and does one online-softmax update
-a block.  The trip count comes from `q_offset` and `valid`, so a dead table
-entry costs nothing - no grid step, no copy, no compute - and an inactive
-slot (`valid` 0: the models pass 0 for a null row) a few scalar
-instructions.  Pages a block and kv heads a score tile are functions of the
-shapes (`_pages_per_block`, `_heads_per_tile`).  The decode kernel
-(`paged_attention_pallas`, legacy `fuse=False` path only) still takes one
-grid step a table entry.
-
-Speculative decode (Leviathan et al. 2023) verifies `spec_len + 1` candidate
-tokens per slot in one pass.  That IS the q_len > 1 decode case: query t sits
-at position `lengths[b] + t` and attends causally through the page table —
-exactly the prefill pair's contract with `q_offset = lengths` and per-slot
-`valid` counts (`valid = 1` degenerates to vanilla single-token decode, which
-is how undrafted slots ride the same fixed-shape verify executable).
-`paged_verify_attention` is that entry.
-
-The fused one-dispatch serving step (`models.gpt.serve_step_paged`) takes the
-q_offset/valid contract to its conclusion: `paged_serve_attention` is the
-single attention entry behind the engine's steady-state step, where EVERY
-slot — vanilla decode (valid = 1), spec verify (valid = 1+K) and the
-interleaved prefill chunk (valid = chunk tokens) — rides one kernel grid with
-its own per-slot q_offset/valid mask.  The per-slot mode is entirely encoded
-by those masks plus the page-table row (inactive slots are null rows), so the
-decode-side program budget collapses to ONE compiled executable.
-
-Multi-chip serving (PR 4) makes every entry mesh-aware: pass `mesh=` with an
-'mp' axis and the attention runs head-sharded tensor-parallel — the
-`paged_*_mp` wrappers shard q on its head axis and the pool on KVH, running
-the unmodified Pallas kernel per-shard (shard_map) or the XLA oracle under
-sharding constraints.  See the block comment above `_POOL_SPEC`.
+- `paged_prefill_attention`: the entry the models call.  The Pallas kernel on
+  a TPU when the layout suits it (`_shapes_ok_for_pallas`), the oracle
+  otherwise; head-sharded over `mesh`'s 'mp' axis when it has one.
+- `paged_prefill_attention_xla`: gather-based (`pool[page_table]`) — the
+  CPU/debug fallback and the numerics oracle for tests.  Fine at test scale,
+  bandwidth-wasteful at pool scale because the gathered `[B, S_max, KVH, hd]`
+  copy round-trips HBM.
+- `paged_prefill_attention_pallas`: the kernel.  One grid step per (slot,
+  query tile), and inside it a loop over the slot's LIVE pages only, in
+  blocks of several pages.  The pools stay in HBM; the kernel starts one
+  asynchronous copy a page, addressed through the scalar-prefetched table,
+  into a double-buffered VMEM scratch, block i + 1 in flight while block i is
+  weighed, and does one online-softmax update a block.  The trip count comes
+  from `q_offset` and `valid`, so a dead table entry costs nothing - no grid
+  step, no copy, no compute - and an inactive slot a few scalar
+  instructions.  Pages a block and kv heads a score tile are functions of
+  the shapes (`_pages_per_block`, `_heads_per_tile`).  GQA folds in as
+  G = H // KVH query rows per kv head.
+- `paged_prefill_attention_mp`: multi-chip serving.  Shards q on its head
+  axis and the pool on KVH and runs the unmodified kernel per shard
+  (shard_map), or the oracle under sharding constraints.  See the block
+  comment above `_POOL_SPEC`.
 """
 from __future__ import annotations
 
@@ -118,58 +94,15 @@ def _pin(mesh, x, spec):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def paged_attention_decode_mp(q, k_pages, v_pages, page_table, lengths,
-                              mesh, scale=None, use_pallas=None,
-                              interpret=False, kv_scales=None):
-    """Head-sharded `paged_attention_decode` over the `mp` axis of `mesh`.
+def paged_prefill_attention_mp(q, k_pages, v_pages, page_table, q_offset,
+                               valid, mesh, scale=None, use_pallas=None,
+                               interpret=False, kv_scales=None):
+    """Head-sharded `paged_prefill_attention` over the `mp` axis of `mesh`.
 
     use_pallas=None auto-selects (TPU + kernel-friendly layout); tests force
     True with interpret=True to run the shard_mapped kernel on CPU.
     kv_scales (int8 pool) shard on the same KVH axis as the pages — the
     dequant is per-head-local, so the mp distribution is unchanged."""
-
-    mp = _mp_degree(mesh)
-    _check_mp_heads(q.shape[1], k_pages.shape[2], mp)
-    if use_pallas is None:
-        use_pallas = _on_tpu() and _shapes_ok_for_pallas(
-            q, k_pages, quantized=kv_scales is not None)
-    if use_pallas:
-        if kv_scales is not None:
-            def local_q(tbl, ln, q_l, k_l, v_l, ks_l, vs_l):
-                return paged_attention_pallas(q_l, k_l, v_l, tbl, ln,
-                                              scale=scale, interpret=interpret,
-                                              kv_scales=(ks_l, vs_l))
-            return jax.shard_map(
-                local_q, mesh=mesh, axis_names={"mp"},
-                in_specs=(P(None, None), P(None), _head_spec(3), _POOL_SPEC,
-                          _POOL_SPEC, _SCALE_SPEC, _SCALE_SPEC),
-                out_specs=_head_spec(3))(page_table, lengths, q, k_pages,
-                                         v_pages, *kv_scales)
-
-        def local(tbl, ln, q_l, k_l, v_l):
-            return paged_attention_pallas(q_l, k_l, v_l, tbl, ln, scale=scale,
-                                          interpret=interpret)
-        return jax.shard_map(
-            local, mesh=mesh, axis_names={"mp"},
-            in_specs=(P(None, None), P(None), _head_spec(3), _POOL_SPEC,
-                      _POOL_SPEC),
-            out_specs=_head_spec(3))(page_table, lengths, q, k_pages, v_pages)
-    q = _pin(mesh, q, _head_spec(3))
-    k_pages = _pin(mesh, k_pages, _POOL_SPEC)
-    v_pages = _pin(mesh, v_pages, _POOL_SPEC)
-    if kv_scales is not None:
-        kv_scales = (_pin(mesh, kv_scales[0], _SCALE_SPEC),
-                     _pin(mesh, kv_scales[1], _SCALE_SPEC))
-    out = paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
-                              scale=scale, kv_scales=kv_scales)
-    return _pin(mesh, out, _head_spec(3))
-
-
-def paged_prefill_attention_mp(q, k_pages, v_pages, page_table, q_offset,
-                               valid, mesh, scale=None, use_pallas=None,
-                               interpret=False, kv_scales=None):
-    """Head-sharded `paged_prefill_attention` (and, via
-    `paged_verify_attention`, the spec-decode verify lane) over `mp`."""
 
     mp = _mp_degree(mesh)
     _check_mp_heads(q.shape[2], k_pages.shape[2], mp)
@@ -213,184 +146,21 @@ def paged_prefill_attention_mp(q, k_pages, v_pages, page_table, q_offset,
 
 def _dequant_gathered(pages, scales, page_table, B, S, KVH, hd):
     """Gather int8 pages through the table and dequantize by their per-token
-    scales (float32) — the oracle twin of the kernels' per-page dequant."""
+    scales (float32) — the oracle twin of the kernel's dequant on read."""
     x = pages[page_table].reshape(B, S, KVH, hd).astype(jnp.float32)
     s = scales[page_table].reshape(B, S, KVH)
     return x * s[..., None]
 
 
-def paged_attention_xla(q, k_pages, v_pages, page_table, lengths, scale=None,
-                        kv_scales=None):
-    """Gather-based paged decode attention (fallback + oracle).
-
-    q: [B, H, hd] — one query token per slot.
-    k_pages/v_pages: [P, page_size, KVH, hd] — a page pool (the model's
-        paged passes hand in all layers' pages as one [L*P, ...] pool and
-        a page table offset to the layer's rows).
-    page_table: [B, max_pages] int32 page ids (0 = reserved null page).
-    lengths: [B] int32 — number of valid tokens per slot (including the token
-        just written at position lengths-1).
-    kv_scales: (k_scale, v_scale) [P, page_size, KVH] float32 for an int8
-        pool — gathered pages dequantize to float32 before the score/PV
-        matmuls (same math as the Pallas kernels, so parity stays exact).
-    Returns [B, H, hd].
-    """
-    B, H, hd = q.shape
-    page = k_pages.shape[1]
-    KVH = k_pages.shape[2]
-    G = H // KVH
-    S = page_table.shape[1] * page
-    s = scale if scale is not None else 1.0 / math.sqrt(hd)
-    if kv_scales is not None:
-        k = _dequant_gathered(k_pages, kv_scales[0], page_table, B, S, KVH, hd)
-        v = _dequant_gathered(v_pages, kv_scales[1], page_table, B, S, KVH, hd)
-    else:
-        k = k_pages[page_table].reshape(B, S, KVH, hd)
-        v = v_pages[page_table].reshape(B, S, KVH, hd)
-    qg = q.reshape(B, KVH, G, hd)
-    logits = jnp.einsum("bkgd,bskd->bkgs", qg, k,
-                        preferred_element_type=jnp.float32) * s
-    kv_pos = jnp.arange(S)
-    logits = jnp.where(kv_pos[None, None, None] < lengths[:, None, None, None],
-                       logits, NEG_INF)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", p.astype(v.dtype), v)
-    return out.reshape(B, H, hd)
-
-
-def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *refs,
-                       page: int, KVH: int, G: int,
-                       n_pages: int, scale: float, quantized: bool = False):
-    """Grid (B, max_pages): slots parallel, pages innermost with online-softmax
-    scratch carry (acc, m, l) — same discipline as the flash forward kernel,
-    but the k/v blocks arrive via the scalar-prefetched page table.  With
-    `quantized`, two extra scale refs ([1, page, KVH] float32) follow v_ref
-    and the int8 page block dequantizes to f32 right after its DMA — the
-    per-page dequant-on-read that keeps the fp pool out of HBM entirely."""
-    from jax.experimental import pallas as pl
-
-    if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        o_ref, acc_ref, m_ref, l_ref = refs
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    H = KVH * G
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    length = len_ref[b]
-    k_start = j * page
-
-    # whole page past the slot's length (null-page tail entries): skip compute
-    @pl.when(k_start < length)
-    def _compute():
-        q = q_ref[0]                                    # [H, hd]
-        k = k_ref[0]                                    # [page, KVH, hd]
-        v = v_ref[0]
-        if quantized:
-            k = k.astype(jnp.float32) * ks_ref[0][..., None]
-            v = v.astype(jnp.float32) * vs_ref[0][..., None]
-        # GQA: per-kv-head score tiles stacked back to [H, page] rows
-        rows = []
-        for kh in range(KVH):
-            qh = q[kh * G:(kh + 1) * G]                 # [G, hd]
-            rows.append(jnp.dot(qh, k[:, kh, :].T,
-                                preferred_element_type=jnp.float32))
-        s = (jnp.concatenate(rows, axis=0) if KVH > 1 else rows[0]) * scale
-        pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        s = jnp.where(pos < length, s, NEG_INF)         # [H, page]
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                          # [H, page]
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        upd = []
-        for kh in range(KVH):
-            ph = p[kh * G:(kh + 1) * G].astype(v.dtype)
-            upd.append(jnp.dot(ph, v[:, kh, :],
-                               preferred_element_type=jnp.float32))
-        pv = jnp.concatenate(upd, axis=0) if KVH > 1 else upd[0]   # [H, hd]
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = m_new
-
-    @pl.when(j == n_pages - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
-                           scale=None, interpret=False, kv_scales=None):
-    """Pallas paged decode attention — same contract as `paged_attention_xla`.
-
-    The page table and lengths ride `PrefetchScalarGridSpec` so the k/v
-    BlockSpec index_maps resolve `pool[table[b, j]]` at DMA time; the pool is
-    never gathered into a dense per-slot copy.  With `kv_scales` (int8 pool)
-    the per-page scale blocks ride the SAME table-indexed DMA and the page
-    dequantizes in VMEM on read.  `interpret=True` runs the kernel on CPU
-    for numerics tests.
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, H, hd = q.shape
-    page = k_pages.shape[1]
-    KVH = k_pages.shape[2]
-    G = H // KVH
-    n_pages = page_table.shape[1]
-    s = scale if scale is not None else 1.0 / math.sqrt(hd)
-
-    kernel = functools.partial(_paged_attn_kernel, page=page, KVH=KVH, G=G,
-                               n_pages=n_pages, scale=s,
-                               quantized=kv_scales is not None)
-    pool_spec = pl.BlockSpec((1, page, KVH, hd),
-                             lambda b, j, tbl, ln: (tbl[b, j], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, H, hd), lambda b, j, tbl, ln: (b, 0, 0)),
-        pool_spec, pool_spec,
-    ]
-    args = [q, k_pages, v_pages]
-    if kv_scales is not None:
-        scale_spec = pl.BlockSpec((1, page, KVH),
-                                  lambda b, j, tbl, ln: (tbl[b, j], 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        args += [kv_scales[0], kv_scales[1]]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,          # (page_table, lengths)
-        grid=(B, n_pages),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, j, tbl, ln: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, hd), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        name="paged_decode",
-        grid_spec=grid_spec,
-        out_shape=_out_struct((B, H, hd), q.dtype, q, k_pages, v_pages),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(jnp.asarray(page_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      *args)
-
-
 def paged_prefill_attention_xla(q, k_pages, v_pages, page_table, q_offset,
                                 valid, scale=None, kv_scales=None):
-    """Gather-based chunked-prefill paged attention (fallback + oracle).
+    """Gather-based paged attention (fallback + oracle).
 
     q: [B, T, H, hd] — a chunk of T query tokens per slot; query t sits at
         absolute position q_offset[b] + t.
-    k_pages/v_pages: [P, page_size, KVH, hd] — a page pool (see above).
+    k_pages/v_pages: [P, page_size, KVH, hd] — a page pool (the model's
+        paged passes hand in all layers' pages as one [L*P, ...] pool and
+        a page table offset to the layer's rows).
     page_table: [B, max_pages] int32 page ids (0 = reserved null page).
     q_offset: [B] int32 — absolute position of q[:, 0] (prefix already
         written below it: cached pages or earlier chunks).
@@ -484,8 +254,8 @@ def _paged_prefill_kernel(tbl_ref, qoff_ref, val_ref, q_ref, k_hbm, v_hbm,
                           ppb: int, hg: int, scale: float,
                           quantized: bool = False):
     """Grid (B, T/bt), both parallel: one grid step per slot and query tile
-    of bt*H rows (kh-major stacking, same discipline as the decode kernel;
-    VMEM use is set by the tile, not by T).  The pools stay in HBM.  Inside
+    of bt*H rows (kh-major stacking; VMEM use is set by the tile, not by
+    T).  The pools stay in HBM.  Inside
     the step a `fori_loop` walks the slot's LIVE pages only — those at or
     below the tile's highest real query position — in blocks of `ppb` pages:
     block i+1's page copies (one `make_async_copy` a live page, addressed
@@ -504,7 +274,7 @@ def _paged_prefill_kernel(tbl_ref, qoff_ref, val_ref, q_ref, k_hbm, v_hbm,
     lanes, gathered through its table by the caller ([1, entries, page, KVH]
     blocks: Mosaic cannot address a copy into an HBM array whose minor
     dimension is narrower than the lanes): the int8 block dequantizes to f32
-    on read, same math as the decode kernel."""
+    on read, the oracle's math."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -619,7 +389,7 @@ def _paged_prefill_kernel(tbl_ref, qoff_ref, val_ref, q_ref, k_hbm, v_hbm,
 def paged_prefill_attention_pallas(q, k_pages, v_pages, page_table, q_offset,
                                    valid, scale=None, interpret=False,
                                    kv_scales=None):
-    """Pallas chunked-prefill paged attention — same contract as
+    """Pallas paged attention — same contract as
     `paged_prefill_attention_xla`.  page_table / q_offset / valid ride
     `PrefetchScalarGridSpec` and are all the walk needs: the trip count of a
     slot's loop and the address of every page copy come from them.  The T
@@ -694,10 +464,11 @@ def paged_prefill_attention_pallas(q, k_pages, v_pages, page_table, q_offset,
 
 def paged_prefill_attention(q, k_pages, v_pages, page_table, q_offset, valid,
                             scale=None, mesh=None, kv_scales=None):
-    """Entry used by `models.gpt.prefill_chunk_paged`: Pallas on TPU when the
-    layout is kernel-friendly, gather fallback otherwise.  mesh (with an 'mp'
-    axis > 1) runs head-sharded tensor-parallel.  kv_scales (int8 pool)
-    selects the per-page dequant-on-read lane in every route."""
+    """The serving passes' attention (`models.gpt._paged_chunk_hidden`,
+    `models.hybrid`; contract in the module docstring): Pallas on TPU when
+    the layout is kernel-friendly, gather fallback otherwise.  mesh (with an
+    'mp' axis > 1) runs head-sharded tensor-parallel.  kv_scales (int8 pool)
+    selects the dequant-on-read lane in every route."""
     if _mp_degree(mesh) > 1:
         return paged_prefill_attention_mp(q, k_pages, v_pages, page_table,
                                           q_offset, valid, mesh, scale=scale,
@@ -723,51 +494,3 @@ def _shapes_ok_for_pallas(q, k_pages, quantized=False):
         # on real hardware; anything else takes the XLA dequant-gather path
         ok = ok and hd in (128, 256) and page % 32 == 0
     return ok
-
-
-def paged_verify_attention(q, k_pages, v_pages, page_table, lengths, valid,
-                           scale=None, mesh=None, kv_scales=None):
-    """Entry used by `models.gpt.verify_step_paged`: multi-token (q_len > 1)
-    decode over the paged pool.  q [B, T, H, hd] holds the last emitted token
-    plus up to T-1 drafted tokens per slot; query t sits at absolute position
-    `lengths[b] + t`, and rows t >= valid[b] are padding whose output the
-    scheduler ignores (their KV was routed to the null page).  Same math as
-    the chunked-prefill pair with `q_offset = lengths` — one kernel serves
-    both lanes, keeping the decode-side compiled-program count at two."""
-    return paged_prefill_attention(q, k_pages, v_pages, page_table, lengths,
-                                   valid, scale=scale, mesh=mesh,
-                                   kv_scales=kv_scales)
-
-
-def paged_serve_attention(q, k_pages, v_pages, page_table, q_offset, valid,
-                          scale=None, mesh=None, kv_scales=None):
-    """Entry used by `models.gpt.serve_step_paged` — the fused one-dispatch
-    engine step.  Identical math to the prefill/verify pair (causal-at-offset
-    through the page table), but the batch is heterogeneous: each slot's
-    (q_offset, valid) pair selects its mode — decode rides at valid=1 with
-    q_offset = cached length, verify at valid=1+K, a prefill chunk at
-    valid = chunk tokens with q_offset = tokens already written — and padded
-    rows (t >= valid) are masked per slot, their KV routed to the null page
-    by the caller.  One kernel serves every lane of the steady-state step,
-    which is what lets the engine dispatch exactly one program per
-    iteration."""
-    return paged_prefill_attention(q, k_pages, v_pages, page_table, q_offset,
-                                   valid, scale=scale, mesh=mesh,
-                                   kv_scales=kv_scales)
-
-
-def paged_attention_decode(q, k_pages, v_pages, page_table, lengths,
-                           scale=None, mesh=None, kv_scales=None):
-    """Entry used by `models.gpt.decode_step_paged`: Pallas on TPU when the
-    layout is kernel-friendly, gather fallback otherwise.  mesh (with an 'mp'
-    axis > 1) runs head-sharded tensor-parallel."""
-    if _mp_degree(mesh) > 1:
-        return paged_attention_decode_mp(q, k_pages, v_pages, page_table,
-                                         lengths, mesh, scale=scale,
-                                         kv_scales=kv_scales)
-    if _on_tpu() and _shapes_ok_for_pallas(q, k_pages,
-                                           quantized=kv_scales is not None):
-        return paged_attention_pallas(q, k_pages, v_pages, page_table, lengths,
-                                      scale=scale, kv_scales=kv_scales)
-    return paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
-                               scale=scale, kv_scales=kv_scales)

@@ -13,8 +13,8 @@ and Orca's iteration-level scheduling (Yu et al., OSDI 2022), under the same
   pages per layer (`models.gpt.init_paged_cache`) + per-slot page tables
   (`inference.cache.PagedKVCache`): memory scales with live tokens, pages
   recycle as requests retire.
-- **Slot-indexed decode** — ONE compiled decode program of fixed batch
-  `num_slots` (`models.gpt.decode_step_paged`) serves a churning request set;
+- **Slot-indexed decode** — ONE compiled step program of fixed batch
+  `num_slots` (`models.gpt.serve_step_paged`) serves a churning request set;
   retired slots are refilled without recompiling.
 - **Prefix cache** (vLLM copy-on-write page sharing) — prompt pages are
   content-hashed at page granularity as their KV lands; admission maps the
@@ -27,34 +27,31 @@ and Orca's iteration-level scheduling (Yu et al., OSDI 2022), under the same
   (`models.gpt.prefill_chunk_paged`, any q_offset), and `step()` interleaves
   at most one chunk with each decode iteration: a 4k-token prompt no longer
   stalls every decode slot for a whole bucket-padded pass, and the prefill
-  program count collapses from #buckets to <= 2 (to 0 under the default
-  fused step, where the chunk rides the fused batch).  The legacy bucketed
-  one-shot path (`prefill_paged`, power-of-2 buckets) remains the default for
-  uncached prompts when `prefill_chunk=None`.
+  program count collapses from #buckets to 0: the chunk rides the step's
+  one batch.  The bucketed one-shot path (`prefill_paged`, power-of-2
+  buckets) is the default for uncached prompts (`prefill_chunk=None`); there
+  the standalone chunk program serves the tails of prefix hits.
 - **Speculative decoding** (Leviathan et al. 2023; prompt-lookup drafting a la
   vLLM) — `spec_len=K` breaks the one-token-per-step decode bound: a pluggable
   `DraftProposer` (default: n-gram self-drafting from the slot's own
   prompt+generated history, `inference.spec.NgramProposer`) guesses up to K
-  continuation tokens per slot, ONE fixed-shape verify executable
-  (`models.gpt.verify_step_paged`) scores all K+1 positions through the same
-  paged attention, and greedy longest-prefix acceptance emits 1..K+1 tokens
-  with output exactly identical to vanilla decode whenever the verify and
-  decode executables agree at argmax — guaranteed at matching kernel
-  numerics (asserted token-exact on CPU in tests; under TPU bf16 matmuls a
-  near-tie could in principle resolve differently between the two programs,
-  still a valid greedy decode of the model).  Rejected candidates roll
-  back as a per-slot length decrement (their KV is stale garbage inside the
-  slot's own reserved pages, overwritten on reuse); slots with no draft ride
-  at valid=1 (plain decode).  Under the default fused step the verify lane
-  is part of the ONE fused program (decode-side count: 1); with `fuse=False`
-  it is its own executable and sampled slots fall back to vanilla decode in
-  the same iteration (decode-side count: 2).
+  continuation tokens per slot, the step program scores all K+1 positions
+  through the same paged attention, and greedy longest-prefix acceptance
+  emits 1..K+1 tokens with output exactly identical to vanilla decode
+  whenever the K+1-wide and the one-token scoring agree at argmax —
+  guaranteed at matching kernel numerics (asserted token-exact on CPU in
+  tests; under TPU bf16 matmuls a near-tie could in principle resolve
+  differently, still a valid greedy decode of the model).  Rejected
+  candidates roll back as a per-slot length decrement (their KV is stale
+  garbage inside the slot's own reserved pages, overwritten on reuse); slots
+  with no draft ride at valid=1 (plain decode).  The verify lane is part of
+  the ONE step program (decode-side count: 1).
 - **Scheduler** — each `step()` admits queued requests into free slots
   (reservation-based page admission with prefix matching), advances at most
   one prefill chunk, runs one decode iteration over all fully-prefilled
   slots, and retires finished sequences (EOS or max_new_tokens), returning
   their pages to the refcounted pool.
-- **One-dispatch fused step** (default, `fuse=True`; the reference's
+- **One-dispatch fused step** (the reference's
   single-graph `AnalysisPredictor::ZeroCopyRun` step + true Sarathi
   piggybacking) — the steady-state step dispatches exactly ONE fixed-shape
   program (`models.gpt.serve_step_paged`): vanilla decode slots ride at
@@ -65,10 +62,8 @@ and Orca's iteration-level scheduling (Yu et al., OSDI 2022), under the same
   and the spec longest-prefix accept scan all run inside the program, so the
   per-step host fetch is a `[B, K+1] + [B]` int32 token/accept buffer —
   ~3 orders of magnitude smaller than `[B, V]` logits — and the decode-side
-  compiled-program count is ONE.  `fuse=False` keeps the legacy
-  three-program step (decode + chunk + verify, host-side sampling) as the
-  A/B baseline (`bench_serve.py --no-fuse`).
-- **Double-buffered scheduling** (`double_buffer=True`, fused mode only) —
+  compiled-program count is ONE.
+- **Double-buffered scheduling** (`double_buffer=True`) —
   the fused dispatch returns un-synced: the host finishes its step-n
   bookkeeping and the caller's loop while the device computes, and the token
   fetch for step n happens at the TOP of step n+1 inside the
@@ -103,10 +98,10 @@ and Orca's iteration-level scheduling (Yu et al., OSDI 2022), under the same
   chunk interleave, verify dispatches, tokens emitted, page-pool levels —
   the victim-selection signal the ROADMAP's preemption work needs.
   `engine.trace(dir)` wraps a serving window in `profiler.RecordEvent` spans
-  around the host phases (admit, chunk dispatch, proposer scan, verify/decode
-  dispatch, acceptance, sample sync), exports them as a chrome trace next to
-  the step timeline and a metrics dump, and starts/stops a `jax.profiler`
-  device capture when available.  Instrumentation is host-only: zero new
+  around the host phases (admit, prefill dispatch, proposer scan, batch
+  build, fused dispatch, sample sync, emit), exports them as a chrome trace
+  next to the step timeline and a metrics dump, and starts/stops a
+  `jax.profiler` device capture when available.  Instrumentation is host-only: zero new
   compiled programs, spans skipped entirely unless a trace is recording.
 
 - **Health & perf signals** (the router-grade signal plane over the
@@ -355,14 +350,12 @@ _NULL_SPAN = _NullSpan()
 # scheduler.  admit covers prefix matching + reservation (+ the one-shot
 # bucketed prefill when taken synchronously); dispatch spans end when the
 # async call returns, sample/accept spans contain the blocking device sync.
-# The fused step (default) dispatches through engine.fused.dispatch; the
-# decode/verify/prefill dispatch spans belong to the legacy fuse=False path
-# (prefill.dispatch also covers the bucketed cold path in fused mode).
-# engine.turnaround (fused mode, see `LLMEngine._turn_begin`) is the host
-# stretch the device waits for between two fused programs; emit, admit,
+# The step dispatches through engine.fused.dispatch; prefill.dispatch covers
+# the bucketed one-shot prefill and the standalone chunk program of a
+# prefix-hit tail.  engine.turnaround (see `LLMEngine._turn_begin`) is the
+# host stretch the device waits for between two fused programs; emit, admit,
 # batch.build and fused.dispatch (with fused.h2d, its five puts, inside) tile
-# it.  spec.accept is the legacy verify path's host-side draft acceptance.
-# swap.d2h holds .ready (wait for the gather) and .copy (device -> host).
+# it.  swap.d2h holds .ready (wait for the gather) and .copy (device -> host).
 ENGINE_SPANS = (
     "engine.step",
     "engine.turnaround",
@@ -373,9 +366,6 @@ ENGINE_SPANS = (
     "engine.batch.build",
     "engine.fused.dispatch",
     "engine.fused.h2d",
-    "engine.verify.dispatch",
-    "engine.spec.accept",
-    "engine.decode.dispatch",
     "engine.sample.sync",
     "engine.swap.d2h",
     "engine.swap.d2h.ready",
@@ -445,27 +435,25 @@ class LLMEngine:
 
     `spec_len=K` (> 0) enables speculative decoding: `draft_proposer`
     (default `NgramProposer`) guesses up to K continuation tokens per greedy
-    slot each iteration, one fixed-shape verify executable scores K+1
-    positions, and greedy longest-prefix acceptance emits 1..K+1 tokens per
-    step with exact vanilla-decode token parity.  Drafting applies only to
-    greedy slots — acceptance needs a deterministic pick — so sampled slots
-    keep the vanilla decode program.  `spec_backoff_window=W` (adaptive
+    slot each iteration, the step program scores K+1 positions, and greedy
+    longest-prefix acceptance emits 1..K+1 tokens per step with exact
+    vanilla-decode token parity.  Drafting applies only to greedy slots —
+    acceptance needs a deterministic pick — so sampled slots ride the same
+    program at valid=1.  `spec_backoff_window=W` (adaptive
     spec_len, 0 disables): a slot whose drafts go W consecutive verify events
     without a single accepted token stops being drafted for — it skips the
     proposer scan and rides verify at valid=1 (`stats()["spec_backoffs"]`).
 
-    `fuse=True` (default) collapses the steady-state step to ONE fixed-shape
-    dispatch with on-device sampling/acceptance (`gpt.serve_step_paged`): a
+    The steady-state step is ONE fixed-shape dispatch with on-device
+    sampling/acceptance (`gpt.serve_step_paged`): a
     busy step's decode slots, verify slots and the interleaved prefill chunk
     share one `[num_slots, max(spec_len+1, prefill_chunk)]` batch, and the
     host fetches a small int token/accept buffer instead of `[B, V]` logits.
-    `double_buffer=True` (default in fused mode) makes the dispatch return
+    `double_buffer=True` (default) makes the dispatch return
     un-synced, moving the token fetch for step *n* to the top of step *n+1*
     (inside the `engine.sample.sync` span) so the device computes while the
     host schedules — finishes are then observed one `step()` later than in
-    synchronous mode, which `run()`/`has_work` account for.  `fuse=False` is
-    the legacy three-program step (`bench_serve.py --no-fuse`), byte-exact
-    greedy-parity with the fused path.
+    synchronous mode, which `run()`/`has_work` account for.
 
     Observability: `engine.metrics` is the metrics registry (counters,
     page/queue gauges, latency histograms; `to_prometheus()` for scraping),
@@ -511,7 +499,7 @@ class LLMEngine:
     scale lanes, quantized at every in-program write and dequantized per
     page on read inside the paged-attention kernels.  Both default off and
     the fp engine is byte-identical to a quantization-free build; the
-    quantized engine keeps every internal parity bar (fused/mp/preempt)
+    quantized engine keeps every internal parity bar (mp/preempt)
     against itself, while outputs vs the fp engine are a top-1 agreement
     RATE (quantization is lossy) reported by `bench_serve.py
     --weight-dtype/--kv-dtype int8`.
@@ -531,8 +519,12 @@ class LLMEngine:
     host memory — the paging/prefix/COW logic is mp-oblivious — and greedy
     outputs are token-identical to single-chip serving.  Per-mesh-config the
     compiled decode-side program count is unchanged: the ONE fused step
-    program (<= 2 with `fuse=False`).
+    program.
     """
+
+    # always True: benchmarks/drivers/serve.py:66 and serve_hybrid.py:115
+    # read it in their default-mode check
+    fused = True
 
     def __init__(self, params, config: gpt_mod.GPTConfig, *,
                  num_slots: int = 4, page_size: int = 16,
@@ -546,7 +538,6 @@ class LLMEngine:
                  spec_len: int = 0,
                  draft_proposer: Optional[DraftProposer] = None,
                  spec_backoff_window: int = 8,
-                 fuse: bool = True,
                  double_buffer: Optional[bool] = None,
                  admission: str = "reservation",
                  preempt: str = "recompute",
@@ -588,7 +579,7 @@ class LLMEngine:
         self.recurrent = getattr(config, "layer_pattern", None) is not None
         if self.recurrent:
             self._refuse_for_recurrent(
-                spec_len=spec_len, fuse=fuse, admission=admission,
+                spec_len=spec_len, admission=admission,
                 preempt=preempt, weight_dtype=self.weight_dtype,
                 kv_dtype=self.kv_dtype, mp=mp, mesh=mesh, role=role)
         self._kv_page_bytes = kv_page_bytes(config, page_size, self.kv_dtype)
@@ -713,9 +704,8 @@ class LLMEngine:
         # width covers the widest lane that can ride it — K+1 verify rows
         # and, in chunked mode, the prefill chunk (choose prefill_chunk near
         # spec_len+1 to minimize decode-row padding)
-        self.fused = bool(fuse)
-        self.double_buffer = self.fused and \
-            (True if double_buffer is None else bool(double_buffer))
+        self.double_buffer = True if double_buffer is None \
+            else bool(double_buffer)
         self._fused_T = max(self.spec_len + 1,
                             prefill_chunk if self.chunked else 1)
         if admission not in ("reservation", "optimistic"):
@@ -917,7 +907,7 @@ class LLMEngine:
         self._turnaround_ms_c = m.counter(
             "turnaround_ms",
             "host milliseconds between a fused program's result in hand and "
-            "the next fused launch's return (fused mode)")
+            "the next fused launch's return")
         self._recomputed_tokens = m.counter(
             "recomputed_tokens",
             "prompt tokens re-prefilled because of preemption")
@@ -1118,13 +1108,6 @@ class LLMEngine:
             return {n: jax.lax.with_sharding_constraint(a, pool_sh)
                     for n, a in pool.items()}
 
-        def decode_impl(params, tokens, pool, table, lengths, key, greedy):
-            logits, pool = gpt_mod.decode_step_paged(params, tokens, pool,
-                                                     table, lengths, cfg,
-                                                     mesh=mesh_)
-            nxt, key = pick(logits, key, greedy)
-            return nxt, pin_pool(pool), key
-
         def prefill_impl(params, ids, pool, pages, length, key, greedy):
             logits, pool = gpt_mod.prefill_paged(params, ids, cfg, pool,
                                                  pages, length, mesh=mesh_)
@@ -1137,14 +1120,6 @@ class LLMEngine:
                                                        mesh=mesh_)
             tok, key = pick(logits, key, greedy)
             return tok, pin_pool(pool), key
-
-        def verify_impl(params, tokens, pool, table, lengths, valid):
-            # greedy-only lane: acceptance compares argmax at every position,
-            # no key threads through (spec parity requires determinism)
-            logits, pool = gpt_mod.verify_step_paged(params, tokens, pool,
-                                                     table, lengths, valid,
-                                                     cfg, mesh=mesh_)
-            return gpt_mod.sharded_argmax(logits, mesh_), pin_pool(pool)
 
         temp_, topk_ = temperature, top_k
 
@@ -1214,26 +1189,14 @@ class LLMEngine:
             if self.mp > 1 \
             else (lambda fn, donate, skip=0:
                   jax.jit(fn, donate_argnums=donate))
-        if self.recurrent:
-            # fused mode only (`_refuse_for_recurrent`); no prefix hit, so no
-            # tail for a standalone chunk program to serve
-            self._decode_fn = jit_(fused_impl, (2,), 1)
-            self._verify_fn = None
-            self._chunk_fn = None
-        elif self.fused:
-            # the fused program IS the decode-side executable; the legacy
-            # verify program is never built (decode-side count: exactly 1),
-            # and in chunked mode the chunk rides the fused batch so the
-            # standalone chunk program goes too.  Bucketed mode keeps the
-            # chunk program for prefix-hit tails (cold path, like the
-            # bucketed one-shot prefill).
-            self._decode_fn = jit_(fused_impl, (2,), 1)  # skip=1: params static
-            self._verify_fn = None
-            self._chunk_fn = None if self.chunked else jit_(chunk_impl, (2,), 1)
-        else:
-            self._decode_fn = jit_(decode_impl, (2,), 1)
-            self._verify_fn = jit_(verify_impl, (2,), 1)
-            self._chunk_fn = jit_(chunk_impl, (2,), 1)
+        # the fused program IS the decode-side executable (count: exactly
+        # 1).  In chunked mode the chunk rides its batch; bucketed mode keeps
+        # a standalone chunk program for prefix-hit tails (cold path, like
+        # the bucketed one-shot prefill) — which a recurrent configuration
+        # never has (no prefix hit)
+        self._decode_fn = jit_(fused_impl, (2,), 1)  # skip=1: params static
+        self._chunk_fn = None if self.chunked or self.recurrent \
+            else jit_(chunk_impl, (2,), 1)
         self._prefill_fn = jit_(prefill_impl, (2,), 1)
         self._copy_fn = jit_(copy_impl, (0,))
         self._swap_out_fn = jit_(swap_out_impl, ())
@@ -1571,8 +1534,8 @@ class LLMEngine:
         return _NULL_SPAN
 
     def _turn_begin(self, t: float) -> None:
-        """Open `engine.turnaround`, the host stretch the device waits for
-        (fused mode only): from `t`, the instant the previous fused program's
+        """Open `engine.turnaround`, the host stretch the device waits for:
+        from `t`, the instant the previous fused program's
         result was in hand — `_harvest`'s device_get has returned — or the
         step's start when nothing was in flight, to the return of this
         step's fused launch (`_turn_end`).  Always measured (one more clock
@@ -1644,8 +1607,7 @@ class LLMEngine:
         buffered mode), admit queued requests into free slots (prefix-cache
         matching + page reservation), stage at most ONE prefill chunk, then
         dispatch decode work — ONE fused program covering every decode/
-        verify/chunk slot (default), or the legacy per-mode programs
-        (`fuse=False`).  Returns the requests that finished this iteration
+        verify/chunk slot.  Returns the requests that finished this iteration
         (under double-buffering a request finishes the step its tokens are
         harvested, one after its last dispatch).
 
@@ -1671,29 +1633,24 @@ class LLMEngine:
         self._step_aux = dict.fromkeys(("moe_pairs_here", "moe_pairs_away",
                                         "moe_experts_touched"), 0)
         with self._step_marker(), self._span("engine.step"):
-            if self.fused and self._inflight is None:
+            if self._inflight is None:
                 self._turn_begin(t0)
             # step n-1's tokens land first
-            self._harvest(finished, turnaround=self.fused)
+            self._harvest(finished, turnaround=True)
             if self._has_deadlines:
                 # right after harvest: bookkeeping is exact, nothing in flight
                 self._expire_deadlines(finished)
             with self._span("engine.admit"):
                 self._admit(finished)
-            if self.fused:
-                if self.chunked:
-                    chunk_job = self._stage_chunk()
-                else:
-                    # bucketed mode: prefix-hit tails keep the standalone
-                    # chunk program (cold path, next to the one-shot prefill)
-                    self._prefill_tick(finished)
-                    chunk_job = None
-                if self._running or chunk_job is not None:
-                    self._fused_iter(chunk_job, finished)
+            if self.chunked:
+                chunk_job = self._stage_chunk()
             else:
+                # bucketed mode: prefix-hit tails keep the standalone
+                # chunk program (cold path, next to the one-shot prefill)
                 self._prefill_tick(finished)
-                if self._running:
-                    self._decode_iter(finished)
+                chunk_job = None
+            if self._running or chunk_job is not None:
+                self._fused_iter(chunk_job, finished)
             # decode-batch occupancy of what actually DISPATCHED: on a
             # preemption step the pre-dispatch running count overstates the
             # batch (victims left before the program ran)
@@ -1720,9 +1677,7 @@ class LLMEngine:
         self._step_idx += 1
         mgr = self.cache
         self._step_trace.append({
-            # v2 record (PR "one-dispatch step"): v1 keys unchanged, plus
-            # `v`/`fused`/`dispatches`/`sync_ms`/`slots` — consumers keyed on
-            # the v1 schema keep working, fusion-aware ones check `v`
+            # v2 record: the v1 keys plus `v`/`dispatches`/`sync_ms`/`slots`
             "v": 2,
             "step": self._step_idx,
             "t": t0,
@@ -1741,16 +1696,15 @@ class LLMEngine:
             "pages_walked": self._step_pages_walked,
             "pages_free": mgr.num_free_pages,
             "pages_evictable": mgr.num_evictable_pages,
-            "fused": self.fused,
-            # decode-path dispatches this step (fused/decode/verify/chunk-
-            # interleave programs; the admission-time one-shot prefill is the
-            # cold path and is not counted)
+            # decode-path dispatches this step (the fused program and the
+            # standalone chunk program; the admission-time one-shot prefill
+            # is the cold path and is not counted)
             "dispatches": self._step_dispatches,
             # blocking device->host sync time spent inside this step's
-            # engine.sample.sync spans (harvest + legacy inline fetches)
+            # engine.sample.sync spans (harvest + prefill first-token fetches)
             "sync_ms": self._step_sync_s * 1e3,
-            # engine.turnaround of this step (0 when it launched nothing or
-            # in legacy mode) and the swap/spill fetches it drained
+            # engine.turnaround of this step (0 when it launched nothing)
+            # and the swap/spill fetches it drained
             "turnaround_ms": self._step_turnaround_s * 1e3,
             "d2h_ms": self._step_d2h_s * 1e3,
             # per-mode slot occupancy of this step's decode-path dispatches
@@ -1772,7 +1726,7 @@ class LLMEngine:
 
     # ---- fused one-dispatch step machinery --------------------------------
     def _stage_chunk(self) -> Optional[Dict[str, object]]:
-        """Chunked+fused mode: pick the oldest mid-prefill slot's next chunk
+        """Chunked mode: pick the oldest mid-prefill slot's next chunk
         and describe it for the fused batch (no standalone dispatch).  The
         host bookkeeping that doesn't need the result — filled counter,
         prefix registration — happens here; a chunk that completes its
@@ -1931,9 +1885,8 @@ class LLMEngine:
         EOS cut, length advance (rejected candidate KV above it is stale
         garbage inside the slot's own reservation), token/spec counters, the
         zero-accept back-off streak — and retire the slot if it finished.
-        The ONE copy both the fused harvest and the legacy `_verify_iter` go
-        through, so their byte parity cannot drift.  Returns True when the
-        caller must drop the slot from the running set."""
+        Returns True when the caller must drop the slot from the running
+        set."""
         room = seq.request.max_new_tokens - len(seq.generated)
         emitted = emitted[:room]
         if self.eos_token_id is not None and self.eos_token_id in emitted:
@@ -1965,7 +1918,7 @@ class LLMEngine:
         return self._maybe_finish(seq, finished)
 
     @staticmethod
-    def _refuse_for_recurrent(*, spec_len, fuse, admission, preempt,
+    def _refuse_for_recurrent(*, spec_len, admission, preempt,
                               weight_dtype, kv_dtype, mp, mesh, role) -> None:
         """What a configuration with recurrent state cannot be served with
         yet, each with the reason (ROADMAP queue B has what would lift it)."""
@@ -1979,9 +1932,6 @@ class LLMEngine:
             raise ValueError(
                 why + "cannot be preempted by swap: the swap programs move "
                 "pages, not the slot's state (use preempt='recompute')")
-        if not fuse:
-            raise ValueError(
-                why + "is served by the fused step only (fuse=True)")
         if weight_dtype is not None or kv_dtype is not None:
             raise ValueError(
                 why + "has no quantized serving path (weight_dtype and "
@@ -2037,7 +1987,7 @@ class LLMEngine:
         candidate).  A failed growth is THE preemption trigger: victims are
         evicted until the growth fits, the growing slot itself last of all
         (it re-queues at the head and replays later).  Runs strictly after
-        the step-top harvest, so no fused batch is in flight while page
+        the step-top harvest, so nothing is in flight while page
         state moves (the TPL007 discipline).  `drafts` is pruned of any slot
         that got preempted.  Reservation mode returns immediately — every
         slot's full footprint is already reserved."""
@@ -2636,7 +2586,7 @@ class LLMEngine:
                 self._prefix_cached_tokens.inc(matched)
                 self._prefix_hit_requests.inc()
             if not self.chunked and matched == 0:
-                # legacy one-shot bucketed prefill, synchronous at admission
+                # one-shot bucketed prefill, synchronous at admission
                 bucket = self._bucket_for(lp)
                 self._tev(rid, "prefill", n=int(lp), bucket=int(bucket))
                 ids = np.zeros((1, bucket), np.int32)
@@ -2676,10 +2626,9 @@ class LLMEngine:
     def _prefill_tick(self, finished: List[RequestOutput]) -> None:
         """Advance the oldest admitted prompt by ONE chunk through the
         standalone chunk program (the Sarathi interleave cap: long prompts
-        share each iteration with decode instead of stalling it).  Legacy
-        `fuse=False` path, plus prefix-hit tails in fused bucketed mode; in
-        fused chunked mode the chunk rides the fused batch instead
-        (`_stage_chunk`)."""
+        share each iteration with decode instead of stalling it).  Serves
+        prefix-hit tails in bucketed mode; in chunked mode the chunk rides
+        the fused batch instead (`_stage_chunk`)."""
         if not self._prefilling:
             return
         slot, st = next(iter(self._prefilling.items()))
@@ -2752,29 +2701,6 @@ class LLMEngine:
         if not self._maybe_finish(seq, finished):
             self._running[slot] = seq
 
-    def _decode_iter(self, finished: List[RequestOutput]) -> None:
-        """One decode iteration over every fully-prefilled slot: when any
-        greedy slot has a draft, greedy slots ride the verify executable
-        (undrafted ones at valid=1 — plain decode through the same program)
-        and sampled slots fall back to the vanilla decode executable in the
-        same iteration; otherwise everything takes the vanilla path."""
-        if self.spec_len:
-            with self._span("engine.spec.propose"):
-                drafts = self._propose_drafts()
-        else:
-            drafts = {}
-        self._grow_running(drafts)
-        if not self._running:
-            return                      # everything got preempted this step
-        self._decode_iters.inc()
-        if drafts:
-            self._verify_iter(drafts, finished)
-            rest = [s for s, seq in self._running.items() if not seq.greedy]
-        else:
-            rest = list(self._running)
-        if rest:
-            self._vanilla_decode_iter(rest, finished)
-
     def _propose_drafts(self) -> Dict[int, np.ndarray]:
         """Ask the proposer for up to spec_len continuation tokens per greedy
         slot, capped at the slot's remaining decode budget so speculative KV
@@ -2808,144 +2734,27 @@ class LLMEngine:
                 drafts[slot] = np.asarray(d, np.int32).reshape(-1)[:cap]
         return drafts
 
-    def _verify_iter(self, drafts: Dict[int, np.ndarray],
-                     finished: List[RequestOutput]) -> None:
-        """Score spec_len + 1 positions for every greedy slot in ONE verify
-        dispatch, then accept the longest drafted prefix the model agrees
-        with plus the bonus token from the first disagreeing position.
-        Rollback of rejected candidates is a length decrement: lengths only
-        ever advances past KV that is certainly correct, and the stale
-        candidate KV above it sits in the slot's own reserved pages where the
-        next decode/verify write overwrites it before it can be attended."""
-        mgr = self.cache
-        B, T = mgr.num_slots, self.spec_len + 1
-        tokens = np.zeros((B, T), np.int32)
-        valid = np.ones((B,), np.int32)
-        qoff = np.zeros((B,), np.int32)
-        # free/prefilling/sampled slots must look inactive: null table rows
-        # route their (garbage) KV writes to the null page
-        table = mgr.page_table.copy()
-        active = []
-        for slot in range(B):
-            seq = self._running.get(slot)
-            if seq is None or not seq.greedy:
-                table[slot, :] = 0
-                continue
-            active.append(slot)
-            tokens[slot, 0] = seq.generated[-1]
-            d = drafts.get(slot)
-            if d is not None:
-                tokens[slot, 1:1 + d.size] = d
-                valid[slot] = 1 + d.size
-            qoff[slot] = mgr.lengths[slot]
-        self._note_walk(table, qoff, valid)
-        with self._span("engine.verify.dispatch"):
-            preds, self._pool = self._verify_fn(
-                self.params, self._h2d(tokens), self._pool,
-                self._h2d(table), self._h2d(qoff), self._h2d(valid))
-        self._decode_used = True
-        self._step_dispatches += 1
-        self._step_slots["verify"] += len(active)
-        t_sync = self._now()
-        with self._span("engine.sample.sync"):
-            preds = jax.device_get(preds)   # blocks on the device result
-        self._step_sync_s += self._now() - t_sync
-        self._verify_steps.inc()
-        with self._span("engine.spec.accept"):
-            for slot in active:
-                seq = self._running[slot]
-                d = drafts.get(slot)
-                nd = 0 if d is None else d.size
-                a = 0
-                while a < nd and int(d[a]) == int(preds[slot, a]):
-                    a += 1          # greedy longest-prefix acceptance
-                emitted = [int(x) for x in d[:a]] if nd else []
-                emitted.append(int(preds[slot, a]))        # bonus token
-                if self._emit_slot(seq, slot, emitted, nd, a, finished):
-                    del self._running[slot]
-
-    def _vanilla_decode_iter(self, slots: List[int],
-                             finished: List[RequestOutput]) -> None:
-        mgr = self.cache
-        active = set(slots)
-        tokens = np.zeros((mgr.num_slots,), np.int32)
-        greedy = np.zeros((mgr.num_slots,), bool)
-        for slot in active:
-            seq = self._running[slot]
-            tokens[slot] = seq.generated[-1]
-            greedy[slot] = seq.greedy
-        table = mgr.page_table
-        # mid-prefill slots and running slots already served by this
-        # iteration's verify dispatch must look inactive to the decode
-        # executable: a null table row routes its (garbage) KV write to the
-        # null page instead of a position inside the slot's REAL pages
-        masked = [s for s in range(mgr.num_slots)
-                  if s in self._prefilling or
-                  (s in self._running and s not in active)]
-        if masked:
-            table = table.copy()
-            for slot in masked:
-                table[slot, :] = 0
-        with self._span("engine.decode.dispatch"):
-            nxt, self._pool, self._key = self._decode_fn(
-                self.params, self._h2d(tokens), self._pool,
-                self._h2d(table), self._h2d(mgr.lengths), self._key,
-                self._h2d(greedy))
-        self._decode_used = True
-        self._step_dispatches += 1
-        self._step_slots["decode"] += len(active)
-        self._decode_tokens.inc(len(active))
-        t_sync = self._now()
-        with self._span("engine.sample.sync"):
-            nxt = jax.device_get(nxt)       # blocks on the device result
-        self._step_sync_s += self._now() - t_sync
-        for slot in slots:
-            seq = self._running[slot]
-            mgr.lengths[slot] += 1          # the token we just fed is cached
-            seq.generated.append(int(nxt[slot]))
-            self._stamp_emit(seq.request.request_id, 1)
-            if self._maybe_finish(seq, finished):
-                del self._running[slot]
-
     def warm_spec(self) -> None:
-        """Compile the verify executable against inert inputs (all slots
-        masked to the null page) — benches call this during warmup so the
-        one-off compile stays out of timed counters.  Fused engines have no
-        standalone verify program (`warm_decode` already compiled the one
-        fused executable every lane rides), so this is a no-op there — which
-        also keeps the PRNG stream of a sampled spec-on pass aligned with
-        its spec-off comparison pass."""
-        if not self.spec_len or self._verify_fn is None:
-            return
-        B, T = self.cache.num_slots, self.spec_len + 1
-        _, self._pool = self._verify_fn(
-            self.params, self._h2d(np.zeros((B, T), np.int32)), self._pool,
-            self._h2d(np.zeros((B, self.cache.max_pages_per_slot), np.int32)),
-            self._h2d(np.zeros((B,), np.int32)),
-            self._h2d(np.ones((B,), np.int32)))
+        """Nothing to compile: speculation's verify lane rides the one fused
+        program `warm_decode` warms.  Kept because
+        `benchmarks/drivers/serve.py` calls it."""
 
     def warm_decode(self) -> None:
         """Compile the decode-side executable against inert inputs (all
         slots masked to the null page) — a 1-token warmup request picks its
         only token at prefill and retires without ever decoding, so benches
-        warm the decode program explicitly.  In fused mode this compiles THE
-        one fused program (decode/verify/chunk share its fixed shape).  On a
+        warm the decode program explicitly.  This compiles THE one fused
+        program (decode/verify/chunk share its fixed shape).  On a
         sampling engine this advances the PRNG stream by one split, like any
         real decode dispatch would."""
         B = self.cache.num_slots
         tbl = np.zeros((B, self.cache.max_pages_per_slot), np.int32)
-        if self.fused:
-            _, _, self._pool, self._key, *_ = self._decode_fn(
-                self.params, self._h2d(np.zeros((B, self._fused_T), np.int32)),
-                self._pool, self._h2d(tbl),
-                self._h2d(np.zeros((B,), np.int32)),
-                self._h2d(np.ones((B,), np.int32)), self._key,
-                self._h2d(np.zeros((B,), bool)))
-        else:
-            _, self._pool, self._key = self._decode_fn(
-                self.params, self._h2d(np.zeros((B,), np.int32)), self._pool,
-                self._h2d(tbl), self._h2d(np.zeros((B,), np.int32)),
-                self._key, self._h2d(np.zeros((B,), bool)))
+        _, _, self._pool, self._key, *_ = self._decode_fn(
+            self.params, self._h2d(np.zeros((B, self._fused_T), np.int32)),
+            self._pool, self._h2d(tbl),
+            self._h2d(np.zeros((B,), np.int32)),
+            self._h2d(np.ones((B,), np.int32)), self._key,
+            self._h2d(np.zeros((B,), bool)))
         self._decode_used = True
         # warmup is also where the live roofline arms: one abstract trace of
         # the decode-side program (cached; zero dispatches, zero programs)
@@ -3321,8 +3130,8 @@ class LLMEngine:
         """Capture a serving trace window into `dir_name`:
 
         - ``host_trace.json`` — chrome-tracing export of the engine's host
-          phase spans (`ENGINE_SPANS`: admit, prefill/verify/decode dispatch,
-          proposer scan, acceptance, sample sync) recorded through
+          phase spans (`ENGINE_SPANS`: admit, prefill and fused dispatch,
+          proposer scan, sample sync, emit) recorded through
           `paddle_tpu.profiler.RecordEvent`, so it opens in the same
           ``chrome://tracing`` / Perfetto flow as the trainer's traces;
         - ``step_timeline.json`` — the step-trace ring as captured at exit;
@@ -3385,14 +3194,13 @@ class LLMEngine:
                       "reasons": [f"health evaluation failed: "
                                   f"{type(e).__name__}: {e}"],
                       "burn_rates": {}}
-        # fused mode: _decode_fn IS the one fused program (decode-side count
-        # 1); the standalone verify/chunk programs are never built (None)
+        # _decode_fn IS the one fused program (decode-side count 1); there
+        # is no verify program (its key stays for the benchmark's sum), and
+        # the standalone chunk program exists in bucketed mode only
         return {
             "decode_executables": execs(self._decode_fn,
                                         1 if self._decode_used else 0),
-            "verify_executables": 0 if self._verify_fn is None else
-                                  execs(self._verify_fn,
-                                        1 if self._verify_steps.value else 0),
+            "verify_executables": 0,
             "prefill_executables": execs(self._prefill_fn,
                                          len(self._seen_buckets)) +
                                    (0 if self._chunk_fn is None else
@@ -3633,7 +3441,7 @@ class LLMEngine:
                 "num_pages": mgr.num_pages,
                 "max_model_len": self.max_model_len,
                 "prefill_chunk": self.prefill_chunk,
-                "spec_len": self.spec_len, "fused": self.fused,
+                "spec_len": self.spec_len,
                 "double_buffer": self.double_buffer,
                 "admission": self.admission, "preempt": self.preempt,
                 "kv_tier": self.kv_tier, "spill_dir": self.spill_dir,

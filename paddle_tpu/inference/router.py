@@ -84,8 +84,8 @@ ROUTER_POLICIES = ("affinity", "round_robin", "least_loaded")
 
 # the jitted step executables an engine builds in __init__ — per-instance
 # attributes so a dp fleet can share ONE compiled set across replicas
-_EXEC_ATTRS = ("_decode_fn", "_verify_fn", "_chunk_fn", "_prefill_fn",
-               "_copy_fn", "_swap_out_fn", "_swap_in_fn")
+_EXEC_ATTRS = ("_decode_fn", "_chunk_fn", "_prefill_fn", "_copy_fn",
+               "_swap_out_fn", "_swap_in_fn")
 
 # health states a request must never be routed to
 _UNROUTABLE = ("overloaded", "error")
@@ -309,7 +309,6 @@ class EngineFleet:
         compiling N times."""
         for eng in self.engines.values():
             eng.warm_decode()
-            eng.warm_spec()
             eng.warm_swap()
 
     def __enter__(self) -> "EngineFleet":

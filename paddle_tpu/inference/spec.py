@@ -1,7 +1,7 @@
 """Draft proposers for speculative decoding (Leviathan et al. 2023).
 
-The serving engine's verify step (`models.gpt.verify_step_paged`) scores
-`spec_len + 1` candidate tokens per slot in one fixed-shape pass; anything
+The serving engine's step (`models.gpt.serve_step_paged`) scores
+`spec_len + 1` candidate tokens per slot in its one fixed-shape pass; anything
 that can guess the next few tokens cheaply is a valid draft source.  This
 module holds the host-side proposers:
 
@@ -16,9 +16,9 @@ module holds the host-side proposers:
   continuations (code, structured text, self-looping generations).
 
 Proposals are *guesses*: the engine's greedy longest-prefix acceptance only
-ever emits tokens the verify logits argmax to, so a bad proposer can only
+ever emits tokens the step's logits argmax to, so a bad proposer can only
 cost speed, never correctness — output is token-identical to vanilla decode
-as long as the verify and decode executables agree at argmax (exact at
+as long as the K+1-wide and the one-token scoring agree at argmax (exact at
 matching kernel numerics; see the engine docstring for the TPU bf16 caveat).
 """
 from __future__ import annotations

@@ -1025,65 +1025,6 @@ def _scan_paged_layers(params, x, cache, layer):
     return x, {n: a.reshape(cache[n].shape) for n, a in pool.items()}
 
 
-def decode_step_paged(params, tokens, cache, page_table, lengths,
-                      config: GPTConfig, mesh=None):
-    """Slot-indexed decode against the paged pool — ONE fixed-shape executable
-    serves a churning request set (the continuous-batching hot loop).
-
-    tokens [B] int32 — last emitted token per slot; cache {"k","v"}
-    [L, P, page, KVH, hd]; page_table [B, max_pages] int32 page ids (0 = null
-    page); lengths [B] int32 — tokens already cached per slot.  The new
-    token's KV is written at position lengths[b] and attention masks each slot
-    to its own lengths[b] + 1 positions.  Inactive slots (lengths 0, all-null
-    table row) compute garbage the scheduler ignores.
-
-    mesh (an 'mp' axis > 1) runs the step tensor-parallel: qkv/fc1 column- and
-    proj/fc2 row-sharded (`parallel.hybrid.serving_param_specs`), the page
-    pool sharded on its KVH axis (each chip holds num_heads/mp heads of every
-    page), attention head-sharded per chip; page tables and lengths stay
-    replicated host state.
-
-    Returns (logits [B, V], updated cache).
-    """
-    from ..incubate.kernels.paged_attention import paged_attention_decode
-    c = config
-    assert c.causal, "KV-cache decoding requires a causal model"
-    B = tokens.shape[0]
-    page = cache["k"].shape[2]
-    quant = "k_scale" in cache          # int8 pool: quantize writes in-program
-    pos = lengths
-    pin = serving_mp_constraint(mesh)
-    parts = _mesh_mp(mesh)
-    x = _embed(params, tokens, c, mesh=mesh)                 # [B, D]
-    if not c.use_rope:
-        x = x + jnp.take(params["wpe"], pos, axis=0)
-    page_idx = jnp.take_along_axis(page_table, (pos // page)[:, None],
-                                   axis=1)[:, 0]             # [B]
-    offset = pos % page
-
-    def layer(bp, x, kv, base):             # kv: the flat pool [L*P, ...]
-        q, k, v = _decode_qkv(bp, x, c, pos, parts=parts)
-        if pin:
-            q, k, v = pin(q, "heads"), pin(k, "heads"), pin(v, "heads")
-        rows = base + page_idx
-        if quant:
-            k, ks = _quantize_kv(k)
-            v, vs = _quantize_kv(v)
-            kv = dict(kv, k_scale=kv["k_scale"].at[rows, offset].set(ks),
-                      v_scale=kv["v_scale"].at[rows, offset].set(vs))
-        kv = dict(kv, k=kv["k"].at[rows, offset].set(k),     # page scatter
-                  v=kv["v"].at[rows, offset].set(v))
-        attn = paged_attention_decode(q, kv["k"], kv["v"], page_table + base,
-                                      pos + 1, mesh=mesh,
-                                      kv_scales=_kv_scales(kv))
-        x = _layer_tail(bp, x, attn.reshape(B, -1), c, pin)
-        return x, kv
-
-    x, new_cache = _scan_paged_layers(params, x, cache, layer)
-    x = epilogue(params, x, c)
-    return head_logits(x, params, c, mesh=mesh), new_cache
-
-
 def prefill_paged(params, input_ids, config: GPTConfig, cache, pages, length,
                   mesh=None):
     """Bucketed paged prefill: one dense causal pass over the bucket-padded
@@ -1096,7 +1037,7 @@ def prefill_paged(params, input_ids, config: GPTConfig, cache, pages, length,
     length [B] int32 real prompt lengths.  Pool positions >= length hold
     padding garbage — masked by length during decode, overwritten as decode
     appends real tokens.  mesh: tensor-parallel over 'mp' (see
-    `decode_step_paged`); the dense flash attention runs per-shard over the
+    `_paged_chunk_hidden`); the dense flash attention runs per-shard over the
     local head slice.  Returns (logits [B, V], cache).
     """
     c = config
@@ -1161,20 +1102,23 @@ def prefill_paged(params, input_ids, config: GPTConfig, cache, pages, length,
 
 
 def _paged_chunk_hidden(params, input_ids, config: GPTConfig, cache,
-                        page_table, q_offset, valid, attn_entry=None,
-                        mesh=None):
-    """Shared trunk of the q_offset-masked paged passes (`prefill_chunk_paged`
-    and `verify_step_paged`): embed a [B, C] token chunk starting at per-slot
-    absolute position q_offset, write its KV token-granularly at
+                        page_table, q_offset, valid, mesh=None):
+    """Shared trunk of the q_offset-masked paged passes (`serve_step_paged`
+    and `prefill_chunk_paged`): embed a [B, C] token chunk starting at
+    per-slot absolute position q_offset, write its KV token-granularly at
     page_table[(q_offset+t) // page][(q_offset+t) % page] (padded tail rows
     t >= valid route to the reserved null page 0), and attend through the page
-    table to everything already written below it.  attn_entry overrides the
-    attention routing (the verify lane passes its own entry so lane-specific
-    kernel behavior lands there, not here).  Returns (hidden states [B, C, D]
-    BEFORE the final norm/head — callers pick their positions — and the
-    updated cache)."""
+    table to everything already written below it.
+
+    mesh (an 'mp' axis > 1) runs the pass tensor-parallel: qkv/fc1 column- and
+    proj/fc2 row-sharded (`parallel.hybrid.serving_param_specs`), the page
+    pool sharded on its KVH axis (each chip holds num_heads/mp heads of every
+    page), attention head-sharded per chip; page tables and offsets stay
+    replicated host state.
+
+    Returns (hidden states [B, C, D] BEFORE the final norm/head — callers
+    pick their positions — and the updated cache)."""
     from ..incubate.kernels.paged_attention import paged_prefill_attention
-    attn_fn = attn_entry or paged_prefill_attention
     c = config
     assert c.causal, "KV-cache decoding requires a causal model"
     B, C = input_ids.shape
@@ -1215,8 +1159,9 @@ def _paged_chunk_hidden(params, input_ids, config: GPTConfig, cache,
             kv = dict(kv, k=kv["k"].at[rows, off].set(k),   # token-granular
                       v=kv["v"].at[rows, off].set(v))
         with jax.named_scope("attn"):
-            attn = attn_fn(q, kv["k"], kv["v"], page_table + base, q_offset,
-                           n_real, mesh=mesh, kv_scales=_kv_scales(kv))
+            attn = paged_prefill_attention(
+                q, kv["k"], kv["v"], page_table + base, q_offset, n_real,
+                mesh=mesh, kv_scales=_kv_scales(kv))
         with jax.named_scope("mlp"):
             x = _layer_tail(bp, x, attn.reshape(B, C, -1), c, pin)
         return x, kv
@@ -1251,38 +1196,6 @@ def prefill_chunk_paged(params, input_ids, config: GPTConfig, cache,
     return head_logits(x, params, config, mesh=mesh), cache
 
 
-def verify_step_paged(params, tokens, cache, page_table, lengths, valid,
-                      config: GPTConfig, mesh=None):
-    """Speculative-decode verify (Leviathan et al. 2023): score spec_len + 1
-    positions per slot in ONE fixed-shape executable — the multi-token sibling
-    of `decode_step_paged`, riding the same q_offset-masked paged attention as
-    `prefill_chunk_paged`.
-
-    tokens [B, T] int32 (T = spec_len + 1): tokens[:, 0] is the slot's last
-    emitted token (exactly what vanilla decode would be fed), tokens[:, 1:]
-    the drafted continuation; token t sits at absolute position lengths[b] + t.
-    lengths [B] int32 — tokens already cached per slot (the verify analogue of
-    decode's per-slot position); valid [B] int32 in [1, T] — real tokens per
-    slot (1 = no draft, plain decode through the verify program).  Candidate
-    KV is written token-granularly into the slot's reserved pages (rows
-    t >= valid route to the null page); the caller rolls rejected positions
-    back by NOT advancing lengths past the accepted prefix — the stale KV is
-    overwritten when decode reaches those positions again.
-
-    Returns (logits [B, T, V] at EVERY position — logits[b, t] predicts the
-    token after tokens[b, t], so greedy acceptance compares argmax(logits[:, t])
-    against tokens[:, t+1] and argmax(logits[:, a]) is the bonus token — and
-    the updated cache).
-    """
-    from ..incubate.kernels.paged_attention import paged_verify_attention
-    x, cache = _paged_chunk_hidden(params, tokens, config, cache,
-                                   page_table, lengths, valid,
-                                   attn_entry=paged_verify_attention,
-                                   mesh=mesh)
-    x = epilogue(params, x, config)
-    return head_logits(x, params, config, mesh=mesh), cache
-
-
 def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
                      config: GPTConfig, key=None, greedy=None, *,
                      sample: bool = False, temperature=1.0, top_k=None,
@@ -1297,9 +1210,12 @@ def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
     device lane):
     - decode slot:  tokens[b, 0] = last emitted token, valid[b] = 1,
       q_offset[b] = tokens already cached;
-    - verify slot:  tokens[b, 1:1+K] = drafted continuation, valid[b] = 1+K
-      (`verify_step_paged` semantics — rejected KV rolls back as a length
-      decrement on the host);
+    - verify slot:  tokens[b, 1:1+K] = drafted continuation (Leviathan et
+      al. 2023), valid[b] = 1+K: token t sits at position q_offset[b] + t,
+      candidate KV is written into the slot's reserved pages, and the host
+      rolls rejected positions back by NOT advancing its length past the
+      accepted prefix — the stale KV is overwritten when decode reaches
+      those positions again;
     - chunk slot:   tokens[b, :n] = the next prompt chunk, valid[b] = n,
       q_offset[b] = prompt tokens already in pages (`prefill_chunk_paged`
       semantics — only the final chunk's pick is consumed);
@@ -1316,11 +1232,8 @@ def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
     the on-device greedy longest-prefix match length over the drafted tokens
     (0 for undrafted slots).  `key` advances by one split iff `sample`.
     """
-    from ..incubate.kernels.paged_attention import paged_serve_attention
     x, cache = _paged_chunk_hidden(params, tokens, config, cache,
-                                   page_table, q_offset, valid,
-                                   attn_entry=paged_serve_attention,
-                                   mesh=mesh)
+                                   page_table, q_offset, valid, mesh=mesh)
     with jax.named_scope("head"):
         x = epilogue(params, x, config)
         logits = head_logits(x, params, config, mesh=mesh)  # [B,T,V] (V/mp ea.)

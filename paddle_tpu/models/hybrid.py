@@ -357,7 +357,7 @@ def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
     whose page-table row is null is inactive and leaves its state alone, as
     do positions t >= valid.  Returns (out_tokens [B, T], accept [B], cache,
     key, aux [len(AUX_FIELDS)] int32)."""
-    from ..incubate.kernels.paged_attention import paged_serve_attention
+    from ..incubate.kernels.paged_attention import paged_prefill_attention
     c = config
     B, T = tokens.shape
     H, hd = c.num_heads, c.head_dim
@@ -389,8 +389,8 @@ def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
                 for n in ("k", "v")}
         flat = {"k": flat["k"].at[rows, off].set(k),
                 "v": flat["v"].at[rows, off].set(v)}
-        attn = paged_serve_attention(q, flat["k"], flat["v"],
-                                     page_table + base, q_offset, n_real)
+        attn = paged_prefill_attention(q, flat["k"], flat["v"],
+                                       page_table + base, q_offset, n_real)
         return jnp.matmul(attn.reshape(B, T, H * hd), lp["proj_w"]), \
             dict(cache, **{n: a.reshape(cache[n].shape)
                            for n, a in flat.items()})

@@ -3,13 +3,14 @@
 double-buffered scheduling (ref `AnalysisPredictor::ZeroCopyRun` single-graph
 step; Sarathi-Serve piggybacking, Agrawal et al. OSDI 2024).
 
-Covers the PR acceptance bars: byte-identical greedy tokens fused vs
-`fuse=False` (spec on/off x bucketed/chunked x mp1/mp2, prefix cache + COW
-on), sampled-path parity under a fixed PRNG key, the busy-step ONE-dispatch
+Covers: byte-identical greedy tokens against the dense-cache
+`gpt.generate` (spec on/off x bucketed/chunked, prefix cache + COW on) and
+mp1 against mp2, the sampled path as a function of the seed alone, the
+busy-step ONE-dispatch
 assertion straight from `step_trace()`, double-buffer ordering (the token for
 step n observed during step n+1), a warmed steady-state loop clean under
 `jax.transfer_guard("disallow")`, page invariants after aborting a fused
-in-flight batch, and the bench-level dispatches_per_step / parity wiring.
+in-flight batch, and the bench-level dispatches_per_step wiring.
 """
 import numpy as np
 import pytest
@@ -46,9 +47,10 @@ def _mixed_prompts(cfg, seed=0, n_extra=4):
 # ---------------------------------------------------------------------------
 
 def test_serve_step_program_matches_verify_and_host_accept(tiny):
-    """serve_step_paged's token buffer is the argmax of verify_step_paged's
-    logits, and its on-device accept counts equal the host-side greedy
-    longest-prefix scan — the contract the harvest path relies on."""
+    """serve_step_paged's token buffer is the argmax of the head over the
+    shared trunk's hidden states at every position, and its on-device accept
+    counts equal the host-side greedy longest-prefix scan — the contract the
+    harvest path relies on."""
     cfg, params = tiny
     rng = np.random.RandomState(3)
     B, T, page = 2, 4, 8
@@ -70,8 +72,9 @@ def test_serve_step_program_matches_verify_and_host_accept(tiny):
     tokens[1, 0] = prompts[1, -1]
     qoff = jnp.full((B,), 6, jnp.int32)
     valid = jnp.asarray([1, 4], jnp.int32)
-    vlog, vpool = G.verify_step_paged(
-        params, jnp.asarray(tokens), pool, tbl, qoff, valid, cfg)
+    x, vpool = G._paged_chunk_hidden(
+        params, jnp.asarray(tokens), cfg, pool, tbl, qoff, valid)
+    vlog = G.head_logits(G.epilogue(params, x, cfg), params, cfg)
     ref = np.asarray(jnp.argmax(vlog, axis=-1))
     out, accept, _, _ = G.serve_step_paged(
         params, jnp.asarray(tokens), vpool, tbl, qoff, valid, cfg)
@@ -85,30 +88,46 @@ def test_serve_step_program_matches_verify_and_host_accept(tiny):
 
 
 # ---------------------------------------------------------------------------
-# engine parity: fused vs --no-fuse, greedy byte-exact
+# engine parity: the paged step against the dense-cache decode, byte-exact
 # ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def dense_greedy(tiny):
+    """`gpt.generate` (dense cache, no paging, no engine) for one prompt;
+    kept across the parametrised cases, which share their prompts."""
+    cfg, params = tiny
+    done = {}
+
+    def greedy(prompt, max_new):
+        key = (prompt.tobytes(), max_new)
+        if key not in done:
+            done[key] = np.asarray(G.generate(
+                params, jnp.asarray(prompt)[None], cfg,
+                max_new_tokens=max_new)[0])
+        return done[key]
+    return greedy
+
 
 @pytest.mark.parametrize("spec_len", [0, 4], ids=["nospec", "spec4"])
 @pytest.mark.parametrize("chunk", [None, 8], ids=["bucketed", "chunked"])
-def test_fused_vs_unfused_greedy_byte_parity(tiny, spec_len, chunk):
-    """Acceptance bar: fused and fuse=False emit byte-identical greedy
-    tokens (prefix cache + COW on), with decode-side compiled programs
-    exactly 1 fused vs <= 2 unfused."""
+def test_greedy_byte_parity_with_dense_generate(tiny, dense_greedy, spec_len,
+                                                chunk):
+    """Acceptance bar: in every mode the engine emits, request by request,
+    the dense-cache greedy decode's tokens (prefix cache + COW on), with
+    exactly 1 decode-side compiled program."""
     cfg, params = tiny
     prompts = _mixed_prompts(cfg)
-    outs, stats = {}, {}
-    for fuse in (True, False):
-        eng = LLMEngine(params, cfg, num_slots=3, page_size=8,
-                        max_model_len=64, prefill_chunk=chunk,
-                        spec_len=spec_len, fuse=fuse)
-        rids = [eng.add_request(p, max_new_tokens=10) for p in prompts]
-        res = eng.run()
-        outs[fuse] = [list(res[r].tokens) for r in rids]
-        stats[fuse] = eng.stats()
-        eng.cache.check_invariants()
-        assert eng.stats()["pages_in_use"] == 0
-    assert outs[True] == outs[False]
-    st = stats[True]
+    eng = LLMEngine(params, cfg, num_slots=3, page_size=8,
+                    max_model_len=64, prefill_chunk=chunk,
+                    spec_len=spec_len)
+    rids = [eng.add_request(p, max_new_tokens=10) for p in prompts]
+    res = eng.run()
+    for r, p in zip(rids, prompts):
+        np.testing.assert_array_equal(res[r].tokens, dense_greedy(p, 10))
+    st = eng.stats()
+    eng.cache.check_invariants()
+    assert st["pages_in_use"] == 0
+    assert st["prefix_cached_tokens"] > 0       # the shared pair did share
     assert st["decode_executables"] + st["verify_executables"] == 1
     if spec_len:
         assert st["verify_steps"] > 0      # drafts rode the fused program
@@ -138,23 +157,23 @@ def test_fused_mp2_parity_and_aot_program_count(tiny):
 
 
 def test_fused_sampled_parity_fixed_key(tiny):
-    """Sampled path: with a fixed seed the fused on-device pick (shared
-    `gpt.sample_token`, one split per decode dispatch) emits exactly the
-    unfused engine's tokens in bucketed spec-off mode, where the two PRNG
-    streams split in lockstep."""
+    """Sampled path: the on-device pick (shared `gpt.sample_token`, one split
+    per decode dispatch) is a function of the seed alone — an engine built
+    alike repeats the stream token for token, another seed does not."""
     cfg, params = tiny
     rng = np.random.RandomState(11)
     prompts = [rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32)
                for n in (5, 12, 20)]
-    outs = {}
-    for fuse in (True, False):
+    outs = []
+    for seed in (42, 42, 43):
         eng = LLMEngine(params, cfg, num_slots=3, page_size=8,
-                        max_model_len=64, temperature=0.8, seed=42,
-                        spec_len=0, fuse=fuse)
+                        max_model_len=64, temperature=0.8, seed=seed,
+                        spec_len=0)
         rids = [eng.add_request(p, max_new_tokens=8) for p in prompts]
         res = eng.run()
-        outs[fuse] = [list(res[r].token_ids) for r in rids]
-    assert outs[True] == outs[False]
+        outs.append([list(res[r].token_ids) for r in rids])
+    assert outs[0] == outs[1]
+    assert outs[0] != outs[2]
     # the same engine still honors the per-request greedy fast path
     eng = LLMEngine(params, cfg, num_slots=2, page_size=8, max_model_len=64,
                     temperature=0.8, seed=42, spec_len=0)
@@ -189,7 +208,7 @@ def test_busy_step_dispatches_exactly_one_program(tiny):
             r["verify_dispatches"] > 0]
     assert busy, "no decode+chunk+verify step in the trace"
     for r in busy:
-        assert r["v"] == 2 and r["fused"]
+        assert r["v"] == 2
         assert r["dispatches"] == 1
         assert r["slots"]["chunk"] == 1
         assert r["slots"]["verify"] >= 1
@@ -310,25 +329,20 @@ def test_abort_mid_inflight_fused_batch_keeps_invariants(tiny):
 # bench + CI wiring
 # ---------------------------------------------------------------------------
 
-def test_bench_dispatches_per_step_and_fuse_parity():
-    """Acceptance bar (CPU smoke): the fused bench run shows
-    dispatches_per_step <= 1.1 with byte-identical outputs vs --no-fuse on
-    the same stream; the unfused chunked run shows the dispatch overhead the
-    fusion removed (> 1 program per busy step)."""
+def test_bench_dispatches_per_step():
+    """Acceptance bar (CPU smoke): the bench run shows dispatches_per_step
+    <= 1.1 on a chunked, speculating, prefix-sharing stream, from one
+    decode-side program."""
     from bench_serve import run_serve_bench
-    kw = dict(num_requests=12, num_slots=2, page_size=8, max_model_len=64,
-              max_new_tokens=6, prefill_chunk=16, shared_prefix_frac=0.5,
-              spec_len=4, seed=11)
-    fused = run_serve_bench(**kw, fuse=True)
-    unfused = run_serve_bench(**kw, fuse=False)
-    assert fused["fused"] and not unfused["fused"]
-    assert fused["dispatches_per_step"] <= 1.1
-    assert unfused["dispatches_per_step"] > 1.0
-    assert fused["outputs_digest"] == unfused["outputs_digest"]
-    assert fused["decode_executables"] + fused["verify_executables"] == 1
-    assert fused["prefill_executables"] == 0    # chunk rides the fused batch
-    assert fused["host_sync_ms_per_step"] >= 0.0
-    assert fused["accepted_per_step"] > 1.0     # spec still pays inside fusion
+    st = run_serve_bench(num_requests=12, num_slots=2, page_size=8,
+                         max_model_len=64, max_new_tokens=6,
+                         prefill_chunk=16, shared_prefix_frac=0.5,
+                         spec_len=4, seed=11)
+    assert st["dispatches_per_step"] <= 1.1
+    assert st["decode_executables"] + st["verify_executables"] == 1
+    assert st["prefill_executables"] == 0       # chunk rides the fused batch
+    assert st["host_sync_ms_per_step"] >= 0.0
+    assert st["accepted_per_step"] > 1.0        # spec still pays inside fusion
 
 
 def test_program_budget_decode_side_one():

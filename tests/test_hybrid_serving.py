@@ -361,7 +361,6 @@ def test_dense_engine_counts_nothing_new():
 @pytest.mark.parametrize("kw,says", [
     (dict(spec_len=2), "speculative decoding"),
     (dict(admission="optimistic", preempt="swap"), "preempted by swap"),
-    (dict(fuse=False), "fused step only"),
     (dict(weight_dtype="int8"), "no quantized serving path"),
     (dict(kv_dtype="int8"), "no quantized serving path"),
     (dict(mp=2), "one chip"),

@@ -314,14 +314,10 @@ def test_chrome_trace_and_step_timeline(spec_eng, tmp_path):
         eng.run()
     host = json.loads((td / "host_trace.json").read_text())
     names = {e["name"] for e in host["traceEvents"]}
-    # fused engine (default): the one-dispatch step emits the fused span in
-    # place of the legacy verify/decode/chunk dispatch spans; acceptance is
-    # on the device, so the harvest's host loop is engine.emit (spec.accept
-    # belongs to the legacy verify path)
+    # acceptance is on the device, so the harvest's host loop is engine.emit
     assert {"engine.step", "engine.turnaround", "engine.emit", "engine.admit",
             "engine.batch.build", "engine.fused.dispatch", "engine.fused.h2d",
             "engine.spec.propose", "engine.sample.sync"} <= names
-    assert "engine.spec.accept" not in names
     assert names <= set(ENGINE_SPANS)
     for e in host["traceEvents"]:
         assert e["ph"] == "X" and e["dur"] >= 0
@@ -330,7 +326,7 @@ def test_chrome_trace_and_step_timeline(spec_eng, tmp_path):
     for key in ("decode_batch", "chunk", "verify_dispatches",
                 "tokens_emitted", "pages_in_use", "pages_free",
                 "pages_evictable", "queued", "running", "prefilling",
-                "v", "fused", "dispatches", "sync_ms", "slots",
+                "v", "dispatches", "sync_ms", "slots",
                 "turnaround_ms", "d2h_ms", "pages_walked"):
         assert key in timeline[-1]
     assert any(r["tokens_emitted"] > 0 for r in timeline)
@@ -380,7 +376,7 @@ def test_step_trace_ring_bounded(tiny):
     assert eng.stats()["decode_tokens"] == 0
 
 
-@pytest.mark.parametrize("mode", ["fused", "chunked", "legacy_spec"])
+@pytest.mark.parametrize("mode", ["bucketed", "chunked", "bucketed_spec"])
 def test_pages_walked_counts_what_the_programs_were_handed(tiny, mode):
     """`pages_walked` (ring) and `paged_pages_walked` / `paged_table_entries`
     (stats, /metrics) equal sum ceil((q_offset + valid) / page) over the
@@ -388,8 +384,8 @@ def test_pages_walked_counts_what_the_programs_were_handed(tiny, mode):
     recomputed here from the arrays the programs actually received - against
     rows x table width; by hand for the first step of the plain engine."""
     cfg, params = tiny
-    kw = {"fused": {}, "chunked": {"prefill_chunk": 16},
-          "legacy_spec": {"fuse": False, "spec_len": 3}}[mode]
+    kw = {"bucketed": {}, "chunked": {"prefill_chunk": 16},
+          "bucketed_spec": {"spec_len": 3}}[mode]
     eng = LLMEngine(params, cfg, num_slots=3, page_size=8, max_model_len=64,
                     seed=1, **kw)
     seen = []
@@ -402,11 +398,8 @@ def test_pages_walked_counts_what_the_programs_were_handed(tiny, mode):
             return fn(*args, **kwargs)
         return call
 
-    if eng.fused:   # the legacy decode program holds the other kernel
-        eng._decode_fn = spy(eng._decode_fn, 3, 4, 5)
-    if getattr(eng, "_verify_fn", None) is not None:
-        eng._verify_fn = spy(eng._verify_fn, 3, 4, 5)
-    if getattr(eng, "_chunk_fn", None) is not None:
+    eng._decode_fn = spy(eng._decode_fn, 3, 4, 5)
+    if eng._chunk_fn is not None:
         eng._chunk_fn = spy(eng._chunk_fn, 3, 4, 5)
     rng = np.random.RandomState(2)
     for n in (18, 5, 30):
@@ -418,7 +411,7 @@ def test_pages_walked_counts_what_the_programs_were_handed(tiny, mode):
     assert st["paged_table_entries"] == sum(e for _, e in seen)
     assert sum(r["pages_walked"] for r in ring) == st["paged_pages_walked"]
     assert 0 < st["paged_pages_walked"] < st["paged_table_entries"]
-    if mode == "fused":
+    if mode == "bucketed":
         # bucketed admission prefills through flash; the first fused step
         # decodes all three slots at q_offset = prompt length, valid = 1:
         # ceil(19/8) + ceil(6/8) + ceil(31/8) pages of 3 x 8 entries
@@ -1449,13 +1442,13 @@ def test_check_bench_tool(tiny, tmp_path):
     row = cb.bench_row(result)
     assert cb.validate_row(row) == []
     assert cb.check_floors(row) == []           # the real row passes
-    assert row["mode"]["fused"] is True and row["mode"]["mp"] == 1
+    assert row["mode"]["mp"] == 1
     assert row["perf"]["dispatches_per_step"] <= 1.0
     assert row["perf"]["model_error"] > 0
     # floors catch each declared regression class
     bad = json.loads(json.dumps(row))
-    bad["parity"]["fuse_parity"] = False
-    assert any("fuse_parity" in e for e in cb.check_floors(bad))
+    bad["parity"]["spec_parity"] = False
+    assert any("spec_parity" in e for e in cb.check_floors(bad))
     bad = json.loads(json.dumps(row))
     bad["perf"]["dispatches_per_step"] = 2.0
     assert any("dispatches_per_step" in e for e in cb.check_floors(bad))

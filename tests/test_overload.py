@@ -194,17 +194,13 @@ def test_victim_selection_prefers_low_priority(cfg, params):
         assert eng._outputs[rid].finish_reason == "length"
 
 
-@pytest.mark.parametrize("mode", ["bucketed", "unfused"])
-def test_preemption_on_legacy_paths(cfg, params, reference, mode):
+def test_preemption_in_bucketed_mode(cfg, params, reference):
     """Growth + preemption also cover the bucketed one-shot prefill (a
-    recompute resume replays its longer prompt through the bucket ladder)
-    and the fuse=False three-program step (growth runs before the legacy
-    verify/decode dispatches)."""
+    recompute resume replays its longer prompt through the bucket ladder)."""
     prompts, ref_tokens = reference
-    kw = dict(prefill_chunk=None) if mode == "bucketed" \
-        else dict(prefill_chunk=8, fuse=False)
     eng = LLMEngine(params, cfg, num_slots=6, page_size=8, num_pages=9,
-                    max_model_len=64, admission="optimistic", **kw)
+                    max_model_len=64, admission="optimistic",
+                    prefill_chunk=None)
     rids = [eng.add_request(p, max_new_tokens=24) for p in prompts]
     outs, st = _drain_checked(eng)
     assert st["preemptions"] > 0

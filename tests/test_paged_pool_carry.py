@@ -1,10 +1,11 @@
 """The page pool is a loop carry, updated in place, in every paged pass.
 
-`models.gpt._scan_paged_layers` runs the layer loop of `serve_step_paged`
-(and the chunk / verify passes that share its trunk), `prefill_paged` and
-`decode_step_paged` with the pool `[L, P, page, KVH, hd]` viewed as
-`[L*P, page, KVH, hd]` and carried through `lax.scan`; layer `l` writes at
-`l*P + page_id` and attends through `page_table + l*P`.  The form it replaced
+`models.gpt._scan_paged_layers` runs the layer loop of `serve_step_paged`,
+`prefill_paged` and `prefill_chunk_paged` (the standalone chunk program of a
+prefix-hit tail, which shares the fused step's trunk) with the pool
+`[L, P, page, KVH, hd]` viewed as `[L*P, page, KVH, hd]` and carried through
+`lax.scan`; layer `l` writes at `l*P + page_id` and attends through
+`page_table + l*P`.  The form it replaced
 scanned over the pool (input sliced per layer, output stacked per layer),
 which on the chip copied the whole pool about three times a program.
 
@@ -28,6 +29,7 @@ from paddle_tpu.incubate.kernels import paged_attention as PA
 
 L, P, PAGE, B, MAXP = 3, 7, 8, 3, 4      # layers, pages, page size, slots
 T = 4                                    # fused-step tokens a slot
+C = 6                                    # chunk program's tokens a slot
 SB = 16                                  # prefill bucket (2 pages)
 
 
@@ -79,11 +81,11 @@ def _old_scan(params, x, cache, layer):
 
 def _old_chunk_hidden(params, ids, c, cache, page_table, q_offset, valid,
                       attn_fn):
-    Bn, C = ids.shape
+    Bn, W = ids.shape
     page = cache["k"].shape[2]
     quant = "k_scale" in cache
-    pos = q_offset[:, None] + jnp.arange(C)
-    real = jnp.arange(C)[None, :] < valid[:, None]
+    pos = q_offset[:, None] + jnp.arange(W)
+    real = jnp.arange(W)[None, :] < valid[:, None]
     x = G._embed(params, ids, c)
     if not c.use_rope:
         x = x + jnp.take(params["wpe"], pos, axis=0)
@@ -103,14 +105,14 @@ def _old_chunk_hidden(params, ids, c, cache, page_table, q_offset, valid,
                   v=kv["v"].at[pidx, off].set(v))
         attn = attn_fn(q, kv["k"], kv["v"], page_table, q_offset, valid,
                        kv_scales=G._kv_scales(kv))
-        return G._layer_tail(bp, x, attn.reshape(Bn, C, c.hidden_size), c), kv
+        return G._layer_tail(bp, x, attn.reshape(Bn, W, c.hidden_size), c), kv
 
     return _old_scan(params, x, cache, layer)
 
 
 def _old_serve(params, tokens, cache, page_table, q_offset, valid, c):
     x, cache = _old_chunk_hidden(params, tokens, c, cache, page_table,
-                                 q_offset, valid, PA.paged_serve_attention)
+                                 q_offset, valid, PA.paged_prefill_attention)
     logits = G.head_logits(G.epilogue(params, x, c), params, c)
     return G.sharded_argmax(logits, None), cache
 
@@ -150,34 +152,11 @@ def _old_prefill(params, ids, c, cache, pages, length):
     return G.head_logits(x, params, c), cache
 
 
-def _old_decode(params, tokens, cache, page_table, lengths, c):
-    Bn = tokens.shape[0]
-    page = cache["k"].shape[2]
-    quant = "k_scale" in cache
-    pos = lengths
-    x = G._embed(params, tokens, c)
-    if not c.use_rope:
-        x = x + jnp.take(params["wpe"], pos, axis=0)
-    page_idx = jnp.take_along_axis(page_table, (pos // page)[:, None],
-                                   axis=1)[:, 0]
-    offset = pos % page
-
-    def layer(x, layer_in):
-        bp, kv = layer_in
-        q, k, v = G._decode_qkv(bp, x, c, pos)
-        if quant:
-            k, ks = G._quantize_kv(k)
-            v, vs = G._quantize_kv(v)
-            kv = dict(kv, k_scale=kv["k_scale"].at[page_idx, offset].set(ks),
-                      v_scale=kv["v_scale"].at[page_idx, offset].set(vs))
-        kv = dict(kv, k=kv["k"].at[page_idx, offset].set(k),
-                  v=kv["v"].at[page_idx, offset].set(v))
-        attn = PA.paged_attention_decode(q, kv["k"], kv["v"], page_table,
-                                         pos + 1, kv_scales=G._kv_scales(kv))
-        return G._layer_tail(bp, x, attn.reshape(Bn, c.hidden_size), c), kv
-
-    x, cache = _old_scan(params, x, cache, layer)
-    return G.head_logits(G.epilogue(params, x, c), params, c), cache
+def _old_chunk(params, ids, c, cache, page_table, q_offset, valid):
+    x, cache = _old_chunk_hidden(params, ids, c, cache, page_table, q_offset,
+                                 valid, PA.paged_prefill_attention)
+    x = G.epilogue(params, x[jnp.arange(ids.shape[0]), valid - 1], c)
+    return G.head_logits(x, params, c), cache
 
 
 # ---------------------------------------------------------------------------
@@ -212,19 +191,22 @@ def _case(pass_name, cfg, seed=0):
         def old(params, pool):
             return _old_prefill(params, ids, cfg, pool, pages, length)
     else:
-        tokens = jnp.asarray(rng.randint(0, cfg.vocab_size, (B,)), jnp.int32)
-        lengths = jnp.asarray([9, 16, 0], jnp.int32)
+        # a tail across a page boundary, one behind two cached pages with
+        # padded rows, and a first chunk
+        ids = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, C)), jnp.int32)
+        q_off = jnp.asarray([4, 16, 0], jnp.int32)
+        valid = jnp.asarray([6, 3, 5], jnp.int32)
 
         def new(params, pool):
-            return G.decode_step_paged(params, tokens, pool, table, lengths,
-                                       cfg)
+            return G.prefill_chunk_paged(params, ids, cfg, pool, table, q_off,
+                                         valid)
 
         def old(params, pool):
-            return _old_decode(params, tokens, pool, table, lengths, cfg)
+            return _old_chunk(params, ids, cfg, pool, table, q_off, valid)
     return new, old
 
 
-PASSES = ["serve_step_paged", "prefill_paged", "decode_step_paged"]
+PASSES = ["serve_step_paged", "prefill_paged", "prefill_chunk_paged"]
 POOLS = [None, "int8"]
 
 

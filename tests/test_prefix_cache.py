@@ -241,6 +241,7 @@ def test_paged_prefill_walk_reads_nothing_it_should_not_weigh(heads,
 def test_chunked_prefill_matches_one_shot_logits(preset):
     """prefill_chunk_paged chunks (q_offset 0, 6, 12) reproduce the one-shot
     dense-forward logits through the page-table indirection, and decode
+    (the same pass at valid 1, as a decode slot rides the fused step)
     continues correctly from the chunk-written pages."""
     cfg = preset(64)
     params = G.init_params(cfg, jax.random.key(0))
@@ -265,9 +266,9 @@ def test_chunked_prefill_matches_one_shot_logits(preset):
                                np.asarray(dense[:, Tp - 1]),
                                atol=2e-4, rtol=2e-4)
     for pos in range(Tp, 15):
-        logits, pool = G.decode_step_paged(
-            params, toks[:, pos], pool, tbl, jnp.asarray([pos], jnp.int32),
-            cfg)
+        logits, pool = G.prefill_chunk_paged(
+            params, toks[:, pos:pos + 1], cfg, pool, tbl,
+            jnp.asarray([pos], jnp.int32), jnp.asarray([1], jnp.int32))
         if pos < 14:
             np.testing.assert_allclose(np.asarray(logits),
                                        np.asarray(dense[:, pos]),

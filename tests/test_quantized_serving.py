@@ -127,29 +127,19 @@ def int8_pool():
     return kq, vq, ks, vs, tbl
 
 
-def test_kernel_oracle_parity_int8_decode(int8_pool):
-    from paddle_tpu.incubate.kernels.paged_attention import (
-        paged_attention_pallas, paged_attention_xla)
-    kq, vq, ks, vs, tbl = int8_pool
-    rng = np.random.RandomState(3)
-    q = jnp.asarray(rng.randn(3, 4, 64).astype(np.float32))
-    lens = jnp.asarray(np.array([5, 17, 30], np.int32))
-    got = paged_attention_pallas(q, kq, vq, tbl, lens, interpret=True,
-                                 kv_scales=(ks, vs))
-    want = paged_attention_xla(q, kq, vq, tbl, lens, kv_scales=(ks, vs))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_kernel_oracle_parity_int8_prefill(int8_pool):
+@pytest.mark.parametrize("T,qo,vl", [
+    (4, [2, 9, 20], [1, 3, 4]),
+    # the decode shape, its third slot inactive (null row, nothing valid)
+    (1, [4, 16, 0], [1, 1, 0]),
+], ids=["chunk", "decode-null-row"])
+def test_kernel_oracle_parity_int8_prefill(int8_pool, T, qo, vl):
     from paddle_tpu.incubate.kernels.paged_attention import (
         paged_prefill_attention_pallas, paged_prefill_attention_xla)
     kq, vq, ks, vs, tbl = int8_pool
     rng = np.random.RandomState(4)
-    T = 4
     q = jnp.asarray(rng.randn(3, T, 4, 64).astype(np.float32))
-    qo = jnp.asarray(np.array([2, 9, 20], np.int32))
-    vl = jnp.asarray(np.array([1, 3, 4], np.int32))
+    tbl = jnp.where(jnp.asarray(vl)[:, None] > 0, tbl, 0)
+    qo, vl = jnp.asarray(qo, jnp.int32), jnp.asarray(vl, jnp.int32)
     got = np.asarray(paged_prefill_attention_pallas(
         q, kq, vq, tbl, qo, vl, interpret=True, kv_scales=(ks, vs)))
     want = np.asarray(paged_prefill_attention_xla(
@@ -157,6 +147,8 @@ def test_kernel_oracle_parity_int8_prefill(int8_pool):
     for b in range(3):      # rows past valid are padding garbage by contract
         np.testing.assert_allclose(got[b, :int(vl[b])], want[b, :int(vl[b])],
                                    rtol=1e-5, atol=1e-5)
+        if not int(vl[b]):
+            assert not got[b].any()     # an inactive slot comes back zeros
 
 
 # ---------------------------------------------------------------------------

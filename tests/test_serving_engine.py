@@ -11,8 +11,6 @@ import jax.numpy as jnp
 from paddle_tpu.models import gpt as G
 from paddle_tpu.inference.cache import PagedKVCache
 from paddle_tpu.inference.engine import LLMEngine
-from paddle_tpu.incubate.kernels.paged_attention import (
-    paged_attention_pallas, paged_attention_xla)
 
 
 PRESETS = [G.gpt_tiny, G.llama_tiny]
@@ -44,9 +42,10 @@ def test_prefill_decode_logits_match_dense_forward(preset):
 
 @pytest.mark.parametrize("preset", PRESETS, ids=IDS)
 def test_paged_decode_logits_match_dense_forward(preset):
-    """prefill_paged + chained decode_step_paged reproduce dense-forward
-    logits through the page-table indirection (bucket-padded prompt, slots in
-    arbitrary page order)."""
+    """prefill_paged + chained one-token prefill_chunk_paged calls (valid 1
+    at q_offset = tokens cached: what a decode slot is in the fused step)
+    reproduce dense-forward logits through the page-table indirection
+    (bucket-padded prompt, slots in arbitrary page order)."""
     cfg = preset(64)
     params = G.init_params(cfg, jax.random.key(1))
     rng = np.random.RandomState(1)
@@ -66,8 +65,9 @@ def test_paged_decode_logits_match_dense_forward(preset):
                                atol=2e-4, rtol=2e-4)
     tbl = jnp.asarray(table)
     for pos in range(Tp, 12):
-        logits, pool = G.decode_step_paged(
-            params, toks[:, pos], pool, tbl, jnp.asarray([pos], jnp.int32), cfg)
+        logits, pool = G.prefill_chunk_paged(
+            params, toks[:, pos:pos + 1], cfg, pool, tbl,
+            jnp.asarray([pos], jnp.int32), jnp.asarray([1], jnp.int32))
         if pos < 11:
             np.testing.assert_allclose(np.asarray(logits),
                                        np.asarray(dense[:, pos]),
@@ -207,26 +207,6 @@ def test_paged_cache_manager_accounting():
     assert mgr.lengths[0] == 0
 
 
-@pytest.mark.parametrize("kvh", [2, 1], ids=["gqa", "mqa"])
-def test_paged_attention_pallas_matches_xla_oracle(kvh):
-    """The Pallas paged-decode kernel (interpret mode on CPU) agrees with the
-    gather-based XLA oracle, including GQA/MQA grouping and length masking."""
-    rng = np.random.RandomState(0)
-    B, H, hd, page, P, mp = 3, 4, 64, 8, 7, 4
-    q = jnp.asarray(rng.randn(B, H, hd), jnp.float32)
-    k = jnp.asarray(rng.randn(P, page, kvh, hd), jnp.float32)
-    v = jnp.asarray(rng.randn(P, page, kvh, hd), jnp.float32)
-    tbl = np.zeros((B, mp), np.int32)
-    tbl[0, :2] = [1, 2]
-    tbl[1, :3] = [3, 4, 5]
-    tbl[2, :1] = [6]
-    lengths = jnp.asarray([13, 20, 5], jnp.int32)
-    ref = paged_attention_xla(q, k, v, jnp.asarray(tbl), lengths)
-    got = paged_attention_pallas(q, k, v, jnp.asarray(tbl), lengths,
-                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
-
-
 def test_generate_cache_lru_bounded(monkeypatch):
     """Satellite: the generate executable cache is LRU-bounded (it used to
     grow without limit under varied prompt shapes) and exposes a compile
@@ -289,7 +269,6 @@ def test_steady_state_decode_loop_transfer_guard_clean():
                         .astype(np.int32), max_new_tokens=4)
     eng.run()
     eng.warm_decode()
-    eng.warm_spec()
     base = rng.randint(0, cfg.vocab_size, (13,)).astype(np.int32)
     eng.add_request(base, max_new_tokens=1)
     eng.run()                           # donor registers its prompt pages

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from paddle_tpu.models import gpt as G
 from paddle_tpu.inference.engine import LLMEngine
 from paddle_tpu.incubate.kernels.paged_attention import (
-    paged_attention_decode_mp, paged_attention_xla,
     paged_prefill_attention_mp, paged_prefill_attention_xla)
 from paddle_tpu.parallel.hybrid import serving_mesh
 
@@ -156,13 +155,17 @@ def _pool_case(rng, kvh):
     return q1, qT, k, v, jnp.asarray(tbl), lengths, valid
 
 
+@pytest.mark.parametrize("T", [5, 1], ids=["verify", "decode"])
 @pytest.mark.parametrize("kvh", [4, 2], ids=["mha", "gqa"])
-def test_sharded_verify_kernel_matches_oracle_qlen_gt1(kvh):
-    """The head-sharded Pallas verify/chunk kernel (shard_map over mp=2,
-    interpret mode on CPU) returns exactly the unsharded oracle's numbers for
-    q_len > 1 — attention never mixes heads, so per-chip slices compose."""
+def test_sharded_verify_kernel_matches_oracle_qlen_gt1(kvh, T):
+    """The head-sharded Pallas kernel (shard_map over mp=2, interpret mode
+    on CPU) returns exactly the unsharded oracle's numbers, for q_len > 1
+    (verify/chunk) and at the decode shape (one query a slot, valid 1) —
+    attention never mixes heads, so per-chip slices compose."""
     rng = np.random.RandomState(3)
-    _, qT, k, v, tbl, lengths, valid = _pool_case(rng, kvh)
+    q1, qT, k, v, tbl, lengths, valid = _pool_case(rng, kvh)
+    if T == 1:
+        qT, valid = q1[:, None], jnp.ones_like(valid)
     mesh = serving_mesh(2)
     ref = paged_prefill_attention_xla(qT, k, v, tbl, lengths, valid)
     got = paged_prefill_attention_mp(qT, k, v, tbl, lengths, valid, mesh,
@@ -179,19 +182,9 @@ def test_sharded_verify_kernel_matches_oracle_qlen_gt1(kvh):
                                    np.asarray(ref)[b, :n], atol=2e-5)
 
 
-@pytest.mark.parametrize("kvh", [4, 2], ids=["mha", "gqa"])
-def test_sharded_decode_kernel_matches_oracle(kvh):
-    rng = np.random.RandomState(4)
-    q1, _, k, v, tbl, lengths, _ = _pool_case(rng, kvh)
-    mesh = serving_mesh(2)
-    ref = paged_attention_xla(q1, k, v, tbl, lengths)
-    got = paged_attention_decode_mp(q1, k, v, tbl, lengths, mesh,
-                                    use_pallas=True, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
-
-
 def test_sharded_kernel_rejects_indivisible_heads():
     rng = np.random.RandomState(5)
-    q1, _, k, v, tbl, lengths, _ = _pool_case(rng, 4)
+    _, qT, k, v, tbl, lengths, valid = _pool_case(rng, 4)
     with pytest.raises(ValueError, match="divisible"):
-        paged_attention_decode_mp(q1, k, v, tbl, lengths, serving_mesh(8))
+        paged_prefill_attention_mp(qT, k, v, tbl, lengths, valid,
+                                   serving_mesh(8))

@@ -4,11 +4,12 @@ cache.
 
 Covers the PR-3 acceptance bars: n-gram proposer unit behaviour, the verify
 lane of the q_offset paged-attention kernel vs its XLA oracle at q_len > 1,
-`verify_step_paged` logit parity against chained single-token decode, exact
+the shared trunk's logit parity against the dense forward at every verified
+position, exact
 greedy token parity spec-on vs spec-off at engine level (prefix cache on AND
 off, chunked and bucketed prefill), rollback/abort refcount invariants, the
 per-request greedy fast path, accepted_per_step > 1 on a repetitive stream,
-and the compiled-program bound (decode-side <= 2 = seed + 1).
+and the compiled-program bound (decode-side exactly 1).
 """
 import numpy as np
 import pytest
@@ -20,7 +21,7 @@ from paddle_tpu.models import gpt as G
 from paddle_tpu.inference.engine import LLMEngine
 from paddle_tpu.inference.spec import NgramProposer
 from paddle_tpu.incubate.kernels.paged_attention import (
-    paged_prefill_attention_pallas, paged_verify_attention)
+    paged_prefill_attention, paged_prefill_attention_pallas)
 
 
 @pytest.fixture(scope="module")
@@ -100,7 +101,7 @@ def test_verify_kernel_matches_xla_oracle_qlen_gt1(kvh):
     tbl[2, :4] = [6, 7, 8, 3]
     lengths = jnp.asarray([9, 4, 17], jnp.int32)     # q_offset = lengths
     valid = jnp.asarray([5, 1, 3], jnp.int32)        # incl. the no-draft edge
-    ref = paged_verify_attention(q, k, v, jnp.asarray(tbl), lengths, valid)
+    ref = paged_prefill_attention(q, k, v, jnp.asarray(tbl), lengths, valid)
     got = paged_prefill_attention_pallas(q, k, v, jnp.asarray(tbl), lengths,
                                          valid, interpret=True)
     for b, n in enumerate(np.asarray(valid)):
@@ -111,13 +112,20 @@ def test_verify_kernel_matches_xla_oracle_qlen_gt1(kvh):
 @pytest.mark.parametrize("preset", [G.gpt_tiny, G.llama_tiny],
                          ids=["gpt", "llama"])
 def test_verify_step_matches_dense_forward(preset):
-    """verify_step_paged scores T positions in one pass with the logits of
+    """The shared trunk (`_paged_chunk_hidden`, the fused step's body) plus
+    the head scores T positions in one pass with the logits of
     the dense forward (== chained single-token decode, per the existing
     decode-parity tests) — the property greedy acceptance relies on — and a
     valid-masked call (the rollback shape) leaves the accepted prefix intact:
     a later verify over the once-rejected positions still matches."""
     cfg = preset(64)
     params = G.init_params(cfg, jax.random.key(1))
+
+    def verify(tokens, pool, tbl, q_offset, valid):
+        x, pool = G._paged_chunk_hidden(params, tokens, cfg, pool, tbl,
+                                        q_offset, valid)
+        return G.head_logits(G.epilogue(params, x, cfg), params, cfg), pool
+
     rng = np.random.RandomState(1)
     toks = jnp.asarray(rng.randint(0, cfg.vocab_size, (1, 13)), jnp.int32)
     dense = np.asarray(G.forward(params, toks, cfg))        # [1, 13, V]
@@ -132,9 +140,9 @@ def test_verify_step_matches_dense_forward(preset):
         params, jnp.asarray(ids), cfg, pool, tbl,
         jnp.asarray([0], jnp.int32), jnp.asarray([Tp], jnp.int32))
     # verify with valid=2: tokens Tp, Tp+1 land, Tp+2.. masked (rollback)
-    vlog, pool = G.verify_step_paged(
-        params, toks[:, Tp:Tp + T], pool, tbl, jnp.asarray([Tp], jnp.int32),
-        jnp.asarray([2], jnp.int32), cfg)
+    vlog, pool = verify(toks[:, Tp:Tp + T], pool, tbl,
+                        jnp.asarray([Tp], jnp.int32),
+                        jnp.asarray([2], jnp.int32))
     for t in range(2):
         np.testing.assert_allclose(np.asarray(vlog[:, t]), dense[:, Tp + t],
                                    atol=2e-4, rtol=2e-4)
@@ -142,9 +150,9 @@ def test_verify_step_matches_dense_forward(preset):
     # tokens + 1 padded row): the accepted prefix survived the masked call
     vt = np.zeros((1, T), np.int32)
     vt[0, :3] = np.asarray(toks[0, Tp + 2:Tp + 5])
-    vlog2, pool = G.verify_step_paged(
-        params, jnp.asarray(vt), pool, tbl,
-        jnp.asarray([Tp + 2], jnp.int32), jnp.asarray([3], jnp.int32), cfg)
+    vlog2, pool = verify(jnp.asarray(vt), pool, tbl,
+                         jnp.asarray([Tp + 2], jnp.int32),
+                         jnp.asarray([3], jnp.int32))
     for t in range(3):
         np.testing.assert_allclose(np.asarray(vlog2[:, t]),
                                    dense[:, Tp + 2 + t],
@@ -157,8 +165,8 @@ def test_verify_step_matches_dense_forward(preset):
 
 def test_engine_spec_parity_and_program_bound(tiny):
     """Acceptance bar: spec-on emits exactly the spec-off greedy tokens —
-    prefix cache on AND off — within <= 2 decode-side programs (seed bound
-    was 1; spec adds exactly the verify executable)."""
+    prefix cache on AND off — from the ONE decode-side program (drafts ride
+    the fused step; there is no verify executable)."""
     cfg, params = tiny
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32)
@@ -182,7 +190,7 @@ def test_engine_spec_parity_and_program_bound(tiny):
         for a, b in zip(outs["off"], outs[key]):
             np.testing.assert_array_equal(a, b)
         st = engines[key].stats()
-        assert st["decode_executables"] + st["verify_executables"] <= 2
+        assert st["decode_executables"] + st["verify_executables"] == 1
         assert st["verify_steps"] > 0 and st["spec_emitted_tokens"] > 0
         assert st["pages_in_use"] == 0
         engines[key].cache.check_invariants()

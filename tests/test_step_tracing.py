@@ -105,7 +105,6 @@ def test_spans_nest(recorded, parent, child):
 def test_spans_tile_the_turnaround_and_are_all_named(recorded):
     names = {e[0] for e in recorded}
     assert names <= set(ENGINE_SPANS)
-    assert "engine.spec.accept" not in names       # speculation is off
     tiles = ("engine.emit", "engine.admit", "engine.spec.propose",
              "engine.batch.build", "engine.fused.dispatch")
     turns = [e for e in recorded if e[0] == "engine.turnaround"]
@@ -159,8 +158,9 @@ def test_no_span_object_is_built_in_a_step_when_nothing_records(
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("mode", [
-    dict(), dict(double_buffer=False), dict(fuse=False),
-    dict(spec_len=3), dict(prefill_chunk=None)])
+    dict(), dict(double_buffer=False), dict(spec_len=3),
+    dict(prefill_chunk=None), dict(prefill_chunk=None, spec_len=3),
+    dict(spec_len=3, double_buffer=False)])
 def test_ring_carries_turnaround_and_d2h(tiny, mode):
     eng = engine(tiny, clock=TickClock(), **mode)
     session_stream(eng)
@@ -169,7 +169,7 @@ def test_ring_carries_turnaround_and_d2h(tiny, mode):
     for r in ring:
         assert r["turnaround_ms"] >= 0.0 and r["d2h_ms"] >= 0.0
         assert r["turnaround_ms"] + r["sync_ms"] <= 1e3 * r["dur_s"] + 1e-6
-        if not eng.fused or not r["dispatches"]:
+        if not r["dispatches"]:
             assert r["turnaround_ms"] == 0.0
     st = eng.stats()
     assert st["turnaround_ms"] == pytest.approx(
@@ -177,12 +177,8 @@ def test_ring_carries_turnaround_and_d2h(tiny, mode):
     assert st["turnaround_ms"] == pytest.approx(
         eng.metrics.snapshot()["counters"]["turnaround_ms"])
     launched = [r for r in ring if r["decode_batch"] or r["slots"]["chunk"]]
-    if eng.fused:
-        assert launched and all(r["turnaround_ms"] > 0 for r in ring
-                                if r["slots"]["decode"] or
-                                r["slots"]["verify"])
-    else:
-        assert st["turnaround_ms"] == 0.0           # legacy: no fused launch
+    assert launched and all(r["turnaround_ms"] > 0 for r in ring
+                            if r["slots"]["decode"] or r["slots"]["verify"])
     # the fetches' time lands in the step that drained them
     assert st["swap_d2h_fetches"] > 0
     assert sum(r["d2h_ms"] for r in ring) > 0
@@ -218,9 +214,10 @@ def test_swap_bytes_match_the_shapes(tiny, direction):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("mode", [
-    dict(), dict(double_buffer=False), dict(fuse=False),
-    dict(prefill_chunk=None), dict(spec_len=3),
-    dict(spec_len=3, fuse=False), dict(num_pages=7, admission="optimistic")])
+    dict(), dict(double_buffer=False), dict(prefill_chunk=None),
+    dict(spec_len=3), dict(prefill_chunk=None, spec_len=3),
+    dict(spec_len=3, double_buffer=False),
+    dict(num_pages=7, admission="optimistic")])
 def test_emit_times_account_for_every_token(tiny, mode):
     eng = engine(tiny, clock=TickClock(), **mode)
     if eng.spec_len:

@@ -95,7 +95,9 @@ def test_rms_norm(one):
 
 # (B, T, H, KVH, table entries): the three widths the benchmark's fused steps
 # run at T = 1, then the default engine's verify width, the README's
-# prefill_chunk and the bucketed engine's prefix-hit tail at GPT-3 widths
+# prefill_chunk and the bucketed engine's prefix-hit tail at GPT-3 widths;
+# last an int8 pool (the kernel route needs whole (32, 128) int8 tiles:
+# page 32) at the decode and the verify width
 _PAGED_PREFILL_SHAPES = {
     "gpt3_t1": (SLOTS, 1, H, H, MAX_PAGES),
     "mistral_t1": (32, 1, 32, 8, 128),
@@ -104,6 +106,8 @@ _PAGED_PREFILL_SHAPES = {
     "mistral_verify5": (32, 5, 32, 8, 128),
     "chunk256": (SLOTS, 256, H, H, MAX_PAGES),
     "tail1024": (1, MAX_LEN, H, H, MAX_PAGES),
+    "int8_t1": (SLOTS, 1, H, H, MAX_LEN // 32, "int8"),
+    "int8_verify5": (SLOTS, 5, H, H, MAX_LEN // 32, "int8"),
 }
 
 
@@ -115,34 +119,25 @@ def test_paged_prefill_kernel_any_width(one, shape):
     and the walk's double buffers and score tile (`_pages_per_block`,
     `_heads_per_tile`) must fit beside the query tile at every page size -
     64 KB at GPT-3's 16 kv heads, 32 KB at Mistral's 8, 8 KB at the
-    hybrid's 2."""
-    B, T, heads, kvh, entries = _PAGED_PREFILL_SHAPES[shape]
+    hybrid's 2.  An int8 pool's pages are copied as they lie too; only its
+    scale lanes are gathered through the table."""
+    B, T, heads, kvh, entries, *int8 = _PAGED_PREFILL_SHAPES[shape]
+    page = 32 if int8 else PAGE
     pool_pages = B * entries // 2 + 1
     i32 = jnp.int32
-    text = _compile(PA.paged_serve_attention, *one([
-        _s(B, T, heads, HD), _s(pool_pages, PAGE, kvh, HD),
-        _s(pool_pages, PAGE, kvh, HD), _s(B, entries, dtype=i32),
-        _s(B, dtype=i32), _s(B, dtype=i32)]))
+    pool = _s(pool_pages, page, kvh, HD, dtype=jnp.int8 if int8 else BF16)
+    args = [_s(B, T, heads, HD), pool, pool, _s(B, entries, dtype=i32),
+            _s(B, dtype=i32), _s(B, dtype=i32)]
+    if int8:
+        args += [_s(pool_pages, page, kvh, dtype=jnp.float32)] * 2
+    text = _compile(
+        lambda q, k, v, t, qo, vl, *sc: PA.paged_prefill_attention(
+            q, k, v, t, qo, vl, kv_scales=sc or None), *one(args))
     # the pools are read where they lie: no copy of one feeds the call
-    assert f"[{pool_pages},{PAGE},{kvh},{HD}]" in text
+    assert f"[{pool_pages},{page},{kvh},{HD}]" in text
     assert not [ln for ln in text.splitlines()
-                if " copy(" in ln and f"[{pool_pages},{PAGE}," in ln]
-
-
-def test_paged_decode_kernel_fp_and_int8(one):
-    i32 = jnp.int32
-    tbl, ln = _s(SLOTS, MAX_PAGES, dtype=i32), _s(SLOTS, dtype=i32)
-    _compile(PA.paged_attention_decode, *one([
-        _s(SLOTS, H, HD), _s(POOL_PAGES, PAGE, H, HD),
-        _s(POOL_PAGES, PAGE, H, HD), tbl, ln]))
-    # int8 pool: the kernel route needs whole (32, 128) int8 tiles -> page 32
-    page, n = 32, MAX_LEN // 32
-    pool = _s(POOL_PAGES, page, H, HD, dtype=jnp.int8)
-    sc = _s(POOL_PAGES, page, H, dtype=jnp.float32)
-    _compile(lambda q, k, v, t, l, ks, vs: PA.paged_attention_decode(
-        q, k, v, t, l, kv_scales=(ks, vs)), *one([
-            _s(SLOTS, H, HD), pool, pool, _s(SLOTS, n, dtype=i32), ln,
-            sc, sc]))
+                if " copy(" in ln and f"[{pool_pages},{page},{kvh},{HD}]"
+                in ln]
 
 
 def _model(layers=2):
@@ -312,7 +307,7 @@ def test_shard_mapped_kernels_on_four_devices(topo):
     pool_sh = NamedSharding(mesh, PA._POOL_SPEC)
     i32 = jnp.int32
     _compile(
-        lambda q, k, v, t, qo, vl: PA.paged_serve_attention(
+        lambda q, k, v, t, qo, vl: PA.paged_prefill_attention(
             q, k, v, t, qo, vl, mesh=mesh),
         _on(heads(4), _s(SLOTS, 5, H, HD)),
         _on(pool_sh, _s(POOL_PAGES, PAGE, H, HD)),

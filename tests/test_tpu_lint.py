@@ -563,8 +563,8 @@ def test_jxp005_oversized_host_output():
 
 def test_serving_executables_jaxpr_clean():
     """Level 2 over the REAL serving set (the fused one-dispatch step with
-    its O(B*K)-int host-output budget, plus the --no-fuse decode/chunk/
-    bucketed-prefill/verify trio and the COW copy, mp1 + mp2): donation
+    its O(B*K)-int host-output budget, plus the chunk and bucketed
+    prefills, the COW copy and the swap pair, mp1 + mp2): donation
     declared == donation traced, no embedded transfers, no f64, mp outputs
     pinned, no logits-shaped host output."""
     assert run_jaxpr_checks(include_mp=True) == []

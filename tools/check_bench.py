@@ -8,8 +8,8 @@ nothing noticed a regression until a human did.  This tool closes that gap:
 
 - **Trajectory** (`BENCH_SERVE.jsonl`): every bench run appends ONE
   schema-versioned row — the mode axes that make rows comparable across PRs
-  (mp, fuse, spec, dtypes, oversubscribe, tracing) plus the key perf
-  metrics (tokens/s, goodput, dispatches/step, host-sync ms, fused_speedup,
+  (mp, spec, dtypes, oversubscribe, tracing) plus the key perf
+  metrics (tokens/s, goodput, dispatches/step, host-sync ms,
   parity flags, tracing overhead, roofline predicted/measured/model_error).
   `bench_serve.py` writes the row by default (`--no-history` opts out)
   through `append_bench_row()` here, so the row shape and its validator
@@ -20,7 +20,7 @@ nothing noticed a regression until a human did.  This tool closes that gap:
   and enforces `SERVE_PERF_FLOORS` — declared ONCE in
   `paddle_tpu/analysis/registry.py` next to the resource budgets: every
   parity flag true (fleet_parity included), dispatches/step within the
-  decode-side program budget, fused_speedup over its floor, the
+  decode-side program budget, the
   deterministic tracing account under 2%, model_error a sane positive
   ratio, and on fleet rows the affinity-vs-round-robin prefix-hit odds
   ratio >= 1 with replicas sharing the leader's compiled programs.  The
@@ -48,6 +48,9 @@ ROW_SCHEMA_VERSION = 5
 # the axes that make rows comparable across PRs: two rows agree on "mode"
 # or their perf numbers are not the same experiment.  v1 rows (pre KV
 # tiering) validate against the v1 sets — old history stays parseable.
+# The "fused" axis, its speedup and its parity flag are carried by rows
+# written while the engine had a second, three-program step; newer rows leave
+# them null.
 MODE_AXES_V1 = ("mp", "fused", "spec_len", "prefill_chunk", "weight_dtype",
                 "kv_dtype", "oversubscribe", "preempt_mode", "admission",
                 "request_tracing")
@@ -156,8 +159,9 @@ def validate_row(row):
 
 def check_floors(row, floors=None):
     """Enforce `SERVE_PERF_FLOORS` on one row; returns error strings.  Mode-
-    conditional bars (dispatch cap, fused_speedup) apply only where the row's
-    mode reaches them; the parity and tracing bars apply wherever the run
+    conditional bars apply only where the row's mode reaches them (the
+    dispatch cap: every row but a history row of the three-program step,
+    `mode.fused` false); the parity and tracing bars apply wherever the run
     produced the number."""
     if floors is None:
         from paddle_tpu.analysis.registry import SERVE_PERF_FLOORS
@@ -175,16 +179,12 @@ def check_floors(row, floors=None):
             tok < floors["tokens_per_sec_min"]:
         errors.append(f"decode_tokens_per_sec_per_chip {tok!r} below "
                       f"{floors['tokens_per_sec_min']}")
-    if mode.get("fused"):
+    if mode.get("fused") is not False:
         d = perf.get("dispatches_per_step")
         cap = floors["dispatches_per_step_max"]
         if not isinstance(d, (int, float)) or d > cap + 1e-9:
             errors.append(f"dispatches_per_step {d!r} exceeds the declared "
                           f"{cap} (the one-dispatch claim broke)")
-        fs = perf.get("fused_speedup")
-        if fs is not None and fs < floors["fused_speedup_min"]:
-            errors.append(f"fused_speedup {fs} below the declared floor "
-                          f"{floors['fused_speedup_min']}")
     # bench_row fills absent keys with None, so fall back on None — not
     # just on a missing key — or a raw run_serve_bench row (which carries
     # only the measured account) would skip the tracing bar entirely
@@ -354,7 +354,7 @@ def main(argv=None):
         report["row_perf"] = {
             k: row["perf"].get(k)
             for k in ("decode_tokens_per_sec_per_chip", "dispatches_per_step",
-                      "fused_speedup", "tracing_overhead", "model_error")}
+                      "tracing_overhead", "model_error")}
         report["row_parity"] = row["parity"]
     for e in errors:
         print(f"FAIL: {e}", file=sys.stderr)

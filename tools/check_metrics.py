@@ -168,7 +168,7 @@ REQUIRED_COUNTERS = frozenset({
 REQUIRED_STEP_RECORD_KEYS = frozenset({
     "v", "step", "t", "dur_s", "queued", "prefilling", "running",
     "decode_batch", "chunk", "verify_dispatches", "tokens_emitted",
-    "finished", "pages_in_use", "pages_free", "pages_evictable", "fused",
+    "finished", "pages_in_use", "pages_free", "pages_evictable",
     "dispatches", "sync_ms", "turnaround_ms", "d2h_ms", "slots", "preempted",
     "pool_pressure", "moe_pairs_here", "moe_pairs_away", "moe_experts_touched",
     "pages_walked",

@@ -10,7 +10,7 @@ cannot silently reintroduce per-shape recompiles:
   (vanilla decode, spec verify and the interleaved prefill chunk all ride
   one fixed-shape batch, sampling + acceptance on device);
 - prefill-side (chunked mode): <= 2 programs for the cold paths (the chunk
-  rides the fused batch, so a chunked fused run measures 0);
+  rides the fused batch, so a chunked run measures 0);
 - copy: <= 1 program (the COW page copy);
 - swap: <= 2 programs — the KV swap-out gather + swap-in scatter, SHARED by
   preemption swap parking and the (default-on) KV tier's prefix
@@ -22,9 +22,7 @@ The budget holds PER MESH CONFIG: a second pass re-measures under mp=2
 tensor-parallel serving (8 forced CPU host devices — the same simulation the
 multichip training dryrun uses) and asserts decode-side <= 1 there too.  The
 mp engine AOT-compiles its executables, so the measured counts are exact
-distinct-program counts, not dispatch-cache sizes.  (`--no-fuse` serving is
-the A/B escape hatch and sits outside this budget — it is still audited by
-tpu_lint's jaxpr level.)
+distinct-program counts, not dispatch-cache sizes.
 
 A third pass measures a 2-replica dp `EngineFleet` (the serving front
 door's scale-out unit): replication must ADD ZERO programs — replicas run

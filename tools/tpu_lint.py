@@ -13,7 +13,7 @@ layer of custom static checks — op-registry audits, API guards):
   without a reason.  Suppress per line with
   `# tpu-lint: disable=TPL001 -- reason`.
 - **jaxpr** (`analysis/jaxpr_checks.py`): traces the serving executables
-  (the fused one-dispatch step AND the --no-fuse legacy trio, mp1+mp2) and
+  (the fused one-dispatch step and the cold-path programs, mp1+mp2) and
   audits the programs — JXP001 embedded transfers, JXP002 donation
   mismatches, JXP003 f64 upcasts, JXP004 missing mp sharding constraints,
   JXP005 oversized host-visible output (the fused step must return O(B*K)
