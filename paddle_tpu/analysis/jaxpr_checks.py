@@ -310,12 +310,13 @@ def serving_targets(mp: int = 1, engines=None
         (f"serve.{tag}cow_copy", unwrap(eng._copy_fn),
          (eng._pool, jnp.zeros((), i32), jnp.ones((), i32)),
          dict(donate_paths=("arg0",), **mp_kw)),
-        # preemption KV swap copies: the swap-out gather reads the pool into
-        # a standalone buffer (pool NOT donated — it stays live; its output
-        # IS a host-bound bulk fetch, so no host_output_budget applies); the
-        # swap-in scatter restores in place (pool donated).
+        # preemption KV swap copies: the swap-out gather reads a slot's
+        # width of pages out of the pool into standalone buffers, one a
+        # piece (pool NOT donated — it stays live; its outputs ARE host-bound
+        # bulk fetches, so no host_output_budget applies); the swap-in
+        # scatter restores a slot's width in place (pool donated).
         (f"serve.{tag}swap_out", unwrap(eng._swap_out_fn),
-         (eng._pool, jnp.zeros((P,), i32)),
+         (eng._pool, jnp.zeros((eng._d2h_slot_w,), i32)),
          dict(keep_paths=("arg0",), **mp_kw)),
         (f"serve.{tag}swap_in", unwrap(eng._swap_in_fn),
          (eng._pool, jnp.zeros((P,), i32),

@@ -41,8 +41,10 @@ from typing import Dict, List, Optional, Tuple
 # prefix-tail chunk in bucketed mode; zero programs in chunked mode, where
 # the chunk rides the fused batch), plus one COW page copy.  The swap budget
 # covers the two KV-copy executables — ONE fixed-shape gather
-# (`swap_out_pages`, page ids padded to the slot capacity) and ONE scatter
-# (`swap_in_pages`) — shared by BOTH host-copy paths: preemption swap
+# (`swap_out_pages` over page ids padded to the slot capacity, its output
+# split into pieces of `LLMEngine._swap_w` pages of which only the wanted
+# ones are fetched) and ONE scatter (`swap_in_pages`, page ids padded to
+# the slot capacity) — shared by BOTH host-copy paths: preemption swap
 # parking (oversubscription PR) and the KV tier's prefix spill/restore
 # (tiering PR), which reuse the same programs so tiering adds ZERO
 # executables.  They compile only when a swap or spill actually fires

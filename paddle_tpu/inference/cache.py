@@ -47,8 +47,10 @@ a collision can never corrupt a match.
 KV tiering (device -> host -> optional disk): when a `HostKVTier` is
 attached (`attach_tier`), `_evict` no longer drops retired prefixes — their
 page CONTENT spills to a bounded host tier through the engine's spill
-callback (the PR-10 `swap_out_pages` gather, d2h overlapped with the next
-dispatch) and the trie node stays matchable with `page = HOST_PAGE`.  A
+callback (the PR-10 `swap_out_pages` gather over the evicted pages alone;
+their copy to the host starts at once beside the engine's steps and the
+entry stays PENDING until the bytes have landed) and the trie node stays
+matchable with `page = HOST_PAGE`.  A
 later `allocate_prefixed` whose prefix lives off-device assigns fresh pages
 to those nodes and returns a restore plan (`take_restore`): the engine
 scatters the parked KV back with ONE `swap_in_pages` dispatch and
@@ -223,8 +225,9 @@ class HostKVTier:
 
     Pure storage + LRU ordering: entries are keyed by prefix-node id and hold
     either host numpy page slabs ({lane name: [L, page_size, ...]}), a
-    PENDING marker (the engine gathered the page on device but the d2h fetch
-    is still deferred past the next dispatch), or a disk path.  Budget policy
+    PENDING marker (the engine gathered the page on device and its d2h copy
+    is in flight, or has landed and waits for the next step boundary), or a
+    disk path.  Budget policy
     lives in the owner: `PagedKVCache.tier_make_room` pushes LRU host entries
     down to disk (or drops them) and the ENGINE decides how many pages of the
     unified host pool the tier may hold (`LLMEngine.swap_pool_pages` shared
@@ -278,7 +281,7 @@ class HostKVTier:
     # ---- spill / fill -----------------------------------------------------
     def add_pending(self, node_id: int) -> None:
         """Reserve a host entry for a page whose device gather is in flight
-        (the engine fills it at the next `_pending_d2h` drain)."""
+        (the engine fills it once the bytes have landed: `_land_d2h`)."""
         if self.has(node_id):
             raise RuntimeError(f"tier node {node_id} already present")
         self._host[node_id] = self._PENDING
@@ -884,12 +887,13 @@ class PagedKVCache:
         """Reclaim LRU unreferenced cached prefixes until `fresh_needed`
         pages are on the free list (or the LRU runs dry).  With a tier
         attached, evicted nodes are offered to the engine's spill callback
-        in ONE batch (one fixed-shape `swap_out_pages` gather per
-        `max_pages_per_slot` pages, d2h deferred): accepted nodes keep their
-        index entry with `page = HOST_PAGE`; the rest drop as before.  The
-        page returns to the free list either way — the gather dispatch is
-        ordered before any dispatch that could overwrite the page, so its
-        content is safe to fetch later."""
+        in ONE batch (the fixed-shape `swap_out_pages` gather, its output in
+        pieces of a few pages, each wanted piece's d2h copy started at once
+        off the engine thread):
+        accepted nodes keep their index entry with `page = HOST_PAGE`; the
+        rest drop as before.  The page returns to the free list either way —
+        the gather dispatch is ordered before any dispatch that could
+        overwrite the page, so its content is safe to fetch later."""
         evicted: List[_PrefixNode] = []
         while len(self._free) < fresh_needed and self._lru:
             _, node = self._lru.popitem(last=False)

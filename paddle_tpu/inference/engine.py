@@ -133,12 +133,15 @@ and Orca's iteration-level scheduling (Yu et al., OSDI 2022), under the same
   **recompute** — the victim's pages are released and it re-queues at the
   head with prompt+generated replayed as a longer prompt through the prefix
   cache and chunked prefill — or **swap** (`preempt="swap"`): its pages are
-  gathered into a standalone device buffer (`models.gpt.swap_out_pages`, ONE
-  fixed-shape executable padded to the slot capacity), the d2h fetch
-  overlapped against the next decode dispatch, content parked in a bounded
-  host-side numpy pool (`swap_pool_pages`, the fourth `swapped` page
-  partition in `PagedKVCache.check_invariants`), and restored by one h2d
-  scatter on re-admission (`swap_in_pages`) — no prefill replay at all.
+  gathered into standalone device buffers (`models.gpt.swap_out_pages`, ONE
+  fixed-shape executable and one dispatch, its output in pieces of `_swap_w`
+  pages), each wanted piece's device->host copy started at once on a worker
+  thread while
+  the engine goes on stepping (`_gather_d2h`; it waits for the bytes only
+  when the victim is re-admitted before they have landed), content parked
+  in a bounded host-side numpy pool (`swap_pool_pages`, the fourth `swapped`
+  page partition in `PagedKVCache.check_invariants`), and restored by one
+  h2d scatter on re-admission (`swap_in_pages`) — no prefill replay at all.
   Greedy outputs are byte-identical preempted-vs-undisturbed: recompute
   replays land on the same chunk/verify logits parity the prefix cache
   already guarantees, and swap restores bit-exact KV.  Requests whose
@@ -155,8 +158,12 @@ and Orca's iteration-level scheduling (Yu et al., OSDI 2022), under the same
   `kv_tier=True` (default), prefix-cache pages evicted under pool pressure
   spill device -> host instead of being dropped: `PagedKVCache._evict`
   routes them through the SAME fixed-shape `swap_out_pages` gather the
-  preemption swap uses (d2h overlapped with the next dispatch via
-  `_pending_d2h`), parking the content in a `HostKVTier` under the UNIFIED
+  preemption swap uses — only the evicted pages, rounded up to a piece;
+  the copy starts at the eviction, off the engine thread, and a record in
+  `_pending_d2h` lands at the first step boundary after its bytes arrive;
+  a restore or an export that needs bytes still in flight waits for them,
+  and so does a gather that would put more than two slots' width of pages
+  in flight — parking the content in a `HostKVTier` under the UNIFIED
   host-pool budget (`swap_pool_pages`, JXP009) shared with swap parking —
   and admission maps a prefix hit from ANY tier: a later request whose
   prefix lives on host (a returning chat session re-submitting its
@@ -186,7 +193,9 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import wait as _wait_futures
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -355,7 +364,8 @@ _NULL_SPAN = _NullSpan()
 # prefix-hit tail.  engine.turnaround (see `LLMEngine._turn_begin`) is the
 # host stretch the device waits for between two fused programs; emit, admit,
 # batch.build and fused.dispatch (with fused.h2d, its five puts, inside) tile
-# it.  swap.d2h holds .ready (wait for the gather) and .copy (device -> host).
+# it.  swap.d2h is the engine thread taking one piece from the fetch worker:
+# .ready its wait for bytes still in flight, .copy the hand-over.
 ENGINE_SPANS = (
     "engine.step",
     "engine.turnaround",
@@ -372,6 +382,32 @@ ENGINE_SPANS = (
     "engine.swap.d2h.copy",
     "engine.swap.h2d",
 )
+
+
+# the most one piece of a spill/swap-out gather may hold (`LLMEngine._swap_w`
+# pages).  Small, because the link is fastest there and a token read issued
+# beside one piece's copy is not held up: on a TPU v5e a 16 MB piece crosses
+# at 4.8 GB/s and a 134 MB buffer at 1.0 GB/s, and a small `device_get`
+# behind the large copy waits 10-13 ms, behind the piece under 1 ms more
+# (PERF.md, PR 31).  Not smaller: 8 MB pieces cross at 3.8 GB/s, and at 16
+# rounding a page count up to a piece already costs a quarter or less
+_D2H_PIECE_BYTES = 16 << 20
+
+
+def _d2h_live(rec: Dict[str, object]) -> bool:
+    """A pending record the engine still has to land — not one consumed by
+    a swap-in, degraded by a failed copy or dropped with its request."""
+    return rec["kind"] in ("spill", "swap") and not rec.get("fetched")
+
+
+def _fetch_piece(data, n: int) -> List[Dict[str, np.ndarray]]:
+    """The fetch worker's task: the blocking device->host copy of one
+    gathered piece, returned as its `n` wanted pages ({lane: [L, page, ...]}
+    each, contiguous — the pads and the piece's buffer die here).  Runs off
+    the engine thread and touches nothing of the engine."""
+    host = jax.device_get(data)
+    return [{name: np.ascontiguousarray(a[:, i]) for name, a in host.items()}
+            for i in range(n)]
 
 
 class _AotCache:
@@ -853,17 +889,34 @@ class LLMEngine:
             "paged_table_entries",
             "page-table entries handed to those dispatches (rows x "
             "max pages a slot)")
-        # what crossed at the two swap boundaries against what was wanted:
-        # both directions move a max_pages_per_slot-wide buffer whatever the
-        # page count, so moved/useful is the padding's share of the copy
+        # what crossed at the two swap boundaries against what was wanted.
+        # Out: `_swap_w`-page pieces, so moved/useful says how tight the
+        # gather is (under 1 + `_swap_w`/n for n pages); in: still a
+        # max_pages_per_slot-wide staging buffer whatever the page count
         self._d2h_fetches = m.counter(
             "swap_d2h_fetches",
-            "gathered swap/spill buffers fetched to the host")
+            "gathered swap/spill pieces whose bytes the engine took")
         self._d2h_bytes = m.counter(
-            "swap_d2h_bytes", "bytes those fetches moved (the buffers' nbytes)")
+            "swap_d2h_bytes", "bytes those fetches moved (the pieces' nbytes)")
         self._d2h_useful = m.counter(
             "swap_d2h_useful_bytes",
             "bytes of the pages those fetches were for (pages x page bytes)")
+        # the copies run on the fetch worker; these say what they still cost
+        # the engine thread and how far ahead of it they run
+        self._d2h_blocked_ms = m.counter(
+            "swap_d2h_blocked_ms",
+            "milliseconds the engine thread waited for spill/swap bytes to "
+            "land (inside swap_ms)")
+        self._d2h_landed_free = m.counter(
+            "swap_d2h_landed_free",
+            "fetches whose bytes had landed when the engine came for them")
+        self._d2h_bp_waits = m.counter(
+            "swap_d2h_backpressure_waits",
+            "gathers held until the oldest piece in flight had landed (the "
+            "bound on gathered pages was reached)")
+        m.gauge("swap_d2h_inflight_pages", lambda: self._d2h_inflight,
+                "pages of gathered device buffers whose bytes the engine has "
+                "not taken yet (bounded by `_d2h_bound`, two slots' width)")
         self._h2d_bytes = m.counter(
             "swap_h2d_bytes",
             "bytes staged to the device by swap-in/tier-restore scatters")
@@ -1160,14 +1213,34 @@ class LLMEngine:
             return pin_pool({n: a.at[:, dst].set(a[:, src])
                              for n, a in pool.items()})
 
+        # how bytes leave the device (spill and swap-out alike): the pages
+        # are gathered in pieces of `_swap_w` — the widest power of two whose
+        # piece stays within `_D2H_PIECE_BYTES`, at most a slot's pages — and
+        # each piece's device->host copy runs on ONE worker thread, a piece
+        # at a time, while the engine thread goes on (`_gather_d2h`); only
+        # the pieces that hold wanted pages are copied.  Gathered buffers
+        # hold HBM until their bytes have landed, so at most `_d2h_bound`
+        # pages of them are in flight: what TWO slot-wide buffers would hold,
+        # one landing while the next is gathered (the engine before PR 31
+        # held one such buffer a pending record, 32 of them at its worst)
+        P = self.cache.max_pages_per_slot
+        w = max(1, min(P, _D2H_PIECE_BYTES // max(1, self._kv_page_bytes)))
+        self._swap_w = W = 1 << (w.bit_length() - 1)
+        self._d2h_slot_w = -(-P // W) * W       # the gather's fixed width
+        self._d2h_bound = 2 * self._d2h_slot_w
+
         def swap_out_impl(pool, ids):
-            # preemption swap-out: gather the victim's pages into a fresh
-            # buffer (pool NOT donated — it stays live) so the d2h fetch can
-            # overlap the next decode dispatch; ids padded to the slot
-            # capacity keep this ONE fixed-shape executable.  The pin keeps
-            # the gathered buffer in the pool's KVH-sharded layout under mp
-            # (the gather stays chip-local; the host fetch assembles).
-            return pin_pool(gpt_mod.swap_out_pages(pool, ids))
+            # swap-out / spill: gather a slot's width of page ids out of the
+            # pool (NOT donated — it stays live) as SEPARATE buffers of
+            # `_swap_w` pages, so that each is its own transfer and the ones
+            # past the wanted pages are dropped unfetched; ONE fixed-shape
+            # executable and ONE dispatch whatever the page count (a dispatch
+            # a piece cost 0.7 ms of host time each on the chip).  The pin
+            # keeps the gathered buffers in the pool's KVH-sharded layout
+            # under mp (the gather stays chip-local; the host fetch
+            # assembles).
+            return [pin_pool(gpt_mod.swap_out_pages(pool, ids[i:i + W]))
+                    for i in range(0, ids.shape[0], W)]
 
         def swap_in_impl(pool, ids, data):
             # preemption swap-in: scatter the parked KV back into freshly
@@ -1209,11 +1282,14 @@ class LLMEngine:
         self._decode_used = False       # any decode-side dispatch happened
         # preemption/overload state: rid -> resume record ("recompute" keeps
         # the banked generation for the longer-prompt replay; "swap" adds the
-        # parked KV, first as un-synced device buffers then host numpy);
-        # _pending_d2h holds swap records whose d2h fetch is deferred past
-        # the next dispatch; _has_deadlines gates the per-step expiry scan
+        # parked KV, first as pieces in flight then host numpy pages);
+        # _pending_d2h holds the swap and spill records whose bytes the
+        # engine has not taken yet, oldest first; _has_deadlines gates the
+        # per-step expiry scan
         self._preempted: Dict[int, Dict[str, object]] = {}
         self._pending_d2h: List[Dict[str, object]] = []
+        self._d2h_inflight = 0          # gathered pages not yet taken
+        self._d2h_worker: Optional[ThreadPoolExecutor] = None
         self._has_deadlines = False
         self._step_preempted = 0
         # double-buffer state: the un-synced result of the last fused
@@ -1265,7 +1341,11 @@ class LLMEngine:
           re-seed on the next busy step (warmup compiles stay excluded the
           same way warmup counter traffic does).  The static
           `predicted_step_ms` survives — it is a property of the engine's
-          shapes, not of any run."""
+          shapes, not of any run.
+
+        Spill/swap-out copies still in flight land first (the engine waits
+        for them): their fetches belong to the traffic before the reset."""
+        self._land_d2h(wait=True)
         self.metrics.reset()
         self.cache.prefix_evictions = 0
         if self.cache._tier is not None:
@@ -1656,11 +1736,11 @@ class LLMEngine:
             # batch (victims left before the program ran)
             decode_batch = self._step_slots["decode"] + \
                 self._step_slots["verify"]
-            # deferred swap-out fetches: the d2h lands while the device is
-            # busy with the dispatch above, not before it
             self._turn_end(launched=False)      # no-op after a launch
+            # spill/swap-out records whose bytes have arrived land here,
+            # behind the dispatch; the others stay in flight
             if self._pending_d2h:
-                self._drain_swap_d2h()
+                self._land_d2h()
         dur = self._now() - t0
         self._h_step.observe(dur)
         if self._step_dispatches:
@@ -2050,23 +2130,15 @@ class LLMEngine:
         }
         L = int(mgr.lengths[slot])
         n = mgr.pages_needed(L)
-        if self.preempt == "swap":
-            # live victims outrank cached prefixes in the unified host pool:
-            # reclaim tier room (demote to disk or drop) before giving up
-            room = mgr.host_pool_room(self.swap_pool_pages)
-            if n > room:
-                room += mgr.tier_make_room(n - room)
-        else:
-            room = -1
+        # live victims outrank cached prefixes in the unified host pool:
+        # reclaim tier room (demote to disk or drop) before giving up
+        room = self._host_room_for(n) if self.preempt == "swap" else -1
         if self.preempt == "swap" and n <= room:
-            # gather the victim's pages into a standalone buffer NOW (the
-            # pages are about to be handed to a new owner); the blocking
-            # d2h fetch is deferred until after the next dispatch
-            ids = np.zeros((mgr.max_pages_per_slot,), np.int32)
-            ids[:n] = mgr.slot_pages(slot)[:n]
-            data = self._swap_out_fn(self._pool, self._h2d(ids))
-            self._swap_out_used = True
-            rec.update(kind="swap", L=L, n=n, data=data, fetched=False)
+            # gather the victim's pages into standalone buffers NOW (the
+            # pages are about to be handed to a new owner); their copies to
+            # the host start at once, off this thread
+            rec.update(kind="swap", L=L, n=n, fetched=False,
+                       pieces=self._gather_d2h(mgr.slot_pages(slot)[:n]))
             mgr.note_swap_out(rid, n)
             self._pending_d2h.append(rec)
             # swapped_pages/preempt_swaps count at d2h SUCCESS (in
@@ -2085,39 +2157,116 @@ class LLMEngine:
         self._free_slots.append(slot)
         self._queue.appendleft(req)
 
+    def _host_room_for(self, n: int) -> int:
+        """Pages of the UNIFIED host pool open to `n` more: what swap
+        parking and the tier have not claimed, reclaiming host-tier room
+        downward (disk or drop) when short.  A pending gather cannot move,
+        so what has arrived lands first, and only if room is still short
+        does the engine wait for the records in flight — no page is turned
+        away that a landed tier would have had room for."""
+        mgr = self.cache
+        room = mgr.host_pool_room(self.swap_pool_pages)
+        for wait in (False, True):
+            if room >= n or (wait and not self._pending_d2h):
+                break
+            self._land_d2h(wait=wait)
+            room += mgr.tier_make_room(n - room)
+        return room
+
+    def _gather_d2h(self, pages: Sequence[int]) -> List[tuple]:
+        """How bytes leave the device, for a spill and a swap-out alike:
+        ONE dispatch gathers `pages` out of the pool as pieces of `_swap_w`
+        pages (one fixed-shape executable, a slot's width of ids padded with
+        the null page 0) and each piece that holds wanted pages starts its
+        device->host copy on the fetch worker right away — the engine
+        thread does not wait for it, and the pieces past the wanted pages
+        are dropped where they lie.  The worker copies one piece at a time,
+        in order, so a token read issued beside spill bytes is held up by
+        one piece at most.  Returns the pieces as (future of the pages' host
+        content, pages wanted, the gathered buffers); a gathered buffer
+        stays in HBM until the engine has taken its piece, so before a
+        dispatch that would put more than `_d2h_bound` pages in flight the
+        oldest records land first."""
+        W, slot_w = self._swap_w, self._d2h_slot_w
+        if self._d2h_worker is None:
+            self._d2h_worker = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="kv-d2h")
+        pieces = []
+        for i in range(0, len(pages), slot_w):
+            chunk = pages[i:i + slot_w]
+            width = -(-len(chunk) // W) * W
+            while self._d2h_inflight + width > self._d2h_bound and \
+                    self._pending_d2h:
+                if not all(p[0].done()
+                           for p in self._pending_d2h[0]["pieces"]):
+                    self._d2h_bp_waits.inc()
+                self._land_record(self._pending_d2h.pop(0))
+            ids = np.zeros((slot_w,), np.int32)
+            ids[:len(chunk)] = chunk
+            gathered = self._swap_out_fn(self._pool, self._h2d(ids))
+            for j in range(0, len(chunk), W):
+                data, n = gathered[j // W], min(W, len(chunk) - j)
+                pieces.append((self._d2h_worker.submit(_fetch_piece, data, n),
+                               n, data))
+            self._d2h_inflight += width
+        self._swap_out_used = True
+        return pieces
+
+    def _take_piece(self, piece: tuple) -> List[Dict[str, np.ndarray]]:
+        """The engine thread takes one piece's pages — the one place it may
+        be held by spill/swap bytes.  `.ready` is its wait for a copy the
+        worker is in the middle of (nothing when the bytes have landed),
+        `.copy` the hand-over, or the copy itself where the worker had not
+        reached the piece; the same calls run traced or not.  Counts what
+        crossed against what was wanted, and whether the engine was held."""
+        fut, n, data = piece
+        t0 = self._now()
+        with self._span("engine.swap.d2h"):
+            with self._span("engine.swap.d2h.ready"):
+                if fut.done():
+                    self._d2h_landed_free.inc()
+                elif not fut.cancel():      # the worker is at it: wait
+                    _wait_futures([fut])
+            t1 = self._now()
+            with self._span("engine.swap.d2h.copy"):
+                # a piece the worker had not reached yet is copied here and
+                # now, rather than waited for behind the ones before it
+                pages = _fetch_piece(data, n) if fut.cancelled() \
+                    else fut.result()
+        dt = self._now() - t0
+        self._d2h_inflight -= self._swap_w
+        self._d2h_blocked_ms.inc((t1 - t0) * 1e3)
+        self._swap_ms_c.inc(dt * 1e3)
+        self._step_d2h_s += dt
+        self._d2h_fetches.inc()
+        self._d2h_bytes.inc(self._swap_w * self._kv_page_bytes)
+        self._d2h_useful.inc(n * self._kv_page_bytes)
+        return pages
+
+    def _release_pieces(self, rec: Dict[str, object]) -> None:
+        """Let go of a record's pieces the engine will never take (a failed
+        copy, a dropped victim): one the worker has not started is
+        cancelled, the others finish and are forgotten."""
+        for piece in rec.pop("pieces", ()):
+            piece[0].cancel()
+            self._d2h_inflight -= self._swap_w
+
     def _materialize_swap(self, rec: Dict[str, object]) -> None:
-        """Fetch a swap record's gathered pages into host numpy (idempotent;
-        pads discarded).  Raises FaultInjected under an injected d2h
-        failure — the caller degrades the record to recompute."""
+        """Take a swap record's pages into host numpy, waiting for those
+        still in flight (idempotent).  Raises FaultInjected under an
+        injected d2h failure — the caller degrades the record to
+        recompute."""
         if rec.get("fetched"):
             return
         self._faults.d2h()
-        data = self._fetch_gathered(rec["data"], rec["n"])
-        rec["data"] = {name: a[:, :rec["n"]] for name, a in data.items()}
+        data: List[Dict[str, np.ndarray]] = []
+        while rec["pieces"]:
+            data += self._take_piece(rec["pieces"].pop(0))
+        rec["data"] = data
         rec["fetched"] = True
         self._swapped_pages_c.inc(rec["n"])
         self._preempt_swaps.inc()
         self._tev(rec["rid"], "swap_out", pages=int(rec["n"]))
-
-    def _fetch_gathered(self, data, n: int) -> Dict[str, np.ndarray]:
-        """The blocking device->host fetch of one `swap_out_pages` buffer
-        (`max_pages_per_slot` pages wide, `n` of them wanted), split where
-        its two causes part: `.ready` waits for the gather and whatever was
-        queued before it, `.copy` moves the bytes.  The same two calls run
-        traced or not.  Counts what crossed against what was wanted."""
-        t0 = self._now()
-        with self._span("engine.swap.d2h"):
-            with self._span("engine.swap.d2h.ready"):
-                jax.block_until_ready(data)
-            with self._span("engine.swap.d2h.copy"):
-                host = jax.device_get(data)
-        dt = self._now() - t0
-        self._swap_ms_c.inc(dt * 1e3)
-        self._step_d2h_s += dt
-        self._d2h_fetches.inc()
-        self._d2h_bytes.inc(sum(a.nbytes for a in host.values()))
-        self._d2h_useful.inc(n * self._kv_page_bytes)
-        return host
 
     def _degrade_to_recompute(self, rec: Dict[str, object]) -> None:
         """A swap whose d2h/h2d copy failed falls back to recompute: drop
@@ -2125,76 +2274,80 @@ class LLMEngine:
         generation — nothing leaks, the replay just costs prefill again."""
         rec["kind"] = "recompute"
         rec.pop("data", None)
+        self._release_pieces(rec)
         self.cache.note_swap_in(rec["rid"])
         self._preempt_recomputes.inc()
         self._tev(rec["rid"], "swap_degrade")
 
-    def _drain_swap_d2h(self) -> None:
-        """Materialize deferred swap-out fetches — called after the step's
-        dispatch so the d2h overlaps device compute instead of stalling the
-        schedule."""
-        while self._pending_d2h:
-            rec = self._pending_d2h.pop()
-            if rec["kind"] == "spill":
-                if not rec.get("fetched"):
-                    try:
-                        self._materialize_spill(rec)
-                    except FaultInjected:
-                        self._degrade_spill_to_drop(rec)
-                continue
-            if rec["kind"] != "swap" or rec.get("fetched"):
-                continue            # consumed, degraded or dropped already
-            try:
-                self._materialize_swap(rec)
-            except FaultInjected:
-                self._degrade_to_recompute(rec)
+    def _land_record(self, rec: Dict[str, object]) -> None:
+        """Land one pending record on the engine thread (the tier and the
+        cache stay single-threaded): its pages go into the host tier or the
+        swap record, waiting for bytes still in flight; a failed copy
+        degrades spill -> drop and swap -> recompute.  A record consumed,
+        degraded or dropped in the meantime only gives up its pieces."""
+        if not _d2h_live(rec):
+            self._release_pieces(rec)
+            return
+        spill = rec["kind"] == "spill"
+        try:
+            (self._materialize_spill if spill else self._materialize_swap)(rec)
+        except FaultInjected:
+            (self._degrade_spill_to_drop if spill
+             else self._degrade_to_recompute)(rec)
+
+    def _land_d2h(self, wait: bool = False, spills_only: bool = False) -> None:
+        """Land the pending records whose bytes have arrived and leave the
+        others in flight — what `step()` does at its boundary, after the
+        dispatch.  `wait=True` lands every record, waiting for its bytes:
+        where the engine needs them (a tier restore or an export:
+        `spills_only`, swap records keep their own seam in `_swap_in`) or
+        is about to rest (`run`, `drain`, `stop_loop`, `reset_counters`)."""
+        rest: List[Dict[str, object]] = []
+        for rec in self._pending_d2h:
+            if _d2h_live(rec) and (
+                    (spills_only and rec["kind"] != "spill") or not (
+                        wait or all(p[0].done() for p in rec["pieces"]))):
+                rest.append(rec)
+            else:
+                self._land_record(rec)
+        self._pending_d2h = rest
 
     # ---- KV tiering: prefix spill (device -> host -> disk) and restore ----
     def _spill_prefix_nodes(self, nodes) -> set:
         """`PagedKVCache._evict`'s spill callback: gather the evicted
-        prefix pages into standalone device buffers (the PR-10
-        `swap_out_pages` executable, one fixed-shape dispatch per
-        `max_pages_per_slot` pages) and defer the blocking d2h fetch past
-        the next dispatch (`_pending_d2h`), exactly the preemption-swap
-        discipline.  Room comes from the UNIFIED host pool: what swap
-        parking has not claimed, reclaiming host-tier room downward (disk
-        or drop) first.  Returns the node ids accepted — the cache drops
-        the rest."""
-        mgr = self.cache
-        room = mgr.host_pool_room(self.swap_pool_pages)
-        if room < len(nodes):
-            room += mgr.tier_make_room(len(nodes) - room)
+        prefix pages and start their copies to the host (`_gather_d2h`, the
+        path a preemption swap-out takes), one pending record a piece, each
+        landing in the host tier at the first step boundary after its bytes
+        arrive.  Room comes from the UNIFIED host pool (`_host_room_for`).
+        Returns the node ids accepted — the cache drops the rest."""
+        room = self._host_room_for(len(nodes))
         if room <= 0:
             return set()
         accept = nodes[-room:] if room < len(nodes) else nodes
-        P = mgr.max_pages_per_slot
-        for i in range(0, len(accept), P):
-            chunk = accept[i:i + P]
-            ids = np.zeros((P,), np.int32)
-            ids[:len(chunk)] = [nd.page for nd in chunk]
-            data = self._swap_out_fn(self._pool, self._h2d(ids))
-            self._swap_out_used = True
-            self._pending_d2h.append({"kind": "spill", "nodes": list(chunk),
-                                      "n": len(chunk), "data": data,
-                                      "fetched": False})
+        pieces = self._gather_d2h([nd.page for nd in accept])
+        # pending only now: the nodes become tier entries when this returns,
+        # and a record landed before that (the bound) would find none
+        W = self._swap_w
+        self._pending_d2h += [
+            {"kind": "spill", "nodes": list(accept[i * W:(i + 1) * W]),
+             "pieces": [piece], "fetched": False}
+            for i, piece in enumerate(pieces)]
         return {nd.node_id for nd in accept}
 
     def _materialize_spill(self, rec: Dict[str, object]) -> None:
-        """Fetch a spill record's gathered pages into the host tier
-        (idempotent; pads discarded).  Raises FaultInjected under an
-        injected d2h failure — the caller degrades spill -> drop."""
+        """Take a spill record's pages into the host tier, waiting for them
+        if they are still in flight (idempotent).  Raises FaultInjected
+        under an injected d2h failure — the caller degrades spill -> drop."""
         if rec.get("fetched"):
             return
         self._faults.d2h()
-        data = self._fetch_gathered(rec["data"], rec["n"])
+        pages = self._take_piece(rec["pieces"].pop())
         rec["fetched"] = True
         tier = self.cache._tier
         landed = 0
-        for i, node in enumerate(rec["nodes"]):
+        for node, data in zip(rec["nodes"], pages):
             if tier is not None and tier.is_pending(node.node_id):
-                tier.fill(node.node_id,
-                          {name: np.ascontiguousarray(a[:, i])
-                           for name, a in data.items()})
+                tier.fill(node.node_id, data)
                 landed += 1
         self._tier_spills.inc(landed)
 
@@ -2203,25 +2356,11 @@ class LLMEngine:
         the pages were already reclaimed, so the only cost is that a later
         match re-prefills instead of restoring.  Nothing leaks."""
         rec["fetched"] = True           # never retried
+        self._release_pieces(rec)
         tier = self.cache._tier
         pend = [nd for nd in rec["nodes"]
                 if tier is not None and tier.is_pending(nd.node_id)]
         self.cache.drop_tier_nodes(pend)
-
-    def _flush_pending_spills(self) -> None:
-        """Materialize every deferred spill fetch NOW (a tier restore needs
-        the bytes) — swap records stay deferred for their usual
-        post-dispatch drain."""
-        rest: List[Dict[str, object]] = []
-        for rec in self._pending_d2h:
-            if rec["kind"] == "spill" and not rec.get("fetched"):
-                try:
-                    self._materialize_spill(rec)
-                except FaultInjected:
-                    self._degrade_spill_to_drop(rec)
-            else:
-                rest.append(rec)
-        self._pending_d2h = rest
 
     def _tier_restore(self, slot: int, plan, rid: int) -> bool:
         """Scatter a matched prefix's parked KV from the host/disk tier into
@@ -2234,7 +2373,8 @@ class LLMEngine:
         mgr = self.cache
         tier = mgr._tier
         if any(tier.is_pending(node.node_id) for _, node, _ in plan):
-            self._flush_pending_spills()
+            # the restore needs bytes still in flight: wait for them
+            self._land_d2h(wait=True, spills_only=True)
         nodes = [node for _, node, _ in plan]
         try:
             datas = [mgr.tier_data(node) for node in nodes]
@@ -2313,7 +2453,7 @@ class LLMEngine:
                 nd.page = HOST_PAGE
                 mgr._tier_nodes[nd.node_id] = nd
                 tier.add_pending(nd.node_id)
-            self._flush_pending_spills()
+            self._land_d2h(wait=True, spills_only=True)
             pages = tokens_out = 0
             for nd in chain:
                 if nd.page >= 0 or tier.is_pending(nd.node_id):
@@ -2361,7 +2501,7 @@ class LLMEngine:
             return None
         if rec["kind"] == "swap":
             self.cache.note_swap_in(rid)
-            rec["kind"] = "dropped"     # _drain_swap_d2h skips it
+            rec["kind"] = "dropped"     # _land_record lets its pieces go
         return rec
 
     def _swap_in(self, req: Request, rec: Dict[str, object],
@@ -2397,11 +2537,12 @@ class LLMEngine:
             # staging uploads count as h2d cost: swap_ms and the span cover
             # the host->device copies AND the scatter dispatch, as in the
             # single-lane (k, v) form this generalizes
-            for name, a in rec["data"].items():
+            for name, a in rec["data"][0].items():
                 pad = np.zeros(
-                    (a.shape[0], mgr.max_pages_per_slot) + a.shape[2:],
+                    (a.shape[0], mgr.max_pages_per_slot) + a.shape[1:],
                     a.dtype)
-                pad[:, :n] = a
+                for i, page in enumerate(rec["data"]):
+                    pad[:, i] = page[name]
                 data[name] = self._h2d(pad)
             self._pool = self._swap_in_fn(self._pool, self._h2d(ids), data)
         self._swap_in_used = True
@@ -2766,18 +2907,25 @@ class LLMEngine:
         content lands on the never-read page 0) — benches call this in
         warmup so the first preemption swap-out OR KV-tier spill/restore
         (both ride the SAME two executables) doesn't pay a compile inside
-        the timed section.  No-op unless the engine can reach them
-        (optimistic admission + preempt="swap", or kv_tier on)."""
+        the timed section.  The gather has ONE shape (a slot's width of
+        ids, `_swap_w` pages a piece) whatever the page count, so this is
+        every shape the path can reach.  No-op
+        unless the engine can reach them (optimistic admission +
+        preempt="swap", or kv_tier on)."""
         if not ((self.optimistic and self.preempt == "swap") or self.kv_tier):
             return
-        mgr = self.cache
-        ids = np.zeros((mgr.max_pages_per_slot,), np.int32)
-        data = self._swap_out_fn(self._pool, self._h2d(ids))
+        P = self.cache.max_pages_per_slot
+        self._swap_out_fn(self._pool,
+                          self._h2d(np.zeros((self._d2h_slot_w,), np.int32)))
         self._swap_out_used = True
-        # round-trip through host numpy so the swap-in signature matches the
-        # real resume path (replicated staging uploads, not device outputs)
-        staged = {n: self._h2d(a) for n, a in jax.device_get(data).items()}
-        self._pool = self._swap_in_fn(self._pool, self._h2d(ids), staged)
+        # the scatter is still a slot wide; staged from host numpy so the
+        # swap-in signature matches the real resume path (replicated staging
+        # uploads, not device outputs)
+        staged = {n: self._h2d(np.zeros((a.shape[0], P) + a.shape[2:],
+                                        a.dtype))
+                  for n, a in self._pool.items()}
+        self._pool = self._swap_in_fn(
+            self._pool, self._h2d(np.zeros((P,), np.int32)), staged)
         self._swap_in_used = True
 
     def _maybe_finish(self, seq: _Running,
@@ -2942,6 +3090,7 @@ class LLMEngine:
         {request_id: RequestOutput} for everything finished so far."""
         while self.has_work:
             self.step()
+        self._land_d2h(wait=True)       # nothing stays in flight at rest
         return dict(self._outputs)
 
     @property
@@ -2973,7 +3122,8 @@ class LLMEngine:
 
     def stop_loop(self, timeout: float = 30.0) -> None:
         """Stop the loop thread (idempotent; queued work stays queued —
-        call drain() first for a clean flush)."""
+        call drain() first for a clean flush).  Spill/swap-out copies in
+        flight land before this returns, and the fetch worker ends."""
         with self._serve_cond:
             self._serve_stop = True
             self._serve_cond.notify_all()
@@ -2981,6 +3131,11 @@ class LLMEngine:
         if t is not None and t.is_alive():
             t.join(timeout)
         self._serve_thread = None
+        with self._serve_lock:
+            self._land_d2h(wait=True)
+            if self._d2h_worker is not None:
+                self._d2h_worker.shutdown()
+                self._d2h_worker = None
 
     @property
     def loop_running(self) -> bool:
@@ -2993,6 +3148,7 @@ class LLMEngine:
                 if self._serve_stop:
                     return
                 if not self.has_work:
+                    self._land_d2h()    # what arrived since the last step
                     self._serve_cond.wait(idle_wait_s)
                     continue
                 try:
@@ -3093,6 +3249,7 @@ class LLMEngine:
                 if rem <= 0.0:
                     return False
                 self._serve_cond.wait(rem)
+            self._land_d2h(wait=True)   # idle means nothing in flight either
             return True
 
     def queue_depth(self) -> int:
@@ -3267,6 +3424,12 @@ class LLMEngine:
             "swap_d2h_fetches": self._d2h_fetches.value,
             "swap_d2h_bytes": self._d2h_bytes.value,
             "swap_d2h_useful_bytes": self._d2h_useful.value,
+            # the copies run beside the engine thread: what it still waited
+            # for them, how many had landed when it came, what is in flight
+            "swap_d2h_blocked_ms": self._d2h_blocked_ms.value,
+            "swap_d2h_landed_free": self._d2h_landed_free.value,
+            "swap_d2h_backpressure_waits": self._d2h_bp_waits.value,
+            "swap_d2h_inflight_pages": self._d2h_inflight,
             "swap_h2d_bytes": self._h2d_bytes.value,
             "swap_h2d_useful_bytes": self._h2d_useful.value,
             "turnaround_ms": self._turnaround_ms_c.value,

@@ -1263,24 +1263,26 @@ def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
 
 
 def swap_out_pages(cache, page_ids):
-    """Preemption swap-out gather (vLLM-style KV swapping): copy the victim's
-    pages out of the pool into a standalone device buffer the host then
-    fetches at its leisure — the gather is a fresh buffer, so the pool pages
-    can be handed to a new owner immediately and the d2h overlaps the next
-    decode dispatch.
+    """Swap-out / spill gather (vLLM-style KV swapping): copy pages out of
+    the pool into a standalone device buffer the host fetches beside the
+    steps that follow — the gather is a fresh buffer, so the pool pages can
+    be handed to a new owner immediately.
 
-    cache {"k","v"} [L, P, page, KVH, hd]; page_ids [max_pages] int32 — the
-    victim's pages PADDED to the slot capacity with the null page 0, so ONE
-    fixed-shape executable serves every victim (padding rows carry null-page
-    garbage the host discards).  Returns {"k","v"} [L, max_pages, page, KVH,
-    hd]."""
+    cache {"k","v"} [L, P, page, KVH, hd]; page_ids [W] int32 — one PIECE of
+    the pages that leave, W fixed by the engine from the page's bytes
+    (`LLMEngine._swap_w`); the engine's program calls this once a piece over
+    a slot's width of ids padded with the null page 0, so ONE fixed-shape
+    executable serves every page count, and fetches only the pieces that
+    hold wanted pages: what crosses the link is the page count rounded up
+    to W (padding rows carry null-page garbage the host discards).  Returns
+    {"k","v"} [L, W, page, KVH, hd]."""
     return {n: a[:, page_ids] for n, a in cache.items()}
 
 
 def swap_in_pages(cache, page_ids, data):
     """Preemption swap-in scatter: restore a previously swapped victim's KV
-    into its freshly allocated pages.  page_ids is padded with the null page
-    0 exactly like `swap_out_pages` — padding rows scatter zeros into page 0,
+    into its freshly allocated pages.  page_ids is a slot's capacity wide,
+    padded with the null page 0 — padding rows scatter zeros into page 0,
     which is written by every inactive slot anyway and never read.  `data`
     is the pool-keyed staging dict (`{"k", "v"}`, plus the scale lanes on a
     quantized pool — int8 pages swap as int8, which is what halves the

@@ -337,3 +337,229 @@ def test_check_bench_v1_rows_still_parse():
     assert validate_row(v1) == []
     v9 = dict(v1, schema_version=9)
     assert any("schema_version" in e for e in validate_row(v9))
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 31: only the evicted pages leave, their copies run beside the steps,
+# and the engine takes the bytes when they have landed
+# ---------------------------------------------------------------------------
+
+
+
+def _narrow_pieces(monkeypatch, params, cfg, pages=2, **kw):
+    """An engine whose pieces are `pages` wide, as a real model's page bytes
+    make them (the tiny model's whole slot would fit one piece)."""
+    from conftest import narrow_d2h_pieces
+    narrow_d2h_pieces(monkeypatch, cfg, pages)
+    eng = _engine(params, cfg, **kw)
+    assert eng._swap_w == pages < eng.cache.max_pages_per_slot
+    return eng
+
+
+def _steps(eng):
+    while eng.has_work:
+        eng.step()
+        eng.cache.check_invariants()
+    return dict(eng._outputs)
+
+
+def _cached_pages(eng):
+    """{node id: {lane: the pool's bytes of its page}} of every cached
+    prefix page an eviction could take."""
+    pool = jax.device_get(eng._pool)
+    return {nid: {lane: a[:, node.page].copy() for lane, a in pool.items()}
+            for nid, node in eng.cache._lru.items()}
+
+
+@pytest.mark.parametrize("when", ["landed", "in_flight"])
+def test_spilled_prefix_restores_byte_for_byte(params, cfg, monkeypatch,
+                                               held_worker, when):
+    """A prefix restored after its copy landed, and one restored while the
+    copy is still in flight (the engine waits at the restore, nowhere
+    else), hold the bytes the pool held and give the tokens of no tiering."""
+    held = held_worker
+    # a bound of 32 pages: the churn's evictions stay under it, so that
+    # nothing but the restore can come for a piece in flight
+    eng = held.watch(_narrow_pieces(monkeypatch, params, cfg,
+                                    max_model_len=128))
+    rng = np.random.RandomState(7)
+    shared = rng.randint(0, cfg.vocab_size, (20,)).astype(np.int32)
+    prompts = [shared] + [rng.randint(0, cfg.vocab_size, (30,))
+                          .astype(np.int32) for _ in range(3)]
+    r1 = eng.add_request(shared, max_new_tokens=5)
+    outs = _steps(eng)
+    before = _cached_pages(eng)
+    for p in prompts[1:]:
+        eng.add_request(p, max_new_tokens=4)
+        outs.update(_steps(eng))
+        before.update(_cached_pages(eng))
+    # every copy is still held: nothing has landed, nothing was waited for
+    assert eng._pending_d2h and held.waited_for == 0
+    assert eng.stats()["swap_d2h_fetches"] == 0
+    assert eng.stats()["swap_d2h_inflight_pages"] > 0
+    if when == "landed":
+        held.gate.set()
+        held.settle(eng)
+        eng.drain()
+        assert not eng._pending_d2h and eng._d2h_inflight == 0
+    tier = eng.cache._tier
+    turn2 = np.concatenate([shared, np.asarray(outs[r1].token_ids, np.int32),
+                            rng.randint(0, cfg.vocab_size, (4,))
+                            .astype(np.int32)])
+    r2 = eng.add_request(turn2, max_new_tokens=5)
+    outs.update(_steps(eng))
+    held.settle(eng)
+    eng.drain()
+    st = eng.stats()
+    assert st["kv_tier"]["restores"] >= 1
+    assert st["kv_tier"]["restored_tokens"] >= 16
+    assert (held.waited_for > 0) == (when == "in_flight")
+    assert st["swap_d2h_landed_free"] == \
+        st["swap_d2h_fetches"] - held.waited_for
+    assert st["swap_d2h_inflight_pages"] == 0
+    # what sits in the host tier is what the pool held, byte for byte
+    parked = [nid for nid in tier._host if nid in before]
+    assert parked
+    for nid in parked:
+        for lane, a in tier._host[nid].items():
+            np.testing.assert_array_equal(a, before[nid][lane])
+    # and the tokens are those of an engine that never tiers
+    base = _engine(params, cfg, kv_tier=False, max_model_len=128)
+    for p in prompts:
+        base.add_request(p, max_new_tokens=5 if p is shared else 4)
+        base.run()
+    rb = base.add_request(turn2, max_new_tokens=5)
+    base_outs = base.run()
+    assert outs[r2].token_ids == base_outs[rb].token_ids
+    assert [outs[r].token_ids for r in sorted(outs)] == \
+        [base_outs[r].token_ids for r in sorted(base_outs)]
+
+
+@pytest.fixture(scope="module")
+def narrow(params, cfg):
+    """One warmed engine with 2-page pieces for the width cases."""
+    mp = pytest.MonkeyPatch()
+    eng = _narrow_pieces(mp, params, cfg, num_pages=17)
+    mp.undo()
+    eng.add_request(np.arange(60, dtype=np.int32) % cfg.vocab_size,
+                    max_new_tokens=2)
+    eng.run()
+    eng.warm_swap()
+    return eng
+
+
+@pytest.mark.parametrize("n", range(1, 9))
+def test_gather_moves_n_pages_rounded_up_to_a_piece(narrow, n):
+    """For every page count a slot can hold: what crosses is at most n + W
+    - 1 pages, what lands is the pool's content, and no program is added
+    to the two `warm_swap()` compiled."""
+    eng = narrow
+    assert eng.cache.max_pages_per_slot == 8
+    eng.reset_counters()
+    execs = eng.stats()["swap_executables"]
+    pages = list(range(1, n + 1))
+    pieces = eng._gather_d2h(pages)
+    assert len(pieces) == -(-n // eng._swap_w)
+    got = [page for piece in pieces for page in eng._take_piece(piece)]
+    st, pb = eng.stats(), eng._kv_page_bytes
+    assert st["swap_d2h_useful_bytes"] == n * pb
+    assert n * pb <= st["swap_d2h_bytes"] <= (n + eng._swap_w - 1) * pb
+    assert st["swap_d2h_fetches"] == len(pieces)
+    assert st["swap_executables"] == execs == 2
+    assert eng._d2h_inflight == 0
+    pool = jax.device_get(eng._pool)
+    assert len(got) == n
+    for page, data in zip(pages, got):
+        for lane, a in data.items():
+            np.testing.assert_array_equal(a, pool[lane][:, page])
+
+
+def test_d2h_fault_with_copies_in_flight_drops_and_leaks_nothing(
+        params, cfg, monkeypatch, held_worker):
+    """Every spill's copy is in flight when its fault fires: the nodes drop
+    from the index, the pieces are let go, outputs are those of no tiering."""
+    held = held_worker
+    eng = held.watch(_narrow_pieces(
+        monkeypatch, params, cfg, fault_plan=FaultPlan(fail_d2h=1000)))
+    rng = np.random.RandomState(7)
+    prompts = [rng.randint(0, cfg.vocab_size, (30,)).astype(np.int32)
+               for _ in range(4)]
+    for p in prompts:
+        eng.add_request(p, max_new_tokens=4)
+    outs = _steps(eng)
+    assert eng._pending_d2h and eng._d2h_inflight > 0
+    eng.drain()
+    held.gate.set()
+    st = eng.stats()
+    assert not eng._pending_d2h and st["swap_d2h_inflight_pages"] == 0
+    assert st["swap_d2h_fetches"] == 0 and held.waited_for == 0
+    assert st["kv_tier"]["spills"] == 0 and st["kv_tier"]["pages_host"] == 0
+    eng.cache.check_invariants()
+    base = _engine(params, cfg, kv_tier=False)
+    for p in prompts:
+        base.add_request(p, max_new_tokens=4)
+    base_outs = base.run()
+    assert [outs[r].token_ids for r in sorted(outs)] == \
+        [base_outs[r].token_ids for r in sorted(base_outs)]
+
+
+def test_backpressure_holds_a_gather_at_two_slots_width(params, cfg,
+                                                        monkeypatch,
+                                                        held_worker):
+    """Gathered pages in flight never pass two slots' width: the gather that
+    would is held until the oldest piece has landed, and counted."""
+    held = held_worker
+    eng = held.watch(_narrow_pieces(monkeypatch, params, cfg))
+    assert eng._d2h_bound == 2 * eng.cache.max_pages_per_slot == 16
+    rng = np.random.RandomState(3)
+    for _ in range(8):
+        eng.add_request(rng.randint(0, cfg.vocab_size, (30,))
+                        .astype(np.int32), max_new_tokens=4)
+    peak = 0
+    while eng.has_work:
+        eng.step()
+        peak = max(peak, eng._d2h_inflight)
+        assert eng._d2h_inflight <= eng._d2h_bound
+    st = eng.stats()
+    assert st["swap_d2h_backpressure_waits"] >= 1
+    assert held.waited_for >= st["swap_d2h_backpressure_waits"]
+    assert st["swap_d2h_blocked_ms"] >= 0.0
+    assert peak > 0
+    eng.drain()
+    assert eng.stats()["swap_d2h_inflight_pages"] == 0
+    # nothing was lost to the bound: every accepted page landed
+    assert eng.stats()["kv_tier"]["spills"] == eng.cache.tier_pages_host > 0
+    eng.cache.check_invariants()
+
+
+@pytest.mark.parametrize("seam", ["run", "drain", "stop_loop",
+                                  "reset_counters", "export_prefix"])
+def test_nothing_stays_in_flight_past(params, cfg, monkeypatch, held_worker,
+                                      tmp_path, seam):
+    """Where the engine rests or hands its pages on, every copy has landed
+    in the tier: no record pending, no gathered page in flight."""
+    held = held_worker
+    eng = held.watch(_narrow_pieces(monkeypatch, params, cfg,
+                                    max_model_len=128,
+                                    spill_dir=str(tmp_path)))
+    rng = np.random.RandomState(5)
+    first = rng.randint(0, cfg.vocab_size, (30,)).astype(np.int32)
+    eng.add_request(first, max_new_tokens=4)
+    for _ in range(3):
+        eng.add_request(rng.randint(0, cfg.vocab_size, (30,))
+                        .astype(np.int32), max_new_tokens=4)
+    _steps(eng)
+    assert eng._pending_d2h and eng._d2h_inflight > 0
+    if seam == "export_prefix":
+        eng.export_prefix(first)
+    else:
+        getattr(eng, seam)()
+    assert not eng._pending_d2h and eng._d2h_inflight == 0
+    assert held.waited_for > 0
+    tier = eng.cache._tier
+    assert not any(tier.is_pending(nid) for nid in list(tier._host))
+    if seam == "stop_loop":
+        assert eng._d2h_worker is None
+    if seam != "reset_counters":
+        assert eng.stats()["kv_tier"]["spills"] > 0
+    eng.cache.check_invariants()

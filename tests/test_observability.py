@@ -500,6 +500,12 @@ NEW_STATS_KEYS = frozenset({
     # added by the paged-walk PR (ISSUE 29): pages the paged kernel walks
     # against the table entries its programs were handed
     "paged_pages_walked", "paged_table_entries",
+}) | frozenset({
+    # added by the spill-fetch PR (ISSUE 31): the copies run beside the
+    # engine thread — what it still waited for, what had landed, what is in
+    # flight
+    "swap_d2h_blocked_ms", "swap_d2h_landed_free",
+    "swap_d2h_backpressure_waits", "swap_d2h_inflight_pages",
 })
 
 

@@ -522,3 +522,106 @@ def test_bench_oversubscribe_completes_with_parity(preempt):
     if preempt == "swap":
         assert pressured["preempt_swaps"] > 0
         assert pressured["swap_executables"] == 2
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 31: a victim's pages leave in pieces whose copies run beside the steps
+# ---------------------------------------------------------------------------
+
+def _swap_engine(cfg, params, monkeypatch, **kw):
+    """preempt="swap" with 2-page pieces, as a real model's page bytes make
+    them (the tiny model's whole slot would fit one piece)."""
+    from conftest import narrow_d2h_pieces
+    narrow_d2h_pieces(monkeypatch, cfg)
+    eng = LLMEngine(params, cfg, num_slots=6, page_size=8, num_pages=9,
+                    max_model_len=64, prefill_chunk=8,
+                    admission="optimistic", preempt="swap", **kw)
+    assert eng._swap_w == 2
+    return eng
+
+
+@pytest.mark.parametrize("when", ["landed", "in_flight"])
+def test_swap_in_of_a_victim_whose_copy_is_in_flight(cfg, params, reference,
+                                                     monkeypatch, held_worker,
+                                                     when):
+    """A victim re-admitted while its pages' copies are still in flight is
+    the one place a swap waits for bytes; re-admitted after they landed it
+    waits for nothing.  Either way the KV comes back bit-exact: the tokens
+    are the undisturbed run's."""
+    prompts, ref_tokens = reference
+    eng = held_worker.watch(_swap_engine(cfg, params, monkeypatch))
+    if when == "landed":
+        from conftest import InlineWorker
+        held_worker.gate.set()
+        eng._d2h_worker = InlineWorker()    # every copy lands at once
+    rids = [eng.add_request(p, max_new_tokens=24) for p in prompts]
+    while eng.has_work:
+        eng.step()
+        eng.cache.check_invariants()
+        assert eng._d2h_inflight <= eng._d2h_bound
+    eng.drain()
+    st = eng.stats()
+    assert st["preempt_swaps"] > 0 and st["swapped"] == 0
+    assert st["pages_in_use"] == 0 and st["swap_d2h_inflight_pages"] == 0
+    assert (held_worker.waited_for > 0) == (when == "in_flight")
+    assert st["swap_d2h_landed_free"] == \
+        st["swap_d2h_fetches"] - held_worker.waited_for
+    # a victim of n pages crossed as ceil(n / 2) pieces, pads and all
+    page = eng._kv_page_bytes
+    assert st["swap_d2h_bytes"] == st["swap_d2h_fetches"] * 2 * page
+    assert st["swap_d2h_bytes"] - st["swap_d2h_useful_bytes"] <= \
+        st["preempt_swaps"] * page + st["kv_tier"]["spills"] * page
+    assert st["swap_executables"] == 2
+    _assert_parity(dict(eng._outputs), rids, ref_tokens)
+
+
+def test_swap_d2h_fault_with_the_copy_in_flight_degrades_to_recompute(
+        cfg, params, reference, monkeypatch, held_worker):
+    """The d2h fault fires while every piece of the victim is still in
+    flight: the record degrades to recompute, its pieces are let go, the
+    host-pool obligation clears and the tokens do not change."""
+    prompts, ref_tokens = reference
+    eng = held_worker.watch(_swap_engine(
+        cfg, params, monkeypatch, kv_tier=False,
+        fault_plan=FaultPlan(fail_d2h=1000)))
+    rids = [eng.add_request(p, max_new_tokens=24) for p in prompts]
+    outs, st = _drain_checked(eng)
+    eng.drain()
+    held_worker.gate.set()
+    assert st["preemptions"] > 0 and st["preempt_swaps"] == 0
+    assert st["preempt_recomputes"] == st["preemptions"]
+    assert st["swap_d2h_fetches"] == 0 and held_worker.waited_for == 0
+    assert eng._d2h_inflight == 0 and not eng._pending_d2h
+    assert eng.cache.swapped_page_count == 0
+    _assert_parity(outs, rids, ref_tokens)
+
+
+def test_abort_of_a_victim_in_flight_lets_its_pieces_go(cfg, params,
+                                                        reference,
+                                                        monkeypatch,
+                                                        held_worker):
+    """swap-then-abort with the copies held: the dropped record gives up
+    its pieces at the next step boundary and nothing stays in flight."""
+    prompts, _ = reference
+    eng = held_worker.watch(_swap_engine(cfg, params, monkeypatch,
+                                         kv_tier=False))
+    for p in prompts:
+        eng.add_request(p, max_new_tokens=24)
+    aborted = None
+    while eng.has_work:
+        eng.step()
+        eng.cache.check_invariants()
+        swapped = [r for r, rec in eng._preempted.items()
+                   if rec["kind"] == "swap" and not rec["fetched"]]
+        if aborted is None and swapped:
+            aborted = swapped[0]
+            rec = eng._preempted[aborted]
+            assert rec["pieces"] and eng._d2h_inflight > 0
+            assert eng.abort(aborted)
+            eng.step()
+            assert "pieces" not in rec and rec not in eng._pending_d2h
+    assert aborted is not None, "no request was ever swapped out"
+    eng.drain()
+    assert eng._outputs[aborted].finish_reason == "abort"
+    assert eng._d2h_inflight == 0 and eng.cache.swapped_page_count == 0
+    assert eng.stats()["pages_in_use"] == 0

@@ -186,20 +186,29 @@ def test_ring_carries_turnaround_and_d2h(tiny, mode):
 
 
 @pytest.mark.parametrize("direction", ["d2h", "h2d"])
-def test_swap_bytes_match_the_shapes(tiny, direction):
+def test_swap_bytes_match_the_shapes(tiny, direction, monkeypatch):
+    from conftest import narrow_d2h_pieces
+    page_bytes = narrow_d2h_pieces(monkeypatch, tiny[0])
     eng = engine(tiny)
     session_stream(eng)
-    st, cfg, mgr = eng.stats(), eng.config, eng.cache
-    page_bytes = 2 * cfg.num_layers * mgr.page_size * cfg.kv_heads * \
-        cfg.head_dim * np.dtype(cfg.dtype).itemsize
-    buffer_bytes = mgr.max_pages_per_slot * page_bytes
+    st, mgr = eng.stats(), eng.cache
+    assert eng._kv_page_bytes == page_bytes and mgr.page_size == 8
     if direction == "d2h":
         moved, useful = st["swap_d2h_bytes"], st["swap_d2h_useful_bytes"]
         calls = st["swap_d2h_fetches"]
-        assert useful >= st["kv_tier"]["spills"] * page_bytes
+        buffer_bytes = eng._swap_w * page_bytes
+        assert eng._swap_w == 2 < mgr.max_pages_per_slot
+        assert useful == st["kv_tier"]["spills"] * page_bytes
+        # moved against USEFUL: every piece but the last of an eviction is
+        # full, so a fetch moves under one piece more than it was for — not
+        # a slot's width whatever the page count, as it did before PR 31
+        assert moved - useful < calls * page_bytes * eng._swap_w
+        assert moved - useful <= st["prefix_evictions"] * page_bytes
+        assert moved < 2 * useful
     else:
         moved, useful = st["swap_h2d_bytes"], st["swap_h2d_useful_bytes"]
         calls = st["kv_tier"]["restores"]
+        buffer_bytes = mgr.max_pages_per_slot * page_bytes
     assert calls > 0
     assert moved == calls * buffer_bytes
     assert moved >= useful > 0 and useful % page_bytes == 0
@@ -207,6 +216,43 @@ def test_swap_bytes_match_the_shapes(tiny, direction):
     snap = eng.metrics.snapshot()["counters"]
     assert snap[f"swap_{direction}_bytes"] == moved
     assert snap[f"swap_{direction}_useful_bytes"] == useful
+
+
+def test_one_d2h_span_a_fetch_on_the_engine_thread(tiny, monkeypatch,
+                                                   held_worker):
+    """`engine.swap.d2h` (with `.ready` and `.copy` inside) is recorded
+    where the ENGINE thread takes a piece's bytes, once a fetch, landed or
+    waited for — the worker thread that copies records nothing — and the
+    ring's `d2h_ms`, `swap_ms` and `swap_d2h_blocked_ms` follow the same
+    rule: what a fetch still costs the step."""
+    import threading
+    from conftest import narrow_d2h_pieces
+    narrow_d2h_pieces(monkeypatch, tiny[0])
+    eng = held_worker.watch(engine(tiny, clock=TickClock()))
+    with prof.Profiler(timer_only=True):
+        session_stream(eng)
+        events = list(prof._events)
+    st = eng.stats()
+    me = threading.get_ident()
+    for name in ("engine.swap.d2h", "engine.swap.d2h.ready",
+                 "engine.swap.d2h.copy"):
+        spans = [e for e in events if e.name == name]
+        assert len(spans) == st["swap_d2h_fetches"] > 0
+        assert all(e.tid == me for e in spans)
+    assert {e.tid for e in events} == {me}
+    # some pieces were waited for (the gate opened at the first such take),
+    # the others had landed: both kinds are fetches, both have their span
+    assert 0 < held_worker.waited_for <= st["swap_d2h_fetches"]
+    assert st["swap_d2h_landed_free"] == \
+        st["swap_d2h_fetches"] - held_worker.waited_for
+    ring = eng.step_trace()
+    # TickClock: a take reads the clock three times, 2 ms a fetch, 1 ms of
+    # it the wait's half
+    assert st["swap_d2h_blocked_ms"] == pytest.approx(
+        1.0 * st["swap_d2h_fetches"])
+    assert st["swap_d2h_blocked_ms"] <= st["swap_ms"]
+    assert sum(r["d2h_ms"] for r in ring) <= 2.0 * st["swap_d2h_fetches"] + 1e-6
+    assert st["swap_d2h_inflight_pages"] == 0 and not eng._pending_d2h
 
 
 # ---------------------------------------------------------------------------

@@ -103,6 +103,10 @@ REQUIRED_STATS_KEYS = frozenset({
     # swap boundaries, and the host turnaround between fused programs
     "swap_d2h_fetches", "swap_d2h_bytes", "swap_d2h_useful_bytes",
     "swap_h2d_bytes", "swap_h2d_useful_bytes", "turnaround_ms",
+    # spill-fetch PR (ISSUE 31): the copies run beside the engine thread —
+    # what it still waited for, what had landed, what is in flight
+    "swap_d2h_blocked_ms", "swap_d2h_landed_free",
+    "swap_d2h_backpressure_waits", "swap_d2h_inflight_pages",
     # hybrid PR (ISSUE 28): the expert layers' routing account and the
     # recurrent state lanes (0 for a dense configuration)
     "moe_pairs_here", "moe_pairs_away", "moe_experts_touched", "moe_load_max",
@@ -157,6 +161,9 @@ REQUIRED_COUNTERS = frozenset({
     # tracing PR: the swap boundaries' byte account + host turnaround
     "swap_d2h_fetches", "swap_d2h_bytes", "swap_d2h_useful_bytes",
     "swap_h2d_bytes", "swap_h2d_useful_bytes", "turnaround_ms",
+    # spill-fetch PR (ISSUE 31): the engine thread's wait for spill bytes
+    "swap_d2h_blocked_ms", "swap_d2h_landed_free",
+    "swap_d2h_backpressure_waits",
     # hybrid PR: expert routing and recurrent state
     "moe_pairs_here", "moe_pairs_away", "moe_experts_touched",
     "ssm_slots_live", "ssm_state_resets", "ssm_state_bytes",
@@ -187,6 +194,8 @@ REQUIRED_GAUGES = frozenset({
     "slo_burn_rate_1m", "slo_burn_rate_5m",
     # KV tiering PR: per-tier-level occupancy
     "kv_tier_pages_host", "kv_tier_pages_disk",
+    # spill-fetch PR (ISSUE 31): gathered pages whose bytes are in flight
+    "swap_d2h_inflight_pages",
 }) | frozenset(
     # windowed-rate pull gauges: one per (family, window)
     f"{fam}_{w}" for fam in RATE_FAMILIES for w in RATE_WINDOW_LABELS)
