@@ -10,13 +10,16 @@ another chip.  This layer has no capacity and drops nothing:
     top-k  of s + b_corr                         (the bias steers the choice only)
     w_i    = s_i / (sum_topk s + 1e-20) * routed_scaling_factor
     out    = sum_{i in top-k, i held here} w_i * expert_i(h)  +  shared(h)
-    expert(h) = relu(h U)^2 D                    (not gated; `up_w` holds U^T)
+    expert(h) = relu(h U)^2 D                    (`up_w` holds U^T), or, where
+                the layer's tree holds gate matrices (`gate_w`, kept as G^T
+                like U; `shared_gate_w`), silu(h G) * (h U) D   (SwiGLU)
 
 `experts_here` / `expert_offset` say which experts this chip holds.  A
 token-expert pair that falls on an absent expert is left out (the chip that
 holds it adds that part; on one chip nothing does, and the reference is given
 the same share).  The shared expert is computed once, here.  The held pairs
-are sorted by expert and go through `kernels.grouped_matmul` twice.
+are sorted by expert and go through `kernels.grouped_matmul` twice (three
+times when gated: gate and up are two products of the same shape).
 """
 from __future__ import annotations
 
@@ -32,6 +35,10 @@ COUNTERS = ("moe_pairs_here", "moe_pairs_away", "moe_experts_touched",
 def relu2(x):
     r = jnp.maximum(x.astype(jnp.float32), 0.0)
     return r * r
+
+
+def swiglu(gate, up):
+    return jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
 
 
 def route(h, lp, cfg):
@@ -68,14 +75,21 @@ def moe_serve(lp, h, cfg, real):
     order = jnp.argsort(gid)
     xs = jnp.take(h, order // k, axis=0)
     up = grouped_matmul(xs, lp["up_w"], sizes, first, transpose_rhs=True)
-    act = relu2(up).astype(h.dtype)
+    if "gate_w" in lp:
+        gate = grouped_matmul(xs, lp["gate_w"], sizes, first,
+                              transpose_rhs=True)
+        act = swiglu(gate, up).astype(h.dtype)
+    else:
+        act = relu2(up).astype(h.dtype)
     down = grouped_matmul(act, lp["down_w"], sizes, first)
     held = here.reshape(-1)[order]
     part = jnp.where(held[:, None], down.astype(jnp.float32) *
                      w.reshape(-1)[order][:, None], 0.0)
     routed = jnp.zeros((N, D), jnp.float32).at[order // k].add(part)
-    shared = jnp.matmul(relu2(jnp.matmul(h, lp["shared_up_w"])).astype(h.dtype),
-                        lp["shared_down_w"])
+    shared_up = jnp.matmul(h, lp["shared_up_w"])
+    shared_act = swiglu(jnp.matmul(h, lp["shared_gate_w"]), shared_up) \
+        if "shared_gate_w" in lp else relu2(shared_up)
+    shared = jnp.matmul(shared_act.astype(h.dtype), lp["shared_down_w"])
     load = sizes[first:first + E]
     counters = {
         "moe_pairs_here": jnp.sum(here, dtype=jnp.int32),

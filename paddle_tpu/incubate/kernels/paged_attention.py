@@ -494,3 +494,204 @@ def _shapes_ok_for_pallas(q, k_pages, quantized=False):
         # on real hardware; anything else takes the XLA dequant-gather path
         ok = ok and hd in (128, 256) and page % 32 == 0
     return ok
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) pages: absorbed attention over ONE lane
+# ---------------------------------------------------------------------------
+# A latent-attention layer (DeepSeek-V2, arXiv:2405.04434 section 2.1) caches
+# per token one row [c_kv | k_r | 0..]: the normed latent (`latent` numbers),
+# the one rotated key all heads share, and zeros up to a whole number of
+# 128-lane tiles (W; the chip pads an HBM array's minor dimension to that
+# anyway, and Mosaic refuses to address a row it would have to find inside
+# the padding, so the lane states its real width).  In the absorbed
+# form every query head carries q_lat = q_nope W^K (latent wide) beside its
+# rotated part, scores against the cached row itself, and weighs the row's
+# first `latent` columns as its values: the kv up-projection is never applied
+# to the cache.  Same (`q_offset`, `valid`) contract as above, so decode,
+# chunk and verify lanes stay one program.
+#
+# A sibling of `_paged_prefill_kernel`, not a mode of it.  What that kernel
+# is built around does not exist here (two pools of one width copied in
+# lock-step, kv heads stacked kh-major into the score tile and their
+# neighbours' columns masked out, the per-head gather `hg` chooses against,
+# the int8 scale lanes), and what this one needs does not exist there (the
+# score is two products of different widths against column slices of ONE
+# buffer, the values are a slice of the keys, the output is narrower than the
+# query): as modes of one body each would sit behind a branch at every line
+# of the block, in the kernel three accepted cells run.  The walk - live
+# pages only, double-buffered blocks of page copies, one online-softmax
+# update a block - is the same and reads the same.
+
+# query rows (tokens x heads) a grid step keeps in VMEM, and keys a block
+# holds at most: a decode step's 32 rows leave room for long blocks, which is
+# what amortises a block's fixed cost against 1,280 B a key (PERF.md has the
+# chip's readings)
+_LATENT_MAX_Q_ROWS = 512
+_LATENT_MAX_BLOCK_KEYS = 512
+
+
+def _latent_pages_per_block(page: int, W: int, latent: int, itemsize: int,
+                            rows: int, n_pages: int) -> int:
+    """Pages a block of the latent kernel's walk holds: the double buffer,
+    the block as a value and the float32 score tile with its exp and mask
+    scale with it; the query/output tiles and the carry do not."""
+    fixed = rows * (2 * W * itemsize + 2 * latent * itemsize +
+                    latent * 4 + 2 * 128 * 4)
+    per_page = 3 * page * W * itemsize + 3 * rows * page * 4
+    fit = (_VMEM_BUDGET - fixed) // per_page
+    return int(max(1, min(fit, _LATENT_MAX_BLOCK_KEYS // page, n_pages)))
+
+
+def paged_latent_attention_xla(q, c_pages, page_table, q_offset, valid,
+                               latent: int, scale: float):
+    """Gather-based absorbed attention (fallback + oracle).
+
+    q [B, T, H, W]: per head [q_lat | q_rope | 0..]; c_pages [P, page, W]:
+    per token [c_kv | k_r | 0..]; page_table / q_offset / valid as in
+    `paged_prefill_attention_xla`.  Returns [B, T, H, latent]: the
+    probability-weighted latents, which the caller takes through W^V."""
+    B, T, H, W = q.shape
+    S = page_table.shape[1] * c_pages.shape[1]
+    k = c_pages[page_table].reshape(B, S, W)
+    logits = jnp.einsum("bthw,bsw->bhts", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    qpos = q_offset[:, None] + jnp.arange(T)
+    mask = jnp.arange(S)[None, None] <= qpos[:, :, None]        # [B, T, S]
+    p = jax.nn.softmax(jnp.where(mask[:, None], logits, NEG_INF), axis=-1)
+    return jnp.einsum("bhts,bsc->bthc", p.astype(k.dtype), k[..., :latent])
+
+
+def _paged_latent_kernel(tbl_ref, qoff_ref, val_ref, q_ref, c_hbm, o_ref,
+                         buf, sem, acc_ref, m_ref, l_ref, *, page: int,
+                         H: int, bt: int, ppb: int, latent: int,
+                         scale: float):
+    """Grid (B, T/bt): a slot and a query tile of bt*H rows (token-major) a
+    step; the walk is `_paged_prefill_kernel`'s (live pages only, block
+    i + 1's copies in flight while block i is weighed, rows past the last
+    real query's position zeroed and masked)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    ti = pl.program_id(1)
+    R = bt * H
+    keys = ppb * page
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    qoff = qoff_ref[b]
+    t0 = ti * bt
+    n_real = jnp.minimum(val_ref[b] - t0, bt)
+    last_q = qoff + t0 + n_real - 1
+    n_live = jnp.where(n_real > 0, last_q // page + 1, 0)
+    n_blocks = (n_live + ppb - 1) // ppb
+
+    def each_live_page(i, half, act):
+        def one(p, carry):
+            pid = tbl_ref[b, i * ppb + p]
+            act(pltpu.make_async_copy(c_hbm.at[pid], buf.at[half, p],
+                                      sem.at[half]))
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(n_live - i * ppb, ppb), one, 0)
+
+    def start(i, half):
+        each_live_page(i, half, lambda cp: cp.start())
+
+    def wait(i, half):
+        each_live_page(i, half, lambda cp: cp.wait())
+
+    start(0, 0)
+
+    def block(i, carry):
+        half = i % 2
+        start(i + 1, 1 - half)
+        wait(i, half)
+        q = q_ref[0].reshape(R, -1)                     # [R, W]
+        k = buf[half].reshape(keys, -1)                 # [keys, W]
+        k_start = i * keys
+        # a page not copied holds anything, NaN included: 0 * NaN is NaN
+        row = k_start + jax.lax.broadcasted_iota(jnp.int32, (keys, 1), 0)
+        k = jnp.where(row <= last_q, k, jnp.zeros_like(k))
+        c = k[:, :latent]
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        kv_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (R, keys), 1)
+        t_row = t0 + jax.lax.broadcasted_iota(jnp.int32, (R, keys), 0) // H
+        s = jnp.where(kv_pos <= jnp.minimum(qoff + t_row, last_q), s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p.astype(c.dtype), c, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = out.reshape(bt, H, latent).astype(o_ref.dtype)
+
+
+def paged_latent_attention_pallas(q, c_pages, page_table, q_offset, valid,
+                                  latent: int, scale: float,
+                                  interpret=False):
+    """The kernel behind `paged_latent_attention` (same contract as the
+    oracle)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, H, W = q.shape
+    page = c_pages.shape[1]
+    n_pages = page_table.shape[1]
+    bt = min(T, max(1, _LATENT_MAX_Q_ROWS // H))
+    n_t = pl.cdiv(T, bt)
+    if n_t * bt != T:
+        q = jnp.pad(q, ((0, 0), (0, n_t * bt - T), (0, 0), (0, 0)))
+    ppb = _latent_pages_per_block(page, W, latent, c_pages.dtype.itemsize,
+                                  bt * H, n_pages)
+    kernel = functools.partial(_paged_latent_kernel, page=page, H=H, bt=bt,
+                               ppb=ppb, latent=latent, scale=scale)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,          # (page_table, q_offset, valid)
+        grid=(B, n_t),
+        in_specs=[pl.BlockSpec((1, bt, H, W),
+                               lambda b, t, tbl, qo, vl: (b, t, 0, 0)),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((1, bt, H, latent),
+                               lambda b, t, tbl, qo, vl: (b, t, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, page, W), c_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((bt * H, latent), jnp.float32),
+            pltpu.VMEM((bt * H, 1), jnp.float32),
+            pltpu.VMEM((bt * H, 1), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        name="paged_latent",
+        grid_spec=grid_spec,
+        out_shape=_out_struct((B, n_t * bt, H, latent), q.dtype, q, c_pages),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(jnp.asarray(page_table, jnp.int32), jnp.asarray(q_offset, jnp.int32),
+      jnp.asarray(valid, jnp.int32), q, c_pages)
+    return out[:, :T] if n_t * bt != T else out
+
+
+def paged_latent_attention(q, c_pages, page_table, q_offset, valid,
+                           latent: int, scale: float):
+    """The latent layers' attention in both serving passes
+    (`models.hybrid`): the kernel on a TPU where the layout suits it (whole
+    128-lane latents, whole-sublane pages), the oracle otherwise."""
+    if _on_tpu() and latent % 128 == 0 and q.shape[-1] % 128 == 0 \
+            and c_pages.shape[1] % 16 == 0 and q.shape[2] % 8 == 0:
+        return paged_latent_attention_pallas(q, c_pages, page_table, q_offset,
+                                             valid, latent, scale)
+    return paged_latent_attention_xla(q, c_pages, page_table, q_offset, valid,
+                                      latent, scale)

@@ -607,15 +607,20 @@ class LLMEngine:
         # OFF — the fp engine is byte-identical to a quantization-free build.
         self.weight_dtype = normalize_quant_dtype(weight_dtype, "weight_dtype")
         self.kv_dtype = normalize_quant_dtype(kv_dtype, "kv_dtype")
-        # a patterned configuration (`models.hybrid`) keeps recurrent state
-        # per slot beside the page pool.  Every path that moves, shares or
-        # rolls back a request's pages would have to move, snapshot or roll
-        # back that state too; the ones that cannot yet are refused here,
-        # loudly, rather than served from a wrong state
-        self.recurrent = getattr(config, "layer_pattern", None) is not None
-        if self.recurrent:
-            self._refuse_for_recurrent(
-                spec_len=spec_len, admission=admission,
+        # a patterned configuration (`models.hybrid`) has programs of its
+        # own, and with an `M` in its pattern it keeps recurrent state per
+        # slot beside the page pool.  Every path that moves, shares or rolls
+        # back a request's pages would have to move, snapshot or roll back
+        # that state too; the ones that cannot yet are refused here, loudly,
+        # rather than served from a wrong state.  A pattern WITHOUT `M` keeps
+        # everything in pages (K/V or latent rows): it is a paged model, with
+        # the prefix index, parked pages and the spill tier
+        pattern = getattr(config, "layer_pattern", None)
+        self.patterned = pattern is not None
+        self.recurrent = self.patterned and "M" in pattern
+        if self.patterned:
+            self._refuse_for_pattern(
+                self.recurrent, spec_len=spec_len, admission=admission,
                 preempt=preempt, weight_dtype=self.weight_dtype,
                 kv_dtype=self.kv_dtype, mp=mp, mesh=mesh, role=role)
         self._kv_page_bytes = kv_page_bytes(config, page_size, self.kv_dtype)
@@ -802,7 +807,7 @@ class LLMEngine:
         # optimistic-admission watermark: global free-page headroom kept back
         # at admission (vLLM's watermark_blocks), ~1% of the pool
         self._watermark = max(1, (self.cache.num_pages - 1) // 100)
-        if self.recurrent:
+        if self.patterned:
             self._pool = hybrid_mod.init_paged_cache(config, num_pages,
                                                      page_size, num_slots)
         else:
@@ -944,7 +949,19 @@ class LLMEngine:
             "ssm_state_resets": m.counter(
                 "ssm_state_resets",
                 "slots a program started from a zero state (a new request)"),
+            "latent_tokens_written": m.counter(
+                "latent_tokens_written",
+                "latent rows that lay written behind the programs' active "
+                "slots (sum of q_offset + valid: what a latent layer's "
+                "attention read, not what was reserved)"),
+            "mla_absorbed_rows": m.counter(
+                "mla_absorbed_rows",
+                "query tokens that went through absorbed latent attention "
+                "(decode, chunk and prefill rows alike)"),
         }
+        m.gauge("latent_page_bytes", self._latent_page_bytes,
+                "bytes of one page of the latent lane over its layers (0 "
+                "for a configuration without latent attention)")
         self._moe_load_max = 0
         m.gauge("moe_load_max", lambda: self._moe_load_max,
                 "busiest held expert's tokens in one layer of the last "
@@ -1187,12 +1204,19 @@ class LLMEngine:
                 mesh=mesh_)
             return out, accept, pin_pool(pool), key
 
-        if self.recurrent:
-            # the recurrent configuration's two programs, under the names the
+        if self.patterned:
+            # the patterned configuration's programs, under the names the
             # dense ones have (a trace finds `jit_fused_impl` /
             # `jit_prefill_impl` whichever family is served): the same
             # contracts, one more small result (`aux`, the program's
             # counters) and, for the prefill, the slot its state is kept in
+            def chunk_impl(params, ids, pool, table, q_offset, valid, key,
+                           greedy):
+                logits, pool, aux = hybrid_mod.prefill_chunk_paged(
+                    params, ids, cfg, pool, table, q_offset, valid)
+                tok, key = pick(logits, key, greedy)
+                return tok, pool, key, aux
+
             def prefill_impl(params, ids, pool, pages, length, key, greedy,
                              slots):
                 logits, pool, aux = hybrid_mod.prefill_paged(
@@ -1711,7 +1735,8 @@ class LLMEngine:
         self._step_d2h_s = 0.0
         self._step_pages_walked = 0
         self._step_aux = dict.fromkeys(("moe_pairs_here", "moe_pairs_away",
-                                        "moe_experts_touched"), 0)
+                                        "moe_experts_touched",
+                                        "latent_tokens_written"), 0)
         with self._step_marker(), self._span("engine.step"):
             if self._inflight is None:
                 self._turn_begin(t0)
@@ -1998,20 +2023,18 @@ class LLMEngine:
         return self._maybe_finish(seq, finished)
 
     @staticmethod
-    def _refuse_for_recurrent(*, spec_len, admission, preempt,
-                              weight_dtype, kv_dtype, mp, mesh, role) -> None:
-        """What a configuration with recurrent state cannot be served with
-        yet, each with the reason (ROADMAP queue B has what would lift it)."""
-        why = "a configuration with recurrent state (layer_pattern) "
+    def _refuse_for_pattern(recurrent: bool, *, spec_len, admission, preempt,
+                            weight_dtype, kv_dtype, mp, mesh, role) -> None:
+        """What a patterned configuration cannot be served with yet, each
+        with the reason (ROADMAP queue B has what would lift it); the last
+        two only where its pattern holds recurrent state (`M`)."""
+        why = "a patterned configuration (layer_pattern) "
         if spec_len:
             raise ValueError(
-                why + "cannot be served with speculative decoding: a "
-                "rejected draft has already moved the state, and there is "
-                "no roll-back of it (spec_len must be 0)")
-        if admission == "optimistic" and preempt == "swap":
-            raise ValueError(
-                why + "cannot be preempted by swap: the swap programs move "
-                "pages, not the slot's state (use preempt='recompute')")
+                why + "cannot be served with speculative decoding: its fused "
+                "step has no accept scan, and a rejected draft would already "
+                "have moved a recurrent state with no roll-back of it "
+                "(spec_len must be 0)")
         if weight_dtype is not None or kv_dtype is not None:
             raise ValueError(
                 why + "has no quantized serving path (weight_dtype and "
@@ -2019,7 +2042,15 @@ class LLMEngine:
         if (mp is not None and mp > 1) or mesh is not None:
             raise ValueError(
                 why + "is served on one chip (no mp / mesh): its state "
-                "lanes and expert layer have no sharded form yet")
+                "lanes, latent lane and expert layer have no sharded form "
+                "yet")
+        if not recurrent:
+            return
+        why = "a configuration with recurrent state (an M in layer_pattern) "
+        if admission == "optimistic" and preempt == "swap":
+            raise ValueError(
+                why + "cannot be preempted by swap: the swap programs move "
+                "pages, not the slot's state (use preempt='recompute')")
         if role is not None:
             raise ValueError(
                 why + "cannot hand prompts off through the KV tier store "
@@ -2049,8 +2080,14 @@ class LLMEngine:
             self._aux_counters[name].inc(v)
             if name in self._step_aux:
                 self._step_aux[name] += v
-        self._ssm_state_bytes.inc(
-            2 * vals["ssm_slots_live"] * self.cache.state.bytes_per_slot)
+        if self.cache.state is not None:
+            self._ssm_state_bytes.inc(
+                2 * vals["ssm_slots_live"] * self.cache.state.bytes_per_slot)
+
+    def _latent_page_bytes(self) -> int:
+        lane = self._pool.get("c")
+        return 0 if lane is None else \
+            lane.dtype.itemsize * lane.size // lane.shape[1]
 
     def _stamp_emit(self, rid: int, n: int, t: Optional[float] = None) -> None:
         """One `RequestMetrics.emit_times` pair: `n` tokens were appended to
@@ -2742,7 +2779,7 @@ class LLMEngine:
                         self._h2d(pages), self._h2d([lp], np.int32),
                         self._key, self._h2d([self._req_greedy(req)]),
                         *([self._h2d([slot], np.int32)]
-                          if self.recurrent else []))
+                          if self.patterned else []))
                 self._seen_buckets.add(bucket)
                 self._prefilled_tokens.inc(lp)
                 if self.prefix_cache:
@@ -2783,7 +2820,7 @@ class LLMEngine:
         ids[0, :n] = st.prompt[st.filled:st.filled + n]
         self._note_walk(mgr.page_table[slot][None, :], [st.filled], [n])
         with self._span("engine.prefill.dispatch"):
-            tok, self._pool, self._key = self._chunk_fn(
+            tok, self._pool, self._key, *aux = self._chunk_fn(
                 self.params, self._h2d(ids), self._pool,
                 self._h2d(mgr.page_table[slot][None, :]),
                 self._h2d([st.filled], np.int32),
@@ -2801,8 +2838,11 @@ class LLMEngine:
             del self._prefilling[slot]
             t_sync = self._now()
             with self._span("engine.sample.sync"):
-                tok = int(jax.device_get(tok)[0])       # blocks on the result
+                tok, aux = jax.device_get((tok, aux))   # blocks on the result
+                tok = int(tok[0])
             self._step_sync_s += self._now() - t_sync
+            if aux:
+                self._note_aux(aux[0])
             self._start_decoding(st.request, slot, tok, st.cached_tokens,
                                  finished, prompt_len=lp, prior=st.prior,
                                  ttft=st.ttft, spec_off=st.spec_off,
@@ -3437,6 +3477,7 @@ class LLMEngine:
             # and the state lanes (all 0 for a dense configuration)
             **{n: c.value for n, c in self._aux_counters.items()},
             "moe_load_max": self._moe_load_max,
+            "latent_page_bytes": self._latent_page_bytes(),
             "ssm_state_bytes": self._ssm_state_bytes.value,
             "ssm_state_pool_bytes": 0 if self.cache.state is None else
                                     self.cache.state.pool_bytes,

@@ -791,6 +791,52 @@ def _rope_tables_at(config, pos):
     return jnp.sin(freqs), jnp.cos(freqs)
 
 
+def yarn_inv_freq(dim: int, theta: float, scaling: Optional[dict]):
+    """Rotary inverse frequencies [dim / 2] float32: plain (`scaling` None)
+    or YaRN's (Peng et al., arXiv:2309.00071, as the DeepSeek-V2/V3 code
+    computes them): a frequency whose wavelength fits the original context
+    `beta_fast` times or more is kept, one that fits it `beta_slow` times or
+    fewer is divided by `factor`, and a linear ramp over the index blends the
+    two in between."""
+    idx = jnp.arange(0, dim, 2, dtype=jnp.float32)
+    extra = 1.0 / (theta ** (idx / dim))
+    if scaling is None:
+        return extra
+
+    def correction_dim(rotations):
+        return dim * math.log(scaling["original_max_position_embeddings"] /
+                              (rotations * 2 * math.pi)) / \
+            (2 * math.log(theta))
+    low = max(math.floor(correction_dim(scaling["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(scaling["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) /
+                    (high - low), 0.0, 1.0)
+    return extra / scaling["factor"] * ramp + extra * (1.0 - ramp)
+
+
+def yarn_softmax_scale(score_dim: int, scaling: Optional[dict]) -> float:
+    """score_dim^-1/2, times mscale^2 where YaRN's `mscale_all_dim` is set:
+    mscale = 0.1 * mscale_all_dim * ln(factor) + 1."""
+    scale = 1.0 / math.sqrt(score_dim)
+    if scaling and scaling.get("mscale_all_dim") and scaling["factor"] > 1:
+        m = 0.1 * scaling["mscale_all_dim"] * math.log(scaling["factor"]) + 1
+        scale *= m * m
+    return scale
+
+
+def yarn_rope_tables_at(dim: int, theta: float, scaling: Optional[dict], pos):
+    """`_rope_tables_at` for a rotary part of `dim` columns with
+    `yarn_inv_freq`'s frequencies: pos [B, T] -> sin, cos [B, T, dim / 2].
+    (The tables carry no magnitude correction: with `mscale` equal to
+    `mscale_all_dim`, as published, it is 1, and `yarn_softmax_scale`
+    carries mscale^2.)"""
+    freqs = pos.astype(jnp.float32)[..., None] * \
+        yarn_inv_freq(dim, theta, scaling)
+    return jnp.sin(freqs), jnp.cos(freqs)
+
+
 def _prefill_qkv(bp, x, c: GPTConfig, pos=None, parts: int = 1):
     """Pre-norm + packed qkv + rope over a [B, T, D] prompt (positions
     0..T-1, or explicit per-batch positions `pos` [B, T] for chunked

@@ -1,8 +1,14 @@
-"""Hybrid (Nemotron-H style) language models for serving: every layer is ONE
-mixer chosen by a pattern letter — `M` a Mamba-2 state-space layer, `E` a
-dropless mixture of experts with a shared expert, `*` attention with no
-position term — in a pre-norm residual stack (x <- x + mixer(RMSNorm(x))),
-final RMSNorm, untied head.
+"""Patterned language models for serving: every letter of a pattern is ONE
+mixer — `M` a Mamba-2 state-space layer, `E` a dropless mixture of experts
+with a shared expert (relu^2, or gated where its tree holds gate matrices),
+`*` attention with no position term, `L` latent attention (MLA: low-rank
+queries, one cached latent row a token, YaRN rotary on part of the score),
+`F` a dense gated FFN — in a pre-norm residual stack, final RMSNorm, untied
+head.  The residual is plain (x <- x + mixer(RMSNorm(x)); Nemotron-H:
+"MEMEM*E..") or, with `hc_mult` > 1, a state of several streams that every
+mixer reads a learned mix of and writes back through a doubly stochastic
+matrix (manifold-constrained hyper-connections, arXiv:2512.24880; a
+DeepSeek-V3-shaped layer is the two letters "LF" or "LE").
 
 `inference.engine.LLMEngine` reaches this module the way it reaches
 `models.gpt`: `init_paged_cache`, `prefill_paged` (the bucketed prompt pass)
@@ -12,13 +18,20 @@ by `config.layer_pattern`.  What differs:
 - The layers are of three kinds, so they are not one stacked tree under a
   `lax.scan`: `params["layers"]` is a list of per-layer trees and the pattern
   is walked statically.  No weight is ever sliced out of a stack.
-- Two kinds of serving state live in ONE donated tree (`init_paged_cache`):
-  the paged K/V pool of the attention layers ("k", "v": [L_attn, P, page, KVH,
-  hd], read through page tables exactly as in `models.gpt`), and per Mamba
-  layer i a recurrent state indexed by SLOT, not by page: "conv.i" [slots, 3,
-  conv_dim] (the last three inputs of the causal convolution) and "ssm.i"
-  [slots, H, P, N] float32.  Each lane is updated where it lies
-  (`.at[].set` on the donated buffer); nothing pool-sized is copied.
+- The serving state is ONE donated tree (`init_paged_cache`) of lanes of two
+  kinds.  Paged lanes (`HybridConfig.paged_lanes`), read through page tables
+  exactly as in `models.gpt`: "k", "v" [L_attn, P, page, KVH, hd] of the `*`
+  layers, and "c" [L_latent, P, page, W] of the `L` layers — per token the
+  normed latent, the one rotated key all heads share, and zeros up to whole
+  128-lane tiles (`latent_lane`); no values are kept, they are columns of the
+  same row.  A page's bytes, the swap/spill/restore programs and the COW copy
+  take their widths from these lanes.  And per Mamba layer i a recurrent
+  state indexed by SLOT, not by page: "conv.i" [slots, 3, conv_dim] (the last
+  three inputs of the causal convolution) and "ssm.i" [slots, H, P, N]
+  float32.  Each lane is updated where it lies (`.at[].set` on the donated
+  buffer); nothing pool-sized is copied.  A pattern without `M` has no state
+  a page does not hold: the engine serves it as a paged model (prefix index,
+  parked pages, spill tier).
 - A slot's state is zeroed by the data, not by a dispatch: a row whose
   `q_offset` is 0 has no token behind it, so the step starts it from zeros
   (the bucketed prefill always does).  Rows that are padding (t >= valid) or
@@ -41,11 +54,15 @@ import jax.numpy as jnp
 from ..incubate.distributed.models.moe.serve import COUNTERS as MOE_COUNTERS
 from ..incubate.distributed.models.moe.serve import moe_serve
 from ..incubate.kernels.flash_attention import flash_attention_fused
+from ..incubate.kernels.paged_attention import paged_latent_attention
+from ..incubate.kernels.rope import apply_rope
 from ..incubate.kernels.ssm import ssm_chunk_scan, ssm_update
 from . import gpt as gpt_mod
 
-KINDS = {"M": "mamba", "E": "experts", "*": "attention"}
-AUX_FIELDS = MOE_COUNTERS + ("ssm_slots_live", "ssm_state_resets")
+KINDS = {"M": "mamba", "E": "experts", "*": "attention", "L": "mla",
+         "F": "ffn"}
+AUX_FIELDS = MOE_COUNTERS + ("ssm_slots_live", "ssm_state_resets",
+                             "latent_tokens_written", "mla_absorbed_rows")
 
 
 @dataclasses.dataclass
@@ -79,6 +96,26 @@ class HybridConfig(gpt_mod.GPTConfig):
     moe_shared_intermediate_size: int = 128
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    moe_gated: bool = False         # SwiGLU experts and shared expert
+    # latent attention (`L`): num_heads heads of qk_nope + qk_rope score
+    # columns and v_head_dim value columns, from a q_lora_rank-wide query
+    # latent and a kv_lora_rank-wide cached one; rotary on the rope columns
+    # only, YaRN-scaled where `rope_scaling` is given (the published group:
+    # factor, original_max_position_embeddings, beta_fast, beta_slow,
+    # mscale_all_dim)
+    q_lora_rank: int = 48
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[dict] = None
+    # residual streams (1: the plain residual) and the Sinkhorn iterations,
+    # norm epsilon and clip of the write-back matrix
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple = (-30.0, 30.0)
 
     def __post_init__(self):
         super().__post_init__()
@@ -112,6 +149,41 @@ class HybridConfig(gpt_mod.GPTConfig):
     def kv_layers(self) -> int:
         return self.count("*")
 
+    @property
+    def latent_row(self) -> int:
+        """Numbers a latent layer writes per token: latent and rotated key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_lane(self) -> int:
+        """Width of the latent lane: `latent_row` in whole 128-lane tiles
+        (what the chip keeps for a row of it in any case)."""
+        return -(-self.latent_row // 128) * 128
+
+    def paged_lanes(self) -> Dict[str, tuple]:
+        """{lane: (layers, shape of one token's row)} of the lanes that are
+        indexed by page.  A pattern with latent layers only has no K/V lanes;
+        one with no attention at all keeps them, empty, for the pool's
+        geometry."""
+        lanes = {}
+        if self.count("*") or not self.count("L"):
+            lanes["k"] = lanes["v"] = (self.count("*"),
+                                       (self.kv_heads, self.head_dim))
+        if self.count("L"):
+            lanes["c"] = (self.count("L"), (self.latent_lane,))
+        return lanes
+
+    def page_bytes(self, page_size: int) -> int:
+        """Bytes one page holds over every paged lane and layer."""
+        item = jnp.dtype(self.dtype).itemsize
+        return sum(n * page_size * math.prod(row) * item
+                   for n, row in self.paged_lanes().values())
+
+    @property
+    def mla_softmax_scale(self) -> float:
+        return gpt_mod.yarn_softmax_scale(
+            self.qk_nope_head_dim + self.qk_rope_head_dim, self.rope_scaling)
+
     def state_bytes_per_slot(self) -> int:
         """Bytes of recurrent state one slot holds over all Mamba layers."""
         ssm = self.mamba_num_heads * self.mamba_head_dim * \
@@ -119,6 +191,20 @@ class HybridConfig(gpt_mod.GPTConfig):
         conv = (self.conv_kernel - 1) * self.conv_dim * \
             jnp.dtype(self.dtype).itemsize
         return self.count("M") * (ssm + conv)
+
+
+def latent_tiny(seq_len=128, pattern="LFLELE", **kw):
+    """A DeepSeek-V3-shaped toy: latent attention, one leading dense FFN,
+    gated experts, four residual streams, YaRN over a short original
+    context."""
+    kw.setdefault("rope_scaling", dict(
+        factor=4.0, original_max_position_embeddings=32, beta_fast=32.0,
+        beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0))
+    kw.setdefault("hc_mult", 4)
+    return HybridConfig(vocab_size=256, hidden_size=64, num_layers=len(pattern),
+                        num_heads=4, max_seq_len=seq_len,
+                        layer_pattern=pattern, intermediate_size=96,
+                        moe_gated=True, rms_norm_eps=1e-6, **kw)
 
 
 def hybrid_tiny(seq_len=128, pattern="MEM*E", **kw):
@@ -138,7 +224,7 @@ def init_params(config: HybridConfig, key) -> Dict[str, Any]:
     D, L = c.hidden_size, c.num_layers
     std = c.initializer_range
     proj = std / math.sqrt(2 * L)
-    keys = iter(jax.random.split(key, 8 * L + 4))
+    keys = iter(jax.random.split(key, (16 if c.hc_mult > 1 else 8) * L + 4))
 
     def normal(shape, s, dtype=None):
         return (jax.random.normal(next(keys), shape, jnp.float32) * s
@@ -149,9 +235,22 @@ def init_params(config: HybridConfig, key) -> Dict[str, Any]:
                                   ).astype(c.dtype)
 
     bound = 1.0 / math.sqrt(c.conv_kernel)      # a depthwise Conv1d's default
+    n = c.hc_mult
     layers = []
     for letter in c.layer_pattern:
         lp = {"norm_w": jnp.ones((D,), c.dtype)}
+        if n > 1:
+            # the streams' mixes: phi so that x~ phi is of order one, a
+            # write-back bias that favours a stream's own row without
+            # silencing the others (neither the identity nor uniform)
+            lp.update(
+                hc_phi=normal((n * D, 2 * n + n * n), 1.0 / math.sqrt(n * D),
+                              jnp.float32),
+                hc_alpha=jnp.asarray([1.0, 1.0, 0.5], jnp.float32),
+                hc_b=jnp.concatenate([
+                    normal((2 * n,), 1.0, jnp.float32),
+                    (jnp.eye(n) + normal((n, n), 0.3, jnp.float32)
+                     ).reshape(-1)]))
         if letter == "M":
             H = c.mamba_num_heads
             dt = jnp.exp(jax.random.uniform(next(keys), (H,)) * (
@@ -178,6 +277,25 @@ def init_params(config: HybridConfig, key) -> Dict[str, Any]:
                 down_w=normal((c.experts_here, F, D), proj),
                 shared_up_w=normal((D, Fs), std),
                 shared_down_w=normal((Fs, D), proj))
+            if c.moe_gated:
+                lp.update(gate_w=normal((c.experts_here, F, D), std),
+                          shared_gate_w=normal((D, Fs), std))
+        elif letter == "F":
+            F = c.ffn_size
+            lp.update(gate_w=normal((D, F), std), up_w=normal((D, F), std),
+                      down_w=normal((F, D), proj))
+        elif letter == "L":
+            H, C, R = c.num_heads, c.kv_lora_rank, c.qk_rope_head_dim
+            N, V = c.qk_nope_head_dim, c.v_head_dim
+            lp.update(
+                q_a_w=normal((D, c.q_lora_rank), std),
+                q_norm_w=jnp.ones((c.q_lora_rank,), c.dtype),
+                q_b_w=normal((c.q_lora_rank, H * (N + R)), std),
+                kv_a_w=normal((D, C + R), std),
+                kv_norm_w=jnp.ones((C,), c.dtype),
+                kv_b_k_w=normal((H, N, C), std),        # W^K per head, [N, C]
+                kv_b_v_w=normal((H, C, V), std),
+                o_w=normal((H * V, D), proj))
         else:
             lp.update(qkv_w=normal((D, c.qkv_dim), std),
                       proj_w=normal((c.num_heads * c.head_dim, D), proj))
@@ -189,11 +307,12 @@ def init_params(config: HybridConfig, key) -> Dict[str, Any]:
 
 def init_paged_cache(config: HybridConfig, num_pages: int, page_size: int,
                      num_slots: int):
-    """The one tree of serving state: the attention layers' paged pool and
-    each Mamba layer's slot-indexed lanes (see the module docstring)."""
+    """The one tree of serving state: the paged lanes of the attention and
+    latent layers and each Mamba layer's slot-indexed lanes (see the module
+    docstring)."""
     c = config
-    kv = (c.count("*"), num_pages, page_size, c.kv_heads, c.head_dim)
-    cache = {"k": jnp.zeros(kv, c.dtype), "v": jnp.zeros(kv, c.dtype)}
+    cache = {name: jnp.zeros((layers, num_pages, page_size) + row, c.dtype)
+             for name, (layers, row) in c.paged_lanes().items()}
     for i in range(c.count("M")):
         cache[f"conv.{i}"] = jnp.zeros(
             (num_slots, c.conv_kernel - 1, c.conv_dim), c.dtype)
@@ -266,30 +385,129 @@ def _qkv(lp, h, c: HybridConfig):
             v.reshape(B, T, KVH, hd))
 
 
-def _sum_aux(parts, live, resets):
-    """The `AUX_FIELDS` vector from the expert layers' counters."""
+def _rms(x, w, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def latent_qkv(lp, h, pos, c: HybridConfig):
+    """The latent layer's projections of normed h [B, T, D] at positions
+    pos [B, T]: (q_nope [B, T, H, N], q_rope [B, T, H, R] rotated, row
+    [B, T, latent_lane] = [RMSNorm(c_kv) | k_r rotated | 0..], what the cache
+    keeps of a token)."""
+    B, T, _ = h.shape
+    H, C, R, N = (c.num_heads, c.kv_lora_rank, c.qk_rope_head_dim,
+                  c.qk_nope_head_dim)
+    sin, cos = gpt_mod.yarn_rope_tables_at(R, c.rope_theta, c.rope_scaling,
+                                           pos)
+    with jax.named_scope("mla.q"):
+        cq = _rms(jnp.matmul(h, lp["q_a_w"]), lp["q_norm_w"], c.rms_norm_eps)
+        q = jnp.matmul(cq, lp["q_b_w"]).reshape(B, T, H, N + R)
+        q_nope, q_rope = q[..., :N], apply_rope(q[..., N:], sin, cos)
+    with jax.named_scope("mla.kv_write"):
+        ckv = jnp.matmul(h, lp["kv_a_w"])
+        k_r = apply_rope(ckv[..., None, C:], sin, cos)[:, :, 0]
+        row = jnp.concatenate(
+            [_rms(ckv[..., :C], lp["kv_norm_w"], c.rms_norm_eps), k_r,
+             jnp.zeros((B, T, c.latent_lane - c.latent_row), h.dtype)],
+            axis=-1)
+    return q_nope, q_rope, row
+
+
+def latent_attention(lp, q_nope, q_rope, pool, page_table, q_offset, valid,
+                     c: HybridConfig):
+    """Absorbed attention through the latent lane and the output
+    projection: q_lat = q_nope W^K, scores against the cached rows, the
+    weighted latents through W^V and W^O.  pool [P, page, latent_lane]."""
+    B, T, H, _ = q_nope.shape
+    with jax.named_scope("mla.q"):
+        q_lat = jnp.einsum("bthn,hnc->bthc", q_nope, lp["kv_b_k_w"])
+        q = jnp.concatenate(
+            [q_lat, q_rope, jnp.zeros((B, T, H, c.latent_lane - c.latent_row),
+                                      q_lat.dtype)], axis=-1)
+    with jax.named_scope("mla.attn"):
+        o_lat = paged_latent_attention(q, pool, page_table, q_offset, valid,
+                                       c.kv_lora_rank, c.mla_softmax_scale)
+    with jax.named_scope("mla.out"):
+        o = jnp.einsum("bthc,hcv->bthv", o_lat, lp["kv_b_v_w"])
+        return jnp.matmul(o.reshape(B, T, H * c.v_head_dim), lp["o_w"])
+
+
+def ffn_mixer(lp, h):
+    """Dense SwiGLU: down(silu(gate h) * up h)."""
+    g = jax.nn.silu(jnp.matmul(h, lp["gate_w"]).astype(jnp.float32))
+    a = (g * jnp.matmul(h, lp["up_w"]).astype(jnp.float32)).astype(h.dtype)
+    return jnp.matmul(a, lp["down_w"])
+
+
+def sinkhorn(m, iters: int):
+    """`iters` rounds of row- then column-normalisation of positive
+    m [..., n, n]: towards a doubly stochastic matrix."""
+    def body(_, m):
+        m = m / jnp.sum(m, axis=-1, keepdims=True)
+        return m / jnp.sum(m, axis=-2, keepdims=True)
+    return jax.lax.fori_loop(0, iters, body, m)
+
+
+def mhc_mixes(lp, X, c: HybridConfig):
+    """A mixer's three mixes of the residual streams X [B, T, n, D], all in
+    float32: (pre [B, T, n] in (0, 1), post [B, T, n] in (0, 2), res
+    [B, T, n, n] doubly stochastic after `hc_sinkhorn_iters` rounds)."""
+    B, T, n, D = X.shape
+    f32 = jnp.float32
+    x = X.astype(f32).reshape(B, T, n * D)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + c.hc_eps)
+    m = jnp.dot(x, lp["hc_phi"].astype(f32),
+                precision=jax.lax.Precision.HIGHEST)
+    a, b = lp["hc_alpha"].astype(f32), lp["hc_b"].astype(f32)
+    pre = jax.nn.sigmoid(a[0] * m[..., :n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(a[1] * m[..., n:2 * n] + b[n:2 * n])
+    res = (a[2] * m[..., 2 * n:] + b[2 * n:]).reshape(B, T, n, n)
+    res = sinkhorn(jnp.exp(jnp.clip(res, *c.hc_res_clamp)),
+                   c.hc_sinkhorn_iters)
+    return pre, post, res
+
+
+def _sum_aux(parts, live, resets, written, rows):
+    """The `AUX_FIELDS` vector from the expert layers' counters and the
+    passes' own."""
     def over(name, fn):
         vals = [p[name] for p in parts]
         return fn(jnp.stack(vals)) if vals else jnp.zeros((), jnp.int32)
     return jnp.stack([
         over("moe_pairs_here", jnp.sum), over("moe_pairs_away", jnp.sum),
         over("moe_experts_touched", jnp.sum), over("moe_load_max", jnp.max),
-        live.astype(jnp.int32), resets.astype(jnp.int32)]).astype(jnp.int32)
+        live.astype(jnp.int32), resets.astype(jnp.int32),
+        written.astype(jnp.int32), rows.astype(jnp.int32)]).astype(jnp.int32)
 
 
-def _walk(params, x, cache, c: HybridConfig, real, mamba, attention):
+def _walk(params, x, cache, c: HybridConfig, real, mamba, attention, latent):
     """The layer loop of both passes: a static walk of the pattern.
-    `mamba(lp, h, cache, i)` and `attention(lp, h, cache, i)` return (mixer
-    output, cache); the expert layer needs no state.  Returns (x, cache,
-    expert counters per E layer)."""
+    `mamba(lp, h, cache, i)`, `attention(lp, h, cache, i)` and
+    `latent(lp, h, cache, i)` return (mixer output, cache); the expert layer
+    and the dense FFN need no state.  The residual rule is the plain one, or
+    for `hc_mult` streams: x [B, T, D] is repeated into X [B, T, n, D], every
+    mixer reads h = pre X, and X <- res X + post^T y; the streams are summed
+    at the end.  Returns (x [B, T, D], cache, expert counters per E layer)."""
     B, T, D = x.shape
-    seen = {"M": 0, "E": 0, "*": 0}
+    n = c.hc_mult
+    if n > 1:
+        x = jnp.broadcast_to(x[:, :, None, :], (B, T, n, D))
+    seen = dict.fromkeys(KINDS, 0)
     counters = []
     for letter, lp in zip(c.layer_pattern, params["layers"]):
         i = seen[letter]
         seen[letter] += 1
         with jax.named_scope(KINDS[letter]):
-            h = _norm(x, lp["norm_w"], c)
+            if n > 1:
+                with jax.named_scope("mhc"):
+                    pre, post, res = mhc_mixes(lp, x, c)
+                    h = jnp.einsum("btn,btnd->btd", pre, x.astype(jnp.float32)
+                                   ).astype(x.dtype)
+            else:
+                h = x
+            h = _norm(h, lp["norm_w"], c)
             if letter == "M":
                 y, cache = mamba(lp, h, cache, i)
             elif letter == "E":
@@ -297,10 +515,35 @@ def _walk(params, x, cache, c: HybridConfig, real, mamba, attention):
                                    real.reshape(B * T))
                 y = y.reshape(B, T, D)
                 counters.append(ctr)
+            elif letter == "F":
+                y = ffn_mixer(lp, h)
+            elif letter == "L":
+                y, cache = latent(lp, h, cache, i)
             else:
                 y, cache = attention(lp, h, cache, i)
-            x = x + y
+            if n > 1:
+                with jax.named_scope("mhc"):
+                    x = (jnp.einsum("btij,btjd->btid", res,
+                                    x.astype(jnp.float32)) +
+                         post[..., None] * y.astype(jnp.float32)[:, :, None]
+                         ).astype(x.dtype)
+            else:
+                x = x + y
+    if n > 1:
+        x = jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
     return x, cache, counters
+
+
+def _geometry(cache, c: HybridConfig):
+    """(pages, page size) of the paged lanes."""
+    return cache[next(iter(c.paged_lanes()))].shape[1:3]
+
+
+def _write_rows(lane, rows, off, new):
+    """`new` [B, T, ...] written token by token into a paged lane [L, P,
+    page, ...] at flat rows [B, T] (layer * P + page) and offsets [B, T]."""
+    flat = lane.reshape((-1,) + lane.shape[2:])
+    return flat.at[rows, off].set(new)
 
 
 def prefill_paged(params, input_ids, config: HybridConfig, cache, pages,
@@ -308,18 +551,22 @@ def prefill_paged(params, input_ids, config: HybridConfig, cache, pages,
     """Bucketed prefill (`gpt.prefill_paged`'s contract, plus `slots` [B]:
     where each prompt's recurrent state is kept).  The state starts from
     zeros and is written as it stands after position length - 1: bucket
-    padding moves neither lane.  Returns (logits [B, V] at the last real
-    position, cache, aux)."""
+    padding moves neither lane.  A latent layer stays in the absorbed form
+    (the rows it has just written, read back through the paged kernel with
+    q_offset 0).  Returns (logits [B, V] at the last real position, cache,
+    aux)."""
     c = config
     B, Sb = input_ids.shape
     H, KVH, hd = c.num_heads, c.kv_heads, c.head_dim
-    P, page = cache["k"].shape[1:3]
+    P, page = _geometry(cache, c)
     real = jnp.arange(Sb)[None, :] < length[:, None]
-    # keys and values are written token by token, padding to the null page,
-    # as the fused step writes them: a whole-page write of [page, KVH, hd]
-    # windows at KVH = 2 makes the compiler re-lay the pool out and back
+    # rows are written token by token, padding to the null page, as the
+    # fused step writes them: a whole-page write of [page, KVH, hd] windows
+    # at KVH = 2 makes the compiler re-lay the pool out and back
     pidx = jnp.where(real, jnp.take(pages, jnp.arange(Sb) // page, axis=1), 0)
-    off = jnp.arange(Sb) % page
+    off = jnp.broadcast_to(jnp.arange(Sb) % page, (B, Sb))
+    pos = jnp.broadcast_to(jnp.arange(Sb), (B, Sb))
+    zero = jnp.zeros((B,), jnp.int32)
     x = gpt_mod._embed(params, input_ids, c)
 
     def mamba(lp, h, cache, i):
@@ -333,8 +580,7 @@ def prefill_paged(params, input_ids, config: HybridConfig, cache, pages,
     def attention(lp, h, cache, i):
         q, k, v = _qkv(lp, h, c)
         rows = i * P + pidx
-        new = {n: cache[n].reshape((-1,) + cache[n].shape[2:])
-               .at[rows, off].set(a).reshape(cache[n].shape)
+        new = {n: _write_rows(cache[n], rows, off, a).reshape(cache[n].shape)
                for n, a in (("k", k), ("v", v))}
         k = jnp.repeat(k, H // KVH, axis=2)
         v = jnp.repeat(v, H // KVH, axis=2)
@@ -342,26 +588,31 @@ def prefill_paged(params, input_ids, config: HybridConfig, cache, pages,
         return jnp.matmul(attn.reshape(B, Sb, H * hd), lp["proj_w"]), \
             dict(cache, **new)
 
-    x, cache, counters = _walk(params, x, cache, c, real, mamba, attention)
+    def latent(lp, h, cache, i):
+        q_nope, q_rope, row = latent_qkv(lp, h, pos, c)
+        flat = _write_rows(cache["c"], i * P + pidx, off, row)
+        y = latent_attention(lp, q_nope, q_rope, flat, pages + i * P, zero,
+                             length, c)
+        return y, dict(cache, c=flat.reshape(cache["c"].shape))
+
+    x, cache, counters = _walk(params, x, cache, c, real, mamba, attention,
+                               latent)
     x = _norm(x[jnp.arange(B), length - 1], params["lnf_w"], c)
-    aux = _sum_aux(counters, jnp.asarray(B), jnp.asarray(B))
+    has_m, has_l = int(c.count("M") > 0), int(c.count("L") > 0)
+    aux = _sum_aux(counters, jnp.asarray(B * has_m), jnp.asarray(B * has_m),
+                   jnp.sum(length) * has_l, jnp.sum(length) * has_l)
     return gpt_mod.head_logits(x, params, c), cache, aux
 
 
-def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
-                     config: HybridConfig, key=None, greedy=None, *,
-                     sample: bool = False, temperature=1.0, top_k=None):
-    """The fused serving step (`gpt.serve_step_paged`'s contract; one more
-    result, `aux`).  Row b of the batch IS slot b of the state lanes.  A row
-    with q_offset 0 starts from a zero state (nothing lies behind it); a row
-    whose page-table row is null is inactive and leaves its state alone, as
-    do positions t >= valid.  Returns (out_tokens [B, T], accept [B], cache,
-    key, aux [len(AUX_FIELDS)] int32)."""
+def _step_hidden(params, tokens, cache, page_table, q_offset, valid,
+                 c: HybridConfig):
+    """The fused step's and the chunk pass's layers: T tokens a slot at
+    positions q_offset + t, through the page table and the state lanes.
+    Returns (x [B, T, D] before the final norm, cache, aux)."""
     from ..incubate.kernels.paged_attention import paged_prefill_attention
-    c = config
     B, T = tokens.shape
     H, hd = c.num_heads, c.head_dim
-    P, page = cache["k"].shape[1:3]
+    P, page = _geometry(cache, c)
     active = page_table[:, 0] != 0
     fresh = q_offset == 0
     n_real = jnp.where(active, valid, 0)
@@ -384,18 +635,57 @@ def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
     def attention(lp, h, cache, i):
         q, k, v = _qkv(lp, h, c)
         base = i * P
-        rows = base + pidx
-        flat = {n: cache[n].reshape((-1,) + cache[n].shape[2:])
-                for n in ("k", "v")}
-        flat = {"k": flat["k"].at[rows, off].set(k),
-                "v": flat["v"].at[rows, off].set(v)}
+        flat = {n: _write_rows(cache[n], base + pidx, off, a)
+                for n, a in (("k", k), ("v", v))}
         attn = paged_prefill_attention(q, flat["k"], flat["v"],
                                        page_table + base, q_offset, n_real)
         return jnp.matmul(attn.reshape(B, T, H * hd), lp["proj_w"]), \
             dict(cache, **{n: a.reshape(cache[n].shape)
                            for n, a in flat.items()})
 
-    x, cache, counters = _walk(params, x, cache, c, real, mamba, attention)
+    def latent(lp, h, cache, i):
+        q_nope, q_rope, row = latent_qkv(lp, h, pos, c)
+        flat = _write_rows(cache["c"], i * P + pidx, off, row)
+        y = latent_attention(lp, q_nope, q_rope, flat, page_table + i * P,
+                             q_offset, n_real, c)
+        return y, dict(cache, c=flat.reshape(cache["c"].shape))
+
+    x, cache, counters = _walk(params, x, cache, c, real, mamba, attention,
+                               latent)
+    has_m, has_l = int(c.count("M") > 0), int(c.count("L") > 0)
+    aux = _sum_aux(counters, jnp.sum(active) * has_m,
+                   jnp.sum(active & fresh) * has_m,
+                   jnp.sum(jnp.where(active, q_offset + n_real, 0)) * has_l,
+                   jnp.sum(n_real) * has_l)
+    return x, cache, aux
+
+
+def prefill_chunk_paged(params, input_ids, config: HybridConfig, cache,
+                        page_table, q_offset, valid):
+    """A chunk of a prompt behind a prefix hit (`gpt.prefill_chunk_paged`'s
+    contract; one more result, `aux`): the engine reaches it only for a
+    pattern without recurrent state.  Returns (logits [B, V] at chunk index
+    valid - 1, cache, aux)."""
+    c = config
+    x, cache, aux = _step_hidden(params, input_ids, cache, page_table,
+                                 q_offset, valid, c)
+    x = _norm(x[jnp.arange(x.shape[0]), valid - 1], params["lnf_w"], c)
+    return gpt_mod.head_logits(x, params, c), cache, aux
+
+
+def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
+                     config: HybridConfig, key=None, greedy=None, *,
+                     sample: bool = False, temperature=1.0, top_k=None):
+    """The fused serving step (`gpt.serve_step_paged`'s contract; one more
+    result, `aux`).  Row b of the batch IS slot b of the state lanes.  A row
+    with q_offset 0 starts from a zero state (nothing lies behind it); a row
+    whose page-table row is null is inactive and leaves its state alone, as
+    do positions t >= valid.  Returns (out_tokens [B, T], accept [B], cache,
+    key, aux [len(AUX_FIELDS)] int32)."""
+    c = config
+    B, T = tokens.shape
+    x, cache, aux = _step_hidden(params, tokens, cache, page_table, q_offset,
+                                 valid, c)
     with jax.named_scope("head"):
         x = _norm(x, params["lnf_w"], c)
         logits = gpt_mod.head_logits(x, params, c)
@@ -407,8 +697,7 @@ def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
                                         top_k=top_k)
         out = out.at[rows, valid - 1].set(
             jnp.where(greedy, out[rows, valid - 1], ids))
-    # no draft ever rides this step (speculation is refused for a recurrent
+    # no draft ever rides this step (speculation is refused for a patterned
     # configuration: a rejected draft would need the state rolled back)
     accept = jnp.zeros((B,), jnp.int32)
-    aux = _sum_aux(counters, jnp.sum(active), jnp.sum(active & fresh))
     return out, accept, cache, key, aux

@@ -147,7 +147,14 @@ def kv_page_bytes(config, page_size: int,
     """At-rest bytes ONE page pool page occupies across all layers (k + v,
     plus the per-token scale lanes when quantized) — the formula the engine's
     `swap_pool_bytes`, the bench's equal-byte pool sizing and the
-    `tpu_cost` accounts all agree on."""
+    `tpu_cost` accounts all agree on.  A patterned configuration says what
+    its paged lanes hold (`HybridConfig.page_bytes`: K/V of its attention
+    layers, the one latent lane of its latent layers)."""
+    if hasattr(config, "page_bytes"):
+        if normalize_quant_dtype(kv_dtype, "kv_dtype") is not None:
+            raise ValueError("a patterned configuration has no quantized "
+                             "page pool")
+        return config.page_bytes(page_size)
     L, KVH, hd = config.kv_layers, config.kv_heads, config.head_dim
     if normalize_quant_dtype(kv_dtype, "kv_dtype") == "int8":
         per_tok = hd * 1 + np.dtype(KV_SCALE_DTYPE).itemsize

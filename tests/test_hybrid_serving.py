@@ -282,7 +282,7 @@ def test_prefill_then_decode_is_the_reference_forward(case, lengths, kw):
     st = eng.stats()
     assert st["ssm_state_resets"] == (len(lengths) if "M" in pattern else
                                       st["ssm_state_resets"])
-    assert eng.cache.state.live == set()
+    assert not eng.recurrent or eng.cache.state.live == set()
 
 
 def test_recompute_preemption_restarts_the_state():

@@ -497,6 +497,11 @@ NEW_STATS_KEYS = frozenset({
     "ssm_slots_live", "ssm_state_resets", "ssm_state_bytes",
     "ssm_state_pool_bytes", "prefix_lookups_skipped_no_state",
 }) | frozenset({
+    # added by the latent-pages PR (ISSUE 32): rows absorbed attention read,
+    # its query tokens, and the latent lane's bytes a page (all 0 for a
+    # configuration without latent attention)
+    "latent_tokens_written", "mla_absorbed_rows", "latent_page_bytes",
+}) | frozenset({
     # added by the paged-walk PR (ISSUE 29): pages the paged kernel walks
     # against the table entries its programs were handed
     "paged_pages_walked", "paged_table_entries",

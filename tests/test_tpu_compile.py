@@ -297,6 +297,77 @@ def test_hybrid_bucketed_prefill_keeps_both_pools_in_place(one):
     assert _lane_sized_moves(text, pool) == []
 
 
+def _latent(pattern="LFLE"):
+    """The Xing4.0 cell's program at its published widths and engine shapes
+    (`benchmarks/configs/xing4-29b-a4b-d13e16.json` through its driver),
+    cut to two layers."""
+    import json
+    import pathlib
+
+    from benchmarks.drivers import serve_xing4
+    from paddle_tpu.models import hybrid
+    root = pathlib.Path(__file__).resolve().parents[1]
+    conf = json.loads((root / "benchmarks/configs/"
+                       "xing4-29b-a4b-d13e16.json").read_text())
+    model = dict(serve_xing4.model_of(conf), mixer_pattern=pattern)
+    cfg = serve_xing4.program_config(model)
+    eng = conf["engine"]
+    params = jax.eval_shape(functools.partial(hybrid.init_params, cfg),
+                            jax.random.key(0))
+    pool = jax.eval_shape(functools.partial(
+        hybrid.init_paged_cache, cfg, eng["num_pages"], eng["page_size"],
+        eng["num_slots"]))
+    return hybrid, cfg, params, pool, eng
+
+
+def _latent_moves(text, pool):
+    """Copies or slices of the whole latent lane, or of a layer's expert
+    matrices."""
+    import re
+    shapes = {"[" + ",".join(map(str, a.shape)) + "]" for a in pool.values()}
+    shapes.add("[16,1024,3584]")
+    return [line.strip()[:120] for line in text.splitlines()
+            if (m := re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(",
+                              line))
+            and m.group(2) in ("copy", "copy-start", "dynamic-slice")
+            and any(sh in m.group(1) for sh in shapes)]
+
+
+def test_latent_fused_step_keeps_the_lane_in_place(one):
+    """`hybrid.serve_step_paged` as the engine compiles it for the Xing4.0
+    cell (128 slots, T=1, 96 table entries of 64-token pages, the lane
+    donated): the latent kernel at 32 heads on one 640-wide row, the gated
+    expert products, four residual streams - and no copy of the lane."""
+    hybrid, cfg, params, pool, eng = _latent()
+    B, n = eng["num_slots"], eng["max_model_len"] // eng["page_size"]
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    text = _compile(
+        lambda p, tok, pl_, tbl, qo, vl, k, g: hybrid.serve_step_paged(
+            p, tok, pl_, tbl, qo, vl, cfg, key=k, greedy=g),
+        *one([params, _s(B, 1, dtype=i32), pool, _s(B, n, dtype=i32),
+              _s(B, dtype=i32), _s(B, dtype=i32), key,
+              _s(B, dtype=jnp.bool_)]),
+        donate_argnums=(2,))
+    assert pool["c"].shape == (2, eng["num_pages"], eng["page_size"], 640)
+    assert _latent_moves(text, pool) == []
+    assert "paged_latent" in text
+
+
+@pytest.mark.parametrize("bucket", [64, 2048])
+def test_latent_bucketed_prefill_keeps_the_lane_in_place(one, bucket):
+    hybrid, cfg, params, pool, eng = _latent()
+    i32 = jnp.int32
+    text = _compile(
+        lambda p, ids, pl_, pg, ln, sl: hybrid.prefill_paged(
+            p, ids, cfg, pl_, pg, ln, sl),
+        *one([params, _s(1, bucket, dtype=i32), pool,
+              _s(1, bucket // eng["page_size"], dtype=i32), _s(1, dtype=i32),
+              _s(1, dtype=i32)]),
+        donate_argnums=(2,))
+    assert _latent_moves(text, pool) == []
+
+
 def test_shard_mapped_kernels_on_four_devices(topo):
     """Pallas under a mesh: the paged kernel head-sharded over mp=4 (the
     serving route) and the flash kernel per shard of a dp2 x mp2 step (the

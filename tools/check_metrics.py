@@ -115,6 +115,9 @@ REQUIRED_STATS_KEYS = frozenset({
     # paged-walk PR (ISSUE 29): pages the paged kernel walks against the
     # table entries its programs were handed
     "paged_pages_walked", "paged_table_entries",
+    # latent-pages PR (ISSUE 32): rows absorbed attention read and its query
+    # tokens, and the latent lane's bytes a page (0 without latent layers)
+    "latent_tokens_written", "mla_absorbed_rows", "latent_page_bytes",
 })
 REQUIRED_KV_TIER_KEYS = frozenset({
     "enabled", "spill_dir", "pages_host", "pages_disk", "spills",
@@ -178,7 +181,7 @@ REQUIRED_STEP_RECORD_KEYS = frozenset({
     "finished", "pages_in_use", "pages_free", "pages_evictable",
     "dispatches", "sync_ms", "turnaround_ms", "d2h_ms", "slots", "preempted",
     "pool_pressure", "moe_pairs_here", "moe_pairs_away", "moe_experts_touched",
-    "pages_walked",
+    "pages_walked", "latent_tokens_written",
 })
 REQUIRED_DEBUG_BUNDLE_KEYS = frozenset({
     "version", "t", "engine", "pool", "requests", "step_trace", "stats",
