@@ -726,7 +726,7 @@ def engine_step_target(engine):
     fn = getattr(engine._decode_fn, "_jit", engine._decode_fn)
     args = (params, host((B, engine._fused_T)), pool, host((B, P)),
             host((B,)), host((B,)), sds(engine._key, repl),
-            host((B,), np.bool_))
+            host((B,), np.bool_), host((B, engine._fused_T)), host((B,)))
     return fn, args
 
 

@@ -294,7 +294,8 @@ def serving_targets(mp: int = 1, engines=None
         (f"serve.{tag}fused_step", unwrap(eng._decode_fn),
          (eng.params, jnp.zeros((B, Tf), i32), eng._pool,
           jnp.zeros((B, P), i32), jnp.zeros((B,), i32),
-          jnp.ones((B,), i32), eng._key, jnp.zeros((B,), bool)),
+          jnp.ones((B,), i32), eng._key, jnp.zeros((B,), bool),
+          jnp.zeros((B, Tf), i32), jnp.full((B,), -1, i32)),
          dict(donate_paths=("arg2",), keep_paths=("arg0",),
               host_output_budget=B * (Tf + 2) + 2, **mp_kw)),
         (f"serve.{tag}chunk_prefill", unwrap(bkt._chunk_fn),
@@ -349,7 +350,8 @@ def quantized_targets(mp: int = 1, engine=None
                                                 qeng._decode_fn),
          (qeng.params, jnp.zeros((B, Tf), i32), qeng._pool,
           jnp.zeros((B, P), i32), jnp.zeros((B,), i32),
-          jnp.ones((B,), i32), qeng._key, jnp.zeros((B,), bool)),
+          jnp.ones((B,), i32), qeng._key, jnp.zeros((B,), bool),
+          jnp.zeros((B, Tf), i32), jnp.full((B,), -1, i32)),
          dict(donate_paths=("arg2",), keep_paths=("arg0",),
               host_output_budget=B * (Tf + 2) + 2,
               require_sharding_constraint=mp > 1)),

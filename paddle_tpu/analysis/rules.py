@@ -304,7 +304,10 @@ class DoubleBufferHazardRule(Rule):
     Under double-buffered scheduling (`double_buffer=True`, the default)
     the fused dispatch of step *n* is still writing KV when the host runs
     between steps — its result is parked in `self._inflight` until the next
-    harvest.  A public entry point that frees or reassigns page-table/
+    harvest.  At every return from `step()` that is at most ONE program: a
+    step may launch program *n+1* before it harvests *n*, but the two are
+    in flight together only inside `step()`, which harvests before it
+    returns.  A public entry point that frees or reassigns page-table/
     refcount state (release/allocate, `lengths[...]`/`page_table[...]`
     stores) while that batch is in flight hands pages to a new owner whose
     bookkeeping the in-flight result will then corrupt — the invariant

@@ -64,18 +64,34 @@ and Orca's iteration-level scheduling (Yu et al., OSDI 2022), under the same
   ~3 orders of magnitude smaller than `[B, V]` logits — and the decode-side
   compiled-program count is ONE.
 - **Double-buffered scheduling** (`double_buffer=True`) —
-  the fused dispatch returns un-synced: the host finishes its step-n
-  bookkeeping and the caller's loop while the device computes, and the token
-  fetch for step n happens at the TOP of step n+1 inside the
-  `engine.sample.sync` span (by which time the result is usually ready, so
-  the sync is off the critical path).  Host scheduler state (lengths, page
-  tables, EOS/finish) is updated at harvest time, one step after dispatch;
+  the fused dispatch returns un-synced, and where the next batch is
+  predictable the NEXT fused program is launched before the last one's
+  tokens are read: its decode rows take their input token from the last
+  program's `out`, on the device (a select in the jitted wrapper, the same
+  ONE program), so the fetch (`engine.sample.sync`), the emission, the
+  retirements and the caller's loop all run while the device computes and
+  the host's turnaround is behind the device's work.  Predictable
+  (`_plan_ahead`) is decided each step from what the engine observes: a
+  program is in flight, no lane carries or could carry a draft, growth
+  cannot need a victim, and no admission is due (the queue is empty, or no
+  slot is free and the program in flight ends no request by its budget).
+  Any other step keeps the older order — harvest, admit, build, launch —
+  where the token fetch for step n is at the TOP of step n+1 and the device
+  waits for `engine.turnaround`.  INVARIANT: at every return from `step()`
+  at most ONE program is in flight (`_inflight`); two exist only inside
+  `step()`, between the launch of k+1 and the harvest of k.  Host scheduler
+  state (lengths, page tables, EOS/finish) is updated at harvest time;
   `abort()` harvests the in-flight batch first so bookkeeping stays exact.
-  In-flight KV writes of a just-aborted slot are safe: the page pool threads
-  through every dispatch as a donated buffer, so device writes are program-
-  ordered — a page recycled to a new request is rewritten by the new owner's
-  prefill before its attention can read any position the stale write
-  touched.
+  A request that ends where the host cannot foresee it (EOS, a deadline)
+  leaves a lane behind in the program already launched: that lane's write
+  lands at `lengths[slot]`, inside the slot's own reservation and past what
+  `register_prefix` publishes, its harvest drops the token
+  (`fused_ahead_discarded_lanes`), and a recurrent state it moved is zeroed
+  at the slot's next admission.  In-flight KV writes of a just-retired
+  slot are safe: the page pool threads through every dispatch as a donated
+  buffer, so device writes are program-ordered — a page recycled to a new
+  request is rewritten by the new owner's prefill before its attention can
+  read any position the stale write touched.
 - **Multi-chip serving** (vLLM's Megatron-style tensor parallelism) —
   `mp=N` shards the model over N chips: Megatron serving params placed once
   at init (`parallel.hybrid.serving_param_specs`), page pool sharded on its
@@ -364,8 +380,11 @@ _NULL_SPAN = _NullSpan()
 # prefix-hit tail.  engine.turnaround (see `LLMEngine._turn_begin`) is the
 # host stretch the device waits for between two fused programs; emit, admit,
 # batch.build and fused.dispatch (with fused.h2d, its five puts, inside) tile
-# it.  swap.d2h is the engine thread taking one piece from the fetch worker:
-# .ready its wait for bytes still in flight, .copy the hand-over.
+# it.  A step that launches ahead of the last result (`_plan_ahead`) has no
+# such stretch: its batch.build, fused.dispatch, sample.sync and emit lie
+# directly under engine.step, in that order.  swap.d2h is the engine thread
+# taking one piece from the fetch worker: .ready its wait for bytes still in
+# flight, .copy the hand-over.
 ENGINE_SPANS = (
     "engine.step",
     "engine.turnaround",
@@ -486,10 +505,13 @@ class LLMEngine:
     share one `[num_slots, max(spec_len+1, prefill_chunk)]` batch, and the
     host fetches a small int token/accept buffer instead of `[B, V]` logits.
     `double_buffer=True` (default) makes the dispatch return
-    un-synced, moving the token fetch for step *n* to the top of step *n+1*
-    (inside the `engine.sample.sync` span) so the device computes while the
-    host schedules — finishes are then observed one `step()` later than in
-    synchronous mode, which `run()`/`has_work` account for.
+    un-synced and, on every step whose next batch is predictable, launches
+    program *n+1* before it fetches program *n*'s tokens (its rows take
+    them on the device), so the device computes while the host fetches,
+    emits and schedules; any other step fetches at its top, as before —
+    finishes are observed one `step()` later than in synchronous mode,
+    which `run()`/`has_work` account for.  `double_buffer=False` is the
+    synchronous schedule: launch, then harvest, inside one step.
 
     Observability: `engine.metrics` is the metrics registry (counters,
     page/queue gauges, latency histograms; `to_prometheus()` for scraping),
@@ -978,6 +1000,16 @@ class LLMEngine:
             "turnaround_ms",
             "host milliseconds between a fused program's result in hand and "
             "the next fused launch's return")
+        self._launched_ahead = m.counter(
+            "fused_launched_ahead",
+            "fused launches made before the previous program's result was "
+            "read (over decode_iterations: the share of steps whose host "
+            "turnaround ran behind the device's work)")
+        self._ahead_discarded = m.counter(
+            "fused_ahead_discarded_lanes",
+            "lanes of a program launched ahead whose request had ended by "
+            "the time the program before it was read (EOS, deadline): the "
+            "lane's token is dropped at its harvest")
         self._recomputed_tokens = m.counter(
             "recomputed_tokens",
             "prompt tokens re-prefilled because of preemption")
@@ -1193,15 +1225,31 @@ class LLMEngine:
 
         temp_, topk_ = temperature, top_k
 
+        repl_sh = self._repl_sharding
+
+        def feed(tokens, prev_out, from_prev):
+            # the token source of a program launched before the last one's
+            # result was read (`_plan_ahead`): row b's first token is
+            # `prev_out[b, from_prev[b]]`, the last program's own output,
+            # still on the device; -1 keeps the host's row
+            col = jnp.take_along_axis(
+                prev_out, jnp.maximum(from_prev, 0)[:, None], axis=1)[:, 0]
+            return tokens.at[:, 0].set(jnp.where(
+                from_prev >= 0, col.astype(tokens.dtype), tokens[:, 0]))
+
         def fused_impl(params, tokens, pool, table, q_offset, valid, key,
-                       greedy):
+                       greedy, prev_out, from_prev):
             # THE one-dispatch step: decode/verify/chunk slots in one batch,
             # sampling + accept scan on device, host-visible output O(B*K)
             # ints (never [B, V] logits — guarded by the JXP005 jaxpr audit)
             out, accept, pool, key = gpt_mod.serve_step_paged(
-                params, tokens, pool, table, q_offset, valid, cfg, key=key,
-                greedy=greedy, sample=sample, temperature=temp_, top_k=topk_,
-                mesh=mesh_)
+                params, feed(tokens, prev_out, from_prev), pool, table,
+                q_offset, valid, cfg, key=key, greedy=greedy, sample=sample,
+                temperature=temp_, top_k=topk_, mesh=mesh_)
+            if repl_sh is not None:
+                # `out` is the next launch's `prev_out`: pinned to the
+                # layout the AOT executable was compiled for
+                out = jax.lax.with_sharding_constraint(out, repl_sh)
             return out, accept, pin_pool(pool), key
 
         if self.patterned:
@@ -1225,11 +1273,11 @@ class LLMEngine:
                 return first, pool, key, aux
 
             def fused_impl(params, tokens, pool, table, q_offset, valid, key,
-                           greedy):
+                           greedy, prev_out, from_prev):
                 return hybrid_mod.serve_step_paged(
-                    params, tokens, pool, table, q_offset, valid, cfg,
-                    key=key, greedy=greedy, sample=sample, temperature=temp_,
-                    top_k=topk_)
+                    params, feed(tokens, prev_out, from_prev), pool, table,
+                    q_offset, valid, cfg, key=key, greedy=greedy,
+                    sample=sample, temperature=temp_, top_k=topk_)
 
         def copy_impl(pool, src, dst):
             # COW page copy: one [page, KVH, hd] slab per layer, src -> dst
@@ -1321,6 +1369,13 @@ class LLMEngine:
         # finishes surfaced outside step() (an abort-time harvest)
         self._inflight: Optional[Dict[str, object]] = None
         self._orphan_finished: List[RequestOutput] = []
+        # what a launch in today's order hands the program where a launch
+        # ahead hands it the last program's `out` and the rows to take from
+        # it: nothing to take, every row the host's.  Device constants, so
+        # such a launch keeps its five puts
+        self._no_prev = self._h2d(
+            np.zeros((num_slots, self._fused_T), np.int32))
+        self._host_rows = self._h2d(np.full((num_slots,), -1, np.int32))
         self._step_dispatches = 0
         self._step_sync_s = 0.0
         self._step_slots = {"decode": 0, "verify": 0, "chunk": 0}
@@ -1495,7 +1550,8 @@ class LLMEngine:
         finish_reason="abort" and whatever tokens it had produced.  Returns
         False when the id is unknown or already finished.
 
-        Under double-buffering the in-flight fused batch is harvested first,
+        Under double-buffering the in-flight fused batch (at most one
+        between steps, also where steps launch ahead) is harvested first,
         so the abort sees exact bookkeeping (a request the pending tokens
         just finished is reported as already done, not aborted); requests
         that finish during this harvest surface from the NEXT step() call."""
@@ -1642,7 +1698,10 @@ class LLMEngine:
         from `t`, the instant the previous fused program's
         result was in hand — `_harvest`'s device_get has returned — or the
         step's start when nothing was in flight, to the return of this
-        step's fused launch (`_turn_end`).  Always measured (one more clock
+        step's fused launch (`_turn_end`).  Only a step that keeps the
+        harvest-first order opens it: one that launches ahead of the last
+        result (`_plan_ahead`) makes the device wait for nothing, opens no
+        span and adds 0 to ring and counter.  Measured (one more clock
         read a step) into the ring's `turnaround_ms` and the `turnaround_ms`
         counter; a span only while something records.  With
         `double_buffer=False` the harvest follows the launch inside the same
@@ -1707,13 +1766,20 @@ class LLMEngine:
 
     # ---- scheduler --------------------------------------------------------
     def step(self) -> List[RequestOutput]:
-        """One engine iteration: harvest the previous fused dispatch (double-
-        buffered mode), admit queued requests into free slots (prefix-cache
-        matching + page reservation), stage at most ONE prefill chunk, then
-        dispatch decode work — ONE fused program covering every decode/
-        verify/chunk slot.  Returns the requests that finished this iteration
-        (under double-buffering a request finishes the step its tokens are
-        harvested, one after its last dispatch).
+        """One engine iteration, in one of two orders.  Where the next batch
+        is predictable (`_plan_ahead`: double-buffered mode, a program in
+        flight, nothing to admit, draft or preempt): build and launch the
+        next fused program FIRST, its decode rows fed on the device from the
+        one in flight, then harvest that one — the fetch, the emission and
+        the retirements run behind the device's work.  Otherwise: harvest
+        the previous fused dispatch (double-buffered mode), admit queued
+        requests into free slots (prefix-cache matching + page reservation),
+        stage at most ONE prefill chunk, then dispatch decode work — ONE
+        fused program covering every decode/verify/chunk slot.  Either way
+        at most one program is in flight when this returns.  Returns the
+        requests that finished this iteration (under double-buffering a
+        request finishes the step its tokens are harvested, one after its
+        last dispatch).
 
         Each iteration appends one v2 record to the step-trace ring
         (`step_trace()`): what the step dispatched (decode-batch occupancy,
@@ -1738,24 +1804,49 @@ class LLMEngine:
                                         "moe_experts_touched",
                                         "latent_tokens_written"), 0)
         with self._step_marker(), self._span("engine.step"):
-            if self._inflight is None:
-                self._turn_begin(t0)
-            # step n-1's tokens land first
-            self._harvest(finished, turnaround=True)
-            if self._has_deadlines:
-                # right after harvest: bookkeeping is exact, nothing in flight
-                self._expire_deadlines(finished)
-            with self._span("engine.admit"):
-                self._admit(finished)
-            if self.chunked:
-                chunk_job = self._stage_chunk()
+            prev = self._inflight
+            lanes = None if prev is None else self._plan_ahead(prev)
+            if lanes is not None:
+                # the next program goes out BEFORE the last one's tokens are
+                # read: its decode rows take their token from `prev`'s
+                # output on the device, and everything below — the fetch,
+                # the emission, the retirements, the caller's loop — runs
+                # while it computes.  Two programs are in flight only from
+                # here to the harvest; the step returns with one
+                chunk_job = self._stage_chunk() if self.chunked else None
+                self._inflight = None
+                if self.optimistic:
+                    # the page under each lane's write: the plan saw that
+                    # all of them fit without a victim (`_fits_ahead`)
+                    for slot, (_, q, _, _) in lanes.items():
+                        self.cache.grow(slot, q + 1)
+                if lanes or chunk_job is not None:
+                    self._fused_iter(chunk_job, finished, lanes, prev)
+                self._harvest(finished, prev)
+                if self._has_deadlines:
+                    # a slot retired here leaves a lane in the program just
+                    # launched: its harvest drops that lane's token
+                    self._expire_deadlines(finished)
             else:
-                # bucketed mode: prefix-hit tails keep the standalone
-                # chunk program (cold path, next to the one-shot prefill)
-                self._prefill_tick(finished)
-                chunk_job = None
-            if self._running or chunk_job is not None:
-                self._fused_iter(chunk_job, finished)
+                if prev is None:
+                    self._turn_begin(t0)
+                # step n-1's tokens land first
+                self._harvest(finished, turnaround=True)
+                if self._has_deadlines:
+                    # right after harvest: bookkeeping is exact, nothing in
+                    # flight
+                    self._expire_deadlines(finished)
+                with self._span("engine.admit"):
+                    self._admit(finished)
+                if self.chunked:
+                    chunk_job = self._stage_chunk()
+                else:
+                    # bucketed mode: prefix-hit tails keep the standalone
+                    # chunk program (cold path, next to the one-shot prefill)
+                    self._prefill_tick(finished)
+                    chunk_job = None
+                if self._running or chunk_job is not None:
+                    self._fused_iter(chunk_job, finished)
             # decode-batch occupancy of what actually DISPATCHED: on a
             # preemption step the pre-dispatch running count overstates the
             # batch (victims left before the program ran)
@@ -1808,9 +1899,11 @@ class LLMEngine:
             # blocking device->host sync time spent inside this step's
             # engine.sample.sync spans (harvest + prefill first-token fetches)
             "sync_ms": self._step_sync_s * 1e3,
-            # engine.turnaround of this step (0 when it launched nothing)
-            # and the swap/spill fetches it drained
+            # engine.turnaround of this step (0 when it launched nothing,
+            # and when it launched ahead of the last result: `ahead`) and
+            # the swap/spill fetches it drained
             "turnaround_ms": self._step_turnaround_s * 1e3,
+            "ahead": lanes is not None and self._step_dispatches > 0,
             "d2h_ms": self._step_d2h_s * 1e3,
             # per-mode slot occupancy of this step's decode-path dispatches
             "slots": dict(self._step_slots),
@@ -1855,80 +1948,168 @@ class LLMEngine:
             del self._prefilling[slot]      # resolved at harvest
         return job
 
+    def _plan_ahead(self, prev: Dict[str, object]
+                    ) -> Optional[Dict[int, tuple]]:
+        """Whether the next fused program can be launched before `prev`, the
+        one in flight, is read — decided from what the engine observes this
+        step — and, if so, its decode lanes: {slot: (column of `prev`'s
+        `out` that holds the lane's input token, q_offset, greedy, request
+        id)}.  None keeps today's order (harvest, admit, build, launch).
+
+        Predictable means that `prev`'s tokens are the ONLY thing the next
+        batch lacks: `prev` carried no draft (a verify lane's accepted count
+        moves its slot's length) and no lane could carry one now (the
+        proposer reads the token); nothing waits for the standalone chunk
+        program; no admission is due — the queue is empty, or no slot is
+        free and `prev` ends no request by its budget — so a new request
+        never waits behind one more program than it would have; and, under
+        optimistic admission, every lane's next page is there without a
+        victim (`_fits_ahead`).  A lane whose request ends with `prev`'s
+        token by `max_new_tokens` is left out; one that ends where the host
+        cannot foresee it (EOS, a deadline) rides, and `_harvest` drops its
+        token.  A decision only: no state moves here (`step()` grows the
+        lanes' pages once the plan stands)."""
+        if prev["drafts"] or (self._prefilling and not self.chunked) or \
+                (self._queue and self._free_slots):
+            return None
+        lanes: Dict[int, tuple] = {}
+        ending = False
+        lengths = self.cache.lengths
+        for slot, rid in prev["rids"].items():
+            seq = self._running.get(slot)
+            if seq is None or seq.request.request_id != rid:
+                continue                # ended unforeseen: nothing to feed
+            if len(seq.generated) + 1 >= seq.request.max_new_tokens:
+                ending = True
+            elif self.spec_len and seq.greedy and not seq.spec_off:
+                return None
+            else:
+                lanes[slot] = (0, int(lengths[slot]) + 1, seq.greedy, rid)
+        cj = prev["chunk"]
+        if cj is not None and cj["done"]:
+            # the prompt's last chunk: the slot starts decoding at `prev`'s
+            # harvest, from the token under the chunk's last position
+            st = cj["st"]
+            greedy = self._req_greedy(st.request)
+            if len(st.prior or ()) + 1 >= st.request.max_new_tokens:
+                ending = True
+            elif self.spec_len and greedy and not st.spec_off:
+                return None
+            else:
+                lanes[cj["slot"]] = (cj["n"] - 1, st.prompt.size, greedy,
+                                     st.request.request_id)
+        if self._queue and ending:
+            return None                 # its slot is free at the harvest
+        if self.optimistic and not self._fits_ahead(lanes):
+            return None
+        return lanes
+
+    def _fits_ahead(self, lanes: Dict[int, tuple]) -> bool:
+        """Optimistic admission, launching ahead: whether the pages under
+        ALL the lanes' write positions fit with what is free or evictable —
+        no victim, so no page state a program in flight depends on moves.
+        False (today's order, where `_grow_running` may preempt) also on a
+        step with injected pool pressure."""
+        mgr = self.cache
+        short = sum(max(0, mgr.pages_needed(q + 1) - mgr.pages_held(slot))
+                    for slot, (_, q, _, _) in lanes.items())
+        return short <= mgr.num_free_pages + mgr.num_evictable_pages and \
+            not self._faults.pressure_due(self._step_idx)
+
     def _fused_iter(self, chunk_job: Optional[Dict[str, object]],
-                    finished: List[RequestOutput]) -> None:
+                    finished: List[RequestOutput],
+                    lanes: Optional[Dict[int, tuple]] = None,
+                    prev: Optional[Dict[str, object]] = None) -> None:
         """Build and dispatch the ONE fused program covering every active
         lane this step: decode slots at valid=1, drafted (greedy) slots at
         valid=1+len(draft), the staged prefill chunk at valid=chunk tokens.
         Inactive/mid-prefill slots get null table rows.  The dispatch
         returns un-synced; `_harvest` interprets the token/accept buffer —
-        immediately (double_buffer=False) or at the top of the next step."""
+        immediately (double_buffer=False) or in the next step.
+
+        `lanes` / `prev` (`_plan_ahead`): a launch ahead of `prev`'s
+        harvest.  The decode lanes are the plan's, each row's token the
+        program's own to take from `prev["out"]`; the host state a lane's
+        q_offset was read from is one token behind, which the plan added.
+        Without them the lanes are the running slots as the host has them:
+        the token from the host's row (column -1), q_offset the slot's
+        length, drafts where the proposer has any."""
         mgr = self.cache
         B, T = mgr.num_slots, self._fused_T
-        if self.spec_len and self._running:
+        ahead = lanes is not None
+        drafts: Dict[int, np.ndarray] = {}
+        if not ahead and self.spec_len and self._running:
             with self._span("engine.spec.propose"):
                 drafts = self._propose_drafts()
-        else:
-            drafts = {}
-        # optimistic admission: every running slot must own pages for the
-        # positions this dispatch writes — growth failures preempt victims
-        # out of self._running (and out of drafts) before the batch is built
         with self._span("engine.batch.build"):
-            self._grow_running(drafts)
-            if not self._running and chunk_job is None:
-                return                  # everything got preempted this step
-            if self._running:
-                self._decode_iters.inc()
+            if not ahead:
+                # optimistic admission: every running slot must own pages
+                # for the positions this dispatch writes — growth failures
+                # preempt victims out of self._running (and out of drafts)
+                # before the batch is built
+                self._grow_running(drafts)
+                if not self._running and chunk_job is None:
+                    return              # everything got preempted this step
+                lanes = {slot: (-1, int(mgr.lengths[slot]), seq.greedy,
+                                seq.request.request_id)
+                         for slot, seq in self._running.items()}
             tokens = np.zeros((B, T), np.int32)
             valid = np.ones((B,), np.int32)
             qoff = np.zeros((B,), np.int32)
             greedy = np.zeros((B,), bool)
-            table = mgr.page_table.copy()
-            slots: List[int] = []
+            live = np.zeros((B,), bool)
+            from_prev = np.full((B,), -1, np.int32)
+            rids: Dict[int, int] = {}
             nds: Dict[int, int] = {}
-            chunk_slot = chunk_job["slot"] if chunk_job is not None else None
-            for slot in range(B):
-                seq = self._running.get(slot)
-                if seq is not None:
-                    slots.append(slot)
-                    tokens[slot, 0] = seq.generated[-1]
-                    qoff[slot] = mgr.lengths[slot]
-                    greedy[slot] = seq.greedy
-                    d = drafts.get(slot)
-                    if d is not None:
-                        tokens[slot, 1:1 + d.size] = d
-                        valid[slot] = 1 + d.size
-                        nds[slot] = d.size
-                elif slot == chunk_slot:
-                    st = chunk_job["st"]
-                    n = chunk_job["n"]
-                    q0 = chunk_job["q_offset"]
-                    tokens[slot, :n] = st.prompt[q0:q0 + n]
-                    valid[slot] = n
-                    qoff[slot] = q0
-                    greedy[slot] = self._req_greedy(st.request)
-                else:
-                    table[slot, :] = 0      # inactive: KV to the null page
+            for slot, (col, q, g, rid) in sorted(lanes.items()):
+                # harvested in slot order
+                from_prev[slot], qoff[slot], greedy[slot] = col, q, g
+                rids[slot] = rid
+                if col < 0:
+                    tokens[slot, 0] = self._running[slot].generated[-1]
+                d = drafts.get(slot)
+                if d is not None:
+                    tokens[slot, 1:1 + d.size] = d
+                    valid[slot] = 1 + d.size
+                    nds[slot] = d.size
+            slots = list(rids)
+            live[slots] = True
+            if slots:
+                self._decode_iters.inc()
+            if chunk_job is not None:
+                slot, st = chunk_job["slot"], chunk_job["st"]
+                n, q0 = chunk_job["n"], chunk_job["q_offset"]
+                tokens[slot, :n] = st.prompt[q0:q0 + n]
+                valid[slot] = n
+                qoff[slot] = q0
+                greedy[slot] = self._req_greedy(st.request)
+                live[slot] = True
+            table = mgr.page_table.copy()
+            table[~live] = 0            # inactive: KV to the null page
             self._note_walk(table, qoff, valid)
         with self._span("engine.fused.dispatch"):
             with self._span("engine.fused.h2d"):
                 tokens, table, qoff, valid, greedy = (
                     self._h2d(a) for a in (tokens, table, qoff, valid, greedy))
+                # every row the host's: the two device constants, no put
+                prev_out, from_prev = (prev["out"], self._h2d(from_prev)) \
+                    if ahead else (self._no_prev, self._host_rows)
             out, accept, self._pool, self._key, *aux = self._decode_fn(
                 self.params, tokens, self._pool, table, qoff, valid,
-                self._key, greedy)
-        self._turn_end(launched=True)
+                self._key, greedy, prev_out, from_prev)
+        self._turn_end(launched=True)       # adds nothing to a launch ahead
         self._decode_used = True
         self._step_dispatches += 1
         self._step_slots["verify"] += len(nds)
         self._step_slots["decode"] += len(slots) - len(nds)
         self._step_slots["chunk"] += int(chunk_job is not None)
+        self._launched_ahead.inc(int(ahead))
         if nds:
             # the fused dispatch carried >= 1 draft: it IS this step's verify
             # dispatch (the counter keeps its "verify-program dispatches"
             # meaning for timeline/bench consumers)
             self._verify_steps.inc()
-        inflight = {"out": out, "accept": accept, "aux": aux, "slots": slots,
+        inflight = {"out": out, "accept": accept, "aux": aux, "rids": rids,
                     "drafts": {s: drafts[s] for s in nds},
                     "chunk": chunk_job}
         if self.double_buffer:
@@ -1964,8 +2145,14 @@ class LLMEngine:
         with self._span("engine.emit"):
             if aux:
                 self._note_aux(aux[0])
-            for slot in inf["slots"]:
-                seq = self._running[slot]
+            for slot, rid in inf["rids"].items():
+                seq = self._running.get(slot)
+                if seq is None or seq.request.request_id != rid:
+                    # launched ahead of the harvest that ended this request
+                    # (EOS, a deadline): its write landed past what the
+                    # slot's pages publish, its token is nobody's
+                    self._ahead_discarded.inc()
+                    continue
                 d = drafts.get(slot)
                 nd = 0 if d is None else d.size
                 a = int(accept[slot])           # on-device prefix match, <= nd
@@ -2103,11 +2290,14 @@ class LLMEngine:
         (its last token's KV at lengths, plus one slot per drafted
         candidate).  A failed growth is THE preemption trigger: victims are
         evicted until the growth fits, the growing slot itself last of all
-        (it re-queues at the head and replays later).  Runs strictly after
-        the step-top harvest, so nothing is in flight while page
-        state moves (the TPL007 discipline).  `drafts` is pruned of any slot
-        that got preempted.  Reservation mode returns immediately — every
-        slot's full footprint is already reserved."""
+        (it re-queues at the head and replays later).  Runs only in a step
+        that kept the harvest-first order, strictly after the step-top
+        harvest, so nothing is in flight while a victim's page state moves
+        (the TPL007 discipline); a step that launches ahead grows its lanes
+        itself, and only where no victim is needed (`_fits_ahead`).
+        `drafts` is pruned of any slot that got preempted.  Reservation mode
+        returns immediately — every slot's full footprint is already
+        reserved."""
         if not self.optimistic or not self._running:
             return
         forced = self._faults.pool_pressure(self._step_idx)
@@ -2600,7 +2790,9 @@ class LLMEngine:
     def _expire_deadlines(self, finished: List[RequestOutput]) -> None:
         """Retire every request past its deadline, wherever it lives
         (queued/swapped, prefilling, decoding), as finish_reason="timeout".
-        Runs right after the step-top harvest so page bookkeeping is exact;
+        Runs right after the step's harvest so page bookkeeping is exact (in
+        a step that launched ahead the next program is in flight by then: a
+        slot retired here leaves a lane in it, dropped at its harvest);
         injected clock skew (FaultPlan.skew) shifts only this evaluation.
         Also re-derives `_has_deadlines` so an engine that served one
         deadlined request long ago stops paying this scan once no
@@ -2935,7 +3127,7 @@ class LLMEngine:
             self._pool, self._h2d(tbl),
             self._h2d(np.zeros((B,), np.int32)),
             self._h2d(np.ones((B,), np.int32)), self._key,
-            self._h2d(np.zeros((B,), bool)))
+            self._h2d(np.zeros((B,), bool)), self._no_prev, self._host_rows)
         self._decode_used = True
         # warmup is also where the live roofline arms: one abstract trace of
         # the decode-side program (cached; zero dispatches, zero programs)
@@ -3473,6 +3665,10 @@ class LLMEngine:
             "swap_h2d_bytes": self._h2d_bytes.value,
             "swap_h2d_useful_bytes": self._h2d_useful.value,
             "turnaround_ms": self._turnaround_ms_c.value,
+            # launches made before the last result was read, and the lanes
+            # of such launches whose request had ended meanwhile
+            "fused_launched_ahead": self._launched_ahead.value,
+            "fused_ahead_discarded_lanes": self._ahead_discarded.value,
             # recurrent configurations: the expert layers' routing account
             # and the state lanes (all 0 for a dense configuration)
             **{n: c.value for n, c in self._aux_counters.items()},

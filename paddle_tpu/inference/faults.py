@@ -59,10 +59,15 @@ class FaultPlan:
     def pool_pressure(self, step: int) -> bool:
         """True at most ONCE per listed step: the engine treats the first
         growth attempt of that step as a failed allocation."""
-        if step in self._pressure and step not in self._fired_pressure:
+        if self.pressure_due(step):
             self._fired_pressure.add(step)
             return True
         return False
+
+    def pressure_due(self, step: int) -> bool:
+        """Whether `pool_pressure(step)` would still fire (asks without
+        consuming it)."""
+        return step in self._pressure and step not in self._fired_pressure
 
     def d2h(self) -> None:
         """Called before each swap-out materialization; raises while the

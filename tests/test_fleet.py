@@ -224,11 +224,17 @@ def test_shed_when_all_replicas_overloaded(params, cfg):
             fleet.engines[l].health = h
 
 
-def test_abort_frees_pages_and_invariants(params, cfg):
-    fleet = EngineFleet(params, cfg, replicas=2, engine_kwargs=EKW)
+def test_abort_frees_pages_and_invariants():
+    # a request too long to finish before the abort lands (a 40-token one
+    # raced it and, on a quiet machine, won): nearly 2,000 steps to go when
+    # the first token shows
+    cfg = G.gpt_tiny(2048)
+    params = G.init_params(cfg, jax.random.key(0))
+    fleet = EngineFleet(params, cfg, replicas=2,
+                        engine_kwargs=dict(EKW, max_model_len=2048))
     with fleet:
         h = fleet.submit(np.arange(20, dtype=np.int32) % cfg.vocab_size,
-                         max_new_tokens=40)
+                         max_new_tokens=2000)
         # let it get in flight, then abort mid-generation
         deadline = time.monotonic() + 30.0
         while time.monotonic() < deadline:
@@ -408,8 +414,13 @@ def test_http_disconnect_aborts_and_frees_pages(door):
     """A dropped client connection must abort the in-flight request so its
     KV pages free — dead streams cannot pin pool capacity."""
     fleet = door.fleet
-    before = {l: e.stats()["aborted_requests"]
-              for l, e in fleet.engines.items()}
+    def retired():
+        # the hangup races the 48-token request to its end on a loaded
+        # worker: aborted, or finished before the abort reached it
+        return {l: e.stats()["aborted_requests"] +
+                e.stats()["finished_requests"]
+                for l, e in fleet.engines.items()}
+    before = retired()
     payload = json.dumps({"prompt": [9, 8, 7, 6, 5, 4, 3, 2],
                           "max_tokens": 48, "stream": True}).encode("utf-8")
     # raw socket: http.client hands Connection:close sockets to the
@@ -425,14 +436,14 @@ def test_http_disconnect_aborts_and_frees_pages(door):
     sock.shutdown(socket.SHUT_RDWR)
     sock.close()
     deadline = time.monotonic() + 60.0
-    aborted = False
-    while time.monotonic() < deadline and not aborted:
-        aborted = any(e.stats()["aborted_requests"] > before[l]
-                      for l, e in fleet.engines.items())
+    gone = False
+    while time.monotonic() < deadline and not gone:
+        gone = retired() != before
         time.sleep(0.05)
-    assert aborted, "disconnect never aborted the in-flight request"
+    assert gone, "disconnect never retired the in-flight request"
     assert fleet.drain(timeout=60.0)
     fleet.check_invariants()
     for eng in fleet.engines.values():
         st = eng.stats()
         assert st["running"] == 0 and st["prefilling"] == 0
+        assert st["pages_in_use"] == 0      # aborted or finished: all freed

@@ -368,3 +368,49 @@ def test_fused_jaxpr_audit_host_output_budget():
     name, fn, args, kw = targets[0]
     assert kw.get("host_output_budget")
     assert audit_jaxpr(name, fn, args, **kw) == []
+
+
+# ---------------------------------------------------------------------------
+# launch-ahead scheduling: program k+1 goes out before program k is read
+# ---------------------------------------------------------------------------
+
+from conftest import AHEAD_CASES, ahead_parity_case     # noqa: E402
+
+
+@pytest.mark.parametrize("case", sorted(AHEAD_CASES))
+def test_launch_ahead_serves_the_synchronous_schedules_tokens(tiny, case):
+    """`double_buffer=True` launches the next fused program before it reads
+    the last one's tokens wherever the next batch is predictable; what is
+    served is the synchronous schedule's, token for token, in every mode."""
+    cfg, params = tiny
+
+    eng, outs = ahead_parity_case(cfg, params, case, cfg.vocab_size)
+    st = eng.stats()
+    share = st["fused_launched_ahead"] / st["decode_iterations"]
+    if case == "steady_full_batch":
+        # nothing to admit, nothing to draft: every launch but the first
+        assert share >= 0.9 and st["fused_ahead_discarded_lanes"] == 0
+        assert all(o.finish_reason == "length" for o in outs)
+    elif case in ("eos_mid_batch", "chunked_eos"):
+        # a lane is dropped exactly where the host could not foresee the end:
+        # an EOS a fused program produced, short of the request's budget
+        # (a chunked prompt's FIRST token is a fused program's too)
+        first = 1 if case == "chunked_eos" else 2
+        stops = [o for o in outs if o.finish_reason == "stop"]
+        assert stops and st["fused_ahead_discarded_lanes"] == sum(
+            first <= len(o.token_ids) < b for o, b in zip(
+                outs, (12, 15)) if o.finish_reason == "stop")
+    elif case == "spec3":
+        assert st["spec_accepted_tokens"] > 0
+    elif case == "optimistic_forced_preemption":
+        assert st["preemptions"] >= 1
+    elif case == "deadline_in_flight":
+        assert [o.finish_reason for o in outs].count("timeout") == 1
+        assert st["fused_ahead_discarded_lanes"] == 1
+    elif case == "abort_in_flight":
+        assert outs[0].finish_reason == "abort" and outs[0].token_ids
+    if case not in ("spec3",):
+        assert st["fused_launched_ahead"] > 0
+    ring = eng.step_trace()
+    assert sum(r["ahead"] for r in ring) == st["fused_launched_ahead"]
+    assert all(r["turnaround_ms"] == 0.0 for r in ring if r["ahead"])

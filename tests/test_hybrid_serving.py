@@ -426,3 +426,29 @@ def test_dense_block_with_an_explicit_head_dim():
     logits = np.asarray(gpt.forward(params, jnp.asarray(seq[None]), cfg))[0]
     picks = logits[prompt.size - 1:-1].argmax(-1)
     assert picks.tolist() == out.token_ids
+
+
+# ---- launch-ahead scheduling over the state lanes ----------------------------
+
+from conftest import AHEAD_CASES, ahead_parity_case     # noqa: E402
+
+
+@pytest.mark.parametrize("case", sorted(
+    set(AHEAD_CASES) - {"spec3"}))          # refused for a pattern
+def test_launch_ahead_serves_the_synchronous_schedules_tokens(case):
+    """The Nemotron-tiny pattern under `double_buffer=True` against the
+    synchronous schedule: a lane launched for a request that had ended moves
+    a state nobody reads again (zeroed at the slot's next admission)."""
+    cfg, params, _ = setup()
+
+    eng, outs = ahead_parity_case(cfg, params, case, 256)
+    st = eng.stats()
+    assert st["fused_launched_ahead"] > 0
+    assert eng.cache.state.live == set()
+    if case == "steady_full_batch":
+        assert st["fused_launched_ahead"] >= 0.9 * st["decode_iterations"]
+    if case in ("eos_mid_batch", "chunked_eos", "deadline_in_flight"):
+        assert st["fused_ahead_discarded_lanes"] >= 1
+    # a request admitted into a slot whose last owner left a stray lane
+    # behind starts from a zero state: one reset an admission
+    assert st["ssm_state_resets"] == len(outs) + st["preemptions"]

@@ -483,3 +483,25 @@ def test_only_a_pattern_with_recurrent_state_is_recurrent():
     eng = LLMEngine(gpt.init_params(dense, jax.random.key(0)), dense,
                     num_slots=2, page_size=8, max_model_len=64)
     assert not eng.patterned and not eng.recurrent
+
+
+# ---- launch-ahead scheduling over latent pages -------------------------------
+
+from conftest import AHEAD_CASES, ahead_parity_case     # noqa: E402
+
+
+@pytest.mark.parametrize("case", sorted(
+    set(AHEAD_CASES) - {"spec3"}))          # refused for a pattern
+def test_launch_ahead_serves_the_synchronous_schedules_tokens(case):
+    """The xing4-tiny pattern under `double_buffer=True` against the
+    synchronous schedule: a stray lane's latent row lands past what the
+    slot's pages publish to the prefix index."""
+    cfg, params, _ = setup()
+
+    eng, outs = ahead_parity_case(cfg, params, case, 256)
+    st = eng.stats()
+    assert st["fused_launched_ahead"] > 0
+    if case == "steady_full_batch":
+        assert st["fused_launched_ahead"] >= 0.9 * st["decode_iterations"]
+    if case in ("eos_mid_batch", "chunked_eos", "deadline_in_flight"):
+        assert st["fused_ahead_discarded_lanes"] >= 1

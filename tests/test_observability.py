@@ -327,7 +327,7 @@ def test_chrome_trace_and_step_timeline(spec_eng, tmp_path):
                 "tokens_emitted", "pages_in_use", "pages_free",
                 "pages_evictable", "queued", "running", "prefilling",
                 "v", "dispatches", "sync_ms", "slots",
-                "turnaround_ms", "d2h_ms", "pages_walked"):
+                "turnaround_ms", "d2h_ms", "pages_walked", "ahead"):
         assert key in timeline[-1]
     assert any(r["tokens_emitted"] > 0 for r in timeline)
     snap = json.loads((td / "metrics.json").read_text())
@@ -511,6 +511,10 @@ NEW_STATS_KEYS = frozenset({
     # flight
     "swap_d2h_blocked_ms", "swap_d2h_landed_free",
     "swap_d2h_backpressure_waits", "swap_d2h_inflight_pages",
+}) | frozenset({
+    # added by the launch-ahead PR (ISSUE 33): fused launches made before
+    # the previous result was read, and their lanes dropped at harvest
+    "fused_launched_ahead", "fused_ahead_discarded_lanes",
 })
 
 

@@ -79,8 +79,23 @@ def inside(child, parents):
     return any(p[1] <= child[1] and child[2] <= p[2] for p in parents)
 
 
+def steps_of(recorded, ahead):
+    """The `engine.step` spans that launched a fused program, those that
+    opened an `engine.turnaround` (today's order) or those that did not (the
+    launch went out ahead of the last result)."""
+    turns = [e for e in recorded if e[0] == "engine.turnaround"]
+    launches = [e for e in recorded if e[0] == "engine.fused.dispatch"]
+    return [s for s in recorded if s[0] == "engine.step"
+            and any(inside(d, [s]) for d in launches)
+            and ahead != any(inside(t, [s]) for t in turns)]
+
+
 @pytest.mark.parametrize("parent,child", [
     ("engine.step", "engine.turnaround"),
+    ("engine.step", "engine.emit"),
+    ("engine.step", "engine.batch.build"),
+    ("engine.step", "engine.fused.dispatch"),
+    ("engine.step", "engine.sample.sync"),
     ("engine.turnaround", "engine.emit"),
     ("engine.turnaround", "engine.admit"),
     ("engine.turnaround", "engine.batch.build"),
@@ -93,13 +108,33 @@ def test_spans_nest(recorded, parent, child):
     parents = [e for e in recorded if e[0] == parent]
     children = [e for e in recorded if e[0] == child]
     assert parents and children
-    if child == "engine.admit":
-        # admission also runs in steps that launch nothing
-        children = [c for c in children
-                    if inside(c, [e for e in recorded
-                                  if e[0] == "engine.turnaround"])]
+    if parent == "engine.turnaround":
+        # the stretch the device waits for exists in the steps that kept
+        # today's order (and admission also runs in steps that launch
+        # nothing); a step that launched ahead has no such stretch
+        kept = steps_of(recorded, ahead=False) if child != "engine.admit" \
+            else [s for s in recorded if s[0] == "engine.step"
+                  and any(inside(t, [s]) for t in parents)]
+        children = [c for c in children if inside(c, kept)]
         assert children
     assert all(inside(c, parents) for c in children)
+
+
+def test_a_step_that_launched_ahead_has_no_turnaround(recorded):
+    """Launch first, then read: build, the puts and the launch, then the
+    fetch of the LAST program's tokens and their emission, all under
+    `engine.step`; no `engine.turnaround` and no admission in such a step."""
+    ahead = steps_of(recorded, ahead=True)
+    assert ahead and steps_of(recorded, ahead=False)
+    for s in ahead:
+        kids = sorted((e for e in recorded if e is not s and inside(e, [s])),
+                      key=lambda e: e[1])
+        names = [e[0] for e in kids if e[0] in (
+            "engine.batch.build", "engine.fused.dispatch",
+            "engine.sample.sync", "engine.emit", "engine.admit",
+            "engine.turnaround")]
+        assert names == ["engine.batch.build", "engine.fused.dispatch",
+                         "engine.sample.sync", "engine.emit"]
 
 
 def test_spans_tile_the_turnaround_and_are_all_named(recorded):
@@ -177,12 +212,66 @@ def test_ring_carries_turnaround_and_d2h(tiny, mode):
     assert st["turnaround_ms"] == pytest.approx(
         eng.metrics.snapshot()["counters"]["turnaround_ms"])
     launched = [r for r in ring if r["decode_batch"] or r["slots"]["chunk"]]
-    assert launched and all(r["turnaround_ms"] > 0 for r in ring
-                            if r["slots"]["decode"] or r["slots"]["verify"])
+    # a launch in today's order has the device wait for the host's stretch;
+    # one made ahead of the last result (`ahead`) has not
+    assert launched and all((r["turnaround_ms"] > 0) != r["ahead"]
+                            for r in launched)
+    assert st["fused_launched_ahead"] == sum(r["ahead"] for r in ring)
+    if not eng.double_buffer:
+        assert st["fused_launched_ahead"] == 0
+    elif not eng.spec_len:
+        assert st["fused_launched_ahead"] > 0
     # the fetches' time lands in the step that drained them
     assert st["swap_d2h_fetches"] > 0
     assert sum(r["d2h_ms"] for r in ring) > 0
     assert sum(r["d2h_ms"] for r in ring) <= st["swap_ms"] + 1e-6
+
+
+@pytest.mark.parametrize("mode", [
+    dict(), dict(double_buffer=False), dict(prefill_chunk=None),
+    dict(prefill_chunk=None, eos_token_id="drawn", temperature=0.9)])
+def test_launch_ahead_counters_reach_every_surface(tiny, mode):
+    """`fused_launched_ahead` and `fused_ahead_discarded_lanes` in stats(),
+    the registry snapshot and the exposition; the ring's `ahead` adds up to
+    the first, and `turnaround_ms` counts only the steps that kept today's
+    order."""
+    def run(**mode):
+        eng = engine(tiny, clock=TickClock(), num_pages=17, **mode)
+        rng = np.random.RandomState(5)
+        for n in (6, 11):
+            eng.add_request(rng.randint(1, 64, (n,)).astype(np.int32),
+                            max_new_tokens=24)
+        return eng, eng.run()
+
+    if mode.get("eos_token_id") == "drawn":
+        # the EOS is a token the sampled stream holds a few tokens in
+        said = run(**dict(mode, eos_token_id=None))[1][0].token_ids
+        mode = dict(mode, eos_token_id=next(
+            t for i, t in enumerate(said) if i >= 3 and t not in said[:i]))
+    eng, outs = run(**mode)
+    st, ring = eng.stats(), eng.step_trace()
+    snap = eng.metrics.snapshot()["counters"]
+    text = eng.metrics.to_prometheus()
+    for name in ("fused_launched_ahead", "fused_ahead_discarded_lanes"):
+        assert st[name] == snap[name]
+        assert f"llm_engine_{name}" in text
+    assert st["fused_launched_ahead"] == sum(r["ahead"] for r in ring)
+    assert st["turnaround_ms"] == pytest.approx(
+        sum(r["turnaround_ms"] for r in ring if not r["ahead"]))
+    if eng.double_buffer:
+        # two requests on two slots, nothing queued: every launch but the
+        # first goes out ahead
+        assert st["fused_launched_ahead"] >= st["decode_iterations"] - 1
+    else:
+        assert st["fused_launched_ahead"] == 0
+    stops = sum(o.finish_reason == "stop" and len(o.token_ids) > 1
+                for o in outs.values())
+    assert st["fused_ahead_discarded_lanes"] == \
+        (stops if eng.double_buffer else 0)
+    if eng.eos_token_id is not None:
+        assert stops                                    # the case bites
+    eng.reset_counters()
+    assert eng.stats()["fused_launched_ahead"] == 0
 
 
 @pytest.mark.parametrize("direction", ["d2h", "h2d"])

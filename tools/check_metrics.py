@@ -118,6 +118,9 @@ REQUIRED_STATS_KEYS = frozenset({
     # latent-pages PR (ISSUE 32): rows absorbed attention read and its query
     # tokens, and the latent lane's bytes a page (0 without latent layers)
     "latent_tokens_written", "mla_absorbed_rows", "latent_page_bytes",
+    # launch-ahead PR (ISSUE 33): fused launches made before the previous
+    # program's result was read, and the lanes of those dropped at harvest
+    "fused_launched_ahead", "fused_ahead_discarded_lanes",
 })
 REQUIRED_KV_TIER_KEYS = frozenset({
     "enabled", "spill_dir", "pages_host", "pages_disk", "spills",
@@ -173,6 +176,8 @@ REQUIRED_COUNTERS = frozenset({
     "prefix_lookups_skipped_no_state",
     # paged-walk PR (ISSUE 29): how far the kernel's bounded walk engages
     "paged_pages_walked", "paged_table_entries",
+    # launch-ahead PR (ISSUE 33)
+    "fused_launched_ahead", "fused_ahead_discarded_lanes",
 })
 # the v2 step-ring record (`step_trace()`, /debug's "step_trace")
 REQUIRED_STEP_RECORD_KEYS = frozenset({
@@ -181,7 +186,7 @@ REQUIRED_STEP_RECORD_KEYS = frozenset({
     "finished", "pages_in_use", "pages_free", "pages_evictable",
     "dispatches", "sync_ms", "turnaround_ms", "d2h_ms", "slots", "preempted",
     "pool_pressure", "moe_pairs_here", "moe_pairs_away", "moe_experts_touched",
-    "pages_walked", "latent_tokens_written",
+    "pages_walked", "latent_tokens_written", "ahead",
 })
 REQUIRED_DEBUG_BUNDLE_KEYS = frozenset({
     "version", "t", "engine", "pool", "requests", "step_trace", "stats",
