@@ -138,16 +138,23 @@ def _pick_block(S: int, pref: int) -> int:
     return S
 
 
+def _kernel_name(base: str, D: int, Dv: int) -> str:
+    """The dense kernels keep their names; a score width that differs from
+    the value width (latent attention expanded: 192 against 128) gets names
+    of its own, so that a trace tells the two apart."""
+    return f"flash_{base}" if D == Dv else f"flash_mla_{base}"
+
+
 def _flash_fwd_impl(q, k, v, causal, scale):
-    """[B,S,H,D] -> (out [B,S,H,D], lse [B*H, S, 1] f32)."""
+    """q, k [B,S,H,D], v [B,S,H,Dv] -> (out [B,S,H,Dv], lse [B*H, S, 1] f32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, S, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[-1]
     qt = jnp.transpose(q, (0, 2, 1, 3)).reshape(B * H, S, D)
     kt = jnp.transpose(k, (0, 2, 1, 3)).reshape(B * H, Sk, D)
-    vt = jnp.transpose(v, (0, 2, 1, 3)).reshape(B * H, Sk, D)
+    vt = jnp.transpose(v, (0, 2, 1, 3)).reshape(B * H, Sk, Dv)
 
     block_q = _pick_block(S, FWD_BLOCK)
     block_k = _pick_block(Sk, FWD_BLOCK)
@@ -157,30 +164,30 @@ def _flash_fwd_impl(q, k, v, causal, scale):
                                n_k=n_k, causal=causal, scale=scale)
     out, lse = pl.pallas_call(
         kernel,
-        name="flash_fwd",
+        name=_kernel_name("fwd", D, Dv),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            _out_struct((B * H, S, D), q.dtype, qt, kt, vt),
+            _out_struct((B * H, S, Dv), q.dtype, qt, kt, vt),
             _out_struct((B * H, S, 1), jnp.float32, qt, kt, vt),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qt, kt, vt)
-    return jnp.transpose(out.reshape(B, H, S, D), (0, 2, 1, 3)), lse
+    return jnp.transpose(out.reshape(B, H, S, Dv), (0, 2, 1, 3)), lse
 
 
 # ---------------------------------------------------------------------------
@@ -275,19 +282,20 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
 
 def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
-    """Tiled dq/dk/dv.  q,k,v,out,g: [B,S,H,D]; lse: [B*H,S,1] f32."""
+    """Tiled dq/dk/dv.  q, k: [B,S,H,D]; v, out, g: [B,S,H,Dv]; lse:
+    [B*H,S,1] f32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, S, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[-1]
     qt = jnp.transpose(q, (0, 2, 1, 3)).reshape(B * H, S, D)
     kt = jnp.transpose(k, (0, 2, 1, 3)).reshape(B * H, Sk, D)
-    vt = jnp.transpose(v, (0, 2, 1, 3)).reshape(B * H, Sk, D)
-    dot = jnp.transpose(g, (0, 2, 1, 3)).reshape(B * H, S, D)
+    vt = jnp.transpose(v, (0, 2, 1, 3)).reshape(B * H, Sk, Dv)
+    dot = jnp.transpose(g, (0, 2, 1, 3)).reshape(B * H, S, Dv)
     # delta_i = rowsum(dO_i * O_i) — the only residual beyond lse (cheap XLA fuse)
     delta = jnp.sum(dot.astype(jnp.float32) *
-                    jnp.transpose(out, (0, 2, 1, 3)).reshape(B * H, S, D)
+                    jnp.transpose(out, (0, 2, 1, 3)).reshape(B * H, S, Dv)
                     .astype(jnp.float32), axis=-1, keepdims=True)  # [BH,S,1]
 
     block_q = _pick_block(S, BWD_BLOCK)
@@ -300,27 +308,27 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
         causal=causal, scale=scale)
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        name="flash_bwd_dkv",
+        name=_kernel_name("bwd_dkv", D, Dv),
         grid=(B * H, n_k, n_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),   # q
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),   # k
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),   # v
-            pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),   # dO
+            pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0)),  # v
+            pl.BlockSpec((1, block_q, Dv), lambda b, j, i: (b, i, 0)),  # dO
             pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),   # lse
             pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),   # delta
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
             _out_struct((B * H, Sk, D), k.dtype, qt, kt, vt),
-            _out_struct((B * H, Sk, D), v.dtype, qt, kt, vt),
+            _out_struct((B * H, Sk, Dv), v.dtype, qt, kt, vt),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -331,13 +339,13 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
         causal=causal, scale=scale)
     dq = pl.pallas_call(
         dq_kernel,
-        name="flash_bwd_dq",
+        name=_kernel_name("bwd_dq", D, Dv),
         grid=(B * H, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # q
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # k
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # v
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # dO
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),  # v
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),  # dO
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),   # lse
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),   # delta
         ],
@@ -348,7 +356,8 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qt, kt, vt, dot, lse, delta)
 
-    tr = lambda x, L: jnp.transpose(x.reshape(B, H, L, D), (0, 2, 1, 3))
+    tr = lambda x, L: jnp.transpose(x.reshape(B, H, L, x.shape[-1]),
+                                    (0, 2, 1, 3))
     return tr(dq, S), tr(dk, Sk), tr(dv, Sk)
 
 
@@ -396,7 +405,8 @@ def _flash_fwd(q, k, v, causal, scale, shard):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_attention_core(q, k, v, causal, scale, shard=None):
-    """[B, S, H, D] in/out; Pallas forward AND backward."""
+    """q, k [B, S, H, D], v and the result [B, S, H, Dv]; Pallas forward
+    AND backward."""
     out, _ = _flash_fwd(q, k, v, causal, scale, shard)
     return out
 
@@ -407,12 +417,21 @@ def _flash_core_fwd(q, k, v, causal, scale, shard):
     # 'flash_lse')) saves exactly these: the replay then recomputes q/k/v via the
     # cheap qkv matmul but never re-runs the attention kernel
     out = checkpoint_name(out, "flash_out")
+    if q.shape[-1] != v.shape[-1]:
+        # kept without its unit minor dimension: [.., S, 1] float32 lies in
+        # HBM as 128 lanes a row, 0.5 GB a layer at 4 x 32 heads x 8192
+        # positions, and a checkpoint policy keeps it for every layer.  (The
+        # one-width kernels' residual is left as it is: the dense cell's
+        # program is not this PR's to change.)
+        lse = lse[..., 0]
     lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 
 def _flash_core_bwd(causal, scale, shard, res, g):
     q, k, v, out, lse = res
+    if q.shape[-1] != v.shape[-1]:
+        lse = lse[..., None]
     if shard is None:
         return _flash_bwd_impl(q, k, v, out, lse, g, causal, scale)
 
@@ -435,10 +454,15 @@ remat_policy_save_attention = functools.partial(
     "flash_out", "flash_lse", "flash_qkv")
 
 
-def _shapes_ok_for_pallas(q, k):
+# (score width, value width) pairs the kernels take: one width for q, k and
+# v, or latent attention's expanded 128 nope + 64 rope against 128 values
+_WIDTHS = ((64, 64), (128, 128), (256, 256), (192, 128))
+
+
+def _shapes_ok_for_pallas(q, k, v):
     B, S, H, D = q.shape
     Sk = k.shape[1]
-    if D not in (64, 128, 256):
+    if (D, v.shape[-1]) not in _WIDTHS:
         return False
     if S < 128 or Sk < 128:
         return False
@@ -449,7 +473,8 @@ def _shapes_ok_for_pallas(q, k):
 
 def flash_attention_fused(q, k, v, mask=None, causal=False, scale=None,
                           dropout_p=0.0, shard=None):
-    """Entry used by incubate fused ops.  q,k,v: [B, S, H, D].
+    """Entry used by incubate fused ops.  q, k: [B, S, H, D]; v (and the
+    result): [B, S, H, Dv], Dv = D except for the pair (192, 128).
 
     shard: `(mesh, axis_names, qkv_spec)` from a caller whose step is
     partitioned over those mesh axes — the Pallas kernels then run per shard
@@ -457,7 +482,7 @@ def flash_attention_fused(q, k, v, mask=None, causal=False, scale=None,
     D = q.shape[-1]
     s = scale if scale is not None else 1.0 / math.sqrt(D)
     if (mask is None and dropout_p == 0.0 and _on_tpu()
-            and _shapes_ok_for_pallas(q, k)):
+            and _shapes_ok_for_pallas(q, k, v)):
         return _flash_attention_core(q, k, v, causal, s, shard)
     key = None
     if dropout_p > 0.0:
@@ -781,7 +806,8 @@ def flash_attention_varlen(q, k, v, segment_ids, kv_segment_ids=None,
     D = q.shape[-1]
     s = scale if scale is not None else 1.0 / math.sqrt(D)
     seg_k = segment_ids if kv_segment_ids is None else kv_segment_ids
-    if _on_tpu() and _shapes_ok_for_pallas(q, k):
+    if _on_tpu() and q.shape[-1] == v.shape[-1] and \
+            _shapes_ok_for_pallas(q, k, v):
         return _flash_attention_seg_core(q, k, v, segment_ids, seg_k,
                                          causal, s)
     return attention_xla_segmented(q, k, v, segment_ids, seg_k, causal, s)

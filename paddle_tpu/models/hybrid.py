@@ -1,4 +1,4 @@
-"""Patterned language models for serving: every letter of a pattern is ONE
+"""Patterned language models, served and trained: every letter of a pattern is ONE
 mixer — `M` a Mamba-2 state-space layer, `E` a dropless mixture of experts
 with a shared expert (relu^2, or gated where its tree holds gate matrices),
 `*` attention with no position term, `L` latent attention (MLA: low-rank
@@ -40,19 +40,30 @@ by `config.layer_pattern`.  What differs:
   (`AUX_FIELDS`) of expert-routing and state counters that the engine fetches
   in the same `device_get` as the tokens.
 
-Training a patterned configuration is not supported (the trainer refuses it).
+Training (`train_loss`, reached through `parallel.HybridParallelTrainer`) walks
+the SAME parameter tree with no cache and no page table, for patterns of `L`,
+`F` and `E` with one residual stream: a latent layer's projections are
+`latent_qkv`'s, as served, and its attention takes the EXPANDED form (k =
+[W^K c ; k_rope], v = W^V c, causal, through the flash kernels at a score
+width of nope + rope against a value width of its own); the expert layer is
+the dropless one, differentiated.  `num_nextn_predict_layers` = 1 adds the
+DeepSeek-V3 multi-token-prediction module (`params["mtp"]`) and its loss.
+`M` (the chunked scan has no backward) and `hc_mult` > 1 are refused by the
+trainer.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from ..incubate.distributed.models.moe.serve import COUNTERS as MOE_COUNTERS
-from ..incubate.distributed.models.moe.serve import moe_serve
+from ..incubate.distributed.models.moe.dropless import COUNTERS as MOE_COUNTERS
+from ..incubate.distributed.models.moe.dropless import TRAIN_COUNTERS, moe_dropless
+from ..incubate.kernels.grouped_matmul import TRAIN_ROW_TILE
 from ..incubate.kernels.flash_attention import flash_attention_fused
 from ..incubate.kernels.paged_attention import paged_latent_attention
 from ..incubate.kernels.rope import apply_rope
@@ -61,6 +72,9 @@ from . import gpt as gpt_mod
 
 KINDS = {"M": "mamba", "E": "experts", "*": "attention", "L": "mla",
          "F": "ffn"}
+# the next-n module's one layer: latent attention, then experts (the family's
+# module is a whole decoder layer of the kind the model's last layers are)
+MTP_PATTERN = "LE"
 AUX_FIELDS = MOE_COUNTERS + ("ssm_slots_live", "ssm_state_resets",
                              "latent_tokens_written", "mla_absorbed_rows")
 
@@ -116,6 +130,12 @@ class HybridConfig(gpt_mod.GPTConfig):
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: tuple = (-30.0, 30.0)
+    # training only: multi-token-prediction modules behind the main model (0
+    # or 1; DeepSeek-V3, arXiv:2412.19437 section 2.2) and the weight of their
+    # loss; the step of the router's bias rule (`router_bias_step`)
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
+    router_bias_update_rate: float = 0.001
 
     def __post_init__(self):
         super().__post_init__()
@@ -133,6 +153,8 @@ class HybridConfig(gpt_mod.GPTConfig):
             raise ValueError("experts held must lie inside the router's range")
         if self.mamba_num_heads % self.mamba_n_groups:
             raise ValueError("mamba_n_groups must divide mamba_num_heads")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError("num_nextn_predict_layers is 0 or 1")
 
     @property
     def d_inner(self) -> int:
@@ -207,6 +229,19 @@ def latent_tiny(seq_len=128, pattern="LFLELE", **kw):
                         moe_gated=True, rms_norm_eps=1e-6, **kw)
 
 
+def joyai_tiny(seq_len=128, pattern="LFLELE", **kw):
+    """A JoyAI-LLM-Flash-shaped toy: `latent_tiny` with one plain residual
+    stream, no rotary scaling, a router wider than the experts held, and the
+    next-token-but-one module."""
+    kw.setdefault("rope_scaling", None)
+    kw.setdefault("hc_mult", 1)
+    kw.setdefault("num_nextn_predict_layers", 1)
+    kw.setdefault("n_routed_experts", 16)
+    kw.setdefault("experts_here", 4)
+    kw.setdefault("routed_scaling_factor", 2.5)
+    return latent_tiny(seq_len, pattern, **kw)
+
+
 def hybrid_tiny(seq_len=128, pattern="MEM*E", **kw):
     return HybridConfig(vocab_size=256, hidden_size=64, num_layers=len(pattern),
                         num_heads=4, num_kv_heads=2, head_dim=32,
@@ -236,8 +271,8 @@ def init_params(config: HybridConfig, key) -> Dict[str, Any]:
 
     bound = 1.0 / math.sqrt(c.conv_kernel)      # a depthwise Conv1d's default
     n = c.hc_mult
-    layers = []
-    for letter in c.layer_pattern:
+
+    def layer(letter):
         lp = {"norm_w": jnp.ones((D,), c.dtype)}
         if n > 1:
             # the streams' mixes: phi so that x~ phi is of order one, a
@@ -299,10 +334,23 @@ def init_params(config: HybridConfig, key) -> Dict[str, Any]:
         else:
             lp.update(qkv_w=normal((D, c.qkv_dim), std),
                       proj_w=normal((c.num_heads * c.head_dim, D), proj))
-        layers.append(lp)
-    return {"wte": normal((c.vocab_size, D), std), "layers": layers,
-            "lnf_w": jnp.ones((D,), c.dtype),
-            "lm_head": normal((D, c.vocab_size), std)}
+        return lp
+
+    layers = [layer(letter) for letter in c.layer_pattern]
+    params = {"wte": normal((c.vocab_size, D), std), "layers": layers,
+              "lnf_w": jnp.ones((D,), c.dtype),
+              "lm_head": normal((D, c.vocab_size), std)}
+    if c.num_nextn_predict_layers:
+        # the next-n module's own keys, so that a configuration without it
+        # draws what it always drew
+        keys = iter(jax.random.split(jax.random.fold_in(key, 1), 32))
+        params["mtp"] = {
+            "hnorm_w": jnp.ones((D,), c.dtype),
+            "enorm_w": jnp.ones((D,), c.dtype),
+            "eh_proj": normal((2 * D, D), std),
+            "layers": [layer(letter) for letter in MTP_PATTERN],
+            "norm_w": jnp.ones((D,), c.dtype)}
+    return params
 
 
 def init_paged_cache(config: HybridConfig, num_pages: int, page_size: int,
@@ -511,8 +559,8 @@ def _walk(params, x, cache, c: HybridConfig, real, mamba, attention, latent):
             if letter == "M":
                 y, cache = mamba(lp, h, cache, i)
             elif letter == "E":
-                y, ctr = moe_serve(lp, h.reshape(B * T, D), c,
-                                   real.reshape(B * T))
+                y, ctr = moe_dropless(lp, h.reshape(B * T, D), c,
+                                      real.reshape(B * T))
                 y = y.reshape(B, T, D)
                 counters.append(ctr)
             elif letter == "F":
@@ -701,3 +749,187 @@ def serve_step_paged(params, tokens, cache, page_table, q_offset, valid,
     # configuration: a rejected draft would need the state rolled back)
     accept = jnp.zeros((B,), jnp.int32)
     return out, accept, cache, key, aux
+
+
+# ---------------------------------------------------------------------------
+# training: the same parameter tree, no cache, no page table
+# ---------------------------------------------------------------------------
+
+# Held token-expert pairs an expert layer gathers in a training step, as a
+# multiple of what a router with even loads sends here (N k E_here / E_total):
+# the layer's static `pair_bound`.  Pairs past it are counted, never hidden.
+TRAIN_PAIR_SLACK = 2.0
+# rows of the head's logits alive at once in the loss
+LOSS_BLOCK = 4096
+
+
+def train_pair_bound(n_tokens: int, c: HybridConfig) -> int:
+    pairs = n_tokens * c.num_experts_per_tok
+    even = pairs * c.experts_here / c.n_routed_experts
+    return min(pairs, -(-int(TRAIN_PAIR_SLACK * even) // TRAIN_ROW_TILE)
+               * TRAIN_ROW_TILE)
+
+
+def latent_attention_expanded(lp, q_nope, q_rope, row, c: HybridConfig):
+    """Causal attention of a whole sequence in the EXPANDED form, and the
+    output projection: k_h = [W^K_h c ; k_rope] (the rotated key shared by
+    all heads), v_h = W^V_h c, from `latent_qkv`'s results; the score is
+    nope + rope wide, the values `v_head_dim`."""
+    B, T, H, _ = q_nope.shape
+    C, R = c.kv_lora_rank, c.qk_rope_head_dim
+    with jax.named_scope("mla.kv"):
+        ckv, k_r = row[..., :C], row[..., C:C + R]
+        k_nope = jnp.einsum("btc,hnc->bthn", ckv, lp["kv_b_k_w"])
+        v = jnp.einsum("btc,hcv->bthv", ckv, lp["kv_b_v_w"])
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_r[:, :, None, :], (B, T, H, R))],
+            axis=-1)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    with jax.named_scope("mla.attn"):
+        o = flash_attention_fused(q, k, v, causal=True,
+                                  scale=c.mla_softmax_scale)
+    with jax.named_scope("mla.out"):
+        return jnp.matmul(o.reshape(B, T, H * c.v_head_dim), lp["o_w"])
+
+
+def _train_layer(letter: str, c: HybridConfig, lp, x):
+    """One mixer of the training walk with its plain residual: x [B, S, D]
+    -> (x', the expert layer's counters or {})."""
+    B, S, D = x.shape
+    ctr = {}
+    with jax.named_scope(KINDS[letter]):
+        h = _norm(x, lp["norm_w"], c)
+        if letter == "L":
+            pos = jnp.broadcast_to(jnp.arange(S), (B, S))
+            y = latent_attention_expanded(lp, *latent_qkv(lp, h, pos, c), c)
+        elif letter == "F":
+            y = ffn_mixer(lp, h)
+        elif letter == "E":
+            y, ctr = moe_dropless(lp, h.reshape(B * S, D), c,
+                                  jnp.ones((B * S,), bool),
+                                  pair_bound=train_pair_bound(B * S, c),
+                                  row_tile=TRAIN_ROW_TILE)
+            y = y.reshape(B, S, D)
+        else:
+            raise ValueError(f"no training pass for a {KINDS[letter]} layer")
+        return x + y, ctr
+
+
+def _train_walk(pattern: str, layers, x, c: HybridConfig, remat: bool):
+    """(x after the layers, [counters of each E layer])."""
+    from ..incubate.kernels.flash_attention import remat_policy_save_attention
+    counters = []
+    for letter, lp in zip(pattern, layers):
+        fn = functools.partial(_train_layer, letter, c)
+        if remat:
+            fn = jax.checkpoint(fn, policy=remat_policy_save_attention())
+        x, ctr = fn(lp, x)
+        if ctr:
+            counters.append(ctr)
+    return x, counters
+
+
+def _blocked_ce(x, norm_w, head, labels, c: HybridConfig):
+    """Mean cross-entropy (float32) of head(RMSNorm(x)) against labels
+    [B, S] (< 0: ignored), `LOSS_BLOCK` positions at a time under
+    `jax.checkpoint`: the logits never stand whole, forward or backward."""
+    D = x.shape[-1]
+    rows, lab = x.reshape(-1, D), labels.reshape(-1)
+    n = rows.shape[0]
+    block = LOSS_BLOCK if n % LOSS_BLOCK == 0 else n
+
+    def body(carry, xl):
+        xx, ll = xl
+        logits = jnp.matmul(_norm(xx, norm_w, c), head,
+                            preferred_element_type=jnp.float32)
+        ls, cnt = gpt_mod._ce_sums(logits, ll)
+        return (carry[0] + ls, carry[1] + cnt), None
+
+    zero = jnp.zeros((), jnp.float32)
+    (ls, cnt), _ = jax.lax.scan(
+        jax.checkpoint(body), (zero, zero),
+        (rows.reshape(-1, block, D), lab.reshape(-1, block)))
+    return ls / jnp.maximum(cnt, 1.0)
+
+
+def mtp_labels(labels):
+    """The next-n module's targets from the main model's labels [B, S]
+    (labels[i] = token i + 1): position i predicts token i + 2 = labels[i +
+    1], from the embedding of labels[i].  Ignored (-100) where either is
+    missing: the last position always, and wherever labels are ignored —
+    so labels that end in an ignored position mask the last two."""
+    nxt = jnp.concatenate([labels[:, 1:],
+                           jnp.full_like(labels[:, :1], -100)], axis=1)
+    return jnp.where((labels >= 0) & (nxt >= 0), nxt, -100)
+
+
+def train_loss(params, tokens, labels, config: HybridConfig,
+               remat: bool = False):
+    """The training loss of a pattern of `L`, `F`, `E` with one residual
+    stream, over the tree `init_params` builds and the engine serves:
+    CE(main) + mtp_loss_weight x CE(next-n module), both float32.
+
+    Returns (loss, aux): aux["loss_main"], aux["loss_mtp"] (nought without
+    the module), aux["moe"] = {counter: [expert layers] int32} over the main
+    model's expert layers and then the module's (`TRAIN_COUNTERS`), and
+    aux["load"] [expert layers, E_total]: the pairs each of the router's
+    experts was chosen for (what `router_bias_step` reads)."""
+    c = config
+    head = gpt_mod.head_matrix(params, c)
+    x = gpt_mod._embed(params, tokens, c)
+    x, counters = _train_walk(c.layer_pattern, params["layers"], x, c, remat)
+    with jax.named_scope("loss"):
+        loss_main = _blocked_ce(x, params["lnf_w"], head, labels, c)
+    loss_mtp = jnp.zeros((), jnp.float32)
+    if c.num_nextn_predict_layers:
+        m = params["mtp"]
+        with jax.named_scope("mtp"):
+            nxt = gpt_mod._embed(params, jnp.maximum(labels, 0), c)
+            h = jnp.matmul(jnp.concatenate(
+                [_norm(x, m["hnorm_w"], c), _norm(nxt, m["enorm_w"], c)],
+                axis=-1), m["eh_proj"])
+            h, more = _train_walk(MTP_PATTERN, m["layers"], h, c, remat)
+            counters += more
+            with jax.named_scope("loss"):
+                loss_mtp = _blocked_ce(h, m["norm_w"], head,
+                                       mtp_labels(labels), c)
+    aux = {"loss_main": loss_main, "loss_mtp": loss_mtp}
+    if counters:
+        aux["moe"] = {k: jnp.stack([ctr[k] for ctr in counters])
+                      for k in TRAIN_COUNTERS}
+        aux["load"] = jnp.stack([ctr["load"] for ctr in counters])
+    return loss_main + c.mtp_loss_weight * loss_mtp, aux
+
+
+def router_bias_step(params, load, config: HybridConfig):
+    """The router's bias rule (DeepSeek-V3's auxiliary-loss-free balancing):
+    once a step, on the step's own counts, outside the gradient and outside
+    the optimizer, every expert layer's
+
+        router_bias_e += router_bias_update_rate x sign(mean load - load_e)
+
+    `load` [expert layers, E_total] as `train_loss` returns it (the main
+    model's expert layers in order, then the next-n module's).  Returns
+    (the tree with the biases moved, how many entries moved)."""
+    rate = config.router_bias_update_rate
+    it = iter(load)
+    moves = jnp.zeros((), jnp.int32)
+
+    def moved(layers):
+        nonlocal moves
+        out = []
+        for lp in layers:
+            if "router_bias" in lp:
+                n = next(it).astype(jnp.float32)
+                step = jnp.sign(jnp.mean(n) - n)
+                moves = moves + jnp.sum(step != 0, dtype=jnp.int32)
+                lp = dict(lp, router_bias=lp["router_bias"] + rate * step)
+            out.append(lp)
+        return out
+
+    with jax.named_scope("router_bias"):
+        params = dict(params, layers=moved(params["layers"]))
+        if "mtp" in params:
+            params["mtp"] = dict(params["mtp"],
+                                 layers=moved(params["mtp"]["layers"]))
+    return params, moves

@@ -21,6 +21,7 @@ sharding optimizer):
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import functools
@@ -32,6 +33,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import gpt as gpt_mod
+from ..inference.metrics import MetricsRegistry
+from ..models import hybrid as hybrid_mod
 from ..profiler import profiler as _prof
 
 # Host spans of `HybridParallelTrainer.train_step`, recorded through
@@ -39,6 +42,8 @@ from ..profiler import profiler as _prof
 # `ENGINE_SPANS` gate): placing the batch, and the call that launches the step
 # program (it returns before the device finishes).
 TRAINER_SPANS = ("trainer.shard_batch", "trainer.dispatch")
+# steps whose counters may wait on the device before the oldest is read
+_UNREAD_STEPS = 64
 _NO_SPAN = contextlib.nullcontext()
 
 
@@ -681,19 +686,46 @@ def _pp_loss(params, tokens, labels, config, cfg: MeshConfig, mesh):
 # trainer
 # ---------------------------------------------------------------------------
 
+def _refuse_untrainable(config, mesh_cfg: MeshConfig) -> None:
+    """A patterned configuration (`models.hybrid`) trains where its pattern
+    is of latent attention, dense FFN and expert layers with one residual
+    stream, on one device; what cannot is refused with its reason."""
+    def no(why):
+        raise ValueError(f"layer_pattern {config.layer_pattern!r} is served, "
+                         f"not trained: {why}")
+    if "M" in config.layer_pattern:
+        no("the state-space layer's chunked scan (kernels.ssm) has no "
+           "backward pass")
+    if "*" in config.layer_pattern:
+        no("the training walk has no pass for the position-free attention "
+           "layer (`*`); only L, F and E are trained and tested")
+    if config.hc_mult > 1:
+        no(f"hc_mult {config.hc_mult}: the training walk keeps one residual "
+           "stream (the hyper-connections' mixes and Sinkhorn are served "
+           "only)")
+    if mesh_cfg.size > 1:
+        no(f"a mesh of {mesh_cfg.size} devices: a patterned configuration's "
+           "parameters are replicated and its step has no exchange between "
+           "chips yet (ROADMAP B1); it trains on one")
+
+
 class HybridParallelTrainer:
-    """Owns mesh + sharded params/opt-state + the ONE jitted train step."""
+    """Owns mesh + sharded params/opt-state + the ONE jitted train step.
+
+    A patterned configuration (`models.hybrid.HybridConfig`: latent
+    attention, dense FFN, dropless experts; see `_refuse_untrainable`) goes
+    through the same step: per-layer trees, every spec replicated, AdamW over
+    every leaf but `router_bias`, which `hybrid.router_bias_step` moves
+    inside the same program, and the step's counters returned with the loss
+    (`stats()`)."""
 
     def __init__(self, config: gpt_mod.GPTConfig, mesh_cfg: MeshConfig,
                  learning_rate=1e-4, weight_decay=0.01, beta1=0.9, beta2=0.95,
                  grad_clip_norm: Optional[float] = 1.0, seed=0, devices=None,
                  moment_dtype=jnp.float32):
-        if getattr(config, "layer_pattern", None) is not None:
-            raise ValueError(
-                "a patterned configuration (layer_pattern, models.hybrid) "
-                "is served, not trained: the trainer has one stacked dense "
-                "block and no backward pass for the state-space scan or the "
-                "dropless expert layer")
+        self.patterned = getattr(config, "layer_pattern", None) is not None
+        if self.patterned:
+            _refuse_untrainable(config, mesh_cfg)
         self.config = config
         self.cfg = mesh_cfg
         self.mesh = build_mesh(mesh_cfg, devices)
@@ -703,27 +735,30 @@ class HybridParallelTrainer:
         self.clip_norm = grad_clip_norm
         self.moment_dtype = moment_dtype
 
-        specs = gpt_param_specs(mesh_cfg, config)
-        if not config.use_rope:
-            specs["wpe"] = P(None, None)
-        if not config.tie_word_embeddings:
-            specs["lm_head"] = P(None, "mp" if mesh_cfg.mp > 1 else None)
-        # late-bind ZeRO-3 param sharding (needs the shapes)
-        shapes = jax.eval_shape(functools.partial(gpt_mod.init_params, config),
-                                jax.random.key(0))
-        is_marked = lambda x: isinstance(x, P) or (
-            isinstance(x, tuple) and len(x) == 3 and x[0] == "__add__")
-        specs = jax.tree_util.tree_map(
-            lambda sp, sh: _resolve_spec(sp, sh.shape, mesh_cfg), specs, shapes,
-            is_leaf=is_marked)
+        init_params = functools.partial(
+            (hybrid_mod if self.patterned else gpt_mod).init_params, config)
+        shapes = jax.eval_shape(init_params, jax.random.key(0))
+        if self.patterned:
+            specs = jax.tree_util.tree_map(lambda _: P(), shapes)
+        else:
+            specs = gpt_param_specs(mesh_cfg, config)
+            if not config.use_rope:
+                specs["wpe"] = P(None, None)
+            if not config.tie_word_embeddings:
+                specs["lm_head"] = P(None, "mp" if mesh_cfg.mp > 1 else None)
+            # late-bind ZeRO-3 param sharding (needs the shapes)
+            is_marked = lambda x: isinstance(x, P) or (
+                isinstance(x, tuple) and len(x) == 3 and x[0] == "__add__")
+            specs = jax.tree_util.tree_map(
+                lambda sp, sh: _resolve_spec(sp, sh.shape, mesh_cfg), specs,
+                shapes, is_leaf=is_marked)
         self.param_specs = specs
         self.param_shardings = jax.tree_util.tree_map(
             lambda s: NamedSharding(self.mesh, s), specs,
             is_leaf=lambda x: isinstance(x, P))
 
         key = jax.random.key(seed)
-        init = jax.jit(functools.partial(gpt_mod.init_params, config),
-                       out_shardings=self.param_shardings)
+        init = jax.jit(init_params, out_shardings=self.param_shardings)
         self.params = init(key)
 
         m_shardings = jax.tree_util.tree_map(
@@ -740,6 +775,12 @@ class HybridParallelTrainer:
         self._step_fn = self._build_step()
         self._eval_fn = None    # built lazily on first eval_loss
         self.steps_dispatched = 0   # train_step calls; numbers the step marker
+        # what the step program counted, step by step, not yet read from the
+        # device: `stats()` folds it into the registry (a patterned step
+        # returns its counters with the loss; a dense one has none)
+        self.metrics = MetricsRegistry("trainer")
+        self._unread = collections.deque()
+        self.last_step = None   # the newest patterned step's tree, un-synced
 
     # ---- sharding constraint hook handed to the model ----
     def _mp_constraint(self, x, kind):
@@ -776,6 +817,9 @@ class HybridParallelTrainer:
         attn_impl = _flash_per_shard(mesh, config) if cfg.size > 1 else None
 
         def loss_of(params, tokens, labels):
+            if self.patterned:
+                return hybrid_mod.train_loss(params, tokens, labels, config,
+                                             remat=cfg.remat)
             if cfg.pp > 1:
                 return _pp_loss(params, tokens, labels, config, cfg, mesh)
             if cfg.cp > 1:
@@ -790,12 +834,24 @@ class HybridParallelTrainer:
             # operations' names in a profiler trace, the program is the same
             # as jax.value_and_grad(loss_of)'s
             with jax.named_scope("fwd"):
-                loss, pullback = jax.vjp(
-                    lambda p: loss_of(p, tokens, labels), params)
+                loss, pullback, *aux = jax.vjp(
+                    lambda p: loss_of(p, tokens, labels), params,
+                    has_aux=self.patterned)
             with jax.named_scope("bwd"):
                 grads, = pullback(jnp.ones_like(loss))
             with jax.named_scope("opt"):
-                return (loss,) + update(params, opt_state, grads)
+                params, opt_state = update(params, opt_state, grads)
+            if not self.patterned:
+                return loss, params, opt_state
+            # the loss and what the step counted, in one tree
+            aux, = aux
+            out = {"loss": loss, "loss_main": aux["loss_main"],
+                   "loss_mtp": aux["loss_mtp"]}
+            if "load" in aux:
+                params, moves = hybrid_mod.router_bias_step(
+                    params, aux["load"], config)
+                out.update(aux["moe"], router_bias_moves=moves)
+            return out, params, opt_state
 
         def update(params, opt_state, grads):
             if cfg.sharding_stage >= 2 and cfg.zero_axis is not None:
@@ -816,7 +872,12 @@ class HybridParallelTrainer:
 
             mdt = self.moment_dtype
 
-            def upd(p, g, m, v):
+            def upd(path, p, g, m, v):
+                if getattr(path[-1], "key", None) == "router_bias":
+                    # not the optimizer's: no gradient reaches it (it steers
+                    # the top-k choice only) and no decay; its own rule
+                    # (`hybrid.router_bias_step`) moves it
+                    return p, m, v
                 g32 = g.astype(jnp.float32)
                 m32 = b1 * m.astype(jnp.float32) + (1 - b1) * g32
                 v32 = b2 * v.astype(jnp.float32) + (1 - b2) * g32 * g32
@@ -824,8 +885,8 @@ class HybridParallelTrainer:
                 newp = p.astype(jnp.float32) * (1 - lr * wd) - lr * u
                 return newp.astype(p.dtype), m32.astype(mdt), v32.astype(mdt)
 
-            out = jax.tree_util.tree_map(upd, params, grads, opt_state["m"],
-                                         opt_state["v"])
+            out = jax.tree_util.tree_map_with_path(
+                upd, params, grads, opt_state["m"], opt_state["v"])
             new_params = jax.tree_util.tree_map(lambda t: t[0], out,
                                                 is_leaf=lambda x: isinstance(x, tuple))
             new_m = jax.tree_util.tree_map(lambda t: t[1], out,
@@ -868,17 +929,74 @@ class HybridParallelTrainer:
             with span("trainer.shard_batch"):
                 tokens, labels = self.shard_batch(tokens, labels)
             with span("trainer.dispatch"):
-                loss, self.params, self.opt_state = self._step_fn(
+                out, self.params, self.opt_state = self._step_fn(
                     self.params, self.opt_state, tokens, labels)
-        return loss
+        self.metrics.counter("tokens_trained").inc(tokens.size)
+        if not self.patterned:
+            return out
+        # kept on the device until somebody asks: no sync here.  The oldest
+        # is folded once many wait (its step ended long ago)
+        self.last_step = out
+        self._unread.append(out)
+        if len(self._unread) > _UNREAD_STEPS:
+            self._fold(self._unread.popleft())
+        return out["loss"]
+
+    def _fold(self, out) -> None:
+        """One step's tree into the registry: sums over the expert layers
+        into counters, extremes and the losses into gauges."""
+        m = self.metrics
+        vals = jax.device_get(out)
+        for name in ("loss_main", "loss_mtp"):
+            m.gauge(name).set(float(vals[name]))
+        if "router_bias_moves" not in vals:
+            return
+        for name in ("moe_pairs_here", "moe_pairs_away", "moe_experts_touched",
+                     "moe_pairs_over_bound"):
+            m.counter(name).inc(int(vals[name].sum()))
+        m.counter("router_bias_moves").inc(int(vals["router_bias_moves"]))
+        calls = m.counter("moe_layer_calls")
+        hi, lo = (m.gauge(n, agg="max") for n in ("moe_load_max",
+                                                  "moe_load_min"))
+        step_hi = float(vals["moe_load_max"].max())
+        step_lo = float(vals["moe_load_min"].min())
+        hi.set(max(hi.value, step_hi))
+        lo.set(min(lo.value, step_lo) if calls else step_lo)
+        calls.inc(int(vals["moe_load_max"].size))
+
+    def stats(self) -> dict:
+        """The trainer's counters as the engine's `stats()` gives its own: a
+        flat dict of the registry's counters and gauges after every unread
+        step is folded in (this reads the device: the last step's results).
+        `train_steps`, `tokens_trained`; for a patterned configuration the
+        last folded step's `loss_main` / `loss_mtp`, the expert layers' sums
+        over layers and steps (`moe_pairs_here`, `moe_pairs_away`,
+        `moe_experts_touched`, `moe_pairs_over_bound`, `moe_layer_calls`),
+        the largest and smallest load of a held expert in any layer and step
+        (`moe_load_max`, `moe_load_min`) and `router_bias_moves`."""
+        while self._unread:
+            self._fold(self._unread.popleft())
+        snap = self.metrics.snapshot()
+        return {"train_steps": self.steps_dispatched, **snap["counters"],
+                **snap["gauges"]}
 
     def eval_loss(self, tokens, labels):
+        """The loss the step trains on, without a step (a patterned
+        configuration's two terms are left, un-synced, in `eval_terms`)."""
         # jitted once with the trainer's param shardings and reused — the old
         # eager loss_fn call retraced the whole model on every eval batch
         if self._eval_fn is None:
             config = self.config
+            if self.patterned:
+                fn = lambda p, t, l: hybrid_mod.train_loss(p, t, l, config)
+            else:
+                fn = lambda p, t, l: gpt_mod.loss_fn(p, t, l, config)
             self._eval_fn = jax.jit(
-                lambda p, t, l: gpt_mod.loss_fn(p, t, l, config),
-                in_shardings=(self.param_shardings, None, None))
-        return self._eval_fn(self.params, jnp.asarray(tokens),
-                             jnp.asarray(labels))
+                fn, in_shardings=(self.param_shardings, None, None))
+        out = self._eval_fn(self.params, jnp.asarray(tokens),
+                            jnp.asarray(labels))
+        if not self.patterned:
+            return out
+        loss, aux = out
+        self.eval_terms = {k: aux[k] for k in ("loss_main", "loss_mtp")}
+        return loss

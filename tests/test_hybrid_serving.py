@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import nemotron_h as ref
-from paddle_tpu.incubate.distributed.models.moe.serve import moe_serve, route
+from paddle_tpu.incubate.distributed.models.moe.dropless import moe_dropless, route
 from paddle_tpu.incubate.kernels.grouped_matmul import grouped_matmul
 from paddle_tpu.incubate.kernels.ssm import ssm_chunk_scan, ssm_update
 from paddle_tpu.inference.engine import LLMEngine
@@ -201,7 +201,7 @@ def test_expert_layer_matches_the_reference(held, offset):
     lp = params["layers"][0]
     h = jnp.asarray(np.random.default_rng(held).normal(size=(24, 64)), F32)
     real = jnp.ones((24,), bool)
-    y, ctr = moe_serve(lp, h, cfg, real)
+    y, ctr = moe_dropless(lp, h, cfg, real)
     want = ref.expert_mixer(lp, h[None], model)[0]
     np.testing.assert_allclose(y, want, rtol=1e-3, atol=1e-5)
     assert int(ctr["moe_pairs_here"]) + int(ctr["moe_pairs_away"]) == 24 * 3
@@ -227,7 +227,7 @@ def test_expert_shares_add_up_to_the_uncut_layer():
         half = tiny("E", experts_here=4, expert_offset=offset)
         lp_half = dict(lp, up_w=lp["up_w"][offset:offset + 4],
                        down_w=lp["down_w"][offset:offset + 4])
-        parts.append(moe_serve(lp_half, h, half, real)[0])
+        parts.append(moe_dropless(lp_half, h, half, real)[0])
     shared = ref.shared_expert(lp, h)
     uncut = ref.expert_mixer(lp, h[None], dict(model, n_routed_experts=8))[0]
     np.testing.assert_allclose(parts[0] + parts[1] - shared, uncut,
@@ -241,7 +241,7 @@ def test_padded_rows_are_not_routed():
     lp = params["layers"][0]
     h = jnp.asarray(np.random.default_rng(2).normal(size=(10, 64)), F32)
     real = jnp.arange(10) < 6
-    _, ctr = moe_serve(lp, h, cfg, real)
+    _, ctr = moe_dropless(lp, h, cfg, real)
     assert int(ctr["moe_pairs_here"]) + int(ctr["moe_pairs_away"]) == 6 * 3
 
 
@@ -371,12 +371,6 @@ def test_what_recurrent_state_cannot_be_served_with_is_refused(kw, says):
     with pytest.raises(ValueError, match=says):
         LLMEngine(params, cfg, num_slots=2, page_size=8, max_model_len=64,
                   **kw)
-
-
-def test_the_trainer_refuses_a_patterned_configuration():
-    from paddle_tpu.parallel.hybrid import HybridParallelTrainer, MeshConfig
-    with pytest.raises(ValueError, match="served, not trained"):
-        HybridParallelTrainer(tiny(), MeshConfig())
 
 
 @pytest.mark.parametrize("kw", [dict(layer_pattern="MX", num_layers=2),

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import xing4 as ref
-from paddle_tpu.incubate.distributed.models.moe.serve import moe_serve, route
+from paddle_tpu.incubate.distributed.models.moe.dropless import moe_dropless, route
 from paddle_tpu.incubate.kernels import paged_attention as PA
 from paddle_tpu.inference.engine import LLMEngine
 from paddle_tpu.models import gpt, hybrid
@@ -212,7 +212,7 @@ def test_gated_expert_layer_is_a_per_token_loop(held, offset):
     lp = params["layers"][0]
     assert "gate_w" in lp and "shared_gate_w" in lp
     h = np.random.default_rng(held).normal(size=(24, 64)).astype(np.float32)
-    y, ctr = moe_serve(lp, jnp.asarray(h), cfg, jnp.ones((24,), bool))
+    y, ctr = moe_dropless(lp, jnp.asarray(h), cfg, jnp.ones((24,), bool))
     idx, w = (np.asarray(a) for a in route(jnp.asarray(h), lp, cfg))
     p = {k: np.asarray(v, np.float64) for k, v in lp.items()}
 
@@ -250,7 +250,7 @@ def test_four_shares_and_the_shared_expert_once_are_the_uncut_layer():
         share = tiny("E", experts_here=2, expert_offset=offset)
         lp_share = dict(lp, **{n: lp[n][offset:offset + 2]
                                for n in ("gate_w", "up_w", "down_w")})
-        parts.append(moe_serve(lp_share, h, share, real)[0])
+        parts.append(moe_dropless(lp_share, h, share, real)[0])
     nobody = dict(lp, **{n: lp[n][:0] for n in ("gate_w", "up_w", "down_w")})
     shared = ref.gated_experts(nobody, h[None], model)[0]
     uncut = ref.gated_experts(lp, h[None], dict(model, n_routed_experts=8))[0]

@@ -89,6 +89,23 @@ def test_flash_fwd_bwd_and_varlen(one):
              one(_s(4, 2048, dtype=jnp.int32)))
 
 
+def test_flash_at_a_score_width_of_192_and_a_value_width_of_128(one):
+    """Latent attention expanded, at the JoyAI-LLM-Flash training cell's
+    shapes (4 x 8192, 32 heads): Mosaic addresses the 192-wide blocks as the
+    full minor dimension; the three kernels carry names of their own."""
+    q = k = _s(4, 8192, 32, 192)
+
+    def loss(q, k, v):
+        return FA.flash_attention_fused(q, k, v, causal=True) \
+            .astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                    *one([q, k, _s(4, 8192, 32, 128)]))
+    for name in ("flash_mla_fwd", "flash_mla_bwd_dkv", "flash_mla_bwd_dq"):
+        assert name in text
+    assert "flash_bwd_dq" not in text.replace("flash_mla_bwd_dq", "")
+
+
 def test_rms_norm(one):
     _compile(RN.rms_norm_fused, *one([_s(4 * 2048, 2048), _s(2048)]))
 
@@ -244,6 +261,28 @@ def test_grouped_expert_matmul_at_published_widths(one, rows):
              *one([_s(rows, 2688), _s(64, 1856, 2688), _s(128, dtype=i32)]))
     _compile(lambda x, w, sz: GM.grouped_matmul(x, w, sz, 0),
              *one([_s(rows, 1856), _s(64, 1856, 2688), _s(128, dtype=i32)]))
+
+
+def test_grouped_expert_matmul_backward_at_training_widths(one):
+    """A training step's grouped products at JoyAI-LLM-Flash's widths (16
+    held experts of a 256-wide router, 2048 -> 768 -> 2048, 32,768 gathered
+    rows, 512 rows a tile): forward, the rows' gradient (`gmm` against the
+    transposed matrices) and the matrices' (`tgmm`)."""
+    i32 = jnp.int32
+    tile = GM.TRAIN_ROW_TILE
+
+    def up(x, w, sz):
+        return GM.grouped_matmul(x, w, sz, 0, True, tile) \
+            .astype(jnp.float32).sum()
+
+    def down(x, w, sz):
+        return GM.grouped_matmul(x, w, sz, 0, False, tile) \
+            .astype(jnp.float32).sum()
+
+    for fn, k in ((up, 2048), (down, 768)):
+        text = _compile(jax.grad(fn, argnums=(0, 1)), *one(
+            [_s(32768, k), _s(16, 768, 2048), _s(256, dtype=i32)]))
+        assert text.count("tpu_custom_call") >= 2
 
 
 def _lane_sized_moves(text, pool):
