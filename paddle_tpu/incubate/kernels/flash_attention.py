@@ -7,9 +7,14 @@ TPU-native: Pallas kernels with online-softmax tiling —
 - forward: K blocks form the innermost ("arbitrary") grid dimension with VMEM
   scratch carrying (acc, m, l); emits the per-row logsumexp `lse` alongside the
   output so the backward never re-runs the full forward.
-- backward: two tiled kernels recomputing p = exp(s - lse) blockwise (the standard
-  flash-attention-2 dq / dkv split) — no S×S materialization, causal block skip in
-  both directions.
+- backward: ONE tiled kernel recomputing p = exp(s - lse) blockwise and computing
+  s, p, dp, ds once a (query block, key block) pair for all of dq, dk, dv (five
+  products a pair) — no S×S materialization, causal block skip.  dk and dv
+  accumulate in block-sized scratch over the inner (query) axis; dq accumulates
+  over the outer (key) axis in a float32 VMEM scratch resident for the whole
+  (batch, head).  Only a length whose accumulator VMEM cannot hold
+  (`_fused_bwd_fits`: past 65,536 positions at 192/256 wide) takes the
+  flash-attention-2 dq / dkv split, which computes s, p, dp, ds twice.
 
 Remat interplay: the custom_vjp forward tags its residuals (`flash_out`,
 `flash_lse`) with `checkpoint_name`, so a surrounding `jax.checkpoint(policy=
@@ -191,7 +196,160 @@ def _flash_fwd_impl(q, k, v, causal, scale):
 
 
 # ---------------------------------------------------------------------------
-# Pallas backward kernels (flash-attention-2 split: dkv sweep, dq sweep)
+# Pallas backward kernel: ONE sweep, grid (BH, n_k, n_q), queries innermost.
+# s, p, dp and ds are computed once a (query block, key block) pair and feed
+# all three gradients (5 products a pair; the dq / dkv split computed s, p,
+# dp, ds twice: 7).  dk, dv accumulate over the inner axis in block-sized
+# scratch; dq accumulates over the OUTER axis, so its float32 accumulator
+# stays in VMEM for the whole (batch, head): [n_q, block_q, D].
+# ---------------------------------------------------------------------------
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      block_q: int, block_k: int, n_q: int, n_k: int,
+                      causal: bool, scale: float):
+    from jax.experimental import pallas as pl
+
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+
+    @pl.when((ki == 0) & (qi == 0))
+    def _init_head():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    q_start = qi * block_q
+    k_start = ki * block_k
+    run = True
+    if causal:
+        run = q_start + block_q - 1 >= k_start
+
+    @pl.when(run if causal else (qi >= 0))
+    def _compute():
+        q = q_ref[0]                                    # [bq, D]
+        k = k_ref[0]                                    # [bk, D]
+        v = v_ref[0]                                    # [bk, Dv]
+        do = do_ref[0]                                  # [bq, Dv]
+        lse = lse_ref[0]                                # [bq, 1]
+        dl = dl_ref[0]                                  # [bq, 1] rowsum(dO*O)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        if causal:
+            row = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+            col = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(row >= col, s, NEG_INF)
+        p = jnp.exp(s - lse)                            # [bq, bk] f32
+        pt = p.astype(do.dtype).T
+        dv_acc[...] += jnp.dot(pt, do, preferred_element_type=jnp.float32)
+        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)  # [bq, bk]
+        ds = (p * (dp - dl) * scale).astype(q.dtype)
+        dk_acc[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dq_acc[qi] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+
+    @pl.when(qi == n_q - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    # the head's last key block: every query block's sum is complete as its
+    # turn comes (`_dq_block` holds the output block still until then)
+    @pl.when(ki == n_k - 1)
+    def _finalize_dq():
+        dq_ref[0] = dq_acc[qi].astype(dq_ref.dtype)
+
+
+# VMEM: a v5e core has 128 MiB and Mosaic's scoped default is 16.  The fused
+# backward asks for 100: dq's resident accumulator (S rows of D columns held
+# as whole 128-lane tiles: 8.4 MB at [8192, 192]) may take 64 of them, the
+# kernel's own blocks and [bq, bk] float32 tiles take 14 to 24 at 1024 x 1024
+# (both compiled for v5e: tests/test_tpu_compile.py)
+_BWD_VMEM_LIMIT = 100 * 1024 * 1024
+_BWD_DQ_RESIDENT_MAX = 64 * 1024 * 1024
+
+
+def _fused_bwd_fits(S: int, D: int) -> bool:
+    """Whether one (batch, head)'s dq accumulator stays in VMEM: up to
+    131,072 positions at a width of 128, 65,536 at 192 or 256."""
+    return S * -(-D // 128) * 128 * 4 <= _BWD_DQ_RESIDENT_MAX
+
+
+def _bwd_blocks(S: int, Sk: int):
+    """(block_q, block_k) of the backward, from the lengths the call has.
+    1024 x 1024 was the fastest pair of {512, 1024, 2048}^2 on the chip at
+    S = 8192 and at S = 2048 alike (PERF.md section 6, PR 35); a shorter or
+    odd length takes the largest block that divides it."""
+    return _pick_block(S, BWD_BLOCK), _pick_block(Sk, BWD_BLOCK)
+
+
+def _flash_bwd_call(qt, kt, vt, dot, lse, delta, causal, scale, block_q,
+                    block_k):
+    """The fused kernel on head-major operands: qt, kt [BH, S, D]; vt, dot
+    [BH, S, Dv]; lse, delta [BH, S, 1] f32 -> dq, dk, dv."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    BH, S, D = qt.shape
+    Sk, Dv = kt.shape[1], vt.shape[-1]
+    n_q = S // block_q
+    n_k = Sk // block_k
+
+    def _q_block(b, j, i):
+        # under the causal mask the query blocks above key block j's diagonal
+        # are skipped: hold the first one that runs, so nothing is fetched
+        # for a step that computes nothing
+        if causal:
+            i = jnp.maximum(i, (j * block_k) // block_q)
+        return (b, i, 0)
+
+    def _dq_block(b, j, i):
+        # dq's block is complete only in the last key block's sweep; until
+        # then the index stays put and nothing is written back
+        return (b, jnp.where(j == n_k - 1, i, 0), 0)
+
+    kernel = functools.partial(
+        _flash_bwd_kernel, block_q=block_q, block_k=block_k, n_q=n_q,
+        n_k=n_k, causal=causal, scale=scale)
+    return pl.pallas_call(
+        kernel,
+        name=_kernel_name("bwd", D, Dv),
+        grid=(BH, n_k, n_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, D), _q_block),                    # q
+            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),   # k
+            pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0)),  # v
+            pl.BlockSpec((1, block_q, Dv), _q_block),                   # dO
+            pl.BlockSpec((1, block_q, 1), _q_block),                    # lse
+            pl.BlockSpec((1, block_q, 1), _q_block),                    # delta
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, D), _dq_block),
+            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0)),
+        ],
+        out_shape=[
+            _out_struct((BH, S, D), qt.dtype, qt, kt, vt),
+            _out_struct((BH, Sk, D), kt.dtype, qt, kt, vt),
+            _out_struct((BH, Sk, Dv), vt.dtype, qt, kt, vt),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((n_q, block_q, D), jnp.float32),
+            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+    )(qt, kt, vt, dot, lse, delta)
+
+
+# ---------------------------------------------------------------------------
+# The flash-attention-2 split (a dk/dv sweep, then a dq sweep; s, p, dp, ds
+# computed in both): every accumulator is block-sized, so it takes any
+# length.  The route of a call whose dq accumulator does not fit beside the
+# fused kernel's tiles (`_fused_bwd_fits`); no benchmark cell is that long.
 # ---------------------------------------------------------------------------
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
@@ -281,25 +439,14 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
-    """Tiled dq/dk/dv.  q, k: [B,S,H,D]; v, out, g: [B,S,H,Dv]; lse:
-    [B*H,S,1] f32."""
+def _flash_bwd_split_call(qt, kt, vt, dot, lse, delta, causal, scale,
+                          block_q, block_k):
+    """`_flash_bwd_call`'s contract through the two kernels."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, S, H, D = q.shape
-    Sk, Dv = k.shape[1], v.shape[-1]
-    qt = jnp.transpose(q, (0, 2, 1, 3)).reshape(B * H, S, D)
-    kt = jnp.transpose(k, (0, 2, 1, 3)).reshape(B * H, Sk, D)
-    vt = jnp.transpose(v, (0, 2, 1, 3)).reshape(B * H, Sk, Dv)
-    dot = jnp.transpose(g, (0, 2, 1, 3)).reshape(B * H, S, Dv)
-    # delta_i = rowsum(dO_i * O_i) — the only residual beyond lse (cheap XLA fuse)
-    delta = jnp.sum(dot.astype(jnp.float32) *
-                    jnp.transpose(out, (0, 2, 1, 3)).reshape(B * H, S, Dv)
-                    .astype(jnp.float32), axis=-1, keepdims=True)  # [BH,S,1]
-
-    block_q = _pick_block(S, BWD_BLOCK)
-    block_k = _pick_block(Sk, BWD_BLOCK)
+    BH, S, D = qt.shape
+    Sk, Dv = kt.shape[1], vt.shape[-1]
     n_q = S // block_q
     n_k = Sk // block_k
 
@@ -309,7 +456,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
     dk, dv = pl.pallas_call(
         dkv_kernel,
         name=_kernel_name("bwd_dkv", D, Dv),
-        grid=(B * H, n_k, n_q),
+        grid=(BH, n_k, n_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0)),   # q
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),   # k
@@ -323,15 +470,16 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
             pl.BlockSpec((1, block_k, Dv), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            _out_struct((B * H, Sk, D), k.dtype, qt, kt, vt),
-            _out_struct((B * H, Sk, Dv), v.dtype, qt, kt, vt),
+            _out_struct((BH, Sk, D), kt.dtype, qt, kt, vt),
+            _out_struct((BH, Sk, Dv), vt.dtype, qt, kt, vt),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
     )(qt, kt, vt, dot, lse, delta)
 
     dq_kernel = functools.partial(
@@ -340,7 +488,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
     dq = pl.pallas_call(
         dq_kernel,
         name=_kernel_name("bwd_dq", D, Dv),
-        grid=(B * H, n_q, n_k),
+        grid=(BH, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # q
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # k
@@ -350,11 +498,33 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),   # delta
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=_out_struct((B * H, S, D), q.dtype, qt, kt, vt),
+        out_shape=_out_struct((BH, S, D), qt.dtype, qt, kt, vt),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
     )(qt, kt, vt, dot, lse, delta)
+
+    return dq, dk, dv
+
+
+def _flash_bwd_impl(q, k, v, out, lse, g, causal, scale):
+    """Tiled dq/dk/dv.  q, k: [B,S,H,D]; v, out, g: [B,S,H,Dv]; lse:
+    [B*H,S,1] f32."""
+    B, S, H, D = q.shape
+    Sk, Dv = k.shape[1], v.shape[-1]
+    qt = jnp.transpose(q, (0, 2, 1, 3)).reshape(B * H, S, D)
+    kt = jnp.transpose(k, (0, 2, 1, 3)).reshape(B * H, Sk, D)
+    vt = jnp.transpose(v, (0, 2, 1, 3)).reshape(B * H, Sk, Dv)
+    dot = jnp.transpose(g, (0, 2, 1, 3)).reshape(B * H, S, Dv)
+    # delta_i = rowsum(dO_i * O_i) — the only residual beyond lse (cheap XLA fuse)
+    delta = jnp.sum(dot.astype(jnp.float32) *
+                    jnp.transpose(out, (0, 2, 1, 3)).reshape(B * H, S, Dv)
+                    .astype(jnp.float32), axis=-1, keepdims=True)  # [BH,S,1]
+
+    call = _flash_bwd_call if _fused_bwd_fits(S, D) else _flash_bwd_split_call
+    dq, dk, dv = call(qt, kt, vt, dot, lse, delta, causal, scale,
+                      *_bwd_blocks(S, Sk))
 
     tr = lambda x, L: jnp.transpose(x.reshape(B, H, L, x.shape[-1]),
                                     (0, 2, 1, 3))

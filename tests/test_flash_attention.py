@@ -1,8 +1,9 @@
 """Flash attention + loss chunking tests.
 
 The Pallas kernels themselves only compile on real TPU (Mosaic); under the CPU
-conftest these tests cover the XLA fallback path and the chunked-CE parity.  The
-TPU-gated test mirrors what /tmp-drive scripts exercise on hardware.
+conftest these tests cover the XLA fallback path, the chunked-CE parity and, in
+Pallas' TPU interpret mode, the gradient through the kernels.  The TPU-gated
+test mirrors what /tmp-drive scripts exercise on hardware.
 """
 import numpy as np
 import pytest
@@ -77,3 +78,36 @@ def test_pallas_flash_fwd_bwd_vs_xla_on_tpu():
             a32, b32 = np.asarray(a, np.float32), np.asarray(b, np.float32)
             err = np.abs(a32 - b32).max() / max(np.abs(b32).max(), 1e-6)
             assert err < 6e-2
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("widths", [(128, 128), (192, 128)],
+                         ids=["dense_128", "mla_192_128"])
+def test_grad_through_the_kernels_with_four_blocks_a_side(widths, causal,
+                                                          monkeypatch):
+    """`jax.grad` through the custom_vjp (forward kernel, residuals, the one
+    backward kernel) in interpret mode, blocks of 128 at S = 512: dq's
+    resident accumulator is written from four key blocks, and the causal
+    skip is crossed in every row and column of blocks."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.incubate.kernels import flash_attention as FA
+    monkeypatch.setattr(FA, "FWD_BLOCK", 128)
+    monkeypatch.setattr(FA, "BWD_BLOCK", 128)
+    D, Dv = widths
+    r = np.random.default_rng(D + causal)
+    q, k = (jnp.asarray(r.normal(size=(1, 512, 2, D)), jnp.float32)
+            for _ in range(2))
+    v, w = (jnp.asarray(r.normal(size=(1, 512, 2, Dv)), jnp.float32)
+            for _ in range(2))
+    scale = D ** -0.5
+
+    def loss(attn):
+        return lambda q, k, v: (attn(q, k, v) * w).sum()
+
+    want = jax.grad(loss(lambda *a: attention_xla(
+        *a, causal=causal, scale=scale)), argnums=(0, 1, 2))(q, k, v)
+    with pltpu.force_tpu_interpret_mode():
+        got = jax.grad(loss(lambda *a: FA._flash_attention_core(
+            *a, causal, scale)), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)

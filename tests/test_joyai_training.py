@@ -212,8 +212,8 @@ def test_the_trained_tree_is_the_served_tree():
 @pytest.mark.parametrize("widths", [(192, 128), (128, 128)],
                          ids=["mla_192_128", "dense_128"])
 def test_flash_kernels_take_a_score_and_a_value_width(widths):
-    """Forward, dk/dv and dq kernels in interpret mode against the XLA
-    oracle and its gradients."""
+    """The forward and the backward kernel in interpret mode against the
+    XLA oracle and its gradients."""
     from jax.experimental.pallas import tpu as pltpu
     D, Dv = widths
     B, S, H = 1, 256, 2
@@ -236,9 +236,62 @@ def test_flash_kernels_take_a_score_and_a_value_width(widths):
 
 def test_the_192_128_kernels_have_names_of_their_own():
     assert FA._kernel_name("fwd", 192, 128) == "flash_mla_fwd"
+    assert FA._kernel_name("bwd", 192, 128) == "flash_mla_bwd"
+    assert FA._kernel_name("fwd", 128, 128) == "flash_fwd"
+    assert FA._kernel_name("bwd", 128, 128) == "flash_bwd"
     assert FA._kernel_name("bwd_dkv", 192, 128) == "flash_mla_bwd_dkv"
     assert FA._kernel_name("bwd_dq", 192, 128) == "flash_mla_bwd_dq"
-    assert FA._kernel_name("fwd", 128, 128) == "flash_fwd"
+
+
+@pytest.mark.parametrize("S,D,fits", [
+    (8192, 192, True), (2048, 128, True), (65536, 256, True),
+    (65536, 192, True), (131072, 128, True), (131072, 64, True),
+    (131072, 192, False), (98304, 256, False), (262144, 128, False)])
+def test_the_length_picks_the_backward_route(S, D, fits):
+    """dq's accumulator for one (batch, head) stays in VMEM up to 64 MiB of
+    whole 128-lane tiles; both training cells are far inside."""
+    assert FA._fused_bwd_fits(S, D) is fits
+
+
+@pytest.mark.parametrize("route", ["fused", "split"])
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (128, 256)],
+                         ids=lambda b: f"bq{b[0]}_bk{b[1]}")
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("widths", [(192, 128), (128, 128)],
+                         ids=["mla_192_128", "dense_128"])
+def test_the_backward_gives_dq_dk_and_dv(widths, causal, blocks, route,
+                                         monkeypatch):
+    """The backward against `jax.vjp(attention_xla)` with S several blocks
+    long both ways.  `fused`, the ONE kernel every cell runs: dq's resident
+    accumulator is added to from more than one key block, dk and dv from
+    more than one query block, and under the causal mask steps are skipped
+    (their q-side blocks held, not fetched) on both sides of the diagonal,
+    square blocks and not.  `split`: the dk/dv and dq kernels a length past
+    `_fused_bwd_fits` takes."""
+    from jax.experimental.pallas import tpu as pltpu
+    D, Dv = widths
+    B, S, H = 1, 512, 2
+    monkeypatch.setattr(FA, "_bwd_blocks", lambda S, Sk: blocks)
+    if route == "split":
+        monkeypatch.setattr(FA, "_BWD_DQ_RESIDENT_MAX", 0)
+    calls = []
+    for name in ("_flash_bwd_call", "_flash_bwd_split_call"):
+        monkeypatch.setattr(FA, name, lambda *a, _f=getattr(FA, name),
+                            _n=name: calls.append(_n) or _f(*a))
+    r = np.random.default_rng(D + causal)
+    q, k = (jnp.asarray(r.normal(size=(B, S, H, D)), F32) for _ in range(2))
+    v, g = (jnp.asarray(r.normal(size=(B, S, H, Dv)), F32) for _ in range(2))
+    scale = D ** -0.5
+    _, vjp = jax.vjp(lambda *a: FA.attention_xla(*a, causal=causal,
+                                                 scale=scale), q, k, v)
+    with pltpu.force_tpu_interpret_mode():
+        out, lse = FA._flash_fwd_impl(q, k, v, causal, scale)
+        got = FA._flash_bwd_impl(q, k, v, out, lse, g, causal, scale)
+    assert calls == [{"fused": "_flash_bwd_call",
+                      "split": "_flash_bwd_split_call"}[route]]
+    for name, a, b in zip(("dq", "dk", "dv"), got, vjp(g)):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
 
 
 # ---- the dropless layer, differentiated -----------------------------------------

@@ -13,6 +13,7 @@ Shapes are GPT-3 1.3B widths (16 heads x 128, page 16, bf16) — the ones
 its body once, so depth does not change what is checked).
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,14 +73,26 @@ def _s(*shape, dtype=BF16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _flash_calls(text):
+    """The kernels' `name=`s of a compiled program's Mosaic calls, sorted."""
+    return sorted(
+        re.search(r'op_name="[^"]*?(flash_\w+)\)*/pallas_call"', line).group(1)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+
+
+def _flash_loss(q, k, v):
+    return FA.flash_attention_fused(q, k, v, causal=True) \
+        .astype(jnp.float32).sum()
+
+
 def test_flash_fwd_bwd_and_varlen(one):
+    """The dense training cell's shape (4 x 2048, 16 heads of 128): one
+    forward and exactly ONE backward kernel a flash call."""
     qkv = one([_s(4, 2048, H, HD)] * 3)
 
-    def loss(q, k, v):
-        return FA.flash_attention_fused(q, k, v, causal=True) \
-            .astype(jnp.float32).sum()
-
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+    text = _compile(jax.grad(_flash_loss, argnums=(0, 1, 2)), *qkv)
+    assert _flash_calls(text) == ["flash_bwd", "flash_fwd"]
 
     def vloss(q, k, v, seg):
         return FA.flash_attention_varlen(q, k, v, seg) \
@@ -92,18 +105,28 @@ def test_flash_fwd_bwd_and_varlen(one):
 def test_flash_at_a_score_width_of_192_and_a_value_width_of_128(one):
     """Latent attention expanded, at the JoyAI-LLM-Flash training cell's
     shapes (4 x 8192, 32 heads): Mosaic addresses the 192-wide blocks as the
-    full minor dimension; the three kernels carry names of their own."""
+    full minor dimension; the kernels carry names of their own, and the
+    backward is ONE of them, its dq accumulator ([8192, 192] float32)
+    resident in VMEM beside the block-sized dk and dv."""
     q = k = _s(4, 8192, 32, 192)
 
-    def loss(q, k, v):
-        return FA.flash_attention_fused(q, k, v, causal=True) \
-            .astype(jnp.float32).sum()
-
-    text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+    text = _compile(jax.grad(_flash_loss, argnums=(0, 1, 2)),
                     *one([q, k, _s(4, 8192, 32, 128)]))
-    for name in ("flash_mla_fwd", "flash_mla_bwd_dkv", "flash_mla_bwd_dq"):
-        assert name in text
-    assert "flash_bwd_dq" not in text.replace("flash_mla_bwd_dq", "")
+    assert _flash_calls(text) == ["flash_mla_bwd", "flash_mla_fwd"]
+
+
+@pytest.mark.parametrize("shape,names", [
+    ((1, 65536, 2, 256), ["flash_bwd", "flash_fwd"]),
+    ((1, 131072, 1, 256), ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"])],
+    ids=["s65536_fused", "s131072_split"])
+def test_the_flash_backward_at_the_longest_resident_length_and_past_it(
+        one, shape, names):
+    """256 wide, the widest pair: at 65,536 positions dq's accumulator is
+    the 64 MiB the fused kernel may hold; past it the call takes the dk/dv
+    and dq kernels, whose scratch is block-sized."""
+    text = _compile(jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                    *one([_s(*shape)] * 3))
+    assert _flash_calls(text) == names
 
 
 def test_rms_norm(one):
