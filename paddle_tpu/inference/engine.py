@@ -77,7 +77,10 @@ and Orca's iteration-level scheduling (Yu et al., OSDI 2022), under the same
   slot is free and the program in flight ends no request by its budget).
   Any other step keeps the older order — harvest, admit, build, launch —
   where the token fetch for step n is at the TOP of step n+1 and the device
-  waits for `engine.turnaround`.  INVARIANT: at every return from `step()`
+  waits for `engine.turnaround`; why it was kept is counted
+  (`fused_serial_steps{reason}`, `SERIAL_REASONS`), and so is a launch ahead
+  that came too late to hide anything (`fused_ahead_late`: the program in
+  flight had already finished).  INVARIANT: at every return from `step()`
   at most ONE program is in flight (`_inflight`); two exist only inside
   `step()`, between the launch of k+1 and the harvest of k.  Host scheduler
   state (lengths, page tables, EOS/finish) is updated at harvest time;
@@ -382,25 +385,44 @@ _NULL_SPAN = _NullSpan()
 # batch.build and fused.dispatch (with fused.h2d, its five puts, inside) tile
 # it.  A step that launches ahead of the last result (`_plan_ahead`) has no
 # such stretch: its batch.build, fused.dispatch, sample.sync and emit lie
-# directly under engine.step, in that order.  swap.d2h is the engine thread
-# taking one piece from the fetch worker: .ready its wait for bytes still in
-# flight, .copy the hand-over.
+# under engine.step.ahead, in that order.  engine.step holds at most one of
+# step.ahead (the step launched before it read) and step.serial (it read
+# first, and had a program to read or work to launch: turnaround and admit
+# lie inside it); a step with neither only polled, or only read the last
+# program.  prefill.sync, inside sample.sync, is the blocking first-token
+# read of a prefill program alone — never the fused program's.  admit.reserve
+# is one queue head's prefix match and page reservation with the eviction it
+# sets off.  swap.gather is the host side of one gather dispatch (a spill's or
+# a swap-out's); swap.d2h is the engine thread taking one piece from the fetch
+# worker: .ready its wait for bytes still in flight, .copy the hand-over;
+# swap.fetch is the copy itself, on the worker's own thread.
 ENGINE_SPANS = (
     "engine.step",
+    "engine.step.ahead",
+    "engine.step.serial",
     "engine.turnaround",
     "engine.emit",
     "engine.admit",
+    "engine.admit.reserve",
     "engine.prefill.dispatch",
+    "engine.prefill.sync",
     "engine.spec.propose",
     "engine.batch.build",
     "engine.fused.dispatch",
     "engine.fused.h2d",
     "engine.sample.sync",
+    "engine.swap.gather",
+    "engine.swap.fetch",
     "engine.swap.d2h",
     "engine.swap.d2h.ready",
     "engine.swap.d2h.copy",
     "engine.swap.h2d",
 )
+
+# why a step kept the harvest-first order, in the order `_plan_ahead` tests
+# them: the `reason` label of `fused_serial_steps`, the ring's `serial_reason`
+SERIAL_REASONS = ("idle_start", "draft", "prefilling", "admission_due",
+                  "budget_end", "pages")
 
 
 # the most one piece of a spill/swap-out gather may hold (`LLMEngine._swap_w`
@@ -427,6 +449,15 @@ def _fetch_piece(data, n: int) -> List[Dict[str, np.ndarray]]:
     host = jax.device_get(data)
     return [{name: np.ascontiguousarray(a[:, i]) for name, a in host.items()}
             for i in range(n)]
+
+
+def _fetch_on_worker(data, n: int) -> List[Dict[str, np.ndarray]]:
+    """`_fetch_piece` as the fetch worker runs it: under `engine.swap.fetch`
+    while a Profiler records, so the copy shows on the worker's own host
+    line (one span a piece: with `_D2H_PIECE_BYTES` it is the link's rate)."""
+    with _prof.RecordEvent("engine.swap.fetch") if _prof.is_recording() \
+            else _NULL_SPAN:
+        return _fetch_piece(data, n)
 
 
 class _AotCache:
@@ -1005,6 +1036,20 @@ class LLMEngine:
             "fused launches made before the previous program's result was "
             "read (over decode_iterations: the share of steps whose host "
             "turnaround ran behind the device's work)")
+        self._ahead_late = m.counter(
+            "fused_ahead_late",
+            "launches ahead that found the program in flight already "
+            "finished (over fused_launched_ahead: the share of ahead steps "
+            "whose host work outlasted the device's; above a few per cent "
+            "the host is the bottleneck again)")
+        self._serial_steps = {
+            why: m.counter(
+                "fused_serial_steps",
+                "steps that read the last program before launching the next "
+                "(harvest, admit, build, launch), by why the order was kept",
+                labels={"reason": why})
+            for why in SERIAL_REASONS}
+        self._step_late = False
         self._ahead_discarded = m.counter(
             "fused_ahead_discarded_lanes",
             "lanes of a program launched ahead whose request had ended by "
@@ -1803,60 +1848,74 @@ class LLMEngine:
         self._step_aux = dict.fromkeys(("moe_pairs_here", "moe_pairs_away",
                                         "moe_experts_touched",
                                         "latent_tokens_written"), 0)
+        self._step_late = False
         with self._step_marker(), self._span("engine.step"):
             prev = self._inflight
-            lanes = None if prev is None else self._plan_ahead(prev)
+            lanes, serial = self._plan_ahead(prev)
             if lanes is not None:
-                # the next program goes out BEFORE the last one's tokens are
-                # read: its decode rows take their token from `prev`'s
-                # output on the device, and everything below — the fetch,
-                # the emission, the retirements, the caller's loop — runs
-                # while it computes.  Two programs are in flight only from
-                # here to the harvest; the step returns with one
-                chunk_job = self._stage_chunk() if self.chunked else None
-                self._inflight = None
-                if self.optimistic:
-                    # the page under each lane's write: the plan saw that
-                    # all of them fit without a victim (`_fits_ahead`)
-                    for slot, (_, q, _, _) in lanes.items():
-                        self.cache.grow(slot, q + 1)
-                if lanes or chunk_job is not None:
-                    self._fused_iter(chunk_job, finished, lanes, prev)
-                self._harvest(finished, prev)
-                if self._has_deadlines:
-                    # a slot retired here leaves a lane in the program just
-                    # launched: its harvest drops that lane's token
-                    self._expire_deadlines(finished)
+                # a plan with nothing to launch (every lane ended with
+                # `prev`) only reads the last program: neither order
+                order = "engine.step.ahead" if lanes or (
+                    self.chunked and self._prefilling) else None
+            elif prev is None and not (self._queue or self._running or
+                                       self._prefilling):
+                order = serial = None           # an idle poll
             else:
-                if prev is None:
-                    self._turn_begin(t0)
-                # step n-1's tokens land first
-                self._harvest(finished, turnaround=True)
-                if self._has_deadlines:
-                    # right after harvest: bookkeeping is exact, nothing in
-                    # flight
-                    self._expire_deadlines(finished)
-                with self._span("engine.admit"):
-                    self._admit(finished)
-                if self.chunked:
-                    chunk_job = self._stage_chunk()
+                order = "engine.step.serial"
+                self._serial_steps[serial].inc()
+            with _NULL_SPAN if order is None else self._span(order):
+                if lanes is not None:
+                    # the next program goes out BEFORE the last one's tokens
+                    # are read: its decode rows take their token from `prev`'s
+                    # output on the device, and everything below — the fetch,
+                    # the emission, the retirements, the caller's loop — runs
+                    # while it computes.  Two programs are in flight only
+                    # from here to the harvest; the step returns with one
+                    chunk_job = self._stage_chunk() if self.chunked else None
+                    self._inflight = None
+                    if self.optimistic:
+                        # the page under each lane's write: the plan saw that
+                        # all of them fit without a victim (`_fits_ahead`)
+                        for slot, (_, q, _, _) in lanes.items():
+                            self.cache.grow(slot, q + 1)
+                    if lanes or chunk_job is not None:
+                        self._fused_iter(chunk_job, finished, lanes, prev)
+                    self._harvest(finished, prev)
+                    if self._has_deadlines:
+                        # a slot retired here leaves a lane in the program
+                        # just launched: its harvest drops that lane's token
+                        self._expire_deadlines(finished)
                 else:
-                    # bucketed mode: prefix-hit tails keep the standalone
-                    # chunk program (cold path, next to the one-shot prefill)
-                    self._prefill_tick(finished)
-                    chunk_job = None
-                if self._running or chunk_job is not None:
-                    self._fused_iter(chunk_job, finished)
-            # decode-batch occupancy of what actually DISPATCHED: on a
-            # preemption step the pre-dispatch running count overstates the
-            # batch (victims left before the program ran)
-            decode_batch = self._step_slots["decode"] + \
-                self._step_slots["verify"]
-            self._turn_end(launched=False)      # no-op after a launch
-            # spill/swap-out records whose bytes have arrived land here,
-            # behind the dispatch; the others stay in flight
-            if self._pending_d2h:
-                self._land_d2h()
+                    if prev is None:
+                        self._turn_begin(t0)
+                    # step n-1's tokens land first
+                    self._harvest(finished, turnaround=True)
+                    if self._has_deadlines:
+                        # right after harvest: bookkeeping is exact, nothing
+                        # in flight
+                        self._expire_deadlines(finished)
+                    with self._span("engine.admit"):
+                        self._admit(finished)
+                    if self.chunked:
+                        chunk_job = self._stage_chunk()
+                    else:
+                        # bucketed mode: prefix-hit tails keep the standalone
+                        # chunk program (cold path, next to the one-shot
+                        # prefill)
+                        self._prefill_tick(finished)
+                        chunk_job = None
+                    if self._running or chunk_job is not None:
+                        self._fused_iter(chunk_job, finished)
+                # decode-batch occupancy of what actually DISPATCHED: on a
+                # preemption step the pre-dispatch running count overstates
+                # the batch (victims left before the program ran)
+                decode_batch = self._step_slots["decode"] + \
+                    self._step_slots["verify"]
+                self._turn_end(launched=False)      # no-op after a launch
+                # spill/swap-out records whose bytes have arrived land here,
+                # behind the dispatch; the others stay in flight
+                if self._pending_d2h:
+                    self._land_d2h()
         dur = self._now() - t0
         self._h_step.observe(dur)
         if self._step_dispatches:
@@ -1904,6 +1963,12 @@ class LLMEngine:
             # the swap/spill fetches it drained
             "turnaround_ms": self._step_turnaround_s * 1e3,
             "ahead": lanes is not None and self._step_dispatches > 0,
+            # an ahead step whose launch found the program in flight already
+            # finished (the device had gone idle: `fused_ahead_late`), and
+            # why a step kept the harvest-first order (`SERIAL_REASONS`;
+            # None for an ahead step, an idle poll or a last read)
+            "late": self._step_late,
+            "serial_reason": serial,
             "d2h_ms": self._step_d2h_s * 1e3,
             # per-mode slot occupancy of this step's decode-path dispatches
             "slots": dict(self._step_slots),
@@ -1948,13 +2013,15 @@ class LLMEngine:
             del self._prefilling[slot]      # resolved at harvest
         return job
 
-    def _plan_ahead(self, prev: Dict[str, object]
-                    ) -> Optional[Dict[int, tuple]]:
+    def _plan_ahead(self, prev: Optional[Dict[str, object]]
+                    ) -> Tuple[Optional[Dict[int, tuple]], Optional[str]]:
         """Whether the next fused program can be launched before `prev`, the
         one in flight, is read — decided from what the engine observes this
-        step — and, if so, its decode lanes: {slot: (column of `prev`'s
+        step — and, if so, its decode lanes: ({slot: (column of `prev`'s
         `out` that holds the lane's input token, q_offset, greedy, request
-        id)}.  None keeps today's order (harvest, admit, build, launch).
+        id)}, None).  (None, reason) keeps today's order (harvest, admit,
+        build, launch), the reason one of `SERIAL_REASONS`, the first that
+        holds in the order tested here.
 
         Predictable means that `prev`'s tokens are the ONLY thing the next
         batch lacks: `prev` carried no draft (a verify lane's accepted count
@@ -1969,9 +2036,14 @@ class LLMEngine:
         cannot foresee it (EOS, a deadline) rides, and `_harvest` drops its
         token.  A decision only: no state moves here (`step()` grows the
         lanes' pages once the plan stands)."""
-        if prev["drafts"] or (self._prefilling and not self.chunked) or \
-                (self._queue and self._free_slots):
-            return None
+        if prev is None:
+            return None, "idle_start"
+        if prev["drafts"]:
+            return None, "draft"
+        if self._prefilling and not self.chunked:
+            return None, "prefilling"
+        if self._queue and self._free_slots:
+            return None, "admission_due"
         lanes: Dict[int, tuple] = {}
         ending = False
         lengths = self.cache.lengths
@@ -1982,7 +2054,7 @@ class LLMEngine:
             if len(seq.generated) + 1 >= seq.request.max_new_tokens:
                 ending = True
             elif self.spec_len and seq.greedy and not seq.spec_off:
-                return None
+                return None, "draft"
             else:
                 lanes[slot] = (0, int(lengths[slot]) + 1, seq.greedy, rid)
         cj = prev["chunk"]
@@ -1994,15 +2066,15 @@ class LLMEngine:
             if len(st.prior or ()) + 1 >= st.request.max_new_tokens:
                 ending = True
             elif self.spec_len and greedy and not st.spec_off:
-                return None
+                return None, "draft"
             else:
                 lanes[cj["slot"]] = (cj["n"] - 1, st.prompt.size, greedy,
                                      st.request.request_id)
         if self._queue and ending:
-            return None                 # its slot is free at the harvest
+            return None, "budget_end"   # its slot is free at the harvest
         if self.optimistic and not self._fits_ahead(lanes):
-            return None
-        return lanes
+            return None, "pages"
+        return lanes, None
 
     def _fits_ahead(self, lanes: Dict[int, tuple]) -> bool:
         """Optimistic admission, launching ahead: whether the pages under
@@ -2094,6 +2166,11 @@ class LLMEngine:
                 # every row the host's: the two device constants, no put
                 prev_out, from_prev = (prev["out"], self._h2d(from_prev)) \
                     if ahead else (self._no_prev, self._host_rows)
+            if ahead and prev_out.is_ready():
+                # the program in flight finished before its successor
+                # reached the device: this step's launch-ahead hid nothing
+                self._step_late = True
+                self._ahead_late.inc()
             out, accept, self._pool, self._key, *aux = self._decode_fn(
                 self.params, tokens, self._pool, table, qoff, valid,
                 self._key, greedy, prev_out, from_prev)
@@ -2422,19 +2499,22 @@ class LLMEngine:
         for i in range(0, len(pages), slot_w):
             chunk = pages[i:i + slot_w]
             width = -(-len(chunk) // W) * W
-            while self._d2h_inflight + width > self._d2h_bound and \
-                    self._pending_d2h:
-                if not all(p[0].done()
-                           for p in self._pending_d2h[0]["pieces"]):
-                    self._d2h_bp_waits.inc()
-                self._land_record(self._pending_d2h.pop(0))
-            ids = np.zeros((slot_w,), np.int32)
-            ids[:len(chunk)] = chunk
-            gathered = self._swap_out_fn(self._pool, self._h2d(ids))
-            for j in range(0, len(chunk), W):
-                data, n = gathered[j // W], min(W, len(chunk) - j)
-                pieces.append((self._d2h_worker.submit(_fetch_piece, data, n),
-                               n, data))
+            # one span a dispatch of the gather program; the records it has
+            # to land first (`engine.swap.d2h`) are its children
+            with self._span("engine.swap.gather"):
+                while self._d2h_inflight + width > self._d2h_bound and \
+                        self._pending_d2h:
+                    if not all(p[0].done()
+                               for p in self._pending_d2h[0]["pieces"]):
+                        self._d2h_bp_waits.inc()
+                    self._land_record(self._pending_d2h.pop(0))
+                ids = np.zeros((slot_w,), np.int32)
+                ids[:len(chunk)] = chunk
+                gathered = self._swap_out_fn(self._pool, self._h2d(ids))
+                for j in range(0, len(chunk), W):
+                    data, n = gathered[j // W], min(W, len(chunk) - j)
+                    pieces.append((self._d2h_worker.submit(
+                        _fetch_on_worker, data, n), n, data))
             self._d2h_inflight += width
         self._swap_out_used = True
         return pieces
@@ -2883,28 +2963,32 @@ class LLMEngine:
             tokens = prompt if self.prefix_cache else None
             alloc = None
             restored = ()
-            while True:
-                try:
-                    # one shot: the prefix match and the reservation happen in
-                    # the same call (a failed attempt rolls its sharing back),
-                    # instead of re-hashing the prompt in a can_allocate probe
-                    # every step
-                    alloc = mgr.allocate_prefixed(slot, total, tokens)
-                except RuntimeError:        # out of KV pages
-                    alloc = None
-                    break
-                plan = mgr.take_restore(slot)
-                if not plan:
-                    break
-                # the match reached into the KV tier: ONE swap_in scatter
-                # restores the parked prefix into the slot's fresh pages —
-                # no prefill replay.  A degraded restore (failed copy,
-                # vanished data) dropped the offending nodes; roll the slot
-                # back and re-match without them.
-                if self._tier_restore(slot, plan, rid):
-                    restored = plan
-                    break
-                mgr.release(slot)
+            # one span a queue head, pages or none: the match, the
+            # reservation and the eviction it sets off (host-tier room, the
+            # spill's gather) — `engine.admit`'s share that is not prefill
+            with self._span("engine.admit.reserve"):
+                while True:
+                    try:
+                        # one shot: the prefix match and the reservation
+                        # happen in the same call (a failed attempt rolls its
+                        # sharing back), instead of re-hashing the prompt in
+                        # a can_allocate probe every step
+                        alloc = mgr.allocate_prefixed(slot, total, tokens)
+                    except RuntimeError:        # out of KV pages
+                        alloc = None
+                        break
+                    plan = mgr.take_restore(slot)
+                    if not plan:
+                        break
+                    # the match reached into the KV tier: ONE swap_in scatter
+                    # restores the parked prefix into the slot's fresh pages
+                    # — no prefill replay.  A degraded restore (failed copy,
+                    # vanished data) dropped the offending nodes; roll the
+                    # slot back and re-match without them.
+                    if self._tier_restore(slot, plan, rid):
+                        restored = plan
+                        break
+                    mgr.release(slot)
             if alloc is None:
                 if not self._running and not self._prefilling and \
                         mgr.pages_in_use() == 0:
@@ -2977,7 +3061,8 @@ class LLMEngine:
                 if self.prefix_cache:
                     mgr.register_prefix(slot, prompt, lp)
                 t_sync = self._now()
-                with self._span("engine.sample.sync"):
+                with self._span("engine.sample.sync"), \
+                        self._span("engine.prefill.sync"):
                     first, aux = jax.device_get((first, aux))   # blocks
                     first = int(first[0])
                 self._step_sync_s += self._now() - t_sync
@@ -3029,7 +3114,8 @@ class LLMEngine:
         if st.filled == lp:
             del self._prefilling[slot]
             t_sync = self._now()
-            with self._span("engine.sample.sync"):
+            with self._span("engine.sample.sync"), \
+                    self._span("engine.prefill.sync"):
                 tok, aux = jax.device_get((tok, aux))   # blocks on the result
                 tok = int(tok[0])
             self._step_sync_s += self._now() - t_sync
@@ -3668,6 +3754,9 @@ class LLMEngine:
             # launches made before the last result was read, and the lanes
             # of such launches whose request had ended meanwhile
             "fused_launched_ahead": self._launched_ahead.value,
+            "fused_ahead_late": self._ahead_late.value,
+            "fused_serial_steps": {why: c.value for why, c
+                                   in self._serial_steps.items()},
             "fused_ahead_discarded_lanes": self._ahead_discarded.value,
             # recurrent configurations: the expert layers' routing account
             # and the state lanes (all 0 for a dense configuration)

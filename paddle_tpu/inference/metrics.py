@@ -96,13 +96,18 @@ DEFAULT_LATENCY_BUCKETS = log_buckets(1e-4, 100.0, 4)
 
 class Counter:
     """Monotonic counter.  `.value` for host reads; resets only via the
-    registry (bench warmup exclusion), never decrements in between."""
+    registry (bench warmup exclusion), never decrements in between.
+    `labels`: a constant label set that tells this counter from its
+    siblings of the same name (one family in the exposition, one sample a
+    sibling; the registry and the snapshot key it as ``name{k="v"}``)."""
 
-    __slots__ = ("name", "help", "_value")
+    __slots__ = ("name", "help", "labels", "_value")
 
-    def __init__(self, name: str, help: str = ""):
+    def __init__(self, name: str, help: str = "",
+                 labels: Optional[Dict[str, str]] = None):
         self.name = name
         self.help = help
+        self.labels = dict(labels or {})
         self._value = 0
 
     def inc(self, n: int = 1) -> None:
@@ -473,8 +478,10 @@ class MetricsRegistry:
         self._metrics[name] = m
         return m
 
-    def counter(self, name: str, help: str = "") -> Counter:
-        return self._register(name, Counter, lambda: Counter(name, help))
+    def counter(self, name: str, help: str = "",
+                labels: Optional[Dict[str, str]] = None) -> Counter:
+        return self._register(name + _render_labels(labels), Counter,
+                              lambda: Counter(name, help, labels))
 
     def gauge(self, name: str, fn: Optional[Callable[[], float]] = None,
               help: str = "", agg: str = "sum") -> Gauge:
@@ -560,12 +567,21 @@ class MetricsRegistry:
         ns = _sanitize(self.namespace + "_") if self.namespace else ""
         lbl = _render_labels(labels)
         eng = (labels or {}).get("engine")
-        for name, m in list(self._metrics.items()):
-            full = ns + _sanitize(name)
+        metrics = list(self._metrics.values())
+        for m in metrics:
+            full = ns + _sanitize(m.name)
             if isinstance(m, Counter):
                 tname = full if full.endswith("_total") else full + "_total"
                 fam = tname[:-len("_total")] if openmetrics else tname
-                yield fam, "counter", m.help, [f"{tname}{lbl} {m.value}"]
+                # labelled siblings are one family: all its samples go out
+                # with the first of them
+                sibs = [c for c in metrics if isinstance(c, Counter)
+                        and c.name == m.name] if m.labels else [m]
+                if sibs[0] is m:
+                    yield fam, "counter", m.help, [
+                        f"{tname}"
+                        f"{_render_labels({**(labels or {}), **c.labels})} "
+                        f"{c.value}" for c in sibs]
             elif isinstance(m, Gauge):
                 yield full, "gauge", m.help, [f"{full}{lbl} {_fmt(m.value)}"]
             else:
@@ -640,7 +656,7 @@ class MetricsRegistry:
         raises TypeError.  Returns self so merges chain."""
         for name, m in list(other._metrics.items()):
             if isinstance(m, Counter):
-                self.counter(name, m.help).inc(m.value)
+                self.counter(m.name, m.help, m.labels).inc(m.value)
             elif isinstance(m, Gauge):
                 g = self.gauge(name, help=m.help, agg=m.agg)
                 if g.agg != m.agg:      # like mismatched histogram edges:

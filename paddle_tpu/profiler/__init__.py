@@ -1,4 +1,2 @@
-from .profiler import (Profiler, ProfilerState, ProfilerTarget, RecordEvent,  # noqa
-                       SortedKeys, dump_chrome_trace, export_chrome_tracing,
-                       is_recording, load_profiler_result, make_scheduler)
-from .timer import Benchmark, benchmark  # noqa
+from .profiler import (Profiler, RecordEvent, dump_chrome_trace,  # noqa
+                       is_recording)

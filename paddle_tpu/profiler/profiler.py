@@ -1,47 +1,23 @@
-"""Profiler (reference: `python/paddle/profiler/profiler.py:349` + C++
-`fluid/platform/profiler/`).
+"""Host-span recorder (reference: `python/paddle/profiler/profiler.py:349` + C++
+`fluid/platform/profiler/` HostTracer).
 
-TPU-native: host spans are recorded by this module (HostTracer parity); device activity
-comes from `jax.profiler` (XPlane — the CudaTracer/CUPTI analog), exported as a
-TensorBoard trace directory.  `export_chrome_tracing` writes the host span tree in
-chrome-tracing JSON, like ChromeTracingLogger.
+TPU-native: host spans are recorded by this module and forwarded as
+`jax.profiler.TraceAnnotation`s, so they land on the device trace's clock;
+device activity comes from `jax.profiler` (XPlane — the CudaTracer/CUPTI
+analog).  What is kept is what has a reader: `RecordEvent` behind the
+`is_recording()` gate (the engine's and the trainer's spans), `Profiler` as
+the switch (`start` / `stop` / context manager; `timer_only=True` records host
+spans alone, which is how the benchmark turns the program's spans on beside
+its own device trace) and `dump_chrome_trace` (`LLMEngine.trace()`'s
+`host_trace.json`).
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
 import time
 from collections import deque
-from enum import Enum
-from typing import Callable, Iterable, Optional
-
-
-class ProfilerState(Enum):
-    CLOSED = 0
-    READY = 1
-    RECORD = 2
-    RECORD_AND_RETURN = 3
-
-
-class ProfilerTarget(Enum):
-    CPU = 0
-    GPU = 1
-    XPU = 2
-    CUSTOM_DEVICE = 3
-    TPU = 4
-
-
-class SortedKeys(Enum):
-    CPUTotal = 0
-    CPUAvg = 1
-    CPUMax = 2
-    CPUMin = 3
-    GPUTotal = 4
-    GPUAvg = 5
-    GPUMax = 6
-    GPUMin = 7
 
 
 class _HostEvent:
@@ -119,26 +95,6 @@ class RecordEvent:
         return False
 
 
-def make_scheduler(closed: int, ready: int, record: int, repeat: int = 0,
-                   skip_first: int = 0) -> Callable[[int], ProfilerState]:
-    def scheduler(step: int) -> ProfilerState:
-        if step < skip_first:
-            return ProfilerState.CLOSED
-        s = step - skip_first
-        period = closed + ready + record
-        if repeat > 0 and s >= repeat * period:
-            return ProfilerState.CLOSED
-        pos = s % period
-        if pos < closed:
-            return ProfilerState.CLOSED
-        if pos < closed + ready:
-            return ProfilerState.READY
-        if pos == period - 1:
-            return ProfilerState.RECORD_AND_RETURN
-        return ProfilerState.RECORD
-    return scheduler
-
-
 def dump_chrome_trace(fname: str) -> None:
     """Serialize the host spans recorded so far (the module event buffer) as
     chrome-tracing JSON — usable mid-recording, so a capture window nested
@@ -151,39 +107,20 @@ def dump_chrome_trace(fname: str) -> None:
         json.dump({"traceEvents": traceEvents}, f)
 
 
-def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None):
-    def handler(prof):
-        os.makedirs(dir_name, exist_ok=True)
-        fname = os.path.join(dir_name, f"{worker_name or 'worker'}_trace.json")
-        prof._export_chrome(fname)
-        print(f"[profiler] chrome trace written to {fname}")
-    return handler
-
-
-def load_profiler_result(filename: str):
-    with open(filename) as f:
-        return json.load(f)
-
-
 class Profiler:
-    def __init__(self, targets: Optional[Iterable] = None, scheduler=None,
-                 on_trace_ready=None, record_shapes=False, profile_memory=False,
-                 timer_only=False, emit_nvtx=False, custom_device_types=None,
-                 with_flops=False, log_dir="profiler_log"):
-        self._scheduler = scheduler if callable(scheduler) else (
-            make_scheduler(*scheduler) if scheduler else (lambda step: ProfilerState.RECORD))
-        self._on_trace_ready = on_trace_ready
-        self._step = 0
+    """Switches host-span recording on and off; with `timer_only=False` also
+    a `jax.profiler` device capture under `log_dir`."""
+
+    def __init__(self, timer_only: bool = False,
+                 log_dir: str = "profiler_log"):
         self._timer_only = timer_only
         self._log_dir = log_dir
         self._jax_dir = None
-        self._state = ProfilerState.CLOSED
 
     def start(self):
         global _recording, _events
         _events = deque(maxlen=HOST_EVENT_CAP)
         _recording = True
-        self._state = self._scheduler(self._step)
         if not self._timer_only:
             # a device capture that was asked for and cannot start raises:
             # the trace is the source of every device metric, and a
@@ -200,15 +137,6 @@ class Profiler:
             import jax.profiler
             self._jax_dir = None
             jax.profiler.stop_trace()
-        if self._on_trace_ready:
-            self._on_trace_ready(self)
-
-    def step(self, num_samples=None):
-        self._step += 1
-        self._state = self._scheduler(self._step)
-
-    def step_info(self, unit=None):
-        return f"step {self._step}"
 
     def __enter__(self):
         self.start()
@@ -217,23 +145,3 @@ class Profiler:
     def __exit__(self, *exc):
         self.stop()
         return False
-
-    def _export_chrome(self, fname):
-        dump_chrome_trace(fname)
-
-    def export(self, path, format="json"):
-        self._export_chrome(path)
-
-    def summary(self, sorted_by=SortedKeys.CPUTotal, op_detail=True, thread_sep=False,
-                time_unit="ms"):
-        from collections import defaultdict
-        agg = defaultdict(lambda: [0, 0.0])
-        for e in _events:
-            agg[e.name][0] += 1
-            agg[e.name][1] += (e.end - e.start) / 1e6
-        lines = [f"{'name':40s} {'calls':>8s} {'total(ms)':>12s}"]
-        for name, (calls, total) in sorted(agg.items(), key=lambda kv: -kv[1][1]):
-            lines.append(f"{name[:40]:40s} {calls:8d} {total:12.3f}")
-        table = "\n".join(lines)
-        print(table)
-        return table

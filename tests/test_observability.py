@@ -515,6 +515,10 @@ NEW_STATS_KEYS = frozenset({
     # added by the launch-ahead PR (ISSUE 33): fused launches made before
     # the previous result was read, and their lanes dropped at harvest
     "fused_launched_ahead", "fused_ahead_discarded_lanes",
+}) | frozenset({
+    # added by the admission-account PR (ISSUE 36): launches ahead that
+    # found the program in flight finished, and the serial steps by reason
+    "fused_ahead_late", "fused_serial_steps",
 })
 
 
@@ -847,6 +851,57 @@ def test_exemplar_label_escape_roundtrip():
     (labels, value), = exemplars.values()
     assert labels == {"v": tricky}
     assert value == 0.5
+
+
+def test_labelled_counters_are_one_family_and_merge_by_label():
+    """A counter with a constant label set is a sibling of the others of its
+    name: one family in the exposition (one HELP/TYPE, a sample a sibling,
+    the labels beside a fleet's `engine` label), keyed `name{k="v"}` in the
+    registry and the snapshot, summed label by label in a merge."""
+    from tools.check_metrics import check_exposition
+    regs = []
+    for x, y in ((1, 2), (10, 20)):
+        reg = MetricsRegistry("llm_engine")
+        reg.counter("plain", "no labels").inc(5)
+        reg.counter("fam", "by reason", labels={"reason": "x"}).inc(x)
+        reg.counter("other").inc(1)         # siblings need not be adjacent
+        reg.counter("fam", "by reason", labels={"reason": "y"}).inc(y)
+        assert reg.counter("fam", labels={"reason": "x"}) is \
+            reg.counter("fam", labels={"reason": "x"})
+        assert reg.counter("fam", labels={"reason": "x"}) is not \
+            reg.counter("fam", labels={"reason": "y"})
+        regs.append(reg)
+    a, b = regs
+    assert a.snapshot()["counters"] == {
+        "plain": 5, 'fam{reason="x"}': 1, "other": 1, 'fam{reason="y"}': 2}
+    for openmetrics in (False, True):
+        text = a.to_prometheus(openmetrics=openmetrics)
+        errors = []
+        check_exposition(text, errors)
+        assert not errors
+        fam = "llm_engine_fam" + ("" if openmetrics else "_total")
+        assert text.count(f"# TYPE {fam} counter\n") == 1
+        assert text.count(f"# HELP {fam} by reason\n") == 1
+        assert f'# TYPE {fam} counter\n' \
+            'llm_engine_fam_total{reason="x"} 1\n' \
+            'llm_engine_fam_total{reason="y"} 2\n' in text
+        assert "llm_engine_plain_total 5\n" in text
+    merged = MetricsRegistry("llm_fleet").merge(a).merge(b)
+    assert merged.snapshot()["counters"] == {
+        "plain": 10, 'fam{reason="x"}': 11, "other": 2,
+        'fam{reason="y"}': 22}
+    fleet = FleetMetrics().add("e0", a).add("e1", b)
+    text = fleet.to_prometheus()
+    errors = []
+    check_exposition(text, errors)
+    assert not errors
+    assert text.count("# TYPE llm_engine_fam_total counter\n") == 1
+    for line in ('llm_engine_fam_total{engine="e0",reason="x"} 1',
+                 'llm_engine_fam_total{engine="e1",reason="y"} 20',
+                 'llm_fleet_fam_total{reason="x"} 11'):
+        assert line + "\n" in text
+    a.reset()
+    assert not any(a.snapshot()["counters"].values())
 
 
 def test_registry_merge_conflicts_raise():

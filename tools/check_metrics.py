@@ -69,6 +69,10 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+# the `reason` label of `fused_serial_steps` (engine.SERIAL_REASONS, frozen
+# here like every other name of the schema)
+SERIAL_REASONS = ("idle_start", "draft", "prefilling", "admission_due",
+                  "budget_end", "pages")
 REQUIRED_STATS_KEYS = frozenset({
     "decode_executables", "verify_executables", "prefill_executables",
     "copy_executables", "swap_executables", "buckets", "prefill_chunk",
@@ -121,6 +125,9 @@ REQUIRED_STATS_KEYS = frozenset({
     # launch-ahead PR (ISSUE 33): fused launches made before the previous
     # program's result was read, and the lanes of those dropped at harvest
     "fused_launched_ahead", "fused_ahead_discarded_lanes",
+    # admission-account PR (ISSUE 36): launches ahead that found the program
+    # in flight already finished, and the serial steps by reason
+    "fused_ahead_late", "fused_serial_steps",
 })
 REQUIRED_KV_TIER_KEYS = frozenset({
     "enabled", "spill_dir", "pages_host", "pages_disk", "spills",
@@ -178,7 +185,10 @@ REQUIRED_COUNTERS = frozenset({
     "paged_pages_walked", "paged_table_entries",
     # launch-ahead PR (ISSUE 33)
     "fused_launched_ahead", "fused_ahead_discarded_lanes",
-})
+    # admission-account PR (ISSUE 36): one labelled family, a sample a reason
+    "fused_ahead_late",
+}) | frozenset(f'fused_serial_steps{{reason="{why}"}}'
+              for why in SERIAL_REASONS)
 # the v2 step-ring record (`step_trace()`, /debug's "step_trace")
 REQUIRED_STEP_RECORD_KEYS = frozenset({
     "v", "step", "t", "dur_s", "queued", "prefilling", "running",
@@ -186,7 +196,8 @@ REQUIRED_STEP_RECORD_KEYS = frozenset({
     "finished", "pages_in_use", "pages_free", "pages_evictable",
     "dispatches", "sync_ms", "turnaround_ms", "d2h_ms", "slots", "preempted",
     "pool_pressure", "moe_pairs_here", "moe_pairs_away", "moe_experts_touched",
-    "pages_walked", "latent_tokens_written", "ahead",
+    "pages_walked", "latent_tokens_written", "ahead", "late",
+    "serial_reason",
 })
 REQUIRED_DEBUG_BUNDLE_KEYS = frozenset({
     "version", "t", "engine", "pool", "requests", "step_trace", "stats",
