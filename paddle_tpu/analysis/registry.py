@@ -41,9 +41,10 @@ from typing import Dict, List, Optional, Tuple
 # prefix-tail chunk in bucketed mode; zero programs in chunked mode, where
 # the chunk rides the fused batch), plus one COW page copy.  The swap budget
 # covers the two KV-copy executables — ONE fixed-shape gather
-# (`swap_out_pages` over page ids padded to the slot capacity, its output
-# split into pieces of `LLMEngine._swap_w` pages of which only the wanted
-# ones are fetched) and ONE scatter (`swap_in_pages`, page ids padded to
+# (`swap_out_pages` over page ids padded to the slot capacity: one
+# `dynamic_slice` a page, so the pool is read only where a page lies, its
+# output split into pieces of `LLMEngine._swap_w` pages of which only the
+# wanted ones are fetched) and ONE scatter (`swap_in_pages`, page ids padded to
 # the slot capacity) — shared by BOTH host-copy paths: preemption swap
 # parking (oversubscription PR) and the KV tier's prefix spill/restore
 # (tiering PR), which reuse the same programs so tiering adds ZERO
@@ -109,7 +110,9 @@ SERVE_RESOURCE_BUDGET: Dict[str, object] = {
         # preemption KV swap copies (oversubscription PR): the gather holds
         # pool + one slot-capacity staging buffer; the scatter holds pool +
         # the staging uploads and writes the donated pool in place.
-        # Measured 2026-10 (swap_out 139k/172k mp1/mp2, swap_in 139k/172k;
+        # Measured 2026-10 (swap_out 172k/172k mp1/mp2 since PR 38 takes a
+        # page a `dynamic_slice` — the walk counts the slices beside their
+        # concatenation — 139k/172k before; swap_in 139k/172k;
         # collective-free at mp2 — the page axis is unsharded) + ~10%.
         "swap_out": 190_000,
         "swap_in": 190_000,

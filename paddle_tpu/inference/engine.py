@@ -1352,7 +1352,10 @@ class LLMEngine:
             # `_swap_w` pages, so that each is its own transfer and the ones
             # past the wanted pages are dropped unfetched; ONE fixed-shape
             # executable and ONE dispatch whatever the page count (a dispatch
-            # a piece cost 0.7 ms of host time each on the chip).  The pin
+            # a piece cost 0.7 ms of host time each on the chip).  Each page
+            # is one `dynamic_slice` of the pool laid into its piece
+            # (`swap_out_pages`), so the program reads and writes a slot's
+            # width of pages and nothing else of the pool.  The pin
             # keeps the gathered buffers in the pool's KVH-sharded layout
             # under mp (the gather stays chip-local; the host fetch
             # assembles).
@@ -2481,7 +2484,9 @@ class LLMEngine:
         """How bytes leave the device, for a spill and a swap-out alike:
         ONE dispatch gathers `pages` out of the pool as pieces of `_swap_w`
         pages (one fixed-shape executable, a slot's width of ids padded with
-        the null page 0) and each piece that holds wanted pages starts its
+        the null page 0; a page is copied where it lies, so the program's
+        device time is a slot's width of page copies whatever the pool's
+        size) and each piece that holds wanted pages starts its
         device->host copy on the fetch worker right away — the engine
         thread does not wait for it, and the pieces past the wanted pages
         are dropped where they lie.  The worker copies one piece at a time,
@@ -3226,10 +3231,10 @@ class LLMEngine:
         warmup so the first preemption swap-out OR KV-tier spill/restore
         (both ride the SAME two executables) doesn't pay a compile inside
         the timed section.  The gather has ONE shape (a slot's width of
-        ids, `_swap_w` pages a piece) whatever the page count, so this is
-        every shape the path can reach.  No-op
-        unless the engine can reach them (optimistic admission +
-        preempt="swap", or kv_tier on)."""
+        ids, `_swap_w` pages a piece, one page copy an id) whatever the page
+        count, so this is every shape the path can reach.  No-op unless the
+        engine can reach them (optimistic admission + preempt="swap", or
+        kv_tier on)."""
         if not ((self.optimistic and self.preempt == "swap") or self.kv_tier):
             return
         P = self.cache.max_pages_per_slot

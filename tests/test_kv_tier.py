@@ -563,3 +563,116 @@ def test_nothing_stays_in_flight_past(params, cfg, monkeypatch, held_worker,
     if seam != "reset_counters":
         assert eng.stats()["kv_tier"]["spills"] > 0
     eng.cache.check_invariants()
+
+
+# ---------------------------------------------------------------------------
+# the gather itself (PR 38): a page is taken where it lies, a
+# `dynamic_slice` each — every lane of every kind of pool, bit for bit
+# ---------------------------------------------------------------------------
+
+def _xing4_tiny():
+    """The latent configuration of the benchmark's tiny Xing4.0 cell
+    (`benchmarks/checks/tiny_xing4`: one latent lane, 128 wide, three
+    layers of two mixers), as its driver derives it."""
+    import json
+    import pathlib
+
+    from benchmarks.drivers import serve_xing4
+    root = pathlib.Path(__file__).resolve().parents[1]
+    conf = json.loads((root / "benchmarks/checks/tiny_xing4/configs/"
+                       "xing4-tiny.json").read_text())
+    return serve_xing4.program_config(serve_xing4.model_of(conf))
+
+
+def _pool_of(kind, pages=11, page=8):
+    """A pool of `kind` with seeded content in every lane, as numpy."""
+    from paddle_tpu.models import hybrid
+    if kind == "latent":
+        pool = hybrid.init_paged_cache(_xing4_tiny(), pages, page, 2)
+    else:
+        pool = G.init_paged_cache(G.gpt_tiny(64), pages, page,
+                                  kv_dtype="int8" if kind == "int8" else None)
+    rng = np.random.default_rng(0)
+    return {n: (rng.integers(-128, 128, a.shape).astype(a.dtype)
+                if jnp.issubdtype(a.dtype, jnp.integer)
+                else rng.standard_normal(a.shape).astype(a.dtype))
+            for n, a in pool.items()}
+
+
+_GATHER_IDS = {
+    "null_padded": [3, 7, 1, 0, 0, 0, 0, 0],
+    "repeats": [5, 5, 2, 5, 2, 2, 5, 5],
+    "last_page": [10, 0, 10, 9, 1, 10, 0, 0],
+}
+
+
+@pytest.mark.parametrize("ids", list(_GATHER_IDS))
+@pytest.mark.parametrize("kind", ["dense", "latent", "int8"])
+def test_swap_out_pages_is_numpy_indexing_bit_for_bit(kind, ids):
+    """One piece of the gather against `a[:, ids]` of the same pool on the
+    host: a dense {"k","v"} pool, the one latent lane, an int8 pool with
+    its float32 scale lanes; ids padded with the null page, repeated, and
+    holding the pool's last page."""
+    host = _pool_of(kind)
+    assert set(host) == {"dense": {"k", "v"}, "latent": {"c"},
+                         "int8": {"k", "v", "k_scale", "v_scale"}}[kind]
+    page_ids = np.asarray(_GATHER_IDS[ids], np.int32)
+    got = jax.jit(G.swap_out_pages)(
+        {n: jnp.asarray(a) for n, a in host.items()}, jnp.asarray(page_ids))
+    assert set(got) == set(host)
+    for n, a in host.items():
+        piece = np.asarray(got[n])
+        assert piece.dtype == a.dtype
+        assert piece.shape == (a.shape[0], page_ids.size) + a.shape[2:]
+        np.testing.assert_array_equal(
+            piece.view(np.uint8), a[:, page_ids].view(np.uint8))
+
+
+def test_latent_pages_spilled_in_pieces_come_back_as_they_left(monkeypatch):
+    """A spill -> restore round trip on the tiny Xing4.0 configuration with
+    two-page pieces: what the host tier holds is what the pool held, and
+    the pages the restore scatters into hold it again, bit for bit."""
+    from paddle_tpu.inference import engine as E
+    from paddle_tpu.models import hybrid
+    cfg = _xing4_tiny()
+    monkeypatch.setattr(E, "_D2H_PIECE_BYTES", 2 * cfg.page_bytes(8))
+    eng = LLMEngine(hybrid.init_params(cfg, jax.random.key(1)), cfg,
+                    num_slots=2, page_size=8, num_pages=9, max_model_len=64,
+                    prefill_chunk=16, swap_pool_pages=64)
+    assert eng.kv_tier and set(eng._pool) == {"c"}
+    assert eng._swap_w == 2 < eng.cache.max_pages_per_slot
+    rng = np.random.RandomState(7)
+    shared = rng.randint(0, cfg.vocab_size, (20,)).astype(np.int32)
+    r1 = eng.add_request(shared, max_new_tokens=5)
+    outs = _steps(eng)
+    left = _cached_pages(eng)
+    for _ in range(6):
+        eng.add_request(rng.randint(0, cfg.vocab_size, (30,))
+                        .astype(np.int32), max_new_tokens=4)
+    outs.update(_steps(eng))
+    eng.drain()
+    parked = {nid: d for nid, d in eng.cache._tier._host.items()
+              if nid in left}
+    assert parked and eng.stats()["swap_d2h_fetches"] > 0
+    for nid, d in parked.items():
+        np.testing.assert_array_equal(d["c"], left[nid]["c"])
+    back = {}
+    restore = eng._tier_restore
+
+    def restore_and_look(slot, plan, rid):
+        ok = restore(slot, plan, rid)
+        lane = np.asarray(eng._pool["c"])
+        back.update({node.node_id: lane[:, dst].copy()
+                     for dst, node, _ in plan})
+        return ok
+
+    monkeypatch.setattr(eng, "_tier_restore", restore_and_look)
+    eng.add_request(np.concatenate(
+        [shared, np.asarray(outs[r1].token_ids, np.int32),
+         rng.randint(0, cfg.vocab_size, (4,)).astype(np.int32)]),
+        max_new_tokens=5)
+    _steps(eng)
+    assert eng.stats()["kv_tier"]["restores"] >= 1
+    assert back and set(back) <= set(left)
+    for nid, page in back.items():
+        np.testing.assert_array_equal(page, left[nid]["c"])

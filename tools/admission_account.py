@@ -53,6 +53,7 @@ FETCH = "engine.swap.fetch"
 COUNTERS = ("decode_iterations", "admitted_requests",
             "fused_launched_ahead", "fused_ahead_late",
             "fused_ahead_discarded_lanes", "swap_d2h_fetches",
+            "swap_d2h_bytes", "swap_d2h_useful_bytes",
             "swap_d2h_landed_free", "swap_d2h_backpressure_waits",
             "prefix_evictions", "kv_tier_spills", "turnaround_ms")
 PROGRAM = re.compile(r"^(jit_\w+)\(")
