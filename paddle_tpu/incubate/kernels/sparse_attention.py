@@ -22,9 +22,13 @@ reference of the tests:
   k-th largest score and the position of the last tie with it that is kept
   (ties to the lower position), found by counting bit by bit (no sort; the
   kernel `topk_threshold`); `mask_from` turns it into a mask.
-- `topk_rows` + `sparse_latent_attention`: the decode form - the k positions
-  themselves (`lax.top_k`: ties to the lower index), their latent rows
-  gathered, and absorbed attention over those rows only (`sparse_latent`).
+- `topk_rows_decode` + `sparse_latent_attention`: the decode form - the k
+  positions themselves, their latent rows gathered, and absorbed attention
+  over those rows only (`sparse_latent`).  On a TPU the positions come by
+  counting too (`topk_rows_pallas`): the slots' rows as the rows of one
+  `topk_threshold` tile, then `topk_compact` lists each row's kept positions
+  in ascending order; elsewhere `topk_rows` (`lax.top_k`, a sort: ties to
+  the lower index) is the route and the oracle.
 
 The masked form of the latent attention (every live row walked, the rows
 outside S_t masked out) is `paged_attention.paged_latent_attention` with its
@@ -379,6 +383,122 @@ def topk_rows(scores, k: int):
     vals, idx = jax.lax.top_k(flat, min(k, flat.shape[-1]))
     ok = vals > NEG_INF
     return jnp.where(ok, idx, 0), jnp.sum(ok, axis=-1).astype(jnp.int32)
+
+
+def _compact_kernel(nlive_ref, s_ref, thr_ref, last_ref, idx_ref, n_ref, *,
+                    keys: int):
+    """Grid (rows / bt,): bt decode rows' scores [nb, bt, keys] whole in
+    VMEM; the positions `mask_from`'s rule keeps (thr, last [bt, 1]) written
+    in ascending order into idx_ref [tiles, bt, 128] (tile j // 128 holds
+    output j of a row), their count into n_ref.  128 positions of a row at a
+    time: each kept lane moves down by the count of lanes before it that are
+    not kept (one shift a bit of that count, low bit first, so that no two
+    collide), the chunk is turned by the row's running count mod 128 and
+    lands in that count's output tile and the next.  No sort, no scatter."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    tiles, bt, _ = idx_ref.shape
+    thr, last = thr_ref[0], last_ref[0]                   # [bt, 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bt, 128), 1)
+    # before[j, i] = 1 where lane j comes before lane i
+    before = (jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0) <
+              jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
+              ).astype(jnp.float32)
+
+    def up(x, s):                                          # to higher lanes
+        return pltpu.roll(x, s % 128, 1)
+
+    def bit(x, b):
+        return ((x >> b) & 1) == 1
+
+    idx_ref[...] = jnp.zeros_like(idx_ref)
+
+    def chunk(i, c, count):
+        sc = s_ref[0, i, :, c * 128:(c + 1) * 128]        # [bt, 128]
+        key = order_key(sc)
+        pos = i * keys + c * 128 + lane
+        sel = (sc > NEG_INF) & ((key > thr) | ((key == thr) & (pos <= last)))
+        gap = jnp.dot(jnp.where(sel, 0.0, 1.0), before,
+                      preferred_element_type=jnp.float32).astype(jnp.int32)
+        for b in range(7):
+            go = sel & bit(gap, b)
+            come = up(go.astype(jnp.int32), -(1 << b)) == 1
+            pos = jnp.where(come, up(pos, -(1 << b)), pos)
+            gap = jnp.where(come, up(gap, -(1 << b)), gap)
+            sel = come | (sel & ~go)
+        n = jnp.sum(sel.astype(jnp.int32), axis=1, keepdims=True)
+        m, t = count & 127, count >> 7
+        for b in range(7):
+            pos = jnp.where(bit(m, b), up(pos, 1 << b), pos)
+        tile = jnp.where((lane >= m) & (lane < m + n), t,
+                         jnp.where(lane < m + n - 128, t + 1, -1))
+        for j in range(tiles):
+            idx_ref[j] = jnp.where(tile == j, pos, idx_ref[j])
+        return count + n
+
+    def block(i, count):
+        for c in range(keys // 128):
+            count = chunk(i, c, count)
+        return count
+
+    n_ref[...] = jax.lax.fori_loop(0, nlive_ref[0], block,
+                                   jnp.zeros((bt, 1), jnp.int32))
+
+
+def topk_rows_pallas(scores, k: int, q_offset, valid, interpret=False):
+    """`topk_rows` of decode scores [B, nb, 1, keys] by counting: the B rows
+    laid out as the rows of the threshold kernel's tiles ([1, nb, B', keys],
+    B' a multiple of 8, the walk bounded by the largest live position), its
+    (thr, last), then `_compact_kernel`.  The same sets as `topk_rows`, in
+    ascending position order."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, nb, T, keys = scores.shape
+    assert T == 1, "the decode form takes one query token a slot"
+    rows = -(-B // 8) * 8
+    bt = _threshold_rows(rows)
+    s = jnp.pad(scores.reshape(B, nb, keys).transpose(1, 0, 2),
+                ((0, 0), (0, rows - B), (0, 0)),
+                constant_values=NEG_INF)[None]             # [1, nb, rows, keys]
+    valid = jnp.asarray(valid, jnp.int32)
+    end = jnp.max(jnp.where(valid > 0,
+                            jnp.asarray(q_offset, jnp.int32) + valid, 0))
+    # the threshold kernel's tile ends at q_offset + bt - 1: the last position
+    thr, last = topk_threshold_pallas(s, k, (end - bt)[None], interpret)
+    K = min(k, nb * keys)
+    tiles = -(-K // 128)
+    n_live = jnp.minimum(-(-end // keys), nb).astype(jnp.int32)[None]
+    row = pl.BlockSpec((1, bt, 1), lambda t, nl: (0, t, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,          # live blocks
+        grid=(rows // bt,),
+        in_specs=[pl.BlockSpec((1, nb, bt, keys),
+                               lambda t, nl: (0, 0, t, 0)), row, row],
+        out_specs=[pl.BlockSpec((tiles, bt, 128), lambda t, nl: (0, t, 0)),
+                   pl.BlockSpec((bt, 1), lambda t, nl: (t, 0))],
+    )
+    idx, n = pl.pallas_call(
+        functools.partial(_compact_kernel, keys=keys),
+        name="topk_compact",
+        grid_spec=grid_spec,
+        out_shape=[_out_struct((tiles, rows, 128), jnp.int32, scores),
+                   _out_struct((rows, 1), jnp.int32, scores)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(n_live, s, thr, last)
+    idx = idx.transpose(1, 0, 2).reshape(rows, tiles * 128)[:B, :K]
+    return idx.reshape(B, 1, K), n[:B]
+
+
+def topk_rows_decode(scores, k: int, q_offset, valid):
+    """The decode form's top-k (T = 1, `models.hybrid.dsa_attention`):
+    `topk_rows_pallas` on a TPU where the layout suits it, the sort
+    (`topk_rows`) otherwise."""
+    if _on_tpu() and scores.shape[-1] % 128 == 0:
+        return topk_rows_pallas(scores, k, q_offset, valid)
+    return topk_rows(scores, k)
 
 
 # ---------------------------------------------------------------------------

@@ -1021,9 +1021,14 @@ class LLMEngine:
                 "dsa_rows_selected",
                 "rows the sparse latent attention read, sum of min(context, "
                 "index_topk) over query tokens (one DSA layer's worth)"),
+            "dsa_rows_searched": m.counter(
+                "dsa_rows_searched",
+                "query tokens whose selection needed the top-k search: more "
+                "than index_topk candidates (one DSA layer's worth)"),
         }
-        # the same two, of the fused steps alone (decode rows)
-        self._dsa_step_rows = {"dsa_rows_scored": 0, "dsa_rows_selected": 0}
+        # the same three, of the fused steps alone (decode rows)
+        self._dsa_step_rows = {"dsa_rows_scored": 0, "dsa_rows_selected": 0,
+                               "dsa_rows_searched": 0}
         m.gauge("latent_page_bytes", self._latent_page_bytes,
                 "bytes of one page of the latent lane over its layers (0 "
                 "for a configuration without latent attention)")
@@ -3793,10 +3798,12 @@ class LLMEngine:
             "latent_page_bytes": self._latent_page_bytes(),
             "index_page_bytes": self._index_page_bytes(),
             # the DSA rows of the fused steps alone (the totals, with the
-            # prefills', are dsa_rows_scored / dsa_rows_selected above)
+            # prefills', are dsa_rows_scored / _selected / _searched above)
             "dsa_step_rows_scored": self._dsa_step_rows["dsa_rows_scored"],
             "dsa_step_rows_selected":
                 self._dsa_step_rows["dsa_rows_selected"],
+            "dsa_step_rows_searched":
+                self._dsa_step_rows["dsa_rows_searched"],
             "ssm_state_bytes": self._ssm_state_bytes.value,
             "ssm_state_pool_bytes": 0 if self.cache.state is None else
                                     self.cache.state.pool_bytes,

@@ -81,10 +81,12 @@ KINDS = {"M": "mamba", "E": "experts", "*": "attention", "L": "mla",
 MTP_PATTERN = "LE"
 _BASE_AUX = MOE_COUNTERS + ("ssm_slots_live", "ssm_state_resets",
                             "latent_tokens_written", "mla_absorbed_rows")
-# a DSA configuration's two more (one DSA layer's worth: every DSA layer of a
-# program scores and selects the same rows): rows the indexer scored and rows
-# the latent attention read, summed over the program's real query tokens
-DSA_AUX = ("dsa_rows_scored", "dsa_rows_selected")
+# a DSA configuration's three more (one DSA layer's worth: every DSA layer of
+# a program scores and selects the same rows): rows the indexer scored, rows
+# the latent attention read, summed over the program's real query tokens, and
+# the query tokens whose selection needed the search (more than index_topk
+# candidates)
+DSA_AUX = ("dsa_rows_scored", "dsa_rows_selected", "dsa_rows_searched")
 AUX_FIELDS = _BASE_AUX + DSA_AUX
 
 
@@ -598,7 +600,9 @@ def dsa_attention(lp, q_nope, q_rope, qi, w, pool, ix_pool, page_table,
     `valid`) contract of `paged_latent_attention`): the indexer's scores over
     the slot's live index rows, the exact top-`index_topk` of each token,
     and absorbed attention over those rows only - in decode (T = 1) the
-    chosen rows gathered from the latent lane and weighed by
+    chosen positions listed by counting, with no sort (`topk_rows_decode`:
+    the threshold search over the slots as one tile's rows, then a
+    compaction), their rows gathered from the latent lane and weighed by
     `sparse_latent`; in a longer pass every live row walked by
     `masked_latent` with the others masked out (on the chip the masked
     prefill of a 16k / 32k prompt took 2.23 / 7.53 s, this decode form run
@@ -614,7 +618,8 @@ def dsa_attention(lp, q_nope, q_rope, qi, w, pool, ix_pool, page_table,
                                               q_offset, valid)
         with jax.named_scope("dsa.topk"):
             if sparse:
-                idx, n_sel = dsa_k.topk_rows(scores, c.index_topk)
+                idx, n_sel = dsa_k.topk_rows_decode(scores, c.index_topk,
+                                                    q_offset, valid)
             else:
                 sel = (scores,) + dsa_k.topk_threshold(scores, c.index_topk,
                                                        q_offset)
@@ -672,15 +677,17 @@ def _dsa_layer(lp, h, pos, pool, ix_pool, page_table, q_offset, n_real,
 
 
 def _dsa_rows(q_offset, n, k):
-    """(rows scored, rows selected) by n query tokens a slot at positions
-    q_offset .. q_offset + n - 1, summed over slots: sum of (p + 1) and of
-    min(p + 1, k)."""
+    """(rows scored, rows selected, tokens searched) by n query tokens a slot
+    at positions q_offset .. q_offset + n - 1, summed over slots: sum of
+    (p + 1), of min(p + 1, k) and of [p >= k]."""
     def upto(m):
         return jnp.where(m <= k, m * (m + 1) // 2,
                          k * (k + 1) // 2 + (m - k) * k)
     end = q_offset + n
     scored = n * q_offset + n * (n + 1) // 2
-    return jnp.sum(scored), jnp.sum(upto(end) - upto(q_offset))
+    searched = jnp.maximum(end - jnp.maximum(q_offset, k), 0)
+    return jnp.sum(scored), jnp.sum(upto(end) - upto(q_offset)), \
+        jnp.sum(searched)
 
 
 def ffn_mixer(lp, h):
@@ -720,7 +727,7 @@ def mhc_mixes(lp, X, c: HybridConfig):
 
 def _sum_aux(parts, live, resets, written, rows, dsa=()):
     """The `aux_fields` vector from the expert layers' counters and the
-    passes' own (`dsa`: a DSA configuration's two)."""
+    passes' own (`dsa`: a DSA configuration's three)."""
     def over(name, fn):
         vals = [p[name] for p in parts]
         return fn(jnp.stack(vals)) if vals else jnp.zeros((), jnp.int32)

@@ -114,18 +114,28 @@ def test_the_index_lane_sits_beside_the_latent_lane_and_is_counted():
 
 # ---- against the reference --------------------------------------------------
 
-@pytest.mark.parametrize("block", [2048, 32])
+@pytest.mark.parametrize("block,route", [(2048, "sort"), (32, "sort"),
+                                         (2048, "counted")],
+                         ids=["2048", "32", "2048-counted"])
 def test_prefill_then_decode_through_both_lanes_is_the_reference(
-        block, monkeypatch):
+        block, route, monkeypatch):
     """A 90-token prompt (selection binds past 16) in the masked form, whole
     or in query blocks of 32 under the scan, then five decode steps in the
-    sparse form: logits against the reference's full forward."""
+    sparse form: logits against the reference's full forward.  "counted":
+    the decode steps' top-k through the chip's route (the threshold search
+    and the compaction, in interpret mode), against the sort's logits too."""
     monkeypatch.setattr(hybrid, "DSA_QUERY_BLOCK", block)
     cfg, params, model = setup()
     toks = np.random.default_rng(0).integers(0, 256, 96, dtype=np.int32)
+    if route == "counted":
+        sorted_route, _ = program_logits(cfg, params, toks, 90)
+        monkeypatch.setattr(hybrid.dsa_k, "topk_rows_decode", functools.partial(
+            SA.topk_rows_pallas, interpret=True))
     got, _ = program_logits(cfg, params, toks, 90)
     want = reference_logits(params, model, toks, 90)
     assert float(np.abs(got - want).max()) < 1e-4
+    if route == "counted":
+        np.testing.assert_allclose(got, sorted_route, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("fault", ["selection_off", "index_k_unrotated",
@@ -224,9 +234,54 @@ def test_the_masked_and_sparse_kernels_are_their_oracles():
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("case", ["ties", "few", "wide"])
+def decode_rows(B, S, k, rng):
+    """Decode scores of B slots over a table of S positions (not whole
+    blocks), with (q_offset, valid): ties and -0 / 0 in slot 0, a slot with
+    fewer than k candidates, one with exactly k, one with every position
+    live, a padding slot (valid 0), the others at random lengths."""
+    x = np.round(rng.standard_normal((B, 1, S)) * 2).astype(np.float32) / 2
+    x[0, 0, :50] = 0.0
+    x[0, 0, 50:60] = -0.0
+    end = rng.integers(1, S + 1, B)
+    end[:4] = (S, k - 5, S, k)[:B]
+    valid = np.ones(B, np.int32)
+    if B > 2:
+        valid[2] = 0
+    for b in range(B):
+        x[b, 0, end[b] if valid[b] else 0:] = -1e30
+    return x, end - 1, valid
+
+
+@pytest.mark.parametrize("case", ["ties", "few", "wide", "decode-1",
+                                  "decode-3", "decode-12", "decode-16"])
 def test_the_top_k_is_exact_with_ties_to_the_lower_position(case):
+    """The threshold search and the sort (`topk_rows`) against the rule
+    itself; for decode rows (T = 1) also the chip's route in interpret mode
+    (`topk_rows_pallas`): the same sets, in ascending position order, the
+    rest position 0."""
     rng = np.random.default_rng(len(case))
+    if case.startswith("decode"):
+        B = int(case.split("-")[1])
+        S, k = 1100, 300
+        x, qo, vl = decode_rows(B, S, k, rng)
+        scores = SA.blocked(jnp.asarray(x), 16)
+        idx, n_sel = map(np.asarray, SA.topk_rows_pallas(
+            scores, k, jnp.asarray(qo, jnp.int32), jnp.asarray(vl),
+            interpret=True))
+        oidx, on_sel = map(np.asarray, SA.topk_rows(scores, k))
+        assert idx.shape == oidx.shape == (B, 1, k)
+        for b in range(B):
+            row = x[b, 0]
+            want = sorted(s for s in range(S) if row[s] > -1e29)
+            want = sorted(sorted(want, key=lambda s: (-row[s], s))[:k])
+            n = int(n_sel[b, 0])
+            assert n == on_sel[b, 0] == len(want)
+            assert idx[b, 0, :n].tolist() == want
+            assert sorted(oidx[b, 0, :n].tolist()) == want
+            assert not idx[b, 0, n:].any()
+        assert n_sel[0, 0] == k and (B < 4 or n_sel[1:4, 0].tolist() ==
+                                     [k - 5, 0, k])
+        return
     S = {"ties": 700, "few": 40, "wide": 1500}[case]
     k = {"ties": 100, "few": 64, "wide": 333}[case]
     x = rng.standard_normal((2, 3, S)).astype(np.float32)
@@ -399,8 +454,15 @@ def test_the_counters_are_the_rows_scored_and_chosen():
     assert st["dsa_step_rows_selected"] == sum(d[1] for d in dec)
     assert st["dsa_rows_scored"] == sum(r[0] for r in pre + dec)
     assert st["dsa_rows_selected"] == sum(r[1] for r in pre + dec)
+    # the tokens with more than 16 candidates: the selection searched
+    searched = [sum(p >= 16 for p in range(n, n + g - 1))
+                for n, g in zip(lengths, gen)]
+    assert st["dsa_step_rows_searched"] == sum(searched) > 0
+    assert st["dsa_rows_searched"] == sum(searched) + \
+        sum(max(n - 16, 0) for n in lengths)
     snap = eng.metrics.snapshot()["counters"]
     assert snap["dsa_rows_selected"] == st["dsa_rows_selected"]
+    assert snap["dsa_rows_searched"] == st["dsa_rows_searched"]
 
 
 # ---- what index_topk 0 leaves alone ------------------------------------------
@@ -481,5 +543,7 @@ def test_a_latent_configuration_without_dsa_serves_what_it_served():
             eng.add_request(x, max_new_tokens=6)
         outs = eng.run()
         assert set(eng._pool) == {"c"}
+        st = eng.stats()
+        assert st["dsa_rows_searched"] == st["dsa_step_rows_searched"] == 0
         return [outs[r].token_ids for r in sorted(outs)]
     assert run(params) == run(bare)

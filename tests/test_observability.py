@@ -508,6 +508,10 @@ NEW_STATS_KEYS = frozenset({
     "dsa_rows_scored", "dsa_rows_selected", "dsa_step_rows_scored",
     "dsa_step_rows_selected", "index_page_bytes",
 }) | frozenset({
+    # the decode top-k by counting: query tokens whose selection needed the
+    # search, of all programs and of the fused steps (both 0 without DSA)
+    "dsa_rows_searched", "dsa_step_rows_searched",
+}) | frozenset({
     # added by the paged-walk PR (ISSUE 29): pages the paged kernel walks
     # against the table entries its programs were handed
     "paged_pages_walked", "paged_table_entries",

@@ -542,10 +542,13 @@ def glm5_programs(topo):
 
 def test_dsa_decode_step_reads_chosen_rows_through_its_own_kernels(
         glm5_programs):
-    """The GLM-5 fused step: the indexer's paged walk and attention over
-    the 2,048 chosen rows (`sparse_latent`), no dense latent walk, no copy
-    of either lane; the two kernels' signatures are read by their roofline
-    metrics and by no other kernel's metric."""
+    """The GLM-5 fused step: the indexer's paged walk, the top-k by
+    counting (the threshold kernel over the 12 slots as the rows of one
+    16-row tile, then the compaction) and attention over the 2,048 chosen
+    rows (`sparse_latent`), no dense latent walk, no copy of either lane;
+    the index and sparse kernels' signatures are read by their roofline
+    metrics and by no other kernel's metric, the threshold kernel by
+    `dsa_time_share` (the prefill's signature), the compaction by none."""
     pool, step, _ = glm5_programs
     text = step.as_text()
     assert set(pool) == {"c", "ix"}
@@ -557,18 +560,23 @@ def test_dsa_decode_step_reads_chosen_rows_through_its_own_kernels(
     sparse = [x for x in shorts if _reads("dsa_sparse_roofline", x)]
     assert len(index) == 1 and "out=f32[12," in index[0]
     assert len(sparse) == 1 and "out=bf16[12,64,512] in=3" in sparse[0]
+    thr = [x for x in shorts if x.startswith("%topk_threshold")]
+    compact = [x for x in shorts if x.startswith("%topk_compact")]
+    assert [x.split(" custom-call ")[1] for x in thr + compact] == [
+        "tpu_custom_call out=(s32[1,16,1], s32[1,16,1]) in=2",
+        "tpu_custom_call out=(s32[16,16,128], s32[16,1]) in=4"]
     for x in shorts:
-        assert _reads("dsa_time_share", x) == (x in index + sparse), x
+        assert _reads("dsa_time_share", x) == (x in index + sparse + thr), x
         assert not _reads("mla_attention_time_share", x), x
         assert not _reads("mla_paged_roofline", x), x
-    # the top-k is an XLA sort, which the share reads by its opcode: one a
-    # DSA layer over a slot's whole table of scores, beside the expert
-    # layer's routing sorts (top-8 of 256, and the pairs' argsort)
+    # no sort of a slot's scores is left: the sorts the share reads by
+    # their opcode are the expert layer's routing sorts (top-8 of 256, and
+    # the pairs' argsort)
     from benchmarks.harness import xplane
     sorts = [ln.strip().replace("ROOT ", "") for ln in text.splitlines()
              if re.search(r"\) sort\(", ln)]
     top = [x for x in sorts if re.search(r"= \(f32\[12,1,\d+\]", x)]
-    assert len(top) == 1 and len(sorts) == 3, sorts
+    assert top == [] and len(sorts) == 2, sorts
     for x in sorts:
         assert _reads("dsa_time_share", xplane.short_name(x)), x
         assert not _reads("dsa_sparse_roofline", xplane.short_name(x)), x

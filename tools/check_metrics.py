@@ -127,6 +127,9 @@ REQUIRED_STATS_KEYS = frozenset({
     # steps alone), and the index lane's bytes a page (0 without DSA)
     "dsa_rows_scored", "dsa_rows_selected", "dsa_step_rows_scored",
     "dsa_step_rows_selected", "index_page_bytes",
+    # the decode top-k by counting: query tokens whose selection needed the
+    # search (more than index_topk candidates), all programs and fused steps
+    "dsa_rows_searched", "dsa_step_rows_searched",
     # launch-ahead PR (ISSUE 33): fused launches made before the previous
     # program's result was read, and the lanes of those dropped at harvest
     "fused_launched_ahead", "fused_ahead_discarded_lanes",
@@ -192,6 +195,8 @@ REQUIRED_COUNTERS = frozenset({
     "fused_launched_ahead", "fused_ahead_discarded_lanes",
     # sparse-attention PR (ISSUE 39): the indexer's rows and the chosen ones
     "dsa_rows_scored", "dsa_rows_selected",
+    # the decode top-k by counting: the tokens whose selection was searched
+    "dsa_rows_searched",
     # admission-account PR (ISSUE 36): one labelled family, a sample a reason
     "fused_ahead_late",
 }) | frozenset(f'fused_serial_steps{{reason="{why}"}}'

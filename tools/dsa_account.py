@@ -8,10 +8,13 @@ Runs `benchmarks/run.py --trace 1` in this process (its result line is
 printed as ever), then reads the same trace file once more with each device
 operation's whole HLO text, which the benchmark's op table drops
 (`xplane.short_name` keeps an XLA fusion's number only).  By that text it
-tells apart what no per-layer metric can: the decode step's top-k (`lax.top_k`
-over a slot's scores: a sort of (f32[B,1,S], s32[B,1,S])), the expert layers'
-routing sorts, and XLA's gather of the chosen latent rows (a kCustom fusion
-out bf16[B * index_topk, lane]); beside them the four DSA kernels by name.
+tells apart what no per-layer metric can: the decode step's top-k (on the
+chip the threshold kernel over the slots as one tile's rows, out (s32[1,R,1],
+s32[1,R,1]) with R a few dozen at most, and `topk_compact`; where the sort
+route still runs, `lax.top_k` over a slot's scores: a sort of (f32[B,1,S],
+s32[B,1,S])), the expert layers' routing sorts, and XLA's gather of the
+chosen latent rows (a kCustom fusion out bf16[B * index_topk, lane]); beside
+them the DSA kernels by name.
 On the CPU (`--rehearse`) there is no device plane and every piece reads 0.
 Prints `DSA {json}` and writes `<out>/<cell>.json`: seconds and events in the
 slice by piece, ms a fused step, the fused steps counted, and the gather +
@@ -40,6 +43,9 @@ PIECES = (
     ("sparse_latent", r"^%sparse_latent\b"),
     ("index_scores.decode", r"^%paged_index_scores\S* = f32\[\d+,\d+,1,\d+\]"),
     ("index_scores.prefill", r"^%paged_index_scores\b"),
+    ("topk_threshold.decode",
+     r"^%topk_threshold\S* = \(s32\[1,\d{1,2},1\]"),
+    ("topk_compact.decode", r"^%topk_compact\b"),
     ("topk_threshold", r"^%topk_threshold\b"),
     ("masked_latent", r"^%masked_latent\b"),
     ("topk_sort.decode",
@@ -124,6 +130,11 @@ def main(argv=None) -> int:
     acc, drv = kept["acc"], kept["driver"]
     m, facts = drv.model, drv.facts
     acc["workload"], acc["seed"] = args.workload, args.seed
+    # query tokens whose selection needed the search, all programs and the
+    # fused steps' (the `[glm5]` line predates the counter)
+    st = drv.eng.stats()
+    acc["dsa_rows_searched"] = st.get("dsa_rows_searched")
+    acc["dsa_step_rows_searched"] = st.get("dsa_step_rows_searched")
     per_call = facts.get("slice_dsa_step_rows_selected_per_call")
     calls = acc["events"].get("sparse_latent", 0)
     unit_s = acc["seconds"].get("sparse_latent", 0.0) + \
