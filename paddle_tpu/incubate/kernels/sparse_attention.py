@@ -32,7 +32,9 @@ reference of the tests:
 
 The masked form of the latent attention (every live row walked, the rows
 outside S_t masked out) is `paged_attention.paged_latent_attention` with its
-`select` argument.
+`select` argument, absorbed; a long pass takes it expanded, per head
+(`masked_expanded_attention`: keys built once a layer, the selection as one
+keep byte a token and key).
 """
 from __future__ import annotations
 
@@ -577,3 +579,180 @@ def sparse_latent_attention(q, rows, n_sel, latent: int, scale: float):
             and q.shape[1] % 8 == 0 and rows.shape[1] % 128 == 0:
         return sparse_latent_attention_pallas(q, rows, n_sel, latent, scale)
     return sparse_latent_attention_xla(q, rows, n_sel, latent, scale)
+
+
+# ---------------------------------------------------------------------------
+# the masked form expanded: per-head keys and values, a long pass
+# ---------------------------------------------------------------------------
+# A query block of a long pass weighs its chosen keys in the EXPANDED form,
+# per head k_h = [W^K_h c ; k_rope] and v_h = W^V_h c, so a pair and head
+# costs 2 (nope + rope) + 2 v_head_dim operations (1,024 at GLM-5's
+# 256 | 256) where the absorbed walk spends 2 lane + 2 latent (2,304).  The
+# keys are built once a layer by the caller (`models.hybrid._expanded_keys`);
+# the values are formed a key block at a time from the latent rows inside
+# the kernel (2 latent v_head_dim operations a key, head and query tile:
+# 1/8 of the tile's attention at 2,048 queries), because at a 32k prompt
+# the values of every head would hold another 1.07 GB beside the keys'.
+# The selection reaches the kernel as one keep byte a (token, key), read
+# once a head: the float32 scores a head would read are four.
+
+# query rows a grid step holds; the keys of a block of its walk are the
+# scores' own blocks, so that key block j is block j of the keep mask
+_EXPANDED_Q_ROWS = 2048
+
+
+def masked_expanded_attention_xla(q, k, c, wv, q_offset, select,
+                                  scale: float):
+    """Attention of T query tokens a slot over their chosen keys, expanded
+    (fallback + oracle).  q [B, T, H, D]; k [B, H, D, S]: per head the keys
+    of the slot's positions 0 .. S - 1, the position minor (as their product
+    writes them); c [B, S, >= latent] the same positions' latent rows and
+    wv [H, latent, Dv] W^V, whose product is each head's values; `select` as
+    `paged_latent_attention`'s (a row weighs exactly the keys `mask_from`
+    keeps, never one past its own position).  Returns [B, T, H, Dv]."""
+    B, T, H, _ = q.shape
+    S = k.shape[-1]
+    v = jnp.einsum("bsc,hcv->bhsv", c[:, :S, :wv.shape[1]], wv)
+    logits = jnp.einsum("bthd,bhds->bhts", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    qpos = q_offset[:, None] + jnp.arange(T)
+    mask = unblocked(mask_from(*select))[:, :, :S] & \
+        (jnp.arange(S)[None, None] <= qpos[:, :, None])
+    p = jax.nn.softmax(jnp.where(mask[:, None], logits, NEG_INF), axis=-1)
+    return jnp.einsum("bhts,bhsv->bthv", p.astype(v.dtype), v)
+
+
+def _expanded_kernel(qoff_ref, q_ref, wv_ref, k_hbm, c_hbm, keep_hbm, o_ref,
+                     kbuf, cbuf, mbuf, sem, acc_ref, m_ref, l_ref, *, bq: int,
+                     bk: int, n_k: int, scale: float):
+    """Grid (B, T/bq, H): a slot, a tile of bq query tokens and a head a
+    step.  The walk runs over the key blocks up to the tile's last position
+    only (causal), block j + 1's copies in flight while block j is weighed:
+    the head's keys k [B, H, D, S] (the block's columns), the block's latent
+    rows (their first `latent` columns; times the head's W^V, its values)
+    and the tile's rows of block j of the keep mask.  A row with no kept key
+    in a block passes it on the finite NEG_INF; the next kept key's
+    correction erases what that added (every real row keeps its own
+    position)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, i, h = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    last = qoff_ref[b] + i * bq + bq - 1
+    n_live = jnp.minimum(last // bk + 1, n_k)
+    latent = cbuf.shape[-1]
+
+    def copies(j, slot):
+        keys = pl.ds(j * bk, bk)
+        return (pltpu.make_async_copy(k_hbm.at[b, h, :, keys],
+                                      kbuf.at[slot], sem.at[0, slot]),
+                pltpu.make_async_copy(c_hbm.at[b, keys, pl.ds(0, latent)],
+                                      cbuf.at[slot], sem.at[1, slot]),
+                pltpu.make_async_copy(keep_hbm.at[b, j, pl.ds(i * bq, bq)],
+                                      mbuf.at[slot], sem.at[2, slot]))
+
+    for cp in copies(0, 0):
+        cp.start()
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    q = q_ref[0]                                        # [bq, D]
+    wv = wv_ref[0]                                      # [latent, Dv]
+
+    def block(j, carry):
+        slot = j % 2
+
+        @pl.when(j + 1 < n_live)
+        def _next():
+            for cp in copies(j + 1, 1 - slot):
+                cp.start()
+
+        for cp in copies(j, slot):
+            cp.wait()
+        v = jnp.dot(cbuf[slot], wv, preferred_element_type=jnp.float32
+                    ).astype(wv.dtype)                  # [bk, Dv]
+        s = jnp.dot(q, kbuf[slot], preferred_element_type=jnp.float32) \
+            * scale                                     # [bq, bk]
+        s = jnp.where(mbuf[slot].astype(jnp.int32) != 0, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_live, block, 0)
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                ).astype(o_ref.dtype)
+
+
+def _expanded_rows(T: int) -> int:
+    return next((b for b in (_EXPANDED_Q_ROWS, 1024, 512, 256, 128, 64, 32)
+                 if T % b == 0), 0)
+
+
+def masked_expanded_attention_pallas(q, k, c, wv, q_offset, select,
+                                     scale: float, interpret=False):
+    """The kernel behind `masked_expanded_attention` (same contract as the
+    oracle; T a multiple of 32).  q and the output lie [B, T, H * width]
+    (the layouts the query's product writes and W^O reads), a head's query
+    tile a block of whole 128-lane columns."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, H, D = q.shape
+    latent, Dv = wv.shape[1:]
+    keep = mask_from(*select).astype(jnp.int8)         # [B, nb, T, keys]
+    nb, keys = keep.shape[1], keep.shape[-1]
+    if k.shape[-1] < nb * keys:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, nb * keys - k.shape[-1])))
+    if c.shape[1] < nb * keys:
+        c = jnp.pad(c, ((0, 0), (0, nb * keys - c.shape[1]), (0, 0)))
+    bq = _expanded_rows(T)
+    tile = lambda b, i, h, qo: (b, i, h)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,          # q_offset
+        grid=(B, T // bq, H),
+        in_specs=[pl.BlockSpec((1, bq, D), tile),
+                  pl.BlockSpec((1, latent, Dv),
+                               lambda b, i, h, qo: (h, 0, 0)),
+                  pl.BlockSpec(memory_space=pltpu.HBM),
+                  pl.BlockSpec(memory_space=pltpu.HBM),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((1, bq, Dv), tile),
+        scratch_shapes=[pltpu.VMEM((2, D, keys), k.dtype),
+                        pltpu.VMEM((2, keys, latent), c.dtype),
+                        pltpu.VMEM((2, bq, keys), jnp.int8),
+                        pltpu.SemaphoreType.DMA((3, 2)),
+                        pltpu.VMEM((bq, Dv), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_expanded_kernel, bq=bq, bk=keys, n_k=nb,
+                          scale=scale),
+        name="masked_expanded",
+        grid_spec=grid_spec,
+        out_shape=_out_struct((B, T, H * Dv), q.dtype, q, k, c),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=96 << 20),
+        interpret=interpret,
+    )(jnp.asarray(q_offset, jnp.int32), q.reshape(B, T, H * D), wv, k, c,
+      keep)
+    return out.reshape(B, T, H, Dv)
+
+
+def masked_expanded_attention(q, k, c, wv, q_offset, select, scale: float):
+    """A long DSA pass's attention over each token's chosen keys in the
+    expanded form (`models.hybrid.dsa_attention`): the kernel on a TPU where
+    the layout suits it (whole 128-lane heads and latents, whole query
+    tiles), the oracle otherwise."""
+    if _on_tpu() and q.shape[-1] % 128 == 0 and wv.shape[-1] % 128 == 0 \
+            and wv.shape[1] % 128 == 0 and _expanded_rows(q.shape[1]):
+        return masked_expanded_attention_pallas(q, k, c, wv, q_offset, select,
+                                                scale)
+    return masked_expanded_attention_xla(q, k, c, wv, q_offset, select, scale)

@@ -1025,6 +1025,10 @@ class LLMEngine:
                 "dsa_rows_searched",
                 "query tokens whose selection needed the top-k search: more "
                 "than index_topk candidates (one DSA layer's worth)"),
+            "dsa_expanded_tokens": m.counter(
+                "dsa_expanded_tokens",
+                "query tokens whose sparse attention ran in the expanded "
+                "form (per-head keys and values: a long pass)"),
         }
         # the same three, of the fused steps alone (decode rows)
         self._dsa_step_rows = {"dsa_rows_scored": 0, "dsa_rows_selected": 0,

@@ -81,12 +81,14 @@ KINDS = {"M": "mamba", "E": "experts", "*": "attention", "L": "mla",
 MTP_PATTERN = "LE"
 _BASE_AUX = MOE_COUNTERS + ("ssm_slots_live", "ssm_state_resets",
                             "latent_tokens_written", "mla_absorbed_rows")
-# a DSA configuration's three more (one DSA layer's worth: every DSA layer of
+# a DSA configuration's four more (one DSA layer's worth: every DSA layer of
 # a program scores and selects the same rows): rows the indexer scored, rows
-# the latent attention read, summed over the program's real query tokens, and
+# the latent attention read, summed over the program's real query tokens,
 # the query tokens whose selection needed the search (more than index_topk
-# candidates)
-DSA_AUX = ("dsa_rows_scored", "dsa_rows_selected", "dsa_rows_searched")
+# candidates), and the query tokens whose attention ran in the expanded form
+# (a long pass, `DSA_EXPANDED_MIN_TOKENS`)
+DSA_AUX = ("dsa_rows_scored", "dsa_rows_selected", "dsa_rows_searched",
+           "dsa_expanded_tokens")
 AUX_FIELDS = _BASE_AUX + DSA_AUX
 
 
@@ -595,23 +597,28 @@ def latent_attention(lp, q_nope, q_rope, pool, page_table, q_offset, valid,
 
 
 def dsa_attention(lp, q_nope, q_rope, qi, w, pool, ix_pool, page_table,
-                  q_offset, valid, c: HybridConfig):
+                  q_offset, valid, c: HybridConfig, expanded=None):
     """A DSA layer's attention for T query tokens a slot (the (`q_offset`,
     `valid`) contract of `paged_latent_attention`): the indexer's scores over
     the slot's live index rows, the exact top-`index_topk` of each token,
-    and absorbed attention over those rows only - in decode (T = 1) the
+    and attention over those rows only - in decode (T = 1) absorbed, the
     chosen positions listed by counting, with no sort (`topk_rows_decode`:
     the threshold search over the slots as one tile's rows, then a
     compaction), their rows gathered from the latent lane and weighed by
-    `sparse_latent`; in a longer pass every live row walked by
-    `masked_latent` with the others masked out (on the chip the masked
-    prefill of a 16k / 32k prompt took 2.23 / 7.53 s, this decode form run
-    on 256-query blocks 5.50 / 13.69: `PERF.md` §6, PR 39).  pool /
-    ix_pool: the lanes flat over layers, page_table offset to this layer's
-    pages."""
+    `sparse_latent`; in a longer pass every live row walked with the others
+    masked out: absorbed by `masked_latent`, or with `expanded` (a long
+    pass's per-head keys and the latent rows they came from, `_dsa_layer`)
+    expanded by `masked_expanded`.  (On the chip the masked absorbed
+    prefill of a 16k / 32k prompt took 2.23 / 7.53 s, the decode form run
+    on 256-query blocks 5.50 / 13.69: `PERF.md` §6.)  pool / ix_pool: the
+    lanes flat over layers, page_table offset to this layer's pages."""
     B, T, H, _ = q_nope.shape
     sparse = T == 1
-    q = _absorbed_q(lp, q_nope, q_rope, c)
+    if expanded is None:
+        q = _absorbed_q(lp, q_nope, q_rope, c)
+    else:
+        with jax.named_scope("mla.q"):
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
     with jax.named_scope("dsa"):
         with jax.named_scope("dsa.score"):
             scores = dsa_k.paged_index_scores(qi, w, ix_pool, page_table,
@@ -623,6 +630,13 @@ def dsa_attention(lp, q_nope, q_rope, qi, w, pool, ix_pool, page_table,
             else:
                 sel = (scores,) + dsa_k.topk_threshold(scores, c.index_topk,
                                                        q_offset)
+    if expanded is not None:
+        with jax.named_scope("mla.attn"):
+            o = dsa_k.masked_expanded_attention(
+                q, *expanded, lp["kv_b_v_w"], q_offset, sel,
+                c.mla_softmax_scale)
+        with jax.named_scope("mla.out"):
+            return jnp.matmul(o.reshape(B, T, H * c.v_head_dim), lp["o_w"])
     with jax.named_scope("mla.attn"):
         if sparse:
             page = pool.shape[1]
@@ -643,15 +657,53 @@ def dsa_attention(lp, q_nope, q_rope, qi, w, pool, ix_pool, page_table,
 # query tokens a slot of a DSA pass alive at once (a 32k prompt's queries
 # whole would not fit beside the pool)
 DSA_QUERY_BLOCK = 2048
+# query tokens a slot from which a DSA pass attends in the expanded form.
+# A pair and head costs 2 (N + R) + 2 Dv operations there (1,024 at GLM-5's
+# widths) where the absorbed form spends 2 lane + 2 C (2,304): 1,280 fewer.
+# Against that, a key's per-head keys cost 2 (C + R) (N + R) a head, once
+# (the rotated key's identity block included), and its values 2 C Dv a head
+# for every query tile that weighs it (`masked_expanded`; one tile of T up
+# to 2,048).  A pass from position 0 shows a key T / 2 queries on average,
+# so it gains where 640 T > 2 (C + R)(N + R) + C Dv: from T = 666 on at
+# GLM-5's widths; the next power of two.  Decode (T = 1) stays sparse, and
+# a shorter pass absorbed.
+DSA_EXPANDED_MIN_TOKENS = 1024
+
+
+def _expanded_pass(T: int) -> bool:
+    return T >= DSA_EXPANDED_MIN_TOKENS
+
+
+def _expanded_keys(lp, rows, c: HybridConfig):
+    """Per-head keys [W^K_h c ; k_rope] [B, H, N + R, S] of latent rows
+    [B, S, >= latent_row], laid out as their product writes them (nothing is
+    copied after it).  The rotated key all heads share rides the product
+    through an identity block, so the keys are written once."""
+    C, R, N = c.kv_lora_rank, c.qk_rope_head_dim, c.qk_nope_head_dim
+    wk = lp["kv_b_k_w"]                                 # [H, N, C]
+    with jax.named_scope("mla.kv"):
+        w = jnp.zeros((c.num_heads, C + R, N + R), wk.dtype)
+        w = w.at[:, :C, :N].set(jnp.swapaxes(wk, 1, 2)).at[:, C:, N:].set(
+            jnp.eye(R, dtype=wk.dtype))
+        return jnp.einsum("bsw,hwn->bhns", rows[..., :C + R], w)
 
 
 def _dsa_layer(lp, h, pos, pool, ix_pool, page_table, q_offset, n_real,
-               c: HybridConfig):
+               c: HybridConfig, rows=None):
     """A DSA layer's queries over normed h [B, T, D] at pos [B, T], after
     the rows of this call are in both lanes: one `dsa_attention` call, or
-    blocks of `DSA_QUERY_BLOCK` tokens under a scan when T is longer."""
+    blocks of `DSA_QUERY_BLOCK` tokens under a scan when T is longer.  A
+    pass of `DSA_EXPANDED_MIN_TOKENS` or more builds its per-head keys once,
+    ahead of the blocks, from the latent rows of its keys: `rows` (the
+    pass's own, where it starts at position 0) or else the lane's through
+    the page table."""
     B, T, _ = h.shape
     block = min(T, DSA_QUERY_BLOCK)
+    expanded = None
+    if _expanded_pass(T):
+        if rows is None:
+            rows = pool[page_table].reshape(B, -1, pool.shape[-1])
+        expanded = (_expanded_keys(lp, rows, c), rows)
 
     def one(hb, pb, qo, vl):
         sin, cos = _rope_tables(pb, c)
@@ -659,7 +711,7 @@ def _dsa_layer(lp, h, pos, pool, ix_pool, page_table, q_offset, n_real,
         with jax.named_scope("dsa"):
             qi, w = index_query(lp, hb, cq, sin, cos, c)
         return dsa_attention(lp, q_nope, q_rope, qi, w, pool, ix_pool,
-                             page_table, qo, vl, c)
+                             page_table, qo, vl, c, expanded=expanded)
     if block == T:
         return one(h, pos, q_offset, n_real)
     if T % block:
@@ -676,10 +728,11 @@ def _dsa_layer(lp, h, pos, pool, ix_pool, page_table, q_offset, n_real,
     return jnp.moveaxis(y, 0, 1).reshape(h.shape)
 
 
-def _dsa_rows(q_offset, n, k):
-    """(rows scored, rows selected, tokens searched) by n query tokens a slot
-    at positions q_offset .. q_offset + n - 1, summed over slots: sum of
-    (p + 1), of min(p + 1, k) and of [p >= k]."""
+def _dsa_rows(q_offset, n, k, T: int):
+    """(rows scored, rows selected, tokens searched, tokens expanded) by n
+    query tokens a slot at positions q_offset .. q_offset + n - 1 of a pass
+    of T, summed over slots: sum of (p + 1), of min(p + 1, k), of [p >= k],
+    and of 1 where the pass is expanded (`_expanded_pass`)."""
     def upto(m):
         return jnp.where(m <= k, m * (m + 1) // 2,
                          k * (k + 1) // 2 + (m - k) * k)
@@ -687,7 +740,7 @@ def _dsa_rows(q_offset, n, k):
     scored = n * q_offset + n * (n + 1) // 2
     searched = jnp.maximum(end - jnp.maximum(q_offset, k), 0)
     return jnp.sum(scored), jnp.sum(upto(end) - upto(q_offset)), \
-        jnp.sum(searched)
+        jnp.sum(searched), jnp.sum(n) * int(_expanded_pass(T))
 
 
 def ffn_mixer(lp, h):
@@ -844,8 +897,9 @@ def prefill_paged(params, input_ids, config: HybridConfig, cache, pages,
     zeros and is written as it stands after position length - 1: bucket
     padding moves neither lane.  A latent layer stays in the absorbed form
     (the rows it has just written, read back through the paged kernel with
-    q_offset 0).  Returns (logits [B, V] at the last real position, cache,
-    aux)."""
+    q_offset 0); a DSA layer of a long bucket attends in the expanded form
+    from those rows (`_dsa_layer`).  Returns (logits [B, V] at the last real
+    position, cache, aux)."""
     c = config
     B, Sb = input_ids.shape
     H, KVH, hd = c.num_heads, c.kv_heads, c.head_dim
@@ -882,7 +936,7 @@ def prefill_paged(params, input_ids, config: HybridConfig, cache, pages,
     def latent(lp, h, cache, i):
         if c.index_topk:
             return _dsa_write_and_attend(lp, h, pos, cache, i, pidx, off,
-                                         pages, zero, length, c)
+                                         pages, zero, length, c, own=True)
         q_nope, q_rope, row = latent_qkv(lp, h, pos, c)
         flat = _write_rows(cache["c"], i * P + pidx, off, row)
         y = latent_attention(lp, q_nope, q_rope, flat, pages + i * P, zero,
@@ -895,16 +949,17 @@ def prefill_paged(params, input_ids, config: HybridConfig, cache, pages,
     has_m, has_l = int(c.count("M") > 0), int(c.count("L") > 0)
     aux = _sum_aux(counters, jnp.asarray(B * has_m), jnp.asarray(B * has_m),
                    jnp.sum(length) * has_l, jnp.sum(length) * has_l,
-                   _dsa_rows(zero, length, c.index_topk)
+                   _dsa_rows(zero, length, c.index_topk, Sb)
                    if c.index_topk else ())
     return gpt_mod.head_logits(x, params, c), cache, aux
 
 
 def _dsa_write_and_attend(lp, h, pos, cache, i, pidx, off, table, q_offset,
-                          n_real, c: HybridConfig):
+                          n_real, c: HybridConfig, own: bool = False):
     """A DSA layer of either serving pass: the rows of this call written
     into both lanes (the latent row and k^I, in the same pass), then its
-    queries (`_dsa_layer`).  Returns (y, cache)."""
+    queries (`_dsa_layer`; `own`: the pass starts at position 0, so its own
+    rows are every key it reads).  Returns (y, cache)."""
     P = cache["c"].shape[1]
     sin, cos = _rope_tables(pos, c)
     row = _latent_row(lp, h, sin, cos, c)
@@ -912,7 +967,8 @@ def _dsa_write_and_attend(lp, h, pos, cache, i, pidx, off, table, q_offset,
     with jax.named_scope("dsa"):
         fix = _write_rows(cache["ix"], i * P + pidx, off,
                           index_key(lp, h, sin, cos, c))
-    y = _dsa_layer(lp, h, pos, flat, fix, table + i * P, q_offset, n_real, c)
+    y = _dsa_layer(lp, h, pos, flat, fix, table + i * P, q_offset, n_real, c,
+                   rows=row if own else None)
     return y, dict(cache, c=flat.reshape(cache["c"].shape),
                    ix=fix.reshape(cache["ix"].shape))
 
@@ -974,7 +1030,7 @@ def _step_hidden(params, tokens, cache, page_table, q_offset, valid,
                    jnp.sum(jnp.where(active, q_offset + n_real, 0)) * has_l,
                    jnp.sum(n_real) * has_l,
                    _dsa_rows(jnp.where(active, q_offset, 0), n_real,
-                             c.index_topk) if c.index_topk else ())
+                             c.index_topk, T) if c.index_topk else ())
     return x, cache, aux
 
 

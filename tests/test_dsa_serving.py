@@ -19,7 +19,7 @@ from paddle_tpu.incubate.distributed.models.moe.dropless import moe_dropless
 from paddle_tpu.incubate.kernels import paged_attention as PA
 from paddle_tpu.incubate.kernels import sparse_attention as SA
 from paddle_tpu.inference.engine import LLMEngine
-from paddle_tpu.models import hybrid
+from paddle_tpu.models import gpt, hybrid
 from paddle_tpu.quantization.serving import kv_page_bytes
 
 F32 = jnp.float32
@@ -56,7 +56,7 @@ def setup(seed=3, **kw):
 
 @functools.lru_cache(maxsize=None)
 def _setup(seed, kw):
-    cfg = hybrid.dsa_tiny(dtype=F32, **dict(kw))
+    cfg = hybrid.dsa_tiny(**dict(dict(dtype=F32), **dict(kw)))
     return cfg, hybrid.init_params(cfg, jax.random.key(seed)), model_dict(cfg)
 
 
@@ -304,6 +304,163 @@ def test_the_top_k_is_exact_with_ties_to_the_lower_position(case):
                 sorted(want)
 
 
+# ---- the long pass expanded -------------------------------------------------
+
+def _selection(rng, B, T, S, q_offset, valid, k, ties):
+    """Blocked scores of B slots' T query tokens at positions q_offset + t
+    over S positions (page 8), causal, -inf on padding rows (t >= valid),
+    and their exact top-k (thr, last)."""
+    x = rng.standard_normal((B, T, S)).astype(np.float32)
+    if ties:
+        x = np.round(x)                 # a few values only: ties at thr
+        x[:, :, :40] = 0.0
+    pos = np.asarray(q_offset)[:, None, None] + np.arange(T)[None, :, None]
+    pad = np.arange(T)[None, :, None] >= np.asarray(valid)[:, None, None]
+    x[(np.arange(S)[None, None] > pos) | pad] = -1e30
+    scores = SA.blocked(jnp.asarray(x), PAGE)
+    return (scores,) + SA.topk_threshold_xla(scores, k)
+
+
+@pytest.mark.parametrize("case", ["offset0", "later-block", "ties", "few",
+                                  "padding", "slots"])
+def test_the_expanded_form_is_the_absorbed_oracle(case):
+    """The expanded route (per-head keys from the lane's rows, values formed
+    from the latent rows; the XLA form and the kernel in interpret mode)
+    against the absorbed oracle `paged_latent_attention_xla(select=...)`
+    through W^V: the first query block (q_offset 0), a later block (q_offset
+    > 0), ties at the threshold (order key == thr, kept up to `last`),
+    tokens with fewer than index_topk candidates, padding past `valid`,
+    several slots."""
+    cfg, params, _ = setup()
+    lp = params["layers"][0]
+    rng = np.random.default_rng(len(case))
+    T, n_pages = 32, 16
+    B, qo, vl, k = {
+        "offset0": (1, [0], [32], 16), "later-block": (1, [64], [32], 16),
+        "ties": (2, [0, 40], [32, 32], 24), "few": (1, [0], [32], 100),
+        "padding": (2, [10, 0], [20, 7], 16),
+        "slots": (3, [0, 30, 90], [32, 32, 25], 16)}[case]
+    S = n_pages * PAGE
+    sel = _selection(rng, B, T, S, qo, vl, k, case == "ties")
+    W = cfg.latent_lane
+    lane = np.zeros((B * n_pages + 1, PAGE, W), np.float32)
+    lane[:, :, :cfg.latent_row] = rng.standard_normal(
+        (B * n_pages + 1, PAGE, cfg.latent_row))
+    lane = jnp.asarray(lane)
+    table = jnp.asarray(1 + rng.permutation(B * n_pages).reshape(B, n_pages),
+                        jnp.int32)
+    H = cfg.num_heads
+    q_nope = jnp.asarray(rng.standard_normal(
+        (B, T, H, cfg.qk_nope_head_dim)), F32)
+    q_rope = jnp.asarray(rng.standard_normal(
+        (B, T, H, cfg.qk_rope_head_dim)), F32)
+    qo, vl = jnp.asarray(qo, jnp.int32), jnp.asarray(vl, jnp.int32)
+    scale = cfg.mla_softmax_scale
+    o_lat = PA.paged_latent_attention_xla(
+        hybrid._absorbed_q(lp, q_nope, q_rope, cfg), lane, table, qo, vl,
+        cfg.kv_lora_rank, scale, select=sel)
+    want = np.asarray(jnp.einsum("bthc,hcv->bthv", o_lat, lp["kv_b_v_w"]))
+    rows = lane[table].reshape(B, S, W)
+    keys = hybrid._expanded_keys(lp, rows, cfg)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    real = np.arange(T)[None, :] < np.asarray(vl)[:, None]
+    for got in (SA.masked_expanded_attention_xla(
+                    q, keys, rows, lp["kv_b_v_w"], qo, sel, scale),
+                SA.masked_expanded_attention_pallas(
+                    q, keys, rows, lp["kv_b_v_w"], qo, sel, scale,
+                    interpret=True)):
+        np.testing.assert_allclose(np.asarray(got)[real], want[real],
+                                   rtol=1e-5, atol=1e-5)
+    if case == "few":       # every candidate kept: attention over all
+        assert (np.asarray(SA.unblocked(SA.mask_from(*sel))).sum(-1) ==
+                np.arange(1, T + 1)[None]).all()
+
+
+def _pass_logits(cfg, params, toks, n_prompt, form, monkeypatch):
+    """(prefill logits at n_prompt - 1, then a chunk of 32 tokens through the
+    cache: its logits at every position, both passes' aux) with every pass
+    of more than one token in `form`."""
+    monkeypatch.setattr(hybrid, "DSA_EXPANDED_MIN_TOKENS",
+                        2 if form == "expanded" else 1 << 30)
+    pool = hybrid.init_paged_cache(cfg, 40, PAGE, 1)
+    pages = np.arange(1, 17, dtype=np.int32)[None]
+    ids = np.zeros((1, 128), np.int32)
+    ids[0, :n_prompt] = toks[:n_prompt]
+    lg, pool, aux = hybrid.prefill_paged(
+        params, jnp.asarray(ids), cfg, pool, jnp.asarray(pages),
+        jnp.asarray([n_prompt]), jnp.asarray([0]))
+    table = np.zeros((1, 32), np.int32)
+    table[0, :24] = np.arange(1, 25)
+    x, _, aux2 = hybrid._step_hidden(
+        params, jnp.asarray(toks[None, n_prompt:n_prompt + 32]), pool,
+        jnp.asarray(table), jnp.asarray([n_prompt]), jnp.asarray([32]), cfg)
+    chunk = gpt.head_logits(hybrid._norm(x, params["lnf_w"], cfg), params,
+                            cfg)[0]
+    return np.asarray(lg[0], np.float32), np.asarray(chunk, np.float32), \
+        dict(zip(hybrid.aux_fields(cfg), np.asarray(aux))), \
+        dict(zip(hybrid.aux_fields(cfg), np.asarray(aux2)))
+
+
+@pytest.mark.parametrize("block", [2048, 32], ids=["whole", "blocks"])
+def test_prefill_and_chunk_through_the_expanded_form_in_bfloat16(
+        block, monkeypatch):
+    """`tiny_glm5` widths in bfloat16: the bucketed prefill (its own rows,
+    q_offset 0; whole, or 2,048-style query blocks of 32 under the scan with
+    q_offset > 0) and a 32-token chunk behind it (keys from the lane through
+    the page table) in the expanded form lie as close to the float32
+    program as bfloat16 lets the absorbed route lie (within twice its
+    distance), and `dsa_expanded_tokens` counts the passes' tokens."""
+    monkeypatch.setattr(hybrid, "DSA_QUERY_BLOCK", block)
+    cfg, params, _ = setup(dtype=jnp.bfloat16)
+    cfg32 = setup()[0]
+    params32 = jax.tree_util.tree_map(
+        lambda x: x.astype(F32) if x.dtype == jnp.bfloat16 else x, params)
+    toks = np.random.default_rng(3).integers(0, 256, 128, dtype=np.int32)
+    want = _pass_logits(cfg32, params32, toks, 90, "absorbed", monkeypatch)
+    with jax.default_matmul_precision(None):
+        a = _pass_logits(cfg, params, toks, 90, "absorbed", monkeypatch)
+        b = _pass_logits(cfg, params, toks, 90, "expanded", monkeypatch)
+    for x, y, w in zip(a[:2], b[:2], want[:2]):
+        assert 0 < float(np.abs(y - w).max()) <= \
+            2 * float(np.abs(x - w).max())
+    assert a[2]["dsa_expanded_tokens"] == a[3]["dsa_expanded_tokens"] == 0
+    assert b[2]["dsa_expanded_tokens"] == 90
+    assert b[3]["dsa_expanded_tokens"] == 32
+    for name in hybrid.DSA_AUX[:3]:
+        assert a[2][name] == b[2][name] and a[3][name] == b[3][name]
+
+
+@pytest.mark.parametrize("T,route", [(1, "sparse"), (1023, "absorbed"),
+                                     (1024, "expanded")])
+def test_the_route_is_chosen_by_the_pass_length(T, route, monkeypatch):
+    """Decode (T = 1) takes the sparse form, a pass below
+    `DSA_EXPANDED_MIN_TOKENS` the masked absorbed walk, one at it the
+    expanded form; the counter says so."""
+    cfg, params, _ = setup(seq_len=2048)
+    taken = []
+    for name, mod in (("sparse_latent_attention", hybrid.dsa_k),
+                      ("masked_expanded_attention", hybrid.dsa_k),
+                      ("paged_latent_attention", hybrid)):
+        fn = getattr(mod, name)
+        monkeypatch.setattr(mod, name, functools.partial(
+            lambda fn, name, *a, **kw: taken.append(name) or fn(*a, **kw),
+            fn, name))
+    S = jax.ShapeDtypeStruct
+    i32 = jnp.int32
+    pool = jax.eval_shape(functools.partial(hybrid.init_paged_cache, cfg,
+                                            300, PAGE, 1))
+    _, _, aux = jax.eval_shape(
+        lambda p, ids, pl, tb, qo, vl: hybrid.prefill_chunk_paged(
+            p, ids, cfg, pl, tb, qo, vl),
+        params, S((1, T), i32), pool, S((1, 256), i32), S((1,), i32),
+        S((1,), i32))
+    want = {"sparse": "sparse_latent_attention", "absorbed":
+            "paged_latent_attention", "expanded": "masked_expanded_attention"}
+    assert set(taken) == {want[route]}
+    assert aux.shape == (len(hybrid.AUX_FIELDS),)
+    assert hybrid._expanded_pass(T) == (route == "expanded")
+
+
 # ---- the reference's blocked forms -------------------------------------------
 
 @pytest.mark.parametrize("cap", [2, 5, 64])
@@ -463,6 +620,32 @@ def test_the_counters_are_the_rows_scored_and_chosen():
     snap = eng.metrics.snapshot()["counters"]
     assert snap["dsa_rows_selected"] == st["dsa_rows_selected"]
     assert snap["dsa_rows_searched"] == st["dsa_rows_searched"]
+    # no pass here reaches DSA_EXPANDED_MIN_TOKENS
+    assert st["dsa_expanded_tokens"] == snap["dsa_expanded_tokens"] == 0
+
+
+def test_the_engine_counts_the_tokens_of_expanded_passes(monkeypatch):
+    """Every pass of more than one token expanded (the threshold lowered to
+    2): the engine serves the tokens it serves with the absorbed passes,
+    and `dsa_expanded_tokens` is the prefilled tokens (none of a fused
+    step's: decode stays sparse)."""
+    cfg, params, _ = setup()
+    prompts = [np.random.default_rng(s).integers(0, 256, n, dtype=np.int32)
+               for s, n in ((1, 100), (2, 70), (3, 20))]
+
+    def run():
+        eng = LLMEngine(params, cfg, num_slots=2, page_size=8,
+                        max_model_len=256)
+        for x in prompts:
+            eng.add_request(x, max_new_tokens=8)
+        outs = eng.run()
+        return [outs[r].token_ids for r in sorted(outs)], eng.stats()
+    plain, st0 = run()
+    monkeypatch.setattr(hybrid, "DSA_EXPANDED_MIN_TOKENS", 2)
+    expanded, st = run()
+    assert expanded == plain
+    assert st0["dsa_expanded_tokens"] == 0
+    assert st["dsa_expanded_tokens"] == st["prefilled_tokens"] == 190
 
 
 # ---- what index_topk 0 leaves alone ------------------------------------------
@@ -478,6 +661,30 @@ PARENT_JAXPRS = {
     "latent.prefill4096": "3267c3dfeb63ae65",
     "hybrid.prefill4096": "179ff4fb51ba2585",
 }
+# and, computed before a long DSA pass took the expanded form: the serving
+# programs of the two dense families (LayerNorm, GELU and biases; GQA,
+# RMSNorm and SwiGLU), a 1,024-token chunk pass of a latent configuration
+# (a pass as long as the expanded form's threshold), and the training
+# programs the flash kernels run in (the forward and the one backward at
+# one width and at 192 / 128, and the patterned trainer's loss gradient)
+PARENT_JAXPRS_MORE = {
+    "gpt.prefill": "9e50ae67127b0af5", "gpt.step": "57e53e5df70cc8ca",
+    "llama.prefill": "b0b557e6a810dbe2", "llama.step": "4c7c4418a1146ee3",
+    "latent.chunk1024": "34b22df822d46bad",
+}
+PARENT_TRAINING_JAXPRS = {
+    "flash128x128.train": "cbe4c13574f38fe7",
+    "flash192x128.train": "e424d0cc07e80039",
+    "joyai.train": "89324ba9ded74a05",
+}
+
+
+def _jaxpr_hash(j) -> str:
+    """A jaxpr's text without object addresses (a checkpoint policy prints
+    as a function `at 0x..`), hashed."""
+    import re
+    return hashlib.sha256(re.sub(r" at 0x[0-9a-f]+", "", str(j)).encode()
+                          ).hexdigest()[:16]
 
 
 def test_index_topk_0_traces_the_programs_it_always_traced():
@@ -523,7 +730,68 @@ def test_index_topk_0_traces_the_programs_it_always_traced():
                 S((1,), i32), S((1,), i32))
             got[f"{name}.prefill4096"] = hashlib.sha256(
                 str(pre).encode()).hexdigest()[:16]
+        more = {}
+        for name, cfg in (("gpt", gpt.gpt_tiny()),
+                          ("llama", gpt.llama_tiny())):
+            params = jax.eval_shape(functools.partial(gpt.init_params, cfg),
+                                    jax.random.key(0))
+            pool = jax.eval_shape(functools.partial(gpt.init_paged_cache, cfg,
+                                                    9, 8))
+            pre = jax.make_jaxpr(
+                lambda p, ids, pl, pg, ln, c=cfg: gpt.prefill_paged(
+                    p, ids, c, pl, pg, ln))(
+                params, S((1, 32), i32), pool, S((1, 4), i32), S((1,), i32))
+            key = jax.eval_shape(lambda: jax.random.key(0))
+            step = jax.make_jaxpr(
+                lambda p, t, pl, tb, qo, vl, k, g, c=cfg: gpt.serve_step_paged(
+                    p, t, pl, tb, qo, vl, c, key=k, greedy=g))(
+                params, S((2, 1), i32), pool, S((2, 4), i32), S((2,), i32),
+                S((2,), i32), key, S((2,), jnp.bool_))
+            more[f"{name}.prefill"] = _jaxpr_hash(pre)
+            more[f"{name}.step"] = _jaxpr_hash(step)
+        cfg = hybrid.latent_tiny(seq_len=2048)
+        params = jax.eval_shape(functools.partial(hybrid.init_params, cfg),
+                                jax.random.key(0))
+        pool = jax.eval_shape(functools.partial(
+            hybrid.init_paged_cache, cfg, 257, 8, 2))
+        chunk = jax.make_jaxpr(
+            lambda p, ids, pl, tb, qo, vl: hybrid.prefill_chunk_paged(
+                p, ids, cfg, pl, tb, qo, vl))(
+            params, S((1, 1024), i32), pool, S((1, 256), i32), S((1,), i32),
+            S((1,), i32))
+        more["latent.chunk1024"] = _jaxpr_hash(chunk)
     assert got == PARENT_JAXPRS
+    assert more == PARENT_JAXPRS_MORE
+
+
+def test_the_training_flash_programs_trace_as_they_did(monkeypatch):
+    """The expanded serving form is a kernel of its own: the flash kernels'
+    training programs (routed to the kernels as on a TPU) trace what they
+    traced before it, forward and backward, and so does the patterned
+    trainer's loss gradient that runs them at 192 / 128."""
+    from paddle_tpu.incubate.kernels import flash_attention as FA
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    S = jax.ShapeDtypeStruct
+    bf16 = jnp.bfloat16
+    got = {}
+    with jax.default_matmul_precision(None):
+        for D, Dv in ((128, 128), (192, 128)):
+            def loss(q, k, v):
+                return FA.flash_attention_fused(q, k, v, causal=True).astype(
+                    F32).sum()
+            j = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+                S((1, 1024, 2, D), bf16), S((1, 1024, 2, D), bf16),
+                S((1, 1024, 2, Dv), bf16))
+            got[f"flash{D}x{Dv}.train"] = _jaxpr_hash(j)
+        cfg = hybrid.joyai_tiny(seq_len=256, qk_nope_head_dim=128,
+                                qk_rope_head_dim=64, v_head_dim=128)
+        params = jax.eval_shape(functools.partial(hybrid.init_params, cfg),
+                                jax.random.key(0))
+        j = jax.make_jaxpr(jax.grad(
+            lambda p, t, l: hybrid.train_loss(p, t, l, cfg, remat=True)[0]))(
+            params, S((1, 256), jnp.int32), S((1, 256), jnp.int32))
+        got["joyai.train"] = _jaxpr_hash(j)
+    assert got == PARENT_TRAINING_JAXPRS
 
 
 def test_a_latent_configuration_without_dsa_serves_what_it_served():

@@ -512,6 +512,10 @@ NEW_STATS_KEYS = frozenset({
     # search, of all programs and of the fused steps (both 0 without DSA)
     "dsa_rows_searched", "dsa_step_rows_searched",
 }) | frozenset({
+    # the long DSA pass expanded: query tokens whose sparse attention ran
+    # with per-head keys and values (0 without DSA)
+    "dsa_expanded_tokens",
+}) | frozenset({
     # added by the paged-walk PR (ISSUE 29): pages the paged kernel walks
     # against the table entries its programs were handed
     "paged_pages_walked", "paged_table_entries",

@@ -585,24 +585,34 @@ def test_dsa_decode_step_reads_chosen_rows_through_its_own_kernels(
 def test_dsa_prefill_at_the_longest_bucket_fits_beside_the_pool(
         glm5_programs):
     """The 32,768 bucket's prefill in query blocks of 2,048 (index walk,
-    threshold kernel, masked latent walk) and expert / dense mixers in
-    blocks of 2,048 tokens: its temporaries stay under 3.5 GB (the cell's
-    pool and weights take 11.4 GB of the chip's 16), no lane is copied, and
-    the DSA kernels are told apart from the other kernels."""
+    threshold kernel, and the expanded form: per-head keys built once a
+    layer, `masked_expanded` forming the values from the latent rows) and
+    expert / dense mixers in blocks of 2,048 tokens: its temporaries stay
+    under 3.5 GB (the cell's pool and weights take 11.4 GB of the chip's
+    16), no lane is copied, no masked latent walk is left, and the DSA
+    kernels are told apart from the other kernels (the expanded kernel by
+    no metric's pattern yet)."""
     pool, _, prefill = glm5_programs
     text = prefill.as_text()
     assert prefill.memory_analysis().temp_size_in_bytes < 3.5e9
     assert _latent_moves(text, pool) == []
     shorts = set().union(*_kernel_signatures(text).values())
     dsa = {x for x in shorts if _reads("dsa_time_share", x)}
-    assert len(dsa) == 3, shorts        # index walk, threshold, masked walk
+    assert len(dsa) == 2, shorts        # index walk, threshold
     assert any("out=(s32[1,2048,1], s32[1,2048,1]) in=2" in x for x in dsa)
-    assert any("out=bf16[1,2048,64,512] in=8" in x for x in dsa)
+    assert not any(x.startswith("%masked_latent") for x in shorts)
+    expanded = [x for x in shorts if x.startswith("%masked_expanded")]
+    assert [x.split(" custom-call ")[1] for x in expanded] == [
+        "tpu_custom_call out=bf16[1,2048,16384] in=6"]
     for x in shorts:
         # the decode rooflines read the decode step's calls only
         assert not _reads("dsa_index_roofline", x), x
         assert not _reads("dsa_sparse_roofline", x), x
         assert not _reads("moe_expert_roofline", x) or "in=7" in x, x
+    # no metric the GLM-5 cell reports reads the expanded kernel (the
+    # gpt3 training cell's flash_roofline pattern would: PERF.md section 7)
+    assert not any(_reads(m, expanded[0]) for m in (
+        "dsa_time_share", "moe_expert_time_share", "moe_expert_roofline"))
 
 
 def test_shard_mapped_kernels_on_four_devices(topo):

@@ -130,6 +130,9 @@ REQUIRED_STATS_KEYS = frozenset({
     # the decode top-k by counting: query tokens whose selection needed the
     # search (more than index_topk candidates), all programs and fused steps
     "dsa_rows_searched", "dsa_step_rows_searched",
+    # the long pass expanded: query tokens whose sparse attention ran with
+    # per-head keys and values (0 for fused steps and without DSA)
+    "dsa_expanded_tokens",
     # launch-ahead PR (ISSUE 33): fused launches made before the previous
     # program's result was read, and the lanes of those dropped at harvest
     "fused_launched_ahead", "fused_ahead_discarded_lanes",
@@ -197,6 +200,8 @@ REQUIRED_COUNTERS = frozenset({
     "dsa_rows_scored", "dsa_rows_selected",
     # the decode top-k by counting: the tokens whose selection was searched
     "dsa_rows_searched",
+    # the long pass expanded: its query tokens
+    "dsa_expanded_tokens",
     # admission-account PR (ISSUE 36): one labelled family, a sample a reason
     "fused_ahead_late",
 }) | frozenset(f'fused_serial_steps{{reason="{why}"}}'

@@ -14,10 +14,18 @@ s32[1,R,1]) with R a few dozen at most, and `topk_compact`; where the sort
 route still runs, `lax.top_k` over a slot's scores: a sort of (f32[B,1,S],
 s32[B,1,S])), the expert layers' routing sorts, and XLA's gather of the
 chosen latent rows (a kCustom fusion out bf16[B * index_topk, lane]); beside
-them the DSA kernels by name.
+them the DSA kernels by name, and of a long pass's expanded form the
+per-head keys' product (a fusion out bf16[B, H, nope + rope, S]) and the
+keep mask (int8 out) apart from `masked_expanded`, which forms the values
+itself.
 On the CPU (`--rehearse`) there is no device plane and every piece reads 0.
 Prints `DSA {json}` and writes `<out>/<cell>.json`: seconds and events in the
-slice by piece, ms a fused step, the fused steps counted, and the gather +
+slice by piece, ms a fused step, the fused steps counted, the prefill's
+attention a query block (`masked_latent`, or the expanded form's three
+pieces, over the prefill's `topk_threshold` calls: one a block and layer;
+and over the prefill's index walk, which grows with a block's keys as the
+attention does, so that slices of other prompts compare), the share of
+prefilled tokens the expanded form took, and the gather +
 `sparse_latent` unit's roofline (rows selected x 576 x 2 B read once from HBM
 against the absorbed form's operations, over the two pieces' time).  The
 benchmark's own runs never call this.
@@ -48,23 +56,32 @@ PIECES = (
     ("topk_compact.decode", r"^%topk_compact\b"),
     ("topk_threshold", r"^%topk_threshold\b"),
     ("masked_latent", r"^%masked_latent\b"),
+    ("masked_expanded", r"^%masked_expanded\b"),
+    ("keep_mask", r"= s8\[\d+,\d+,\d+,\d+\]\{[^}]*\} fusion\("),
     ("topk_sort.decode",
      r"= \(f32\[\d+,1,\d+\]\{[^}]*\}, s32\[\d+,1,\d+\]\{[^}]*\}\) sort\("),
     ("sort.routing", r"\) sort\("),
 )
 
 
-def piece_of(text: str, gather_out: str) -> str | None:
+# the prefill's attention by piece, booked a query block
+PREFILL_ATTENTION = ("masked_latent", "masked_expanded", "expanded_keys",
+                     "keep_mask")
+
+
+def piece_of(text: str, gather_out: str, keys_out: str) -> str | None:
     for name, rx in PIECES:
         if re.search(rx, text):
             return name
+    if re.search(keys_out, text.split(" = ", 1)[-1]) and " fusion(" in text:
+        return "expanded_keys"
     if text.split(" = ", 1)[-1].startswith(gather_out) and \
             " fusion(" in text and "kind=kCustom" in text:
         return "row_gather"
     return None
 
 
-def account(path: str, trace: dict, gather_out: str) -> dict:
+def account(path: str, trace: dict, gather_out: str, keys_out: str) -> dict:
     import jax.profiler
     t0, t1 = xplane.slice_window(trace)
     secs, events = {}, {}
@@ -77,7 +94,8 @@ def account(path: str, trace: dict, gather_out: str) -> dict:
             for e in line.events:
                 a = max(int(e.start_ns), t0)
                 b = min(int(e.start_ns) + int(e.duration_ns), t1)
-                piece = piece_of(e.name, gather_out) if b > a else None
+                piece = piece_of(e.name, gather_out, keys_out) \
+                    if b > a else None
                 if piece:
                     secs[piece] = secs.get(piece, 0.0) + (b - a) / 1e9
                     events[piece] = events.get(piece, 0) + 1
@@ -86,9 +104,22 @@ def account(path: str, trace: dict, gather_out: str) -> dict:
                                     r"^jit_fused_impl"))
     busy = xplane.busy(trace)["busy_s"] if xplane.device_planes(trace) \
         else 0.0
-    return {"slice_s": (t1 - t0) / 1e9, "busy_s": busy, "fused_steps": steps, "seconds": secs, "events": events,
+    blocks = events.get("topk_threshold", 0)
+    attention = sum(secs.get(k, 0.0) for k in PREFILL_ATTENTION)
+    walk = secs.get("index_scores.prefill", 0.0)
+    return {"slice_s": (t1 - t0) / 1e9, "busy_s": busy, "fused_steps": steps,
+            "seconds": secs, "events": events,
             "ms_per_fused_step": {k: 1e3 * v / max(1, steps)
-                                  for k, v in secs.items()}}
+                                  for k, v in secs.items()},
+            "prefill_blocks": blocks,
+            "prefill_attention_ms_per_block": {
+                k: 1e3 * secs[k] / blocks for k in PREFILL_ATTENTION
+                if k in secs and blocks},
+            # the index walk of the same blocks scales with their keys as
+            # causal attention does: attention over it compares slices
+            # that hold other prompts
+            "prefill_attention_per_index_walk":
+                attention / walk if walk else None}
 
 
 def main(argv=None) -> int:
@@ -116,8 +147,11 @@ def main(argv=None) -> int:
         trace = load(self)
         drv = kept["driver"]
         lane = serve_glm5.program_config(drv.model).latent_lane
-        rows = drv.engine_kwargs["num_slots"] * drv.model["index_topk"]
-        kept["acc"] = account(path, trace, f"bf16[{rows},{lane}]")
+        m = drv.model
+        rows = drv.engine_kwargs["num_slots"] * m["index_topk"]
+        keys = rf"^bf16\[\d+,{m['num_attention_heads']}," \
+            rf"{m['qk_nope_head_dim'] + m['qk_rope_head_dim']},\d+\]"
+        kept["acc"] = account(path, trace, f"bf16[{rows},{lane}]", keys)
         return trace
 
     serve_glm5.Driver.window = keeping_window
@@ -135,6 +169,12 @@ def main(argv=None) -> int:
     st = drv.eng.stats()
     acc["dsa_rows_searched"] = st.get("dsa_rows_searched")
     acc["dsa_step_rows_searched"] = st.get("dsa_step_rows_searched")
+    # how often a long pass took the expanded form
+    acc["dsa_expanded_tokens"] = st.get("dsa_expanded_tokens")
+    acc["prefilled_tokens"] = st.get("prefilled_tokens")
+    if st.get("prefilled_tokens"):
+        acc["dsa_expanded_share"] = \
+            st.get("dsa_expanded_tokens", 0) / st["prefilled_tokens"]
     per_call = facts.get("slice_dsa_step_rows_selected_per_call")
     calls = acc["events"].get("sparse_latent", 0)
     unit_s = acc["seconds"].get("sparse_latent", 0.0) + \
