@@ -1029,6 +1029,15 @@ class LLMEngine:
                 "dsa_expanded_tokens",
                 "query tokens whose sparse attention ran in the expanded "
                 "form (per-head keys and values: a long pass)"),
+            # the expert layers of a long pass (DSA configurations only)
+            "moe_pairs_compacted": m.counter(
+                "moe_pairs_compacted",
+                "held token-expert picks a long pass computed over the held "
+                "pairs only (the compact expert layer; 0 in a fused step)"),
+            "moe_compact_extra_chunks": m.counter(
+                "moe_compact_extra_chunks",
+                "chunks the compact expert layer ran past the first (its "
+                "static chunk was short of the held pairs)"),
         }
         # the same three, of the fused steps alone (decode rows)
         self._dsa_step_rows = {"dsa_rows_scored": 0, "dsa_rows_selected": 0,

@@ -65,7 +65,8 @@ import jax
 import jax.numpy as jnp
 
 from ..incubate.distributed.models.moe.dropless import COUNTERS as MOE_COUNTERS
-from ..incubate.distributed.models.moe.dropless import TRAIN_COUNTERS, moe_dropless
+from ..incubate.distributed.models.moe.dropless import COMPACT_COUNTERS, \
+    TRAIN_COUNTERS, compact_tiling, moe_dropless, moe_dropless_compact
 from ..incubate.kernels.grouped_matmul import TRAIN_ROW_TILE
 from ..incubate.kernels.flash_attention import flash_attention_fused
 from ..incubate.kernels.paged_attention import paged_latent_attention
@@ -81,14 +82,16 @@ KINDS = {"M": "mamba", "E": "experts", "*": "attention", "L": "mla",
 MTP_PATTERN = "LE"
 _BASE_AUX = MOE_COUNTERS + ("ssm_slots_live", "ssm_state_resets",
                             "latent_tokens_written", "mla_absorbed_rows")
-# a DSA configuration's four more (one DSA layer's worth: every DSA layer of
-# a program scores and selects the same rows): rows the indexer scored, rows
-# the latent attention read, summed over the program's real query tokens,
-# the query tokens whose selection needed the search (more than index_topk
-# candidates), and the query tokens whose attention ran in the expanded form
-# (a long pass, `DSA_EXPANDED_MIN_TOKENS`)
+# a DSA configuration's six more (the first four one DSA layer's worth:
+# every DSA layer of a program scores and selects the same rows): rows the
+# indexer scored, rows the latent attention read, summed over the program's
+# real query tokens, the query tokens whose selection needed the search (more
+# than index_topk candidates), the query tokens whose attention ran in the
+# expanded form (a long pass, `DSA_EXPANDED_MIN_TOKENS`); and over the expert
+# layers, the held pairs a long pass computed in the compact layer and its
+# chunks past the first (`COMPACT_COUNTERS`: a fused step reads 0)
 DSA_AUX = ("dsa_rows_scored", "dsa_rows_selected", "dsa_rows_searched",
-           "dsa_expanded_tokens")
+           "dsa_expanded_tokens") + COMPACT_COUNTERS
 AUX_FIELDS = _BASE_AUX + DSA_AUX
 
 
@@ -780,33 +783,44 @@ def mhc_mixes(lp, X, c: HybridConfig):
 
 def _sum_aux(parts, live, resets, written, rows, dsa=()):
     """The `aux_fields` vector from the expert layers' counters and the
-    passes' own (`dsa`: a DSA configuration's three)."""
+    passes' own (`dsa`: a DSA configuration's four; its expert layers'
+    `COMPACT_COUNTERS` follow, nought where a layer took `moe_dropless`)."""
     def over(name, fn):
-        vals = [p[name] for p in parts]
+        vals = [p[name] for p in parts if name in p]
         return fn(jnp.stack(vals)) if vals else jnp.zeros((), jnp.int32)
     return jnp.stack([
         over("moe_pairs_here", jnp.sum), over("moe_pairs_away", jnp.sum),
         over("moe_experts_touched", jnp.sum), over("moe_load_max", jnp.max),
         live.astype(jnp.int32), resets.astype(jnp.int32),
         written.astype(jnp.int32), rows.astype(jnp.int32)] +
-        [v.astype(jnp.int32) for v in dsa]).astype(jnp.int32)
+        [v.astype(jnp.int32) for v in dsa] +
+        [over(n, jnp.sum) for n in COMPACT_COUNTERS if dsa]
+    ).astype(jnp.int32)
 
 
-# rows of an expert or dense FFN mixer alive at once in a pass longer than
-# this (a 32k prompt's 262k token-expert pairs would take 14 GB at once)
+# rows of a dense FFN mixer alive at once in a pass longer than this, which
+# blocks the pass (the FFN is compute-bound: a larger block buys nothing)
 TOKEN_BLOCK = 2048
+# tokens of an expert mixer of a blocked pass at once, the first that divides
+# the pass (else TOKEN_BLOCK): the compact layer holds no N x k rows, so the
+# block is bounded by its float32 output and every held expert's matrices
+# are streamed once a block
+EXPERT_TOKEN_BLOCKS = (8192, 4096)
 
 
-def _by_token_blocks(fn, h, real, blocked: bool):
-    """fn(h [N, D], real [N]) -> (y, counters), over blocks of TOKEN_BLOCK
-    rows under `lax.map` when `blocked` (and N is whole blocks); the counters
-    summed, `moe_load_max` the largest block's."""
+def _expert_block(n: int) -> int:
+    return next((b for b in EXPERT_TOKEN_BLOCKS if n % b == 0), TOKEN_BLOCK)
+
+
+def _by_token_blocks(fn, h, real, block: int):
+    """fn(h [N, D], real [N]) -> (y, counters), over blocks of `block` rows
+    under `lax.map` (N whole blocks, more than one); the counters summed,
+    `moe_load_max` the largest block's."""
     N, D = h.shape
-    if not blocked or N % TOKEN_BLOCK:
+    if N % block or N == block:
         return fn(h, real)
     y, ctr = jax.lax.map(lambda a: fn(*a),
-                         (h.reshape(-1, TOKEN_BLOCK, D),
-                          real.reshape(-1, TOKEN_BLOCK)))
+                         (h.reshape(-1, block, D), real.reshape(-1, block)))
     return y.reshape(N, D), {k: jnp.max(v) if k == "moe_load_max" else
                              jnp.sum(v) for k, v in ctr.items()}
 
@@ -822,9 +836,9 @@ def _walk(params, x, cache, c: HybridConfig, real, mamba, attention, latent):
     B, T, D = x.shape
     n = c.hc_mult
     # a DSA configuration's long pass (a prompt of up to 32k tokens beside
-    # the pool) takes its mixers one after another and its expert / dense
-    # rows a block at a time; every other configuration's programs are the
-    # ones they were
+    # the pool) takes its mixers one after another, its dense rows a block
+    # at a time and its expert rows through the compact layer, a larger block
+    # at a time; every other configuration's programs are the ones they were
     blocked = c.index_topk > 0 and B * T > TOKEN_BLOCK
     if n > 1:
         x = jnp.broadcast_to(x[:, :, None, :], (B, T, n, D))
@@ -846,14 +860,18 @@ def _walk(params, x, cache, c: HybridConfig, real, mamba, attention, latent):
                 y, cache = mamba(lp, h, cache, i)
             elif letter == "E":
                 y, ctr = _by_token_blocks(
-                    lambda hh, rr: moe_dropless(lp, hh, c, rr),
-                    h.reshape(B * T, D), real.reshape(B * T), blocked)
+                    (lambda hh, rr: moe_dropless_compact(
+                        lp, hh, c, rr, *compact_tiling(hh.shape[0], c)))
+                    if blocked else
+                    (lambda hh, rr: moe_dropless(lp, hh, c, rr)),
+                    h.reshape(B * T, D), real.reshape(B * T),
+                    _expert_block(B * T) if blocked else B * T)
                 y = y.reshape(B, T, D)
                 counters.append(ctr)
             elif letter == "F" and blocked:
                 y, _ = _by_token_blocks(lambda hh, rr: (ffn_mixer(lp, hh), {}),
                                         h.reshape(B * T, D),
-                                        real.reshape(B * T), blocked)
+                                        real.reshape(B * T), TOKEN_BLOCK)
                 y = y.reshape(B, T, D)
             elif letter == "F":
                 y = ffn_mixer(lp, h)

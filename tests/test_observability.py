@@ -516,6 +516,10 @@ NEW_STATS_KEYS = frozenset({
     # with per-head keys and values (0 without DSA)
     "dsa_expanded_tokens",
 }) | frozenset({
+    # the long DSA pass's compact expert layer: held pairs it computed and
+    # its chunks past the first (0 for fused steps and without DSA)
+    "moe_pairs_compacted", "moe_compact_extra_chunks",
+}) | frozenset({
     # added by the paged-walk PR (ISSUE 29): pages the paged kernel walks
     # against the table entries its programs were handed
     "paged_pages_walked", "paged_table_entries",

@@ -586,8 +586,9 @@ def test_dsa_prefill_at_the_longest_bucket_fits_beside_the_pool(
         glm5_programs):
     """The 32,768 bucket's prefill in query blocks of 2,048 (index walk,
     threshold kernel, and the expanded form: per-head keys built once a
-    layer, `masked_expanded` forming the values from the latent rows) and
-    expert / dense mixers in blocks of 2,048 tokens: its temporaries stay
+    layer, `masked_expanded` forming the values from the latent rows),
+    dense mixers in blocks of 2,048 tokens and expert mixers through the
+    compact layer in blocks of 8,192: its temporaries stay
     under 3.5 GB (the cell's pool and weights take 11.4 GB of the chip's
     16), no lane is copied, no masked latent walk is left, and the DSA
     kernels are told apart from the other kernels (the expanded kernel by
@@ -613,6 +614,39 @@ def test_dsa_prefill_at_the_longest_bucket_fits_beside_the_pool(
     # gpt3 training cell's flash_roofline pattern would: PERF.md section 7)
     assert not any(_reads(m, expanded[0]) for m in (
         "dsa_time_share", "moe_expert_time_share", "moe_expert_roofline"))
+
+
+def test_dsa_long_prefill_expert_layer_takes_the_held_pairs_only(
+        glm5_programs):
+    """The 32,768 bucket's expert layer, 8,192 tokens a block through the
+    compact layer: no array of a block's N x k rows of width D (bf16 or f32
+    [N k, 6144]: 16,384 rows at the 2,048-token blocks before it, 65,536 at
+    8,192), no sort of a block's N x k pairs (the sorts left there are the
+    router's top-8 over 256 columns and the scatter's of a chunk's rows),
+    and the grouped products one chunk of rows at a time, as many as
+    `compact_tiling` gives: the expected held pairs' room in whole tiles."""
+    from paddle_tpu.incubate.distributed.models.moe import dropless as DL
+    _, _, prefill = glm5_programs
+    _, cfg, _, _, _ = _glm5()
+    chunk, tile = DL.compact_tiling(8192, cfg)
+    assert (chunk, tile) == (7168, 256)
+    lines = [ln.strip() for ln in prefill.as_text().splitlines()
+             if re.search(r'op_name="[^"]*/experts/', ln)]
+    assert lines
+    for ln in lines:
+        assert not re.search(r"\b(bf16|f32)\[(16384|65536),6144\]", ln), \
+            ln[:160]
+        if re.search(r"\) sort\(", ln):
+            head = ln.split(" sort(")[0]
+            keys = [int(n) for n in re.findall(r"\[(\d+)\]", head)]
+            wide = re.findall(r"\[\d+,(\d+)\]", head)
+            assert keys or wide, ln[:160]
+            assert all(n <= chunk for n in keys), ln[:160]
+            assert all(n == str(cfg.n_routed_experts) for n in wide), \
+                ln[:160]
+    gmm = {xs for ln in lines if ln.startswith("%gmm")
+           for xs in re.findall(r"= bf16\[(\d+),(?:2048|6144)\]", ln)}
+    assert gmm == {str(chunk)}, gmm
 
 
 def test_shard_mapped_kernels_on_four_devices(topo):

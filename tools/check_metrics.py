@@ -133,6 +133,9 @@ REQUIRED_STATS_KEYS = frozenset({
     # the long pass expanded: query tokens whose sparse attention ran with
     # per-head keys and values (0 for fused steps and without DSA)
     "dsa_expanded_tokens",
+    # the long pass's compact expert layer: held pairs it computed and the
+    # chunks past the first (0 for fused steps and without DSA)
+    "moe_pairs_compacted", "moe_compact_extra_chunks",
     # launch-ahead PR (ISSUE 33): fused launches made before the previous
     # program's result was read, and the lanes of those dropped at harvest
     "fused_launched_ahead", "fused_ahead_discarded_lanes",
@@ -202,6 +205,8 @@ REQUIRED_COUNTERS = frozenset({
     "dsa_rows_searched",
     # the long pass expanded: its query tokens
     "dsa_expanded_tokens",
+    # the long pass's compact expert layer: its pairs and extra chunks
+    "moe_pairs_compacted", "moe_compact_extra_chunks",
     # admission-account PR (ISSUE 36): one labelled family, a sample a reason
     "fused_ahead_late",
 }) | frozenset(f'fused_serial_steps{{reason="{why}"}}'
