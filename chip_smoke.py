@@ -41,9 +41,10 @@ import time
 import numpy as np
 
 SEED = 0
-# trainer: bench.py's cell (batch 4 x 2048, bf16 params + moments, remat)
+# trainer: the gpt3 training cell's shape (batch 4 x 2048, bf16 params +
+# moments, remat)
 TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS = 4, 2048, 3
-# server: the TPU branch of bench_serve.py
+# server: a 32-slot engine at the 1.3B widths
 SLOTS, PAGE, MAX_LEN, SPEC_LEN = 32, 16, 1024, 4
 # normalised max error (max|got - ref| / max|ref|) allowed against an XLA
 # oracle in bf16 — the scale of tests/test_flash_attention.py's on-TPU test
@@ -285,9 +286,9 @@ def _requests(vocab: int):
 
 
 def _warm(fleet, vocab: int) -> None:
-    """Compile every executable the guarded loop can reach (the warm-up of
-    bench_serve.py): one prompt per bucket, a prefix pair for the COW copy
-    and the tail program, then the decode/swap programs."""
+    """Compile every executable the guarded loop can reach: one prompt per
+    bucket, a prefix pair for the COW copy and the tail program, then the
+    decode/swap programs."""
     eng = fleet.engines["engine0"]
     rng = np.random.RandomState(SEED + 1)
     max_prompt = MAX_LEN - 1

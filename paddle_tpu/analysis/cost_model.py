@@ -37,10 +37,7 @@ static (no profiler, no device counters):
   trip count) over nameplate device specs, against compulsory HBM traffic
   (every input read once + every non-aliased output written once — the
   perfect-fusion lower bound, which for decode is the classic weights-bound
-  roofline).  `bench_serve.py` emits `predicted_step_ms` next to the
-  measured step time with `model_error` = measured/predicted (tight on TPU
-  where the dispatch is device-bound; sanity-bounded only on the CPU smoke,
-  where host scheduling dominates).
+  roofline).  `tools/tpu_cost.py` prints it per executable.
 
 Budgets (per-executable peak-HBM, the replicated-bytes ceiling, per-
 executable collective bytes/step) are declared ONCE in
@@ -78,8 +75,8 @@ DEVICE_SPECS: Dict[str, DeviceSpec] = {
     "v5e": DeviceSpec("tpu-v5e", 197e12, 819e9, 45e9),
     "v5p": DeviceSpec("tpu-v5p", 459e12, 2765e9, 90e9),
     "v6e": DeviceSpec("tpu-v6e", 918e12, 1640e9, 90e9),
-    # host CPU fallback: order-of-magnitude numbers so the CPU smoke's
-    # model_error stays a sanity check, not a fit
+    # host CPU fallback: order-of-magnitude numbers so the CPU audit
+    # (`tools/tpu_cost.py`, the tests) has a column to print, not a fit
     "cpu": DeviceSpec("cpu", 1e11, 2e10, 1e10),
 }
 
@@ -932,8 +929,8 @@ def run_cost_checks(include_mp: bool = True, mp=(2, 4),
             "quantized_pool_ratio": round(pool_ratio, 3),
             "host_pool_bytes": host_bytes,
             "host_pool_bytes_int8": q_host_bytes,
-            # predicted_ms computed HERE through ProgramCost.predicted_ms so
-            # the CLI report and the bench JSON share one roofline formula
+            # predicted_ms computed HERE through ProgramCost.predicted_ms:
+            # one roofline formula for every reader of the report
             "programs": [dict(c.to_json(),
                               predicted_ms=round(c.predicted_ms(spec, mp=m),
                                                  4))

@@ -233,82 +233,6 @@ SERVE_SLO: Dict[str, object] = {
     # the fast 10s window degrades; timeouts at or above this rate mean the
     # engine is shedding load faster than it serves — overloaded.
     "timeout_rate_overloaded": 1.0,
-    # acceptable band for measured/predicted step time (the live roofline
-    # drift gauge).  Wide because it must hold on CPU-smoke hosts where
-    # dispatch overhead dominates; on TPU the ratio sits near 1 and a
-    # tighter operational band belongs in the deployment's alert config.
-    # Excursions count alert TRANSITIONS (roofline_drift_alerts counter),
-    # they do not fold into engine_health (a slow host is not an overload).
-    "roofline_drift_band": (0.02, 50.0),
-}
-
-# ---------------------------------------------------------------------------
-# serving-bench perf floors (consumed by tools/check_bench.py --ci)
-# ---------------------------------------------------------------------------
-
-# The serving-bench trajectory (`BENCH_SERVE.jsonl`, appended by
-# bench_serve.py / tools/check_bench.py) is CI-enforced the same way the
-# HBM/program budgets are: floors declared ONCE here, re-measured on a fresh
-# CPU-smoke bench run by `tools/check_bench.py --ci`.  Wall-clock numbers on
-# a shared CI box swing +-10%, so the floors bind the DETERMINISTIC side of
-# the bench (byte parity, dispatch counts, the stamp-count tracing account)
-# tightly and the wall-clock ratios loosely.
-SERVE_PERF_FLOORS: Dict[str, object] = {
-    "schema_version": 5,
-    # every parity flag a bench run reports must be True — byte-exact greedy
-    # parity is the one bar noise cannot excuse (kv_tier_parity: tier
-    # restores must be bit-exact vs the --no-kv-tier re-prefill;
-    # fleet_parity: routing a session stream across dp replicas must emit
-    # the same tokens as one engine serving it alone; disagg_parity: the
-    # prefill->store->decode handoff AND the engine-restart restore must
-    # both reproduce the colocated single-engine stream byte-for-byte)
-    "parity_flags": ("spec_parity", "oversubscribe_parity",
-                     "tracing_parity", "kv_tier_parity", "fleet_parity",
-                     "disagg_parity"),
-    # the one-dispatch claim in numbers: a fused busy step dispatches
-    # exactly ONE decode-side program — tied to the program budget above so
-    # the two guards cannot drift apart
-    "dispatches_per_step_max": float(
-        SERVE_PROGRAM_BUDGET["decode_side_executables"]),
-    # the always-on tracing plane's deterministic stamp-count x unit-cost
-    # account (bench `tracing_overhead_measured`) must stay under 2%
-    "tracing_overhead_max": 0.02,
-    # roofline sanity: model_error (measured/predicted step ms) must exist
-    # and be a positive finite ratio.  On TPU it is meaningful (~1-3); the
-    # CPU smoke is host-scheduling-bound so the ceiling only catches a
-    # broken prediction (zero, negative, or absurd), not slow hosts.
-    "model_error_max": 1.0e5,
-    # a bench run that emitted nothing has no trajectory row to contribute
-    "tokens_per_sec_min": 1.0,
-    # the KV-tier capacity claim, deterministic on any multi-turn row that
-    # ran the --no-kv-tier comparison: returning sessions must re-prefill
-    # at most half the tokens the drop-on-evict baseline pays (the measured
-    # CPU smoke sits ~0.7-0.85; token counts are scheduling-exact, so this
-    # floor is noise-free)
-    "returning_prefilled_drop_min": 0.5,
-    # the affinity-routing claim (dp fleet PR), deterministic on any
-    # `--replicas > 1` row: the returning-turn prefix-hit odds ratio
-    # (1 + affinity_hit) / (1 + round_robin_hit) on the identical session
-    # stream must be >= 1 — cache-aware routing never hits LESS than the
-    # cache-blind round-robin baseline (the measured CPU smoke sits ~1.45;
-    # hit rates are token-count-exact, so this floor is noise-free).  The
-    # TTFT side of the A/B is wall-clock and stays report-only.
-    "affinity_prefix_hit_ratio_min": 1.0,
-    # the disaggregation handoff ceiling (disagg rows): p99 wall latency of
-    # a prefill->store->decode handoff (prefill submit through decode index
-    # refresh).  Wall-clock on a shared CPU smoke, so the ceiling is set to
-    # catch only a collapse (a handoff path that re-prefills, blocks on a
-    # lock, or re-reads the whole store); measured CPU-smoke handoffs sit
-    # in the tens of ms.  disagg_parity carries the deterministic side.
-    "handoff_p99_ms_max": 5000.0,
-    # the vocab-sharded-head claim (schema v5, deterministic — leaf-shape
-    # arithmetic, no wall clock): on any mp >= 2 row the per-device
-    # replicated param bytes must sit STRICTLY below the fp `wte` size the
-    # row also reports — i.e. the embedding/head genuinely left the
-    # replicated column (a re-replicated head makes replicated >= wte
-    # by definition).  The JXP006 ratchet enforces the same invariant on
-    # the audit engines; this floor enforces it on every bench row.
-    "replicated_below_wte": True,
 }
 
 

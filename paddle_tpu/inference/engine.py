@@ -133,12 +133,8 @@ and Orca's iteration-level scheduling (Yu et al., OSDI 2022), under the same
   anomalies into `health()` / the `engine_health` gauge
   (ok/degraded/overloaded against `analysis.registry.SERVE_SLO`, served by
   the obs server's ``/healthz`` with 200/503 semantics, fleet-merged
-  worst-of); and the static roofline prediction goes live — `warm_decode()`
-  traces `engine_step_cost(...).predicted_ms` once (abstract, zero extra
-  dispatches or executables), steady-state step times feed an EWMA
-  `measured_step_ms` gauge, and `roofline_drift` (measured/predicted) plus
-  a drift-band alert counter and a `steady_state_recompiles` anomaly
-  counter surface silent perf regressions while they happen.
+  worst-of); a `steady_state_recompiles` anomaly counter surfaces a
+  decode-side recompile after warm-up while it happens.
 
 - **Oversubscribed admission** (vLLM preempt-then-swap-or-recompute, Kwon et
   al. §4.3, over the Sarathi chunked-prefill machinery) —
@@ -195,12 +191,6 @@ and Orca's iteration-level scheduling (Yu et al., OSDI 2022), under the same
   page COW-copies (or tier-scatters) the matched fraction and prefills only
   the true remainder.  Zero new executables: spill/restore reuse the two
   swap programs.
-
-`bench_serve.py` replays a Poisson request stream through this engine and
-reports decode tokens/s/chip, TTFT percentiles, prefix-cache hit rate,
-accepted tokens per verify step, compiled-program counts and — under
-`--oversubscribe F` — preemptions/step, the swap-vs-recompute split and
-goodput vs an unpressured replay.
 """
 from __future__ import annotations
 
@@ -230,11 +220,6 @@ from .health import HEALTH_CODES, evaluate_engine_health
 from .metrics import MetricsRegistry
 from .spec import DraftProposer, NgramProposer
 from .tracing import RequestTrace
-
-# measured-step EWMA smoothing: ~the last 10 busy steps dominate, so the
-# drift gauge reacts inside a scrape interval without tracking single-step
-# scheduler noise
-_EWMA_ALPHA = 0.2
 
 
 @dataclasses.dataclass(eq=False)
@@ -297,7 +282,8 @@ class RequestOutput:
     ttft_s: Optional[float] = None  # enqueue -> first generated token
     metrics: Optional[RequestMetrics] = None    # full lifecycle record
     trace: Optional[RequestTrace] = None        # structured event timeline
-                                                # (None with tracing off)
+                                                # (None once it ages out
+                                                # of `trace_retention`)
 
     @property
     def tokens(self) -> np.ndarray:
@@ -547,7 +533,7 @@ class LLMEngine:
     Observability: `engine.metrics` is the metrics registry (counters,
     page/queue gauges, latency histograms; `to_prometheus()` for scraping),
     `stats()` the flat dict benches consume, `step_trace()` the per-iteration
-    ring timeline (`trace_ring` entries), and `engine.trace(dir)` a capture
+    ring timeline (`TRACE_RING` entries), and `engine.trace(dir)` a capture
     window writing chrome-trace + timeline + metrics dumps.  `clock` injects
     the monotonic clock behind every lifecycle stamp (default
     `time.perf_counter`) so tests drive deterministic latencies.
@@ -577,8 +563,7 @@ class LLMEngine:
     admission restores a matched prefix from host (or the optional
     `spill_dir=` disk level) with one `swap_in_pages` scatter — a
     returning session skips its re-prefill entirely.  `kv_tier=False`
-    restores the PR-10 drop-on-evict behavior (`bench_serve.py
-    --no-kv-tier`).
+    drops evicted prefix pages instead.
 
     Quantized serving: `weight_dtype="int8"` PTQ-quantizes the serving
     matmul weights once at init (symmetric per-channel,
@@ -590,8 +575,7 @@ class LLMEngine:
     the fp engine is byte-identical to a quantization-free build; the
     quantized engine keeps every internal parity bar (mp/preempt)
     against itself, while outputs vs the fp engine are a top-1 agreement
-    RATE (quantization is lossy) reported by `bench_serve.py
-    --weight-dtype/--kv-dtype int8`.
+    RATE (quantization is lossy).
 
     `mp=N` (or an explicit `mesh` with an 'mp' axis) serves tensor-parallel
     over N chips: params are placed ONCE at init in the Megatron serving
@@ -614,6 +598,8 @@ class LLMEngine:
     # always True: benchmarks/drivers/serve.py:66 and serve_hybrid.py:115
     # read it in their default-mode check
     fused = True
+    # step-trace ring length: `step_trace()` keeps the last TRACE_RING steps
+    TRACE_RING = 512
 
     def __init__(self, params, config: gpt_mod.GPTConfig, *,
                  num_slots: int = 4, page_size: int = 16,
@@ -642,8 +628,6 @@ class LLMEngine:
                  mesh=None, mp: Optional[int] = None,
                  seed: int = 0,
                  clock: Optional[Callable[[], float]] = None,
-                 trace_ring: int = 512,
-                 request_tracing: bool = True,
                  trace_retention: Optional[int] = 4096):
         import jax.sharding as jsh
 
@@ -885,8 +869,6 @@ class LLMEngine:
 
         # ---- observability state (all host-side: no executable sees any of
         # this, so the compiled-program budget is untouched) ----------------
-        if trace_ring < 1:
-            raise ValueError(f"trace_ring must be >= 1, got {trace_ring}")
         if trace_retention is not None and trace_retention < 0:
             raise ValueError(f"trace_retention must be >= 0 or None "
                              f"(unbounded), got {trace_retention}")
@@ -1204,44 +1186,29 @@ class LLMEngine:
                         f"deadline-attainment burn over the trailing {_lbl} "
                         f"(1.0 = consuming the error budget exactly as fast "
                         f"as the SLO allows)", agg="max")
-        # live roofline drift: predicted_step_ms traced once at warmup
-        # (lazy — never from a scrape), measured EWMA fed by busy steps
-        self._predicted_ms: Optional[float] = None
-        self._measured_ewma_ms: Optional[float] = None
-        self._drift_violation = False
+        # decode-side executable count seen at the first busy step after
+        # warm-up (or a reset); growth past it is a steady-state recompile
         self._exec_baseline: Optional[int] = None
-        self._roofline_alerts = m.counter(
-            "roofline_drift_alerts",
-            "transitions of roofline_drift out of the declared band")
         self._ss_recompiles = m.counter(
             "steady_state_recompiles",
             "decode-side executable-count growth observed after warm")
-        m.gauge("measured_step_ms",
-                lambda: self._measured_ewma_ms or 0.0,
-                "EWMA wall time of busy engine steps (harvest to harvest)",
-                agg="max")
-        m.gauge("roofline_drift", self._roofline_drift,
-                "measured_step_ms / predicted_step_ms (0 until both exist)",
-                agg="max")
         m.gauge("engine_health", self._health_code,
                 "health state code: 0 ok, 1 degraded, 2 overloaded "
                 "(fleet merge folds worst-of, not sum)", agg="max")
         self._lifecycles: Dict[int, RequestMetrics] = {}
-        # per-request tracing (always-on observability plane; request_tracing
-        # =False strips both the timelines and the exemplar attachment — the
-        # bench's overhead A/B axis).  Live traces move to RequestOutput
-        # .trace at retirement, so /requests/<rid> keeps resolving after —
-        # for the last `trace_retention` retired requests: a long-running
-        # server retires millions, and timelines held forever on the
-        # RequestOutput ledger would grow host memory without bound, so the
-        # oldest retired trace is dropped (its output keeps its tokens) once
-        # the cap is passed.  trace_retention=None retains every timeline.
-        self._req_tracing = bool(request_tracing)
+        # per-request tracing (always-on observability plane).  Live traces
+        # move to RequestOutput.trace at retirement, so /requests/<rid> keeps
+        # resolving after — for the last `trace_retention` retired requests:
+        # a long-running server retires millions, and timelines held forever
+        # on the RequestOutput ledger would grow host memory without bound,
+        # so the oldest retired trace is dropped (its output keeps its
+        # tokens) once the cap is passed.  trace_retention=None retains
+        # every timeline.
         self._traces: Dict[int, RequestTrace] = {}
         self._trace_retention = trace_retention
         self._retired_traced: deque = deque()
         self._step_idx = 0
-        self._step_trace: deque = deque(maxlen=trace_ring)
+        self._step_trace: deque = deque(maxlen=self.TRACE_RING)
         self._tracing = False
         self._turn_t0: Optional[float] = None
         self._turn_span = _NULL_SPAN
@@ -1496,12 +1463,10 @@ class LLMEngine:
           traces and already-retired outputs survive, so exemplar handles
           attached AFTER the reset keep resolving;
         - the signal plane restarts with the counters it derives from: rate
-          windows clear their sample rings (`MetricsRegistry.reset`), the
-          measured-step EWMA and the steady-state recompile baseline
-          re-seed on the next busy step (warmup compiles stay excluded the
-          same way warmup counter traffic does).  The static
-          `predicted_step_ms` survives — it is a property of the engine's
-          shapes, not of any run.
+          windows clear their sample rings (`MetricsRegistry.reset`) and the
+          steady-state recompile baseline re-seeds on the next busy step
+          (warmup compiles stay excluded the same way warmup counter
+          traffic does).
 
         Spill/swap-out copies still in flight land first (the engine waits
         for them): their fetches belong to the traffic before the reset."""
@@ -1519,8 +1484,6 @@ class LLMEngine:
         getattr(self.proposer, "reset_stats", lambda: None)()
         self._step_idx = 0
         self._step_trace.clear()
-        self._measured_ewma_ms = None
-        self._drift_violation = False
         self._exec_baseline = None
         # seed every rate ring with (t_reset, 0): events between the reset
         # and the first step-end sample stay countable, and a young window
@@ -1580,13 +1543,12 @@ class LLMEngine:
         req = Request(prompt, max_new_tokens, rid, t, temperature,
                       priority, deadline)
         self._lifecycles[rid] = RequestMetrics(t_enqueue=t)
-        if self._req_tracing:
-            tr = RequestTrace(rid)
-            tr.event(t, "enqueue", prompt_len=int(prompt.size),
-                     max_new_tokens=int(max_new_tokens),
-                     priority=int(priority),
-                     deadline_s=deadline_s)
-            self._traces[rid] = tr
+        tr = RequestTrace(rid)
+        tr.event(t, "enqueue", prompt_len=int(prompt.size),
+                 max_new_tokens=int(max_new_tokens),
+                 priority=int(priority),
+                 deadline_s=deadline_s)
+        self._traces[rid] = tr
         need = self.cache.pages_needed(total)
         if need > self.cache.num_pages - 1:
             # fail fast: even alone on an empty pool this footprint cannot
@@ -1806,8 +1768,8 @@ class LLMEngine:
 
     # ---- per-request tracing ----------------------------------------------
     def _tev(self, rid: int, name: str, **attrs) -> None:
-        """Stamp one event on a request's timeline (no-op with tracing off or
-        for an unknown/finished rid).  Hot-path cost: one dict lookup, one
+        """Stamp one event on a request's timeline (no-op for an
+        unknown/finished rid).  Hot-path cost: one dict lookup, one
         clock read, one dict+list append — plain host data, inside whatever
         ENGINE_SPANS phase the caller already occupies (no new spans, no
         device access, no compiled-program change)."""
@@ -1815,21 +1777,17 @@ class LLMEngine:
         if tr is not None:
             tr.event(self._now(), name, **attrs)
 
-    def _exemplar(self, rid: int) -> Optional[Dict[str, str]]:
+    def _exemplar(self, rid: int) -> Dict[str, str]:
         """Exemplar labels binding a histogram observation to its request:
         the id plus the obs-server handle that resolves it
-        (``GET /requests/<rid>`` returns the chrome-trace span tree).  None
-        with request tracing off — the exposition then carries no exemplars,
-        matching the absent timelines."""
-        if not self._req_tracing:
-            return None
+        (``GET /requests/<rid>`` returns the chrome-trace span tree)."""
         return {"request_id": str(rid), "trace": f"/requests/{rid}"}
 
     def _trace_for(self, rid: int):
         """The request's timeline, live (`_traces`) or retired (riding its
         RequestOutput) — the single lookup behind `export_request_trace`
         and the debug bundle's per-request states; None when the id is
-        unknown or tracing is off."""
+        unknown or the timeline aged out."""
         tr = self._traces.get(rid)
         if tr is None:
             out = self._outputs.get(rid)
@@ -1839,8 +1797,8 @@ class LLMEngine:
     def export_request_trace(self, rid: int) -> Optional[Dict[str, object]]:
         """The chrome-trace span tree of one request's timeline (live or
         retired — retired traces ride their RequestOutput, retained for the
-        last `trace_retention` retirements), or None when the id is unknown,
-        tracing is off, or the timeline aged out.  Served by the obs server
+        last `trace_retention` retirements), or None when the id is unknown
+        or the timeline aged out.  Served by the obs server
         as ``GET /requests/<rid>``; the raw event list is
         `RequestOutput.trace.events`."""
         tr = self._trace_for(rid)
@@ -1956,9 +1914,7 @@ class LLMEngine:
         dur = self._now() - t0
         self._h_step.observe(dur)
         if self._step_dispatches:
-            # busy steps only: an idle/admission-only step measures the
-            # scheduler, not the serving step the roofline predicts
-            self._note_steady_state(dur)
+            self._note_recompiles()
         # one rate-window sample per step (throttled), FORCED on eventful
         # steps (retirements or preemptions) so the last event before the
         # engine goes idle is anchored at its true time — that is what
@@ -2020,7 +1976,7 @@ class LLMEngine:
         return finished
 
     def step_trace(self) -> List[Dict[str, object]]:
-        """The per-step timeline ring, oldest first (bounded at `trace_ring`
+        """The per-step timeline ring, oldest first (bounded at `TRACE_RING`
         records; cleared by `reset_counters()`)."""
         return list(self._step_trace)
 
@@ -3261,10 +3217,6 @@ class LLMEngine:
             self._h2d(np.ones((B,), np.int32)), self._key,
             self._h2d(np.zeros((B,), bool)), self._no_prev, self._host_rows)
         self._decode_used = True
-        # warmup is also where the live roofline arms: one abstract trace of
-        # the decode-side program (cached; zero dispatches, zero programs)
-        # so the drift gauge reads real from the first steady-state step
-        _ = self.predicted_step_ms
 
     def warm_swap(self) -> None:
         """Compile the swap gather/scatter against null-page ids (all
@@ -3337,12 +3289,6 @@ class LLMEngine:
         `kv_pages_swapped` + `kv_tier_pages_host`; this is the ceiling."""
         return self.swap_pool_pages * self._kv_page_bytes
 
-    def swap_pool_bytes(self) -> int:
-        """Legacy alias for `host_pool_bytes` (the PR-10 name, kept for
-        external consumers — the budget it maps to is now the unified
-        host-pool ceiling)."""
-        return self.host_pool_bytes()
-
     def kv_pool_bytes(self) -> int:
         """At-rest bytes of the device KV page pool (all lanes — the number
         the quantized-serving capacity math is about: int8 pools hold the
@@ -3350,64 +3296,11 @@ class LLMEngine:
         return int(sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
                        for a in self._pool.values()))
 
-    def at_rest_bytes(self) -> Dict[str, int]:
-        """Cached at-rest memory account for this engine's params, classified
-        by the serving layout (`analysis.cost_model.params_at_rest` over
-        `serving_param_specs` — the SAME account `tools/tpu_cost.py` audits
-        under JXP006): `{replicated_bytes_per_device, sharded_bytes_per_device,
-        wte_bytes}`.  Host-side arithmetic over leaf shapes — no trace, no
-        dispatch, no new executable — so bench rows report the sharded-head
-        memory win for free.  `wte_bytes` is the FP embedding-table size (the
-        pre-shard replicated ceiling this layout retired): at mp>1 the
-        per-device replicated remainder must sit strictly below it."""
-        if getattr(self, "_at_rest_bytes", None) is None:
-            from ..analysis.cost_model import AtRestAccount, params_at_rest
-            a = AtRestAccount(max(self.mp, 1),
-                              params_at_rest(self.params, self.config,
-                                             self.mp))
-            c = self.config
-            wte_bytes = int(c.vocab_size * c.hidden_size
-                            * np.dtype(c.dtype).itemsize)
-            self._at_rest_bytes = {
-                "replicated_bytes_per_device": int(a.param_bytes_replicated),
-                "sharded_bytes_per_device": int(a.param_bytes_sharded_per_device),
-                "wte_bytes": wte_bytes,
-            }
-        return dict(self._at_rest_bytes)
-
     # ---- health & perf signal plane ---------------------------------------
-    @property
-    def predicted_step_ms(self) -> float:
-        """Static roofline prediction for the decode-side program at this
-        engine's shapes (`analysis.cost_model.engine_step_cost` over the
-        nameplate `device_spec()`), traced abstractly ONCE and cached — no
-        dispatch, no new executable, program counts untouched.
-        `warm_decode()` takes the trace during warmup so the drift gauge is
-        live from the first steady-state step; reading the property earlier
-        pays the one-off trace right here."""
-        if self._predicted_ms is None:
-            from ..analysis.cost_model import device_spec, engine_step_cost
-            self._predicted_ms = engine_step_cost(self).predicted_ms(
-                device_spec(), mp=self.mp)
-        return self._predicted_ms
-
-    def _roofline_drift(self) -> float:
-        """measured_step_ms EWMA / predicted roofline ms — the live drift
-        gauge.  0.0 until BOTH exist (never triggers the trace itself: a
-        metrics scrape must stay a pure read)."""
-        if not self._predicted_ms or not self._measured_ewma_ms:
-            return 0.0
-        return self._measured_ewma_ms / self._predicted_ms
-
-    def _note_steady_state(self, dur_s: float) -> None:
-        """Per-busy-step bookkeeping of the live perf signals: the
-        measured-step EWMA, the drift-band alert counter (TRANSITIONS into
-        violation, not steps spent there) and the steady-state recompile
-        anomaly (decode-side executable count growing after the first busy
-        step fixed the baseline — a fixed-shape engine must never do that)."""
-        ms = dur_s * 1e3
-        self._measured_ewma_ms = ms if self._measured_ewma_ms is None else \
-            _EWMA_ALPHA * ms + (1.0 - _EWMA_ALPHA) * self._measured_ewma_ms
+    def _note_recompiles(self) -> None:
+        """Per-busy-step steady-state recompile account: the decode-side
+        executable count growing after the first busy step fixed the
+        baseline — a fixed-shape engine must never do that."""
         try:
             n = self._decode_fn._cache_size()
         except AttributeError:
@@ -3417,12 +3310,6 @@ class LLMEngine:
         elif n > self._exec_baseline:
             self._ss_recompiles.inc(n - self._exec_baseline)
             self._exec_baseline = n
-        drift = self._roofline_drift()
-        lo, hi = SERVE_SLO["roofline_drift_band"]
-        bad = bool(drift) and not (lo <= drift <= hi)
-        if bad and not self._drift_violation:
-            self._roofline_alerts.inc()
-        self._drift_violation = bad
 
     def _burn_rate(self, window_s: float) -> float:
         """Deadline-attainment burn over one window (`health.burn_rate`
@@ -3887,15 +3774,8 @@ class LLMEngine:
                 "reasons": health["reasons"],
                 "burn_rates": health["burn_rates"],
             },
-            # live roofline: the PR-8 static prediction next to the
-            # steady-state EWMA it is now compared against every step
-            "roofline": {
-                "predicted_step_ms": self._predicted_ms,    # None until armed
-                "measured_step_ms": self._measured_ewma_ms,
-                "drift": self._roofline_drift() or None,
-                "drift_alerts": self._roofline_alerts.value,
-                "steady_state_recompiles": self._ss_recompiles.value,
-            },
+            # decode-side executable growth after warm-up (health reads it)
+            "steady_state_recompiles": self._ss_recompiles.value,
             # latency distributions (engine-side histograms; seconds) — the
             # serving SLO surface: benches report p50/p99 straight from here
             "latency": {
@@ -3914,7 +3794,7 @@ class LLMEngine:
         (queued — including preempted/swapped resumes waiting at the head —
         prefilling, running) plus the last `finished_limit` retired ones,
         each with its scheduler coordinates, its trace timeline (empty
-        with tracing off) and, once decoding, its `emit_times` stamps.  Keys
+        once aged out) and, once decoding, its `emit_times` stamps.  Keys
         are request-id strings (JSON object keys)."""
         def base(req, state, **extra):
             tr = self._trace_for(req.request_id)
@@ -3990,7 +3870,6 @@ class LLMEngine:
                 "kv_tier": self.kv_tier, "spill_dir": self.spill_dir,
                 "mp": self.mp, "weight_dtype": self.weight_dtype,
                 "kv_dtype": self.kv_dtype,
-                "request_tracing": self._req_tracing,
                 "inflight": self._inflight is not None,
             },
             "pool": {
@@ -4014,9 +3893,8 @@ class LLMEngine:
     def dump_debug_bundle(self, dir_name: str,
                           finished_limit: int = 64) -> str:
         """Write `debug_bundle()` to ``<dir_name>/debug_bundle.json`` and
-        return the path — `bench_serve.py` calls this automatically on a
-        crash or a drain-invariant failure, and operators call it on demand
-        (or hit the obs server's ``/debug``) for a live snapshot."""
+        return the path — for a crash or a drain-invariant failure, or on
+        demand (the obs server's ``/debug`` serves the same bundle live)."""
         os.makedirs(dir_name, exist_ok=True)
         path = os.path.join(dir_name, "debug_bundle.json")
         with open(path, "w") as f:

@@ -258,8 +258,8 @@ class ObservabilityServer:
             status, payload = self.render_request(rid, engine)
             if status == "not_found":
                 return json_reply(
-                    {"error": f"unknown request {rid} (tracing off, "
-                              f"never submitted, or not retained)"}, 404)
+                    {"error": f"unknown request {rid} (never "
+                              f"submitted, or not retained)"}, 404)
             if status == "ambiguous":
                 return json_reply(
                     {"error": f"request id {rid} exists on "

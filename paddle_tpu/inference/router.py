@@ -36,10 +36,9 @@ cheapest to serve:
   the front door maps it to 503 + `Retry-After` so clients back off instead
   of deepening queues that already burn their SLO budget.
 
-`router="round_robin"` and `router="least_loaded"` are the A/B baselines
-(`bench_serve.py --replicas N --router ...`): round-robin is what a
-cache-blind balancer does, and the fleet bench measures exactly what that
-blindness costs in prefix-hit rate and returning-turn TTFT.
+`router="round_robin"` and `router="least_loaded"` are the A/B baselines:
+round-robin is what a cache-blind balancer does, the yardstick for what
+that blindness costs in prefix-hit rate and returning-turn TTFT.
 
 Replication must not multiply compiled programs: replicas 0..N-1 run the
 SAME model at the SAME shapes on the SAME mesh, so replica 0 compiles and

@@ -573,8 +573,8 @@ def _pp_loss(params, tokens, labels, config, cfg: MeshConfig, mesh):
         # stacked layer axis to [vpp, Ppp, Lc] and shard the Ppp axis.  The
         # reshape INTERLEAVES layers across the new dims, so the params' at-rest
         # (pp, ..., mp) sharding cannot be pushed through it — the partitioner
-        # used to fall back to involuntary full rematerialization (the [SPMD]
-        # warnings in MULTICHIP_r03.json).  Stage it explicitly instead:
+        # used to fall back to involuntary full rematerialization ([SPMD]
+        # warnings in the multichip dry run).  Stage it explicitly instead:
         # allgather to replicated, reshape, reslice onto pp — each transition
         # is one the partitioner lowers efficiently.  The mp allgather is not
         # extra work: the shard_map below consumes P(None, "pp") inputs, so

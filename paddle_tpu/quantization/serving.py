@@ -146,7 +146,7 @@ def kv_page_bytes(config, page_size: int,
                   kv_dtype: Optional[str] = None) -> int:
     """At-rest bytes ONE page pool page occupies across all layers (k + v,
     plus the per-token scale lanes when quantized) — the formula the engine's
-    `swap_pool_bytes`, the bench's equal-byte pool sizing and the
+    `host_pool_bytes`, the tests' equal-byte pool sizing and the
     `tpu_cost` accounts all agree on.  A patterned configuration says what
     its paged lanes hold (`HybridConfig.page_bytes`: K/V of its attention
     layers, the one latent lane of its latent layers)."""

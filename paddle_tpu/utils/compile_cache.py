@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
-Entry points that compile the big programs (`chip_smoke.py`, `bench.py`,
-`bench_serve.py`) call `enable_compile_cache()` once, before the first
+Entry points that compile the big programs (`chip_smoke.py`,
+`benchmarks/run.py`) call `enable_compile_cache()` once, before the first
 compile; nothing calls it at import time.  The directory is part of the
 cache key, so it is either the one the environment names or a fixed path
 inside the checkout — never a temp dir, a pid or a timestamp.

@@ -222,7 +222,7 @@ def ahead_parity_case(cfg, params, case, vocab, new=12):
         eng._decode_fn = launch
         st = eng.stats()
         assert st["decode_executables"] == n_exec == 1
-        assert st["roofline"]["steady_state_recompiles"] == 0
+        assert st["steady_state_recompiles"] == 0
         assert eng.cache.pages_in_use() == 0
         if not db:
             assert st["fused_launched_ahead"] == 0

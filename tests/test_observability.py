@@ -362,10 +362,10 @@ def test_trace_rides_outer_profiler(spec_eng, tmp_path):
     assert "engine.step" in snap_names and "outer.before" in snap_names
 
 
-def test_step_trace_ring_bounded(tiny):
+def test_step_trace_ring_bounded(tiny, monkeypatch):
     cfg, params = tiny
-    eng = LLMEngine(params, cfg, num_slots=1, page_size=8, max_model_len=64,
-                    trace_ring=4)
+    monkeypatch.setattr(LLMEngine, "TRACE_RING", 4)
+    eng = LLMEngine(params, cfg, num_slots=1, page_size=8, max_model_len=64)
     eng.add_request(np.arange(3, dtype=np.int32), max_new_tokens=10)
     eng.run()
     trace = eng.step_trace()
@@ -475,8 +475,8 @@ NEW_STATS_KEYS = frozenset({
     "slo",
 }) | frozenset({
     # added by the health & signals PR: windowed rates, the folded health
-    # state, and the live roofline account
-    "rates", "health", "roofline",
+    # state, and the steady-state recompile count
+    "rates", "health", "steady_state_recompiles",
 }) | frozenset({
     # added by the KV tiering PR: per-tier occupancy + spill/restore traffic
     # + the rolling-hash partial-index hit counter
@@ -634,24 +634,6 @@ def test_exemplar_roundtrip_exposition_to_request(tiny):
     assert " # {" in eng.metrics.to_prometheus(openmetrics=True)
     assert " # {" not in eng.metrics.to_prometheus(openmetrics=True,
                                                    exemplars=False)
-
-
-def test_request_tracing_off_strips_surface(tiny):
-    """request_tracing=False: no timelines, no /requests resolution, no
-    exemplars — but every histogram still observes (the A/B axis the bench's
-    <2% overhead bar runs on)."""
-    from tools.check_metrics import parse_prometheus_full
-    cfg, params = tiny
-    eng = LLMEngine(params, cfg, num_slots=2, page_size=8, max_model_len=64,
-                    request_tracing=False)
-    rid = eng.add_request(np.arange(6, dtype=np.int32), max_new_tokens=3)
-    out = eng.run()[rid]
-    assert out.trace is None
-    assert eng.export_request_trace(rid) is None
-    samples, exemplars = parse_prometheus_full(
-        eng.metrics.to_prometheus(exemplars=True))
-    assert not exemplars        # none to emit even when asked for
-    assert samples["llm_engine_ttft_seconds_count"][0][1] >= 1
 
 
 def test_trace_retention_bounds_retired_timelines(tiny):
@@ -1115,7 +1097,7 @@ def test_obs_server_fleet_mode(tiny):
 
 
 def test_debug_bundle_valid_after_forced_fault_crash(tiny, tmp_path):
-    """bench_serve's crash hook, reproduced at the engine API: a hard (non-
+    """A crash postmortem at the engine API: a hard (non-
     degradable) fault escapes step() mid-flight with rich scheduler state,
     and dump_debug_bundle still writes a valid, schema-complete JSON
     postmortem — request states with timelines, step ring, pool levels."""
@@ -1143,7 +1125,6 @@ def test_debug_bundle_valid_after_forced_fault_crash(tiny, tmp_path):
     with open(path) as f:
         bundle = json.load(f)
     assert REQUIRED_DEBUG_BUNDLE_KEYS <= set(bundle)
-    assert bundle["engine"]["request_tracing"] is True
     reqs = bundle["requests"]
     assert reqs, "no request states in the postmortem"
     states = {r["state"] for r in reqs.values()}
@@ -1157,7 +1138,7 @@ def test_debug_bundle_valid_after_forced_fault_crash(tiny, tmp_path):
 
 # ---------------------------------------------------------------------------
 # health & perf signal plane (ISSUE 13): windowed rates, burn-rate health,
-# live roofline drift, serving-bench trajectory
+# steady-state recompiles
 # ---------------------------------------------------------------------------
 
 def test_rate_window_golden_values():
@@ -1446,47 +1427,17 @@ def test_health_gauge_fleet_merge_worst_of():
     assert snap["engines"]["e0"]["gauges"]["engine_health"] == 1.0
 
 
-def test_roofline_drift_and_recompile_anomaly(tiny, monkeypatch):
-    """The live roofline: warm_decode arms predicted_step_ms once (cached,
-    zero dispatches), busy steps feed the measured EWMA and the drift
-    gauge; the alert counter counts band-excursion TRANSITIONS; the
-    steady-state recompile counter moves exactly on executable-count
-    growth after warm and degrades health; reset_counters re-seeds it all."""
-    from paddle_tpu.analysis.registry import SERVE_SLO
+def test_steady_state_recompile_anomaly(tiny, monkeypatch):
+    """The steady-state recompile counter stays 0 over a real run (fixed
+    shapes), moves exactly on executable-count growth after the baseline
+    step and degrades health; reset_counters re-seeds it."""
     cfg, params = tiny
     eng = LLMEngine(params, cfg, num_slots=2, page_size=8, max_model_len=64)
-    assert eng.stats()["roofline"]["predicted_step_ms"] is None
-    assert eng.metrics.snapshot()["gauges"]["roofline_drift"] == 0.0
-    eng.warm_decode()                       # arms the prediction
-    p = eng.stats()["roofline"]["predicted_step_ms"]
-    assert p is not None and p > 0
-    assert eng.predicted_step_ms == p       # cached: one trace ever
+    eng.warm_decode()
     eng.add_request(np.arange(6, dtype=np.int32), max_new_tokens=4)
     eng.run()
-    st = eng.stats()["roofline"]
-    assert st["measured_step_ms"] > 0       # real clock: busy steps fed it
-    assert st["drift"] == pytest.approx(st["measured_step_ms"] / p)
-    assert eng.metrics.snapshot()["gauges"]["roofline_drift"] == \
-        pytest.approx(st["drift"])
-    assert st["steady_state_recompiles"] == 0   # fixed shapes: never
-    # drift-band alerts count transitions, not steps spent out of band
-    # (establish a known in-band state first: on a CPU host the real run's
-    # drift may already sit outside the declared band)
-    monkeypatch.setitem(SERVE_SLO, "roofline_drift_band", (1e-9, 1e9))
-    eng._note_steady_state(0.001)
-    assert eng._drift_violation is False
-    alerts0 = eng._roofline_alerts.value
-    monkeypatch.setitem(SERVE_SLO, "roofline_drift_band", (1e-9, 1e-8))
-    eng._note_steady_state(0.001)           # excursion begins: +1
-    eng._note_steady_state(0.001)           # still out: no double count
-    assert eng._roofline_alerts.value == alerts0 + 1
-    monkeypatch.setitem(SERVE_SLO, "roofline_drift_band", (1e-9, 1e9))
-    eng._note_steady_state(0.001)           # back in band
-    monkeypatch.setitem(SERVE_SLO, "roofline_drift_band", (1e-9, 1e-8))
-    eng._note_steady_state(0.001)           # second excursion: +1
-    assert eng._roofline_alerts.value == alerts0 + 2
-    # steady-state recompile anomaly: decode-side cache growth after the
-    # baseline step is counted and degrades health
+    assert eng.stats()["steady_state_recompiles"] == 0  # fixed shapes: never
+
     class _Growing:
         n = 1
 
@@ -1496,91 +1447,20 @@ def test_roofline_drift_and_recompile_anomaly(tiny, monkeypatch):
     fake = _Growing()
     monkeypatch.setattr(eng, "_decode_fn", fake)
     eng._exec_baseline = None
-    eng._note_steady_state(0.001)           # baseline fixed at 1
+    eng._note_recompiles()                  # baseline fixed at 1
     assert eng._ss_recompiles.value == 0
     fake.n = 3
-    eng._note_steady_state(0.001)           # grew after warm: anomaly
+    eng._note_recompiles()                  # grew after warm: anomaly
     assert eng._ss_recompiles.value == 2
+    assert eng.stats()["steady_state_recompiles"] == 2
     h = eng.health()
     assert h["signals"]["recompiles"]["state"] == "degraded"
     assert h["state"] != "ok"
     assert any(r.startswith("recompiles") for r in h["reasons"])
-    # the reset contract: counters, EWMA and the baseline re-seed; the
-    # static prediction survives (a property of shapes, not of a run)
+    # the reset contract: the counter and the baseline re-seed
     eng.reset_counters()
-    st = eng.stats()["roofline"]
-    assert st["steady_state_recompiles"] == 0 and st["drift_alerts"] == 0
-    assert st["measured_step_ms"] is None and st["drift"] is None
-    assert st["predicted_step_ms"] == p
-
-
-def test_check_bench_tool(tiny, tmp_path):
-    """Satellite (CI wiring): the trajectory row projects from a real
-    run_serve_bench result and validates; SERVE_PERF_FLOORS (declared once
-    in the analysis registry) pass the real row and catch tampered parity /
-    dispatch / overhead / roofline values; append + read round-trips and
-    malformed history lines are named."""
-    import tools.check_bench as cb
-    from bench_serve import run_serve_bench
-    cfg, params = tiny
-    result = run_serve_bench(config=cfg, params=params, num_requests=6,
-                             num_slots=2, page_size=8, max_model_len=64,
-                             max_new_tokens=4, prefill_chunk=8, spec_len=2,
-                             debug_bundle_dir="")
-    row = cb.bench_row(result)
-    assert cb.validate_row(row) == []
-    assert cb.check_floors(row) == []           # the real row passes
-    assert row["mode"]["mp"] == 1
-    assert row["perf"]["dispatches_per_step"] <= 1.0
-    assert row["perf"]["model_error"] > 0
-    # floors catch each declared regression class
-    bad = json.loads(json.dumps(row))
-    bad["parity"]["spec_parity"] = False
-    assert any("spec_parity" in e for e in cb.check_floors(bad))
-    bad = json.loads(json.dumps(row))
-    bad["perf"]["dispatches_per_step"] = 2.0
-    assert any("dispatches_per_step" in e for e in cb.check_floors(bad))
-    bad = json.loads(json.dumps(row))
-    bad["perf"]["tracing_overhead_measured"] = 0.5
-    bad["perf"]["tracing_overhead"] = 0.5
-    assert any("tracing overhead" in e for e in cb.check_floors(bad))
-    bad["perf"]["tracing_overhead"] = None      # raw-run shape: only the
-    assert any("tracing overhead" in e         # measured account exists —
-               for e in cb.check_floors(bad))  # the bar must still bind
-    bad = json.loads(json.dumps(row))
-    bad["perf"]["model_error"] = None
-    assert any("model_error" in e for e in cb.check_floors(bad))
-    # schema-versioned append + read round-trip
-    hist = tmp_path / "BENCH_SERVE.jsonl"
-    cb.append_bench_row(result, path=str(hist))
-    cb.append_bench_row(result, path=str(hist))
-    rows, errors = cb.read_history(str(hist))
-    assert len(rows) == 2 and errors == []
-    assert rows[0][1]["schema_version"] == cb.ROW_SCHEMA_VERSION
-    with open(hist, "a") as f:
-        f.write("not json\n")
-    _, errors = cb.read_history(str(hist))
-    assert errors and "not JSON" in errors[0]
-    # a bench that cannot produce a valid row fails loudly
-    with pytest.raises(ValueError, match="trajectory row"):
-        cb.append_bench_row({"garbage": True}, path=str(hist))
-    # CLI: default mode schema-checks the history file
-    assert cb.main(["--history", str(hist)]) == 1       # the bad line
-    # a red run must not mutate the trajectory: the history pass runs
-    # BEFORE any append, so a rerun cannot stack duplicate rows
-    res_json = tmp_path / "res.json"
-    res_json.write_text(json.dumps(result))
-    size_before = hist.stat().st_size
-    assert cb.main(["--history", str(hist),
-                    "--from-json", str(res_json)]) == 1
-    assert hist.stat().st_size == size_before
-    hist2 = tmp_path / "clean.jsonl"
-    cb.append_bench_row(result, path=str(hist2))
-    assert cb.main(["--history", str(hist2)]) == 0
-    # a green --from-json run IS a trajectory point
-    assert cb.main(["--history", str(hist2),
-                    "--from-json", str(res_json)]) == 0
-    assert len(cb.read_history(str(hist2))[0]) == 2
+    assert eng.stats()["steady_state_recompiles"] == 0
+    assert eng._exec_baseline is None
 
 
 def test_check_metrics_tool(tmp_path):

@@ -508,17 +508,17 @@ def test_fault_plan_unit():
 
 @pytest.mark.parametrize("preempt", ["recompute", "swap"])
 def test_bench_oversubscribe_completes_with_parity(preempt):
-    from bench_serve import run_serve_bench
+    from serve_stream import run_stream
     kw = dict(num_requests=16, num_slots=4, page_size=8, max_model_len=64,
               max_new_tokens=12, prefill_chunk=8, seed=7, preempt=preempt)
-    pressured = run_serve_bench(oversubscribe=2.0, **kw)
-    base = run_serve_bench(oversubscribe=1.0, **kw)
-    # every request completed (run_serve_bench asserts the count and the
-    # drain invariants internally), pressure actually materialized, and the
+    pressured = run_stream(oversubscribe=2.0, **kw)
+    base = run_stream(oversubscribe=1.0, **kw)
+    # every request completed (run_stream asserts the count and the drain
+    # invariants internally), pressure actually materialized, and the
     # stream is byte-identical to the unpressured run
     assert pressured["preemptions"] > 0
     assert pressured["outputs_digest"] == base["outputs_digest"]
-    assert pressured["goodput_tokens_per_sec"] > 0
+    assert pressured["final_output_tokens"] > 0
     if preempt == "swap":
         assert pressured["preempt_swaps"] > 0
         assert pressured["swap_executables"] == 2

@@ -401,16 +401,16 @@ def test_engine_abort_frees_pages_immediately():
 # ---------------------------------------------------------------------------
 
 def test_bench_serve_shared_prefix_cpu_smoke():
-    """Acceptance bar: with --shared-prefix-frac 0.5 on the CPU-smoke config,
+    """Acceptance bar: with shared_prefix_frac=0.5 on the CPU-smoke config,
     hit rate > 0 and prefilled tokens DROP vs the no-cache baseline on the
     same workload, within <= 2 prefill executables (chunked) and <= 4
     compiled programs total."""
-    from bench_serve import run_serve_bench
+    from serve_stream import run_stream
     kw = dict(num_requests=10, num_slots=2, page_size=8, max_model_len=64,
               max_new_tokens=4, prefill_chunk=16, shared_prefix_frac=0.5,
               seed=11)
-    stats = run_serve_bench(**kw, prefix_cache=True)
-    base = run_serve_bench(**kw, prefix_cache=False)
+    stats = run_stream(**kw, prefix_cache=True)
+    base = run_stream(**kw, prefix_cache=False)
     assert stats["requests"] == 10
     assert stats["prefix_hit_rate"] > 0
     assert stats["prefix_cached_tokens"] > 0
@@ -420,4 +420,5 @@ def test_bench_serve_shared_prefix_cpu_smoke():
     assert stats["prefill_executables"] <= 2
     assert (stats["decode_executables"] + stats["prefill_executables"] +
             stats["copy_executables"]) <= 4
-    assert stats["ttft_p99_ms"] >= stats["ttft_p50_ms"] > 0
+    ttft = stats["latency"]["ttft_s"]       # in engine steps (step clock)
+    assert ttft["p99"] >= ttft["p50"] > 0

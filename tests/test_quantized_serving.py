@@ -218,7 +218,7 @@ def test_quantized_preempt_parity_and_no_leaks(params, cfg, prompts, preempt):
     if preempt == "swap":
         # int8 pages swap as int8: the host pool bound shrinks with the pool
         assert st["preempt_swaps"] > 0
-        assert eng.swap_pool_bytes() < \
+        assert eng.host_pool_bytes() < \
             (eng.cache.num_pages - 1) * kv_page_bytes(cfg, 8)
     # preempted-vs-undisturbed parity holds WITHIN the quantized mode: swap
     # restores bit-exact int8 pages + scales, recompute re-quantizes the
@@ -321,13 +321,13 @@ def test_cost_checks_quantized_clean():
 
 
 def test_bench_quantized_smoke():
-    from bench_serve import run_serve_bench
+    from serve_stream import run_stream
 
-    q = run_serve_bench(num_requests=6, num_slots=3, max_new_tokens=4,
-                        prefill_chunk=8, spec_len=2, weight_dtype="int8",
-                        kv_dtype="int8")
-    fp = run_serve_bench(num_requests=6, num_slots=3, max_new_tokens=4,
-                         prefill_chunk=8, spec_len=2)
+    q = run_stream(num_requests=6, num_slots=3, max_new_tokens=4,
+                   prefill_chunk=8, spec_len=2, weight_dtype="int8",
+                   kv_dtype="int8")
+    fp = run_stream(num_requests=6, num_slots=3, max_new_tokens=4,
+                    prefill_chunk=8, spec_len=2)
     assert q["kv_dtype"] == "int8" and q["weight_dtype"] == "int8"
     assert q["kv_pool_bytes"] * 2 <= fp["kv_pool_bytes"]
     agree = sum(int(a == b) for qa, fa in zip(q["output_tokens"],

@@ -389,22 +389,22 @@ def test_greedy_fast_path_in_sampling_engine(tiny):
 # ---------------------------------------------------------------------------
 
 def test_bench_serve_spec_cpu_smoke():
-    """Acceptance bar: --spec-len 4 on a repetitive/shared-prefix CPU-smoke
+    """Acceptance bar: spec_len=4 on a repetitive/shared-prefix CPU-smoke
     stream shows accepted_per_step > 1.2 and EXACT greedy token parity with
-    --no-spec (byte-identical output digests), within <= 2 decode-side
+    spec_len=0 (byte-identical output digests), within <= 2 decode-side
     compiled programs."""
-    from bench_serve import run_serve_bench
+    from serve_stream import run_stream
     kw = dict(num_requests=12, num_slots=2, page_size=8, max_model_len=64,
               max_new_tokens=6, prefill_chunk=16, shared_prefix_frac=0.5,
               seed=11)
-    spec = run_serve_bench(**kw, spec_len=4)
-    base = run_serve_bench(**kw, spec_len=0)
+    spec = run_stream(**kw, spec_len=4)
+    base = run_stream(**kw, spec_len=0)
     assert spec["outputs_digest"] == base["outputs_digest"]     # exact parity
     assert spec["accepted_per_step"] > 1.2
     assert spec["decode_executables"] + spec["verify_executables"] <= 2
     assert base["verify_steps"] == 0 and base["accepted_per_step"] == 0.0
     # spec needs fewer decode iterations for the same emitted tokens
-    assert spec["decode_iters"] < base["decode_iters"]
+    assert spec["decode_iterations"] < base["decode_iterations"]
 
 
 def test_check_program_count_tool():

@@ -1,8 +1,7 @@
 """tpu_cost static resource accounting: golden hand-computed byte/flop
 counts on toy programs, mp sharded-vs-replicated at-rest math, donation-
 aware liveness, collective accounting cross-checked against the jaxpr,
-JXP006/JXP007/JXP008 budget enforcement, CLI exit codes, and the bench's
-roofline fields (ref: the reference's memory-optimize / inference-analysis
+JXP006/JXP007/JXP008 budget enforcement and CLI exit codes (ref: the reference's memory-optimize / inference-analysis
 passes over the graph)."""
 import json
 import os
@@ -377,9 +376,9 @@ def test_engine_step_cost_traces_without_dispatch():
 
 
 def test_engine_step_cost_mp2_carries_collectives():
-    """At mp>1 the bench hook compiles (with the engine's real shardings)
-    so its roofline carries the same ICI term tpu_cost reports — the bench
-    JSON and the CLI cannot disagree about the fused step.  The compile
+    """At mp>1 engine_step_cost compiles (with the engine's real
+    shardings) so its roofline carries the same ICI term tpu_cost reports.
+    The compile
     goes through lower(), outside the AOT dispatch cache, so the measured
     program counts stay exact."""
     if len(jax.devices()) < 2:
@@ -401,24 +400,8 @@ def dataclasses_replace_collectives(c):
 
 
 # ---------------------------------------------------------------------------
-# bench integration + CLI exit codes
+# prefill_chunk="auto" + CLI exit codes
 # ---------------------------------------------------------------------------
-
-def test_bench_reports_roofline_fields():
-    """bench_serve emits predicted_step_ms next to the measured step time;
-    on the CPU smoke the model is sanity-bounded, not tight."""
-    from bench_serve import run_serve_bench
-    st = run_serve_bench(num_requests=6, num_slots=2, page_size=8,
-                         max_model_len=64, max_new_tokens=4,
-                         prefill_chunk="auto", spec_len=2, seed=5)
-    assert st["predicted_step_ms"] > 0
-    assert st["measured_step_ms"] > 0
-    assert st["model_error"] is not None and st["model_error"] > 0
-    assert np.isfinite(st["model_error"])
-    assert st["device_spec"]
-    # "auto" resolved by the engine to the spec lane's width
-    assert st["prefill_chunk"] == 3
-
 
 def test_auto_prefill_chunk_resolution_and_parity():
     """prefill_chunk='auto' picks spec_len+1 (one page when spec is off), so

@@ -31,8 +31,8 @@ or make a "counter" go backwards:
   span tree, /debug is valid JSON with the bundle schema;
 - **health & signals schema** — `stats()` carries the windowed-rate block
   (every family over every window), a folded `health` state from the known
-  set with burn rates, and the complete `roofline` account; the exposition
-  carries the rate/burn/health/roofline gauge families; `/healthz` serves
+  set with burn rates, and the steady-state recompile count; the exposition
+  carries the rate/burn/health gauge families; `/healthz` serves
   the REAL health evaluation (structured state + per-signal detail, 200 for
   ok/degraded, 503 for overloaded — never the old hardcoded stub); and the
   `engine_health` gauge fleet-merges WORST-OF (max), not sum;
@@ -98,8 +98,8 @@ REQUIRED_STATS_KEYS = frozenset({
     # + per-priority-class goodput) the router's SLO layer consumes
     "slo",
     # health & signals PR (ISSUE 13): windowed rates, the folded health
-    # state, and the live roofline (predicted/measured/drift/anomalies)
-    "rates", "health", "roofline",
+    # state, and the steady-state recompile count
+    "rates", "health", "steady_state_recompiles",
     # KV tiering PR (ISSUE 15): per-tier occupancy + spill/restore traffic
     # + the rolling-hash partial-index hit counter
     "kv_tier",
@@ -162,10 +162,6 @@ RATE_FAMILIES = ("tokens_per_sec", "admits_per_sec", "preemptions_per_sec",
                  "timeouts_per_sec", "rejects_per_sec")
 RATE_WINDOW_LABELS = ("10s", "1m", "5m")
 REQUIRED_HEALTH_KEYS = frozenset({"state", "code", "reasons", "burn_rates"})
-REQUIRED_ROOFLINE_KEYS = frozenset({
-    "predicted_step_ms", "measured_step_ms", "drift", "drift_alerts",
-    "steady_state_recompiles",
-})
 HEALTH_STATES = ("ok", "degraded", "overloaded")
 REQUIRED_LATENCY_KEYS = frozenset(
     {"queue_s", "ttft_s", "tpot_s", "e2e_s", "step_s"})
@@ -179,7 +175,7 @@ REQUIRED_COUNTERS = frozenset({
     "swap_ms", "recomputed_tokens", "timeouts", "rejected_requests",
     "intake_swap_rejects", "deadline_requests", "deadline_met",
     # health & signals PR: admission-rate numerator + anomaly counters
-    "admitted_requests", "roofline_drift_alerts", "steady_state_recompiles",
+    "admitted_requests", "steady_state_recompiles",
     # KV tiering PR: spill/restore traffic + rolling-hash partial hits
     "kv_tier_spills", "kv_tier_restores", "kv_tier_restored_tokens",
     "partial_page_hits",
@@ -229,10 +225,9 @@ REQUIRED_GAUGES = frozenset({
     "queued", "prefilling", "running", "kv_pages_in_use", "kv_pages_free",
     "kv_pages_evictable", "prefix_cached_pages", "kv_pages_swapped",
     "kv_pool_pressure", "kv_pool_bytes",
-    # health & signals PR: the folded health code (worst-of fleet merge),
-    # the live roofline pair, and the SLO burn-rate pair
-    "engine_health", "measured_step_ms", "roofline_drift",
-    "slo_burn_rate_1m", "slo_burn_rate_5m",
+    # health & signals PR: the folded health code (worst-of fleet merge)
+    # and the SLO burn-rate pair
+    "engine_health", "slo_burn_rate_1m", "slo_burn_rate_5m",
     # KV tiering PR: per-tier-level occupancy
     "kv_tier_pages_host", "kv_tier_pages_disk",
     # spill-fetch PR (ISSUE 31): gathered pages whose bytes are in flight
@@ -853,7 +848,7 @@ def main() -> int:
         if slo_missing:
             errors.append(f"stats()['slo'] missing: {sorted(slo_missing)}")
         # health & signals PR: the rates block carries every family over
-        # every window, health folds to a known state, roofline is complete
+        # every window, health folds to a known state
         rates = st["rates"]
         miss = set(RATE_FAMILIES) - set(rates)
         if miss:
@@ -868,9 +863,6 @@ def main() -> int:
             errors.append(f"stats()['health'] missing: {sorted(hmiss)}")
         elif st["health"]["state"] not in HEALTH_STATES:
             errors.append(f"unknown health state {st['health']['state']!r}")
-        rmiss = REQUIRED_ROOFLINE_KEYS - set(st["roofline"])
-        if rmiss:
-            errors.append(f"stats()['roofline'] missing: {sorted(rmiss)}")
         tmiss = REQUIRED_KV_TIER_KEYS - set(st["kv_tier"])
         if tmiss:
             errors.append(f"stats()['kv_tier'] missing: {sorted(tmiss)}")

@@ -42,9 +42,9 @@ objects literally shared (leader adoption, same mesh) — and the pass
 asserts at least one handoff actually crossed the store, so a silent
 degrade to colocated serving cannot fake compliance.
 
-Runs the bench_serve CPU smoke (chunked prefill + prefix cache + speculative
-decoding — every lane the scheduler can dispatch) and exits non-zero with a
-diff against the budget on violation.
+Serves the tests' deterministic stream (`tests/serve_stream.py`: chunked
+prefill + prefix cache + speculative decoding — every lane the scheduler can
+dispatch) and exits non-zero with a diff against the budget on violation.
 
 The budget itself is DECLARED in `paddle_tpu/analysis/registry.py` (the
 central program registry) — this script re-measures the live counts against
@@ -74,12 +74,11 @@ from paddle_tpu.analysis.registry import (  # noqa: E402
 
 
 def measure(mp=1):
-    from bench_serve import run_serve_bench
-    stats = run_serve_bench(num_requests=12, num_slots=2, page_size=8,
-                            max_model_len=64, max_new_tokens=6,
-                            prefill_chunk=16, prefix_cache=True,
-                            shared_prefix_frac=0.5, spec_len=4, seed=11,
-                            mp=mp)
+    from tests.serve_stream import run_stream
+    stats = run_stream(num_requests=12, num_slots=2, page_size=8,
+                       max_model_len=64, max_new_tokens=6,
+                       prefill_chunk=16, prefix_cache=True,
+                       shared_prefix_frac=0.5, spec_len=4, seed=11, mp=mp)
     got = {
         "decode_side_executables": stats["decode_executables"] +
                                    stats["verify_executables"],

@@ -19,8 +19,7 @@ four accounts are read off them (`paddle_tpu/analysis/cost_model.py`):
   undeclared or over-budget collective bytes/step; mp1 programs must be
   collective-free.
 - **roofline**: analytic flops + compulsory HBM traffic over nameplate
-  device specs -> a predicted step time per executable (`bench_serve.py`
-  emits the same model's `predicted_step_ms` next to measured time).
+  device specs -> a predicted step time per executable.
 
 Budgets are declared ONCE in `paddle_tpu/analysis/registry.py::
 SERVE_RESOURCE_BUDGET`, next to the program-count budget — one declaration,

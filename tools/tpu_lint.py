@@ -43,7 +43,7 @@ if "--xla_force_host_platform_device_count" not in \
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                " --xla_force_host_platform_device_count=8")
 
-CI_PATHS = ["paddle_tpu", "tools", "bench.py", "bench_serve.py"]
+CI_PATHS = ["paddle_tpu", "tools"]
 
 
 def main() -> int:
